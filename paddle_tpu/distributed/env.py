@@ -47,11 +47,7 @@ def init_parallel_env():
     if coord and nprocs > 1:
         port = os.environ.get("MASTER_PORT", coord.split(":")[-1]
                               if ":" in coord else "8476")
-        try:
-            already = jax.distributed.is_initialized()
-        except AttributeError:   # older jax
-            already = False
-        if not already:
+        if not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=f"{coord.split(':')[0]}:{port}",
                 num_processes=nprocs, process_id=pid)

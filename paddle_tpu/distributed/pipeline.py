@@ -26,8 +26,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ..core.jax_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["pipeline_spmd", "make_pipeline_train_step",

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from ..core import enforce as E
-from ..core import jax_compat as _jax_compat  # noqa: F401  (jax.export shim)
 
 __all__ = ["Config", "Predictor", "create_predictor", "Tensor",
            "PrecisionType", "PlaceType", "get_version",

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import monitor as _monitor
-from ..core import jax_compat as _jax_compat  # noqa: F401  (jax.export shim)
 from ..core import enforce as E
 from ..core import state
 from ..core.dtype import convert_dtype

@@ -22,15 +22,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams as _CompilerParams
-
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +126,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -263,7 +257,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -297,7 +291,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -358,7 +352,7 @@ _flash.defvjp(lambda q, k, v, *a: _flash_fwd(q, k, v, *a),
 
 def flash_attention(q, k, v, *, causal=False, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=None):
+                    interpret=False):
     """Flash attention on [B, S, H, D] (paddle layout); supports GQA
     (fewer kv heads) and causal masking. Differentiable (custom VJP,
     flash backward). Sequence lengths must divide the block sizes —
@@ -366,8 +360,6 @@ def flash_attention(q, k, v, *, causal=False, scale=None,
     otherwise."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
     bq = min(block_q, q.shape[1])
     bk = min(block_k, k.shape[1])
     return _flash(q, k, v, float(scale), bool(causal), bq, bk, interpret)
@@ -685,7 +677,7 @@ def _seg_fwd(q, k, v, segq, segk, posq, posk, stats, stride, nh, *, scale,
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(stats, q, k, v, segq, segk, posq, posk)
@@ -723,7 +715,7 @@ def _seg_bwd(res, g, *, scale, causal, block_q, block_k, interpret):
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(stats, q, k, v, do, lse, delta, segq, segk, posq, posk)
@@ -758,7 +750,7 @@ def _seg_bwd(res, g, *, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(stats, q, k, v, do, lse, delta, segq, segk, posq, posk)
@@ -814,7 +806,7 @@ _flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 def flash_attention_segments(q, k, v, seg_q, seg_k, pos_q, pos_k, *,
                              causal=False, scale=None,
                              block_q=DEFAULT_BLOCK_Q,
-                             block_k=DEFAULT_BLOCK_K, interpret=None):
+                             block_k=DEFAULT_BLOCK_K, interpret=False):
     """Segment-masked flash attention on [B, S, H, D] packed rows.
 
     ``seg_q``/``seg_k`` [B, S] int32 tag each token with its document
@@ -828,8 +820,6 @@ def flash_attention_segments(q, k, v, seg_q, seg_k, pos_q, pos_k, *,
     position extrema (see ``count_skipped_blocks`` for the predicate)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
     bq = min(block_q, q.shape[1])
     bk = min(block_k, k.shape[1])
     return _flash_seg(q, k, v, jnp.asarray(seg_q, jnp.int32),

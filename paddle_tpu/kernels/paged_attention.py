@@ -45,10 +45,6 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _sublane(dtype) -> int:
     return 16 if jnp.dtype(dtype).itemsize == 2 else 8
 
@@ -57,12 +53,21 @@ def _sublane(dtype) -> int:
 # kernel
 # ---------------------------------------------------------------------------
 
+def _page_scale(s_ref, pi):
+    """This page's scale, as a [1, 1] tile, out of the sequence's
+    [1, max_pages] row of per-page scales: a masked lane reduction
+    (Mosaic has no dynamic lane index)."""
+    row = s_ref[0, 0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == pi, row, 0.0), axis=1, keepdims=True)
+
+
 def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
                    quant):
     if quant:
-        # int8 pages ride with per-(page, kv-head) scale scalars (SMEM,
-        # same block-table index map): dequant is a scalar multiply
-        # FOLDED into the dots — the page DMA itself stays int8
+        # int8 pages ride with this (sequence, kv-head)'s row of per-page
+        # scales: dequant is one multiply FOLDED into the dots — the page
+        # DMA itself stays int8
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc, m_s, l_s = refs
     else:
         q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s = refs
@@ -90,7 +95,7 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
                 q.astype(jnp.float32), k.astype(jnp.float32),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) \
-                * (ks_ref[0, 0] * scale)                 # [gp, ps]
+                * (_page_scale(ks_ref, pi) * scale)      # [gp, ps]
         else:
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -111,7 +116,8 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
             acc[:] = acc[:] * alpha + jax.lax.dot_general(
                 p, v_ref[0, 0].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * vs_ref[0, 0]
+                preferred_element_type=jnp.float32) \
+                * _page_scale(vs_ref, pi)
         else:
             acc[:] = acc[:] * alpha + jax.lax.dot_general(
                 p.astype(v_ref.dtype), v_ref[0, 0],
@@ -128,7 +134,7 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            scale=None, k_scales=None, v_scales=None,
-                           interpret=None):
+                           interpret=False):
     """Paged decode attention. q: [B, num_heads, head_dim]; k_pages /
     v_pages: [num_pages, kv_heads, page_size, head_dim]; block_tables:
     [B, max_pages] page ids (entries past a sequence's pages may hold
@@ -136,11 +142,11 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     positions per sequence (0 = empty slot -> zero output row).
 
     With ``k_scales``/``v_scales`` ([num_pages, kv_heads] f32, both or
-    neither) the pages are int8 codes (FLAGS_serving_kv_quant): each
-    (page, kv-head) scale rides the SAME block-table index map as its
-    page, lands in SMEM as a (1, 1) scalar block, and dequantization
-    folds into the two dots — HBM page traffic stays int8.
-    Returns [B, num_heads, head_dim]."""
+    neither) the pages are int8 codes (FLAGS_serving_kv_quant): the
+    block table gathers each sequence's scales into a [max_pages] row
+    per kv head (a tiny XLA gather), the row is fetched once per
+    (sequence, kv head), and dequantization folds into the two dots —
+    HBM page traffic stays int8. Returns [B, num_heads, head_dim]."""
     quant = k_scales is not None
     B, nh, hd = q.shape
     P, kv, ps, _ = k_pages.shape
@@ -150,8 +156,6 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     gp = max(sub, (g + sub - 1) // sub * sub)
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
-    if interpret is None:
-        interpret = _interpret_default()
 
     qg = q.reshape(B, kv, g, hd)
     if gp != g:
@@ -163,9 +167,6 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     def _page_map(b, h, p, bt_, ln_, mp=maxp):
         return (bt_[b * mp + p], h, 0, 0)
 
-    def _scale_map(b, h, p, bt_, ln_, mp=maxp):
-        return (bt_[b * mp + p], h)
-
     in_specs = [
         pl.BlockSpec((1, 1, gp, hd),
                      lambda b, h, p, bt_, ln_: (b, h, 0, 0)),
@@ -174,12 +175,18 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     ]
     operands = [qg, k_pages, v_pages]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), _scale_map, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), _scale_map, memory_space=pltpu.SMEM),
-        ]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        # Mosaic refuses a (1, 1) block of the [P, kv] scale plane (the
+        # last two block dims must divide (8, 128) or equal the array's),
+        # so the plane is gathered per sequence here: [B, kv, 1, maxp],
+        # whose (1, 1, 1, maxp) block is legal by the "equal" arm
+        def rows(scales):
+            per_seq = scales.astype(jnp.float32)[bt.reshape(B, maxp)]
+            return jnp.swapaxes(per_seq, 1, 2)[:, :, None, :]
+
+        row_spec = pl.BlockSpec((1, 1, 1, maxp),
+                                lambda b, h, p, bt_, ln_: (b, h, 0, 0))
+        in_specs += [row_spec, row_spec]
+        operands += [rows(k_scales), rows(v_scales)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -21,8 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ..core.jax_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["ring_attention"]

@@ -26,16 +26,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams as _CompilerParams
-
 DEFAULT_BLOCK_ROWS = 256
 # rows*cols budget per block: ~6 live (br, d) f32 buffers double-buffered
 # must fit the ~16MB scoped-vmem limit (v5e OOMs at br=256, d=4096)
 _MAX_BLOCK_ELEMS = 128 * 1024
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block_rows(block_rows, n, d):
@@ -74,7 +68,7 @@ def _rows(x):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def rms_norm(x, w, eps=1e-6, block_rows=DEFAULT_BLOCK_ROWS, interpret=None):
+def rms_norm(x, w, eps=1e-6, block_rows=DEFAULT_BLOCK_ROWS, interpret=False):
     y, _ = _rms_fwd(x, w, eps, block_rows, interpret)
     return y
 
@@ -102,8 +96,6 @@ def _call_fwd(x2, w, eps, br, interpret):
 
 
 def _rms_fwd(x, w, eps, block_rows, interpret):
-    if interpret is None:
-        interpret = _interpret_default()
     x2 = _rows(x)
     n, d = x2.shape
     br = _pick_block_rows(block_rows, n, d)
@@ -148,7 +140,7 @@ def _rms_bwd(eps, block_rows, _interp_unused, res, dy):
             jax.ShapeDtypeStruct((n, d), x.dtype),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2, w.reshape(1, d), rstd, dy2)

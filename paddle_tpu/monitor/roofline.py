@@ -17,10 +17,10 @@ is communication*.
 
 Peak tables mirror ``monitor/mfu.py``'s resolution order: env override
 (``PADDLE_TPU_PEAK_HBM_GBS`` / ``PADDLE_TPU_PEAK_ICI_GBS`` — the
-CPU-smoke escape hatch) → per-TPU-generation table → v5p for unknown
-TPUs → a nominal host figure. Interconnect numbers are *modeling*
-figures (per-chip aggregate ICI), not wire-protocol guarantees; the
-point is a consistent denominator, not a datasheet.
+CPU-test escape hatch) → ``device_kind`` → per-TPU-generation table (an
+unknown TPU kind raises) → a nominal host figure. Interconnect numbers
+are *modeling* figures (per-chip aggregate ICI), not wire-protocol
+guarantees; the point is a consistent denominator, not a datasheet.
 
 All verdicts are honest about missing inputs: a program whose backend
 reported no FLOPs or bytes (``monitor.cost_analysis.unavailable``)
@@ -79,10 +79,10 @@ def _resolve_bw(env_name: str, table: dict, nominal: float,
     """Bandwidth adapter over the ONE shared resolver
     (``monitor/mfu.py::resolve_peak`` — the FLOPs and bandwidth
     denominators must never match different generations for the same
-    device): env (GB/s) -> generation table (GB/s) -> v5p for unknown
-    TPUs -> nominal (bytes/s). Returns ``{"bytes_per_sec", "source",
-    "generation"}`` so consumers (the smoke stage) can assert a real
-    table hit vs a fallback."""
+    device): env (GB/s) -> ``device_kind`` -> generation table (GB/s;
+    unknown TPU kinds raise) -> nominal (bytes/s). Returns
+    ``{"bytes_per_sec", "source", "generation"}`` so consumers
+    (chip_smoke.py) can assert a real table hit."""
     from . import mfu as _mfu
 
     r = _mfu.resolve_peak(env_name, table, nominal, device, scale=1e9)
@@ -113,11 +113,8 @@ def resolve_peaks(device=None) -> dict:
     from . import mfu as _mfu
 
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0]
-        except Exception:
-            device = None
+        import jax
+        device = jax.devices()[0]
     hbm = _resolve_bw("PADDLE_TPU_PEAK_HBM_GBS", PEAK_HBM_GBS_TABLE,
                       _CPU_NOMINAL_HBM, device)
     ici = _resolve_bw("PADDLE_TPU_PEAK_ICI_GBS", PEAK_ICI_GBS_TABLE,

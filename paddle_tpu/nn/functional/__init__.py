@@ -80,7 +80,7 @@ def sdp_kernel(enable_math=True, enable_flash=True,
         yield
     finally:
         # restore whatever was installed on entry verbatim — a
-        # tpu_only=False registration (interpret-mode tests) or a
+        # interpret=True registration (interpret-mode tests) or a
         # deliberately-unregistered state must survive the scope
         if not enable_flash:
             _att.register_flash_impl(prev)

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import jax_compat as _jax_compat  # noqa: F401  (jax.export shim)
 from ..core.dtype import convert_dtype
 from ..core.tensor import Parameter, Tensor
 from ..jit.api import InputSpec  # noqa  (paddle.static.InputSpec)

@@ -147,7 +147,7 @@ class TestHloScan:
 
 class TestCollectiveByteAudit:
     def test_trace_and_eager_paths_agree_and_count_once(self, mon):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from paddle_tpu.distributed import collective as coll
         from paddle_tpu.distributed import comm_ops
@@ -179,7 +179,7 @@ class TestCollectiveByteAudit:
         assert deltas() == (1, 16, 1, 16)
 
     def test_monitor_internal_retrace_is_suppressed(self, mon):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from paddle_tpu.distributed import comm_ops
 
@@ -210,7 +210,7 @@ class TestCollectiveByteAudit:
         assert h["comm.latency.barrier_ms"]["count"] == 1
 
     def test_off_path_registers_nothing(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from paddle_tpu.distributed import collective as coll
         from paddle_tpu.distributed import comm_ops
@@ -362,26 +362,44 @@ class TestRoofline:
         assert peaks["ici_source"] == "env"
 
     def test_generation_table(self):
-        class FakeDev:
-            device_kind = "TPU v5p"
+        # the chip on record reports "TPU v5 lite": no generation name is
+        # a substring of that, so the entry is explicit
+        class V5e:
+            device_kind = "TPU v5 lite"
             platform = "tpu"
 
         hbm = roofline._resolve_bw("PADDLE_TPU_PEAK_HBM_GBS",
                                    roofline.PEAK_HBM_GBS_TABLE,
-                                   1.0, FakeDev())
+                                   1.0, V5e())
         assert hbm["source"] == "table"
-        assert hbm["generation"] == "v5p"
-        assert hbm["bytes_per_sec"] == pytest.approx(2765e9)
+        assert hbm["generation"] == "v5e"
+        assert hbm["bytes_per_sec"] == pytest.approx(819e9)
         # ONE shared resolver: the FLOPs denominator must match the
         # same generation for the same device
         fl = mfu_mod.resolve_peak("PADDLE_TPU_PEAK_FLOPS",
-                                  mfu_mod.PEAK_FLOPS_TABLE, 1.0,
-                                  FakeDev())
+                                  mfu_mod.PEAK_FLOPS_TABLE, 1.0, V5e())
         assert fl["generation"] == hbm["generation"]
-        assert fl["value"] == mfu_mod.PEAK_FLOPS_TABLE["v5p"]
-        peaks = roofline.resolve_peaks(FakeDev())
-        assert peaks["flops_source"] == "table"
-        assert peaks["flops_generation"] == "v5p"
+        assert fl["value"] == 197e12
+        peaks = roofline.resolve_peaks(V5e())
+        assert peaks["peak_flops_per_sec"] == 197e12
+        assert peaks["peak_hbm_bytes_per_sec"] == pytest.approx(819e9)
+        assert (peaks["flops_source"], peaks["hbm_source"],
+                peaks["flops_generation"]) == ("table", "table", "v5e")
+
+    def test_unknown_tpu_kind_raises(self):
+        # never another part's peaks: a TPU missing from the table is an
+        # error; only a non-TPU device gets the nominal figure
+        class Unknown:
+            device_kind = "TPU v9 mega"
+            platform = "tpu"
+
+        with pytest.raises(KeyError, match="TPU v9 mega"):
+            mfu_mod.peak_flops(Unknown())
+        with pytest.raises(KeyError, match="TPU v9 mega"):
+            roofline.resolve_peaks(Unknown())
+        assert mfu_mod.resolve_peak(
+            "PADDLE_TPU_PEAK_FLOPS", mfu_mod.PEAK_FLOPS_TABLE, 1e12,
+            jax.devices()[0])["source"] == "nominal"
 
     def test_snapshot_attribution_and_gauges(self, mon):
         f, x = _sharded_program()

@@ -201,7 +201,7 @@ class TestCommOps:
 
     def test_psum_all_gather_reduce_scatter(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
         data = np.arange(32, dtype=np.float32).reshape(8, 4)
 
@@ -225,7 +225,7 @@ class TestCommOps:
 
     def test_ppermute_ring(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
         data = np.arange(8, dtype=np.float32).reshape(8, 1)
         perm = [(i, (i + 1) % 8) for i in range(8)]
@@ -242,7 +242,7 @@ class TestCommOps:
 
     def test_broadcast_axis(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
         data = np.arange(8, dtype=np.float32).reshape(8, 1)
 
@@ -258,7 +258,7 @@ class TestCommOps:
 
     def test_all_to_all(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
         data = np.arange(64, dtype=np.float32).reshape(8, 8)
 

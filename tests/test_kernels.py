@@ -66,7 +66,7 @@ class TestFlashAttention:
         from paddle_tpu.nn.functional import attention as att
         q = rand((1, 64, 2, 32))
         try:
-            kernels.register()
+            kernels.register(interpret=True)
             assert att._FLASH_IMPL is not None
             out = F.scaled_dot_product_attention(
                 paddle.to_tensor(np.asarray(q)),
@@ -146,7 +146,7 @@ class TestDispatchGuards:
         w2d = paddle.to_tensor(np.ones((1, 128), "float32"))
         ref = F.rms_norm(x, w2d).numpy()
         try:
-            kernels.register()
+            kernels.register(interpret=True)
             out = F.rms_norm(x, w2d).numpy()
         finally:
             kernels.unregister()
@@ -160,7 +160,7 @@ class TestDispatchGuards:
         w = paddle.to_tensor(np.ones((128,), "float32"))
         ref = F.rms_norm(x, w)
         try:
-            kernels.register()
+            kernels.register(interpret=True)
             out = F.rms_norm(x, w)
         finally:
             kernels.unregister()
@@ -172,7 +172,7 @@ class TestDispatchGuards:
         from paddle_tpu import kernels
         from paddle_tpu.nn.functional import attention as att
         try:
-            kernels.register(tpu_only=True)
+            kernels.register()
             assert att._FLASH_IMPL is not None
             # off-TPU it must route to the XLA reference path
             q = rand((1, 64, 2, 32))

@@ -9,7 +9,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -55,13 +54,6 @@ class TestLaunchCLI:
         assert "MP_OK rank=0" in logs and "MP_OK rank=1" in logs, \
             logs[-6000:]
 
-    @pytest.mark.skipif(
-        jax.__version__.startswith("0.4."),
-        reason="environment limit: jax 0.4.x CPU backend has no "
-               "multi-process compiled collectives (broadcast_one_to_all "
-               "in device_put raises 'Multiprocess computations aren't "
-               "implemented on the CPU backend'); needs jax >= 0.5 or a "
-               "real accelerator")
     @pytest.mark.parametrize("nprocs", [2, 4])
     def test_cross_process_compiled_collective_training(self, tmp_path,
                                                         nprocs):

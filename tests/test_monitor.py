@@ -367,7 +367,6 @@ class TestAutotuneInstrumentation:
         import jax.numpy as jnp
 
         from paddle_tpu.kernels import autotune as at
-        at._FAILED_KEYS.clear()   # other test modules share the process
         cache = at.AutotuneCache(str(tmp_path / "c.json"))
         at.flash_blocks((2, 1024, 4, 128), (2, 1024, 2, 128),
                         jnp.bfloat16, True,

@@ -555,7 +555,7 @@ class TestVarlenAttention:
 class TestSdpKernelRestore:
     """ADVICE-r4: sdp_kernel(enable_flash=False) must restore the exact
     dispatcher installed on entry, not clobber it with a fresh
-    tpu_only=True registration."""
+    default registration."""
 
     def test_restores_prior_impl_verbatim(self):
         import paddle_tpu.nn.functional as F

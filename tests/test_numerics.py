@@ -298,18 +298,23 @@ class TestOffFlagPins:
 
     def test_guarded_update_math_unchanged_by_numerics(self):
         """The numerics block is pure observation: params/opt/loss of
-        the numerics step equal the plain guarded step's exactly."""
+        the numerics step equal the plain guarded step's. They are two
+        compiled programs, and XLA promises no bit equality between
+        those (the extra outputs can re-associate a gradient's token
+        reduction), so every leaf agrees to a few ulp of its own largest
+        magnitude — the error scale of a re-ordered sum."""
         cfg, params, opt = _llama()
         a = L.make_train_step(cfg, guard=True, donate=False)
         b = L.make_train_step(cfg, guard=True, numerics=True,
                               donate=False)
         pa, oa, la, _ = a(params, opt, _batch(0), INF_CAP)
         pb, ob, lb, _ = b(params, opt, _batch(0), INF_CAP)
-        assert float(la) == float(lb)
-        for x, y in zip(jax.tree.leaves(pa), jax.tree.leaves(pb)):
-            assert np.array_equal(np.asarray(x), np.asarray(y))
-        for x, y in zip(jax.tree.leaves(oa), jax.tree.leaves(ob)):
-            assert np.array_equal(np.asarray(x), np.asarray(y))
+        eps = 8 * np.finfo(np.float32).eps
+        np.testing.assert_allclose(float(la), float(lb), rtol=eps)
+        for x, y in zip(jax.tree.leaves((pa, oa)), jax.tree.leaves((pb, ob))):
+            x, y = np.asarray(x), np.asarray(y)
+            np.testing.assert_allclose(
+                x, y, rtol=0, atol=eps * float(np.max(np.abs(x))))
 
 
 # ---------------------------------------------------------------------------

@@ -807,7 +807,7 @@ class TestBenchRegressionGuard:
         root = str(tmp_path)
         self._write(root, 1, _bench_blob(1000.0))
         self._write(root, 2, _bench_blob(
-            0.0, error="tpu tunnel relay dead"))
+            0.0, error="backend init failed"))
         self._write(root, 3, _bench_blob(990.0))
         ok, lines = guard.check(root)
         assert ok, "\n".join(lines)    # r02 must not read as a 0 floor
