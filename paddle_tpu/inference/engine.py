@@ -46,6 +46,22 @@ bucket-interpolated p50/p90/p95/p99 in their snapshots. The same
 milestones land in the ``monitor.trace`` ring as lifecycle events, so
 a flight record shows which requests were in flight at a crash.
 
+Spans (always on; ``monitor.trace.span``): every phase of ``step()`` —
+expire, retire, compact, admit with each group's prefill, the page
+reservation, the decode or verify chunk — and inside prefill and chunk
+the host's work apart from its waiting (``.build``, ``.dispatch``,
+``.fetch``, ``.emit``; a program's first call under
+``serving.compile``) is a span under the prefix ``serving.`` (the tree
+is in ``ServingEngine.step``'s docstring). In the ring they ride the
+monitor flag like everything above; as ``jax.profiler`` annotations
+they are in ANY open profiler session (``/profile``, a benchmark's, a
+user's ``start_trace``) on the device trace's clock, so a device idle
+gap reads as what the host was doing. With no session a span is a
+no-op of about a microsecond, ten to fifteen a step. Add one only at a
+boundary between layers or between host work and waiting, never inside
+a loop over slots. The device programs are named to match:
+``jit_decode_chunk``, ``jit_spec_verify``, ``jit__pf``.
+
 Token accounting contract (pinned by tests/test_trace.py):
 ``serving.tokens.generated`` counts every SAMPLED token (prefill's
 first token + decode emissions — work done, including work later
@@ -102,12 +118,12 @@ expired / shed, with a typed reason — nothing is dropped silently.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
 import weakref
 from collections import deque
-from functools import partial
 from typing import Dict, List, Optional
 
 import jax
@@ -116,7 +132,6 @@ import numpy as np
 
 from .. import monitor as _monitor
 from ..core import enforce as E
-from ..monitor import profile_capture as _pcap
 from ..monitor import server as _mserver
 from ..monitor import trace as _trace
 from ..monitor import slo as _slo
@@ -125,6 +140,8 @@ from ..monitor.registry import LATENCY_BUCKETS_MS as _LATENCY_BUCKETS_MS
 from .paged import (PagedKVCache, PrefixCache, paged_decode_step,
                     paged_prefill, paged_prefill_shared,
                     paged_verify_window)
+
+_NO_SPAN = contextlib.nullcontext()     # what _first_call gives after the first
 
 
 def _engine_health_provider(ref):
@@ -352,6 +369,7 @@ class EngineStats:
                 "spec_accepted": self.spec_accepted}
 
 
+@jax.named_scope("head")
 def _sample_rows(logits, temps, keys, sampled=True):
     """Vectorised per-slot sampling: greedy rows where temperature is 0,
     else categorical on the tempered logits with that slot's own key —
@@ -505,11 +523,20 @@ class ServingEngine:
         # happen mid-chunk), quartering per-chunk host+dispatch overhead
         # through the long middle of large generations
         self.turbo_chunk = self.decode_chunk * 4
+        def chunk_fn(c, s):
+            # a def, not a functools.partial: the function's name is the
+            # program's (XLA module jit_decode_chunk) in every trace
+            def decode_chunk(*args):
+                return _decode_chunk(family, config, c, s, *args)
+            return jax.jit(decode_chunk, donate_argnums=(1, 2))
+
         self._chunk_fns = {
-            (c, s): jax.jit(partial(_decode_chunk, family, config, c, s),
-                            donate_argnums=(1, 2))
+            (c, s): chunk_fn(c, s)
             for c in (self.decode_chunk, self.turbo_chunk)
             for s in (False, True)}
+        # programs already called once: the first call of each compiles
+        # (or loads), and runs under a serving.compile span
+        self._called: set = set()
         # KV-page absmax sampling (monitor/numerics.py): 1-in-N decode
         # chunks dispatch a tiny per-layer per-page |K|/|V| max over
         # the pool AFTER the chunk's emitted-grid download has already
@@ -1176,16 +1203,29 @@ class ServingEngine:
         if fn is None:
             family, config = self.family, self.config
 
-            def _vf(params, pool_k, pool_v, bt, drafts, kv_len, live):
+            def spec_verify(params, pool_k, pool_v, bt, drafts, kv_len,
+                            live):
                 pk, pv, logits = paged_verify_window(
                     family, params, drafts, config, pool_k, pool_v,
                     bt, kv_len, live)
                 return pk, pv, jnp.argmax(
                     logits, axis=-1).astype(jnp.int32)
 
-            fn = jax.jit(_vf, donate_argnums=(1, 2))
+            fn = jax.jit(spec_verify, donate_argnums=(1, 2))
             self._spec_fns[C] = fn
         return fn
+
+    def _first_call(self, fn):
+        """A ``serving.compile`` span for the first call of a jitted
+        serving program (it compiles, or loads from the cache), a null
+        context after: a compile in the middle of serving is a named gap
+        in a trace, not a slow step. The call itself stays inline at its
+        site: a Python frame between the scheduler and a jitted call is
+        not free while the program is traced (PERF.md section 6, PR 24)."""
+        if id(fn) in self._called:
+            return _NO_SPAN
+        self._called.add(id(fn))
+        return _trace.span("serving.compile")
 
     def _free_slack(self) -> int:
         """Free pages the admission watermark may count: the free list
@@ -1723,134 +1763,139 @@ class ServingEngine:
                     wait_ms=round(wait_ms, 3)
                     if wait_ms is not None else None,
                     pfx_cached=int(getattr(r, "_pfx_cached", 0)))
-        g = 1
-        while g < len(group):
-            g *= 2
-        # with the prefix cache on, every member of this group shares
-        # the same cached page-aligned prefix length (admission grouped
-        # by it): the program prefills only the uncached tail, reading
-        # the shared context pages without ever writing them
-        cached = int(getattr(group[0], "_pfx_cached", 0)) \
-            if self._prefix is not None else 0
-        ncp = cached // self.page_size
-        s_eff = s_pad - cached
-        need_eff = need - ncp
-        ids = np.zeros((g, s_eff), np.int32)
-        rows = np.full((g, need_eff), self.cache.num_pages, np.int32)
-        ctx_rows = np.full((g, ncp), self.cache.num_pages, np.int32)
-        slen = np.ones(g, np.int32)
-        temps = np.zeros(g, np.float32)
-        keys = np.zeros((g, 2), np.uint32)
-        slots = []
-        for j, r in enumerate(group):
-            plen = int(np.asarray(r.prompt).shape[0])
-            ids[j, :plen - cached] = np.asarray(r.prompt,
-                                                np.int32)[cached:]
-            brow = self.cache.alloc.block_row(r.rid, need)
-            ctx_rows[j] = brow[:ncp]
-            rows[j] = brow[ncp:]
-            slen[j] = plen - cached
-            temps[j] = r.temperature
-            slot = _Slot(r, self._keys_for(r))
-            slot.kv_len = plen
-            slot.preemptions = getattr(r, "_preempt_count", 0)
-            keys[j] = slot.keys[0]
-            slots.append(slot)
-        sampled = any(r.temperature > 0 for r in group)
-        pf = self._prefill_shared_fn(g, s_eff, ncp, sampled) if cached \
-            else self._prefill_fn(g, s_pad, sampled)
-        pf_args = (self.params, jnp.asarray(ids), self.cache.pool["k"],
-                   self.cache.pool["v"])
-        pf_kwargs = dict(page_rows=jnp.asarray(rows),
-                         slen=jnp.asarray(slen), temp=jnp.asarray(temps),
-                         key=jnp.asarray(keys))
-        if cached:
-            pf_kwargs["ctx_rows"] = jnp.asarray(ctx_rows)
-        exec_rec = None
-        pf_flops_share = None
-        if mon:
-            # introspection-registry record, BEFORE the dispatch that
-            # donates the pool buffers (once per specialization)
-            key = self._record_serving_program(
-                ("serving.prefill_shared", g, s_eff, ncp, sampled)
-                if cached else ("serving.prefill", g, s_pad, sampled),
-                f"serving.prefill_shared[g{g},s{s_eff},ctx{ncp}]"
-                if cached else f"serving.prefill[g{g},s{s_pad}]",
-                pf, pf_args, pf_kwargs, donated=(2, 3))
-            from ..monitor import exectime as _exectime
-            exec_rec = _exectime.maybe_sample(key, feed_last=False)
-            # modeled-FLOPs attribution: the registered program's
-            # cost-analysis count split across the real requests that
-            # shared this dispatch (dummy pad rows attribute nowhere)
-            pf_flops = self._program_flops(key)
-            if pf_flops:
-                pf_flops_share = pf_flops / len(group)
         with _trace.span("serving.prefill", group=len(group),
-                         s_pad=s_pad), \
-                _pcap.annotate("serving.prefill"):
-            pk, pv, tok_a = pf(*pf_args, **pf_kwargs)
-            self.cache.pool = {"k": pk, "v": pv}
-            # the np.asarray download syncs the device — the span ends
-            # (and TTFT is stamped) when the first token actually EXISTS
-            # on the host, not when the dispatch returned
-            toks = np.asarray(tok_a)
-        if exec_rec is not None:
-            # the download above already synchronized: rec(None) adds
-            # ZERO extra block_until_ready calls at this seam
-            exec_rec(None)
-        t_first = None
-        if mon:
-            # TTFT is NOT observed here: a preemption would discard
-            # this run's tokens and re-prefill, double-sampling the
-            # histogram with a first token the client never saw. The
-            # slot carries t_first to _retire, which observes once per
-            # completed request. The lifecycle instant still marks
-            # every prefill (preempted runs included) in the trace.
-            t_first = time.perf_counter()
-            for r in group:
-                _trace.instant("serving.first_token", rid=r.rid)
-                # pure host bookkeeping AFTER the np.asarray download
-                # above already synchronized: zero added device syncs
-                _forensics.note(r.rid, "first_token", t=t_first)
-        for j, (r, slot) in enumerate(zip(group, slots)):
-            self.cache.alloc.advance(r.rid, int(slen[j]) + cached)
-            tok = int(toks[j])
-            slot.tokens.append(tok)
-            slot.pending = tok
-            slot.gen = 1
-            slot.t_first = slot.t_last = t_first
+                         s_pad=s_pad):
+            with _trace.span("serving.prefill.build"):
+                g = 1
+                while g < len(group):
+                    g *= 2
+                # with the prefix cache on, every member of this group shares
+                # the same cached page-aligned prefix length (admission grouped
+                # by it): the program prefills only the uncached tail, reading
+                # the shared context pages without ever writing them
+                cached = int(getattr(group[0], "_pfx_cached", 0)) \
+                    if self._prefix is not None else 0
+                ncp = cached // self.page_size
+                s_eff = s_pad - cached
+                need_eff = need - ncp
+                ids = np.zeros((g, s_eff), np.int32)
+                rows = np.full((g, need_eff), self.cache.num_pages, np.int32)
+                ctx_rows = np.full((g, ncp), self.cache.num_pages, np.int32)
+                slen = np.ones(g, np.int32)
+                temps = np.zeros(g, np.float32)
+                keys = np.zeros((g, 2), np.uint32)
+                slots = []
+                for j, r in enumerate(group):
+                    plen = int(np.asarray(r.prompt).shape[0])
+                    ids[j, :plen - cached] = np.asarray(r.prompt,
+                                                        np.int32)[cached:]
+                    brow = self.cache.alloc.block_row(r.rid, need)
+                    ctx_rows[j] = brow[:ncp]
+                    rows[j] = brow[ncp:]
+                    slen[j] = plen - cached
+                    temps[j] = r.temperature
+                    slot = _Slot(r, self._keys_for(r))
+                    slot.kv_len = plen
+                    slot.preemptions = getattr(r, "_preempt_count", 0)
+                    keys[j] = slot.keys[0]
+                    slots.append(slot)
+                sampled = any(r.temperature > 0 for r in group)
+                pf = self._prefill_shared_fn(g, s_eff, ncp, sampled) \
+                    if cached else self._prefill_fn(g, s_pad, sampled)
+                pf_args = (self.params, jnp.asarray(ids), self.cache.pool["k"],
+                           self.cache.pool["v"])
+                pf_kwargs = dict(page_rows=jnp.asarray(rows),
+                                 slen=jnp.asarray(slen),
+                                 temp=jnp.asarray(temps),
+                                 key=jnp.asarray(keys))
+                if cached:
+                    pf_kwargs["ctx_rows"] = jnp.asarray(ctx_rows)
+            exec_rec = None
+            pf_flops_share = None
             if mon:
-                slot.cost = getattr(r, "_cost", None)
-                # page-seconds integrate from admission (pages were
-                # allocated in _admit) at chunk-edge resolution
-                slot.t_tick = t_admit
-                slot.steps0 = self.stats.decode_steps
-                if slot.cost is not None:
-                    slot.cost.prefill_tokens += int(slen[j])
-                    if cached:
-                        slot.cost.prefix_cached_tokens += cached
-                        if pf_flops_share:
-                            # modeled: the tail program's per-padded-
-                            # token cost scaled by the tokens the cache
-                            # served — what a full prefill would have
-                            # added, to first order
-                            slot.cost.prefill_flops_saved += (
-                                pf_flops_share / s_eff * cached)
-                    if pf_flops_share:
-                        slot.cost.model_flops += pf_flops_share
-            slot.done = (tok == r.eos_token_id
-                         if r.eos_token_id is not None else False) \
-                or slot.gen >= r.max_new_tokens
-            self.slots[free[j]] = slot
-            self.stats.admitted += 1
-            self.stats.tokens_generated += 1
-            self.stats.tokens_prefilled += int(slen[j])
-            _monitor.inc("serving.requests.admitted")
-            # the prefill-sampled first token counts here so the counter
-            # agrees with stats.tokens_generated
-            _monitor.inc("serving.tokens.generated")
-            _monitor.inc("serving.tokens.prefilled", int(slen[j]))
-        self._state_dirty = self._bt_dirty = True
+                # introspection-registry record, BEFORE the dispatch that
+                # donates the pool buffers (once per specialization)
+                key = self._record_serving_program(
+                    ("serving.prefill_shared", g, s_eff, ncp, sampled)
+                    if cached else ("serving.prefill", g, s_pad, sampled),
+                    f"serving.prefill_shared[g{g},s{s_eff},ctx{ncp}]"
+                    if cached else f"serving.prefill[g{g},s{s_pad}]",
+                    pf, pf_args, pf_kwargs, donated=(2, 3))
+                from ..monitor import exectime as _exectime
+                exec_rec = _exectime.maybe_sample(key, feed_last=False)
+                # modeled-FLOPs attribution: the registered program's
+                # cost-analysis count split across the real requests that
+                # shared this dispatch (dummy pad rows attribute nowhere)
+                pf_flops = self._program_flops(key)
+                if pf_flops:
+                    pf_flops_share = pf_flops / len(group)
+            with _trace.span("serving.prefill.dispatch"), \
+                    self._first_call(pf):
+                pk, pv, tok_a = pf(*pf_args, **pf_kwargs)
+            self.cache.pool = {"k": pk, "v": pv}
+            with _trace.span("serving.prefill.fetch"):
+                # the np.asarray download syncs the device — the span ends
+                # (and TTFT is stamped) when the first token actually EXISTS
+                # on the host, not when the dispatch returned
+                toks = np.asarray(tok_a)
+            if exec_rec is not None:
+                # the download above already synchronized: rec(None) adds
+                # ZERO extra block_until_ready calls at this seam
+                exec_rec(None)
+            t_first = None
+            if mon:
+                # TTFT is NOT observed here: a preemption would discard
+                # this run's tokens and re-prefill, double-sampling the
+                # histogram with a first token the client never saw. The
+                # slot carries t_first to _retire, which observes once per
+                # completed request. The lifecycle instant still marks
+                # every prefill (preempted runs included) in the trace.
+                t_first = time.perf_counter()
+                for r in group:
+                    _trace.instant("serving.first_token", rid=r.rid)
+                    # pure host bookkeeping AFTER the np.asarray download
+                    # above already synchronized: zero added device syncs
+                    _forensics.note(r.rid, "first_token", t=t_first)
+            with _trace.span("serving.prefill.emit"):
+                for j, (r, slot) in enumerate(zip(group, slots)):
+                    self.cache.alloc.advance(r.rid, int(slen[j]) + cached)
+                    tok = int(toks[j])
+                    slot.tokens.append(tok)
+                    slot.pending = tok
+                    slot.gen = 1
+                    slot.t_first = slot.t_last = t_first
+                    if mon:
+                        slot.cost = getattr(r, "_cost", None)
+                        # page-seconds integrate from admission (pages were
+                        # allocated in _admit) at chunk-edge resolution
+                        slot.t_tick = t_admit
+                        slot.steps0 = self.stats.decode_steps
+                        if slot.cost is not None:
+                            slot.cost.prefill_tokens += int(slen[j])
+                            if cached:
+                                slot.cost.prefix_cached_tokens += cached
+                                if pf_flops_share:
+                                    # modeled: the tail program's per-padded-
+                                    # token cost scaled by the tokens the cache
+                                    # served — what a full prefill would have
+                                    # added, to first order
+                                    slot.cost.prefill_flops_saved += (
+                                        pf_flops_share / s_eff * cached)
+                            if pf_flops_share:
+                                slot.cost.model_flops += pf_flops_share
+                    slot.done = (tok == r.eos_token_id
+                                 if r.eos_token_id is not None else False) \
+                        or slot.gen >= r.max_new_tokens
+                    self.slots[free[j]] = slot
+                    self.stats.admitted += 1
+                    self.stats.tokens_generated += 1
+                    self.stats.tokens_prefilled += int(slen[j])
+                    _monitor.inc("serving.requests.admitted")
+                    # the prefill-sampled first token counts here so the
+                    # counter agrees with stats.tokens_generated
+                    _monitor.inc("serving.tokens.generated")
+                    _monitor.inc("serving.tokens.prefilled", int(slen[j]))
+                self._state_dirty = self._bt_dirty = True
 
     def _pick_chunk(self, live_idx: List[int]) -> int:
         """Turbo chunk when no retire/join/EOS could land mid-chunk:
@@ -1899,95 +1944,133 @@ class ServingEngine:
     def step(self) -> bool:
         """One scheduling iteration: expire (when any request carries a
         deadline) -> retire -> compact -> admit -> one decode chunk.
-        Returns False when the engine is fully idle."""
-        if self._deadlines_seen:
-            self._expire_due()
-        for idx in range(self.num_slots):
-            if self.slots[idx] is not None and self.slots[idx].done:
-                self._retire(idx)
-        self._compact()
-        self._admit()
-        _monitor.set_gauge("serving.queue.depth", len(self.queue),
-                           doc="requests waiting for admission")
-        in_use = self.cache.alloc.used_pages
-        self.stats.peak_pages_in_use = max(self.stats.peak_pages_in_use,
-                                           in_use)
-        _monitor.set_gauge("serving.pages.in_use", in_use,
-                           doc="KV pages currently allocated")
+        Returns False when the engine is fully idle.
 
-        live_idx = [i for i, s in enumerate(self.slots)
-                    if s is not None and not s.done]
-        if _monitor.enabled():
-            # autoscale feed (monitor/slo.py): one host tick per
-            # scheduling step — queue depth, live slots, page slack.
-            # The gauges themselves are recomputed at scrape time.
-            _slo.note_sched_tick(
-                len(self.queue), len(live_idx), self.num_slots,
-                self.cache.alloc.free_pages / self.cache.num_pages
-                if self.cache.num_pages else 0.0)
-        if self._frame_pub is not None:
-            # federation frame on the same host tick (rate-limited
-            # inside; pure host state — zero device syncs)
-            self._frame_pub.maybe_publish(self)
-        if not live_idx:
-            return bool(self.queue) or any(
-                s is not None for s in self.slots)
-        C = self._pick_chunk(live_idx)
-        live_idx = self._ensure_chunk_capacity(live_idx, C)
-        if not live_idx:
-            return True
-        if (self._spec_decode and C == self.turbo_chunk
-                and not any(self.slots[i].req.temperature > 0
-                            for i in live_idx)):
-            # greedy turbo chunk: verify a self-drafted window in ONE
-            # model pass instead of C sequential decode steps. The
-            # turbo preconditions (full grid, no EOS, remaining run
-            # covers the chunk) already hold, so accept/reject lands at
-            # the same chunk boundary the sequential path downloads at.
-            return self._spec_step(live_idx, C)
+        The span tree of a step (``monitor.trace.span``: in the ring
+        when the monitor is on, and in any open ``jax.profiler`` session
+        on the device trace's clock), one span a boundary between
+        phases, or between host work and waiting for the device::
 
-        B = self.num_slots
-        if self._state_dirty:
-            # (re)build the device-side slot state. The steady state —
-            # chunk after chunk with no join/retire/new-page — reuses the
-            # PREVIOUS chunk's returned device arrays untouched: the
-            # scheduler's host work then stays off the per-token path.
-            tokens = np.zeros(B, np.int32)
-            kv_len = np.zeros(B, np.int32)
-            done = np.ones(B, bool)
-            gen = np.zeros(B, np.int32)
-            temps = np.zeros(B, np.float32)
-            max_new = np.zeros(B, np.int32)
-            eos = np.full(B, -1, np.int32)
-            for i in live_idx:
-                s = self.slots[i]
-                tokens[i], kv_len[i], done[i] = s.pending, s.kv_len, False
-                gen[i], temps[i] = s.gen, s.req.temperature
-                max_new[i] = s.req.max_new_tokens
-                if s.req.eos_token_id is not None:
-                    eos[i] = s.req.eos_token_id
-            self._dev.update(
-                tokens=jnp.asarray(tokens), kv_len=jnp.asarray(kv_len),
-                done=jnp.asarray(done), gen=jnp.asarray(gen),
-                temps=jnp.asarray(temps), max_new=jnp.asarray(max_new),
-                eos=jnp.asarray(eos))
-            self._sampled = any(self.slots[i].req.temperature > 0
-                                for i in live_idx)
-            self._state_dirty = False
-        if self._bt_dirty:
-            seq_ids = [self.slots[i].req.rid
-                       if i in set(live_idx) else None for i in range(B)]
-            self._dev["bt"] = jnp.asarray(self.cache.block_tables(seq_ids))
-            self._bt_dirty = False
-        if self._sampled:
-            keys = np.zeros((C, B, 2), np.uint32)
-            for i in live_idx:
-                s = self.slots[i]
-                for t in range(C):
-                    keys[t, i] = s.keys[min(s.gen + t, len(s.keys) - 1)]
-            keys = jnp.asarray(keys)
-        else:
-            keys = self._zero_keys[C]  # greedy: keys are never read
+            serving.step
+              serving.step.expire | .retire | .compact
+              serving.step.admit          policy, page allocation, grouping
+                serving.prefill           per admitted group
+                  .build                  numpy rows, keys, their upload
+                  .dispatch               the jitted call
+                    serving.compile       first call of a program only
+                  .fetch                  the download that waits
+                  .emit                   per-request bookkeeping
+              serving.step.reserve        chunk length, pages, preemption
+              serving.decode_chunk | serving.spec_chunk
+                .build | .dispatch [serving.compile] | .fetch | .emit
+        """
+        with _trace.span("serving.step"):
+            if self._deadlines_seen:
+                with _trace.span("serving.step.expire"):
+                    self._expire_due()
+            with _trace.span("serving.step.retire"):
+                for idx in range(self.num_slots):
+                    if self.slots[idx] is not None \
+                            and self.slots[idx].done:
+                        self._retire(idx)
+            with _trace.span("serving.step.compact"):
+                self._compact()
+            with _trace.span("serving.step.admit"):
+                self._admit()
+            _monitor.set_gauge("serving.queue.depth", len(self.queue),
+                               doc="requests waiting for admission")
+            in_use = self.cache.alloc.used_pages
+            self.stats.peak_pages_in_use = max(
+                self.stats.peak_pages_in_use, in_use)
+            _monitor.set_gauge("serving.pages.in_use", in_use,
+                               doc="KV pages currently allocated")
+
+            live_idx = [i for i, s in enumerate(self.slots)
+                        if s is not None and not s.done]
+            if _monitor.enabled():
+                # autoscale feed (monitor/slo.py): one host tick per
+                # scheduling step — queue depth, live slots, page slack.
+                # The gauges themselves are recomputed at scrape time.
+                _slo.note_sched_tick(
+                    len(self.queue), len(live_idx), self.num_slots,
+                    self.cache.alloc.free_pages / self.cache.num_pages
+                    if self.cache.num_pages else 0.0)
+            if self._frame_pub is not None:
+                # federation frame on the same host tick (rate-limited
+                # inside; pure host state — zero device syncs)
+                self._frame_pub.maybe_publish(self)
+            if not live_idx:
+                return bool(self.queue) or any(
+                    s is not None for s in self.slots)
+            with _trace.span("serving.step.reserve"):
+                C = self._pick_chunk(live_idx)
+                live_idx = self._ensure_chunk_capacity(live_idx, C)
+            if not live_idx:
+                return True
+            if (self._spec_decode and C == self.turbo_chunk
+                    and not any(self.slots[i].req.temperature > 0
+                                for i in live_idx)):
+                # greedy turbo chunk: verify a self-drafted window in
+                # ONE model pass instead of C sequential decode steps.
+                # The turbo preconditions (full grid, no EOS, remaining
+                # run covers the chunk) already hold, so accept/reject
+                # lands at the same chunk boundary the sequential path
+                # downloads at.
+                with _trace.step_span("serving.spec_chunk",
+                                      self.stats.decode_steps, chunk=C,
+                                      live=len(live_idx)):
+                    return self._spec_step(live_idx, C)
+            with _trace.step_span("serving.decode_chunk",
+                                  self.stats.decode_steps, chunk=C,
+                                  live=len(live_idx)):
+                return self._chunk_step(live_idx, C)
+
+    def _chunk_step(self, live_idx: List[int], C: int) -> bool:
+        """``C`` sequential decode steps over the slot grid as one
+        program, and the one download that brings its tokens back."""
+        with _trace.span("serving.decode_chunk.build"):
+            B = self.num_slots
+            if self._state_dirty:
+                # (re)build the device-side slot state. The steady state —
+                # chunk after chunk with no join/retire/new-page — reuses the
+                # PREVIOUS chunk's returned device arrays untouched: the
+                # scheduler's host work then stays off the per-token path.
+                tokens = np.zeros(B, np.int32)
+                kv_len = np.zeros(B, np.int32)
+                done = np.ones(B, bool)
+                gen = np.zeros(B, np.int32)
+                temps = np.zeros(B, np.float32)
+                max_new = np.zeros(B, np.int32)
+                eos = np.full(B, -1, np.int32)
+                for i in live_idx:
+                    s = self.slots[i]
+                    tokens[i], kv_len[i], done[i] = s.pending, s.kv_len, False
+                    gen[i], temps[i] = s.gen, s.req.temperature
+                    max_new[i] = s.req.max_new_tokens
+                    if s.req.eos_token_id is not None:
+                        eos[i] = s.req.eos_token_id
+                self._dev.update(
+                    tokens=jnp.asarray(tokens), kv_len=jnp.asarray(kv_len),
+                    done=jnp.asarray(done), gen=jnp.asarray(gen),
+                    temps=jnp.asarray(temps), max_new=jnp.asarray(max_new),
+                    eos=jnp.asarray(eos))
+                self._sampled = any(self.slots[i].req.temperature > 0
+                                    for i in live_idx)
+                self._state_dirty = False
+            if self._bt_dirty:
+                seq_ids = [self.slots[i].req.rid
+                           if i in set(live_idx) else None for i in range(B)]
+                self._dev["bt"] = jnp.asarray(self.cache.block_tables(seq_ids))
+                self._bt_dirty = False
+            if self._sampled:
+                keys = np.zeros((C, B, 2), np.uint32)
+                for i in live_idx:
+                    s = self.slots[i]
+                    for t in range(C):
+                        keys[t, i] = s.keys[min(s.gen + t, len(s.keys) - 1)]
+                keys = jnp.asarray(keys)
+            else:
+                keys = self._zero_keys[C]  # greedy: keys are never read
 
         d = self._dev
         ck = self._chunk_fns[(C, self._sampled)]
@@ -2014,14 +2097,12 @@ class ServingEngine:
             ck_flops = self._program_flops(key)
             if ck_flops:
                 ck_flops_share = ck_flops / len(live_idx)
-        with _trace.span("serving.decode_chunk", chunk=C,
-                         live=len(live_idx)), \
-                _pcap.annotate_step("serving.decode_chunk",
-                                    self.stats.decode_steps):
+        with _trace.span("serving.decode_chunk.dispatch"), \
+                self._first_call(ck):
             pk, pv, tok, kvl, done_a, gen_a, emitted = ck(*ck_args)
-            self.cache.pool = {"k": pk, "v": pv}
-            self._dev.update(tokens=tok, kv_len=kvl, done=done_a,
-                             gen=gen_a)
+        self.cache.pool = {"k": pk, "v": pv}
+        self._dev.update(tokens=tok, kv_len=kvl, done=done_a, gen=gen_a)
+        with _trace.span("serving.decode_chunk.fetch"):
             # ONE device->host transfer per chunk: every host-side fact
             # is derivable from the emitted grid (-1 = slot was done at
             # that step; a write and a sample happen exactly on non -1
@@ -2035,36 +2116,37 @@ class ServingEngine:
         if _monitor.enabled():
             self._maybe_sample_kv_absmax()
         t_chunk = time.perf_counter() if _monitor.enabled() else None
-        new_tokens = 0
-        for i in live_idx:
-            s = self.slots[i]
-            toks = emitted[:, i]
-            toks = toks[toks >= 0].tolist()
-            if toks:
-                s.tokens.extend(toks)
-                new_tokens += len(toks)
-                self.cache.alloc.advance(s.req.rid, len(toks))
-                s.kv_len += len(toks)
-                s.gen += len(toks)
-                s.pending = toks[-1]
-                s.t_last = t_chunk if t_chunk is not None else s.t_last
-            if t_chunk is not None and s.cost is not None:
-                # cost attribution at the chunk edge the emitted-grid
-                # download above already synchronized: pure host reads
-                # (allocator page counts, the cached program FLOPs) —
-                # zero added device synchronizations at any rate
-                if s.t_tick is not None:
-                    s.cost.page_seconds += (
-                        self.cache.alloc.page_count(s.req.rid)
-                        * (t_chunk - s.t_tick))
-                s.t_tick = t_chunk
-                s.cost.slot_steps += C
-                s.cost.decode_tokens += len(toks)
-                if ck_flops_share:
-                    s.cost.model_flops += ck_flops_share
-            s.done = s.gen >= s.req.max_new_tokens or (
-                s.req.eos_token_id is not None and bool(toks)
-                and toks[-1] == s.req.eos_token_id)
+        with _trace.span("serving.decode_chunk.emit"):
+            new_tokens = 0
+            for i in live_idx:
+                s = self.slots[i]
+                toks = emitted[:, i]
+                toks = toks[toks >= 0].tolist()
+                if toks:
+                    s.tokens.extend(toks)
+                    new_tokens += len(toks)
+                    self.cache.alloc.advance(s.req.rid, len(toks))
+                    s.kv_len += len(toks)
+                    s.gen += len(toks)
+                    s.pending = toks[-1]
+                    s.t_last = t_chunk if t_chunk is not None else s.t_last
+                if t_chunk is not None and s.cost is not None:
+                    # cost attribution at the chunk edge the emitted-grid
+                    # download above already synchronized: pure host reads
+                    # (allocator page counts, the cached program FLOPs) —
+                    # zero added device synchronizations at any rate
+                    if s.t_tick is not None:
+                        s.cost.page_seconds += (
+                            self.cache.alloc.page_count(s.req.rid)
+                            * (t_chunk - s.t_tick))
+                    s.t_tick = t_chunk
+                    s.cost.slot_steps += C
+                    s.cost.decode_tokens += len(toks)
+                    if ck_flops_share:
+                        s.cost.model_flops += ck_flops_share
+                s.done = s.gen >= s.req.max_new_tokens or (
+                    s.req.eos_token_id is not None and bool(toks)
+                    and toks[-1] == s.req.eos_token_id)
         self.stats.decode_steps += C
         self.stats.tokens_generated += new_tokens
         self.stats.tokens_decoded += new_tokens
@@ -2119,25 +2201,26 @@ class ServingEngine:
         precision an argmax near-tie can flip — exact in f32.)
         Rejected positions' KV stays in the pool as garbage masked out
         by sequence length and overwritten by later commits."""
-        B = self.num_slots
-        if self._bt_dirty:
-            seq_ids = [self.slots[i].req.rid
-                       if i in set(live_idx) else None for i in range(B)]
-            self._dev["bt"] = jnp.asarray(self.cache.block_tables(seq_ids))
-            self._bt_dirty = False
-        drafts = np.zeros((B, C), np.int32)
-        kv_len = np.zeros(B, np.int32)
-        live_m = np.zeros(B, bool)
-        for i in live_idx:
-            s = self.slots[i]
-            drafts[i] = self._draft_for(s, C)
-            kv_len[i] = s.kv_len
-            live_m[i] = True
-        vf = self._spec_fn(C)
-        vf_args = (self.params, self.cache.pool["k"],
-                   self.cache.pool["v"], self._dev["bt"],
-                   jnp.asarray(drafts), jnp.asarray(kv_len),
-                   jnp.asarray(live_m))
+        with _trace.span("serving.spec_chunk.build"):
+            B = self.num_slots
+            if self._bt_dirty:
+                seq_ids = [self.slots[i].req.rid
+                           if i in set(live_idx) else None for i in range(B)]
+                self._dev["bt"] = jnp.asarray(self.cache.block_tables(seq_ids))
+                self._bt_dirty = False
+            drafts = np.zeros((B, C), np.int32)
+            kv_len = np.zeros(B, np.int32)
+            live_m = np.zeros(B, bool)
+            for i in live_idx:
+                s = self.slots[i]
+                drafts[i] = self._draft_for(s, C)
+                kv_len[i] = s.kv_len
+                live_m[i] = True
+            vf = self._spec_fn(C)
+            vf_args = (self.params, self.cache.pool["k"],
+                       self.cache.pool["v"], self._dev["bt"],
+                       jnp.asarray(drafts), jnp.asarray(kv_len),
+                       jnp.asarray(live_m))
         exec_rec = None
         vf_flops_share = None
         if _monitor.enabled():
@@ -2150,53 +2233,53 @@ class ServingEngine:
             vf_flops = self._program_flops(key)
             if vf_flops:
                 vf_flops_share = vf_flops / len(live_idx)
-        with _trace.span("serving.spec_chunk", chunk=C,
-                         live=len(live_idx)), \
-                _pcap.annotate_step("serving.spec_chunk",
-                                    self.stats.decode_steps):
+        with _trace.span("serving.spec_chunk.dispatch"), \
+                self._first_call(vf):
             pk, pv, preds_a = vf(*vf_args)
-            self.cache.pool = {"k": pk, "v": pv}
+        self.cache.pool = {"k": pk, "v": pv}
+        with _trace.span("serving.spec_chunk.fetch"):
             preds = np.asarray(preds_a)                  # [B, C]
         if exec_rec is not None:
             exec_rec(None)
         if _monitor.enabled():
             self._maybe_sample_kv_absmax()
         t_chunk = time.perf_counter() if _monitor.enabled() else None
-        new_tokens = 0
-        accepted_total = 0
-        for i in live_idx:
-            s = self.slots[i]
-            dr = drafts[i]
-            col = preds[i]
-            a = 0
-            while a < C - 1 and dr[a + 1] == col[a]:
-                a += 1
-            emitted = [int(t) for t in col[:a + 1]]
-            s.tokens.extend(emitted)
-            new_tokens += len(emitted)
-            accepted_total += a
-            self.cache.alloc.advance(s.req.rid, len(emitted))
-            s.kv_len += len(emitted)
-            s.gen += len(emitted)
-            s.pending = emitted[-1]
-            s.t_last = t_chunk if t_chunk is not None else s.t_last
-            if t_chunk is not None and s.cost is not None:
-                if s.t_tick is not None:
-                    s.cost.page_seconds += (
-                        self.cache.alloc.page_count(s.req.rid)
-                        * (t_chunk - s.t_tick))
-                s.t_tick = t_chunk
-                s.cost.slot_steps += C
-                s.cost.decode_tokens += len(emitted)
-                if vf_flops_share:
-                    s.cost.model_flops += vf_flops_share
-            if t_chunk is not None:
-                # aggregate fold, no event append: spec rounds are
-                # per-chunk-rate and would flood the bounded timeline
-                _forensics.note_spec(s.req.rid, C - 1, a)
-            # turbo preconditions rule out EOS; only the length bound
-            # can finish a sequence here
-            s.done = s.gen >= s.req.max_new_tokens
+        with _trace.span("serving.spec_chunk.emit"):
+            new_tokens = 0
+            accepted_total = 0
+            for i in live_idx:
+                s = self.slots[i]
+                dr = drafts[i]
+                col = preds[i]
+                a = 0
+                while a < C - 1 and dr[a + 1] == col[a]:
+                    a += 1
+                emitted = [int(t) for t in col[:a + 1]]
+                s.tokens.extend(emitted)
+                new_tokens += len(emitted)
+                accepted_total += a
+                self.cache.alloc.advance(s.req.rid, len(emitted))
+                s.kv_len += len(emitted)
+                s.gen += len(emitted)
+                s.pending = emitted[-1]
+                s.t_last = t_chunk if t_chunk is not None else s.t_last
+                if t_chunk is not None and s.cost is not None:
+                    if s.t_tick is not None:
+                        s.cost.page_seconds += (
+                            self.cache.alloc.page_count(s.req.rid)
+                            * (t_chunk - s.t_tick))
+                    s.t_tick = t_chunk
+                    s.cost.slot_steps += C
+                    s.cost.decode_tokens += len(emitted)
+                    if vf_flops_share:
+                        s.cost.model_flops += vf_flops_share
+                if t_chunk is not None:
+                    # aggregate fold, no event append: spec rounds are
+                    # per-chunk-rate and would flood the bounded timeline
+                    _forensics.note_spec(s.req.rid, C - 1, a)
+                # turbo preconditions rule out EOS; only the length bound
+                # can finish a sequence here
+                s.done = s.gen >= s.req.max_new_tokens
         self.stats.decode_steps += C
         self.stats.tokens_generated += new_tokens
         self.stats.tokens_decoded += new_tokens

@@ -500,6 +500,7 @@ def _kv_quantize(xf, s):
                     -_KV_QMAX, _KV_QMAX).astype(jnp.int8)
 
 
+@jax.named_scope("attn.kv_write")
 def _kv_pool_write(pool, pages, page_rows):
     """Scatter freshly computed whole-page grids ``pages``
     [L, ..., kv, ps, hd] into a pool leaf at ``page_rows`` with the
@@ -528,6 +529,7 @@ def _kv_pool_gather(pool, rows, dtype):
     return pool[rows].astype(dtype)
 
 
+@jax.named_scope("attn.kv_write")
 def _kv_page_append(leaf, rows, off, val, P):
     """Append one token's [B, kv, hd] values at slot ``off`` of pages
     ``rows`` (sentinel ``P`` drops) — the decode-step write. Quantized
@@ -560,6 +562,20 @@ def _kv_page_append(leaf, rows, off, val, P):
         val.astype(leaf.dtype), mode="drop", unique_indices=True)
 
 
+@jax.named_scope("attn.proj")
+def _qkv_rope(x, lp, c, cos, sin):
+    """One layer's attention inputs: ln1, the q/k/v products, rope."""
+    h = _rms(x, lp["ln1"], c.rms_norm_eps)
+    q, k, v = _qkv_proj(h, lp, c)
+    return rope_raw(q, cos, sin), rope_raw(k, cos, sin), v
+
+
+@jax.named_scope("attn.proj")
+def _attn_out(x, a, lp):
+    """The output product of one layer's attention, and its residual."""
+    return x + _mm(a.astype(x.dtype), lp["wo"])
+
+
 def paged_prefill(family, params, ids, config, pool_k, pool_v, page_rows,
                   slen):
     """Consume a batch of padded prompts [G, S_pad] (S_pad a page
@@ -576,32 +592,33 @@ def paged_prefill(family, params, ids, config, pool_k, pool_v, page_rows,
     L, P, kv, ps, hd = (pool_k["q"] if quant else pool_k).shape
     E.enforce(S % ps == 0, f"padded prompt {S} not a multiple of "
               f"page_size {ps}")
-    x = jnp.take(params["embed"], ids, axis=0)
-    cos, sin = rope_tables(S, c.head_dim, theta=c.rope_theta)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0)
+        cos, sin = rope_tables(S, c.head_dim, theta=c.rope_theta)
 
     from ..nn.functional.attention import sdpa_raw
 
     def step(carry, lp):
         x = carry
-        h = _rms(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv_proj(h, lp, c)
-        q = rope_raw(q, cos, sin)
-        k = rope_raw(k, cos, sin)
-        a = sdpa_raw(q, k, v, is_causal=True).reshape(G, S, -1)
-        x = x + _mm(a.astype(x.dtype), lp["wo"])
+        q, k, v = _qkv_rope(x, lp, c, cos, sin)
+        with jax.named_scope("attn.kernel"):
+            a = sdpa_raw(q, k, v, is_causal=True).reshape(G, S, -1)
+        x = _attn_out(x, a, lp)
         return family.decode_mlp(x, lp, c), (k, v)
 
     x, (ks, vs) = lax.scan(step, x, params["layers"])
     npad = S // ps
-    # [L, G, S, kv, hd] -> [L, G, npad, kv, ps, hd] page grids
-    ks = jnp.moveaxis(ks.reshape(L, G, npad, ps, kv, hd), 4, 3)
-    vs = jnp.moveaxis(vs.reshape(L, G, npad, ps, kv, hd), 4, 3)
+    with jax.named_scope("attn.kv_write"):
+        # [L, G, S, kv, hd] -> [L, G, npad, kv, ps, hd] page grids
+        ks = jnp.moveaxis(ks.reshape(L, G, npad, ps, kv, hd), 4, 3)
+        vs = jnp.moveaxis(vs.reshape(L, G, npad, ps, kv, hd), 4, 3)
     pool_k = _kv_pool_write(pool_k, ks, page_rows)
     pool_v = _kv_pool_write(pool_v, vs, page_rows)
-    x = _rms(x, params["ln_f"], c.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
-    logits = _head_logits(last, family._head(params, c))
+    with jax.named_scope("head"):
+        x = _rms(x, params["ln_f"], c.rms_norm_eps)
+        last = jnp.take_along_axis(
+            x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
+        logits = _head_logits(last, family._head(params, c))
     return pool_k, pool_v, logits
 
 
@@ -619,14 +636,16 @@ def paged_decode_step(family, params, pool_k, pool_v, block_tables,
     maxp = block_tables.shape[1]
     n = lengths
     posw = jnp.maximum(n - 1, 0)                       # [B] write position
-    x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]
-    # rope angles computed directly at the ragged positions (identical
-    # floats to a rope_tables row: same product, same cos — but a fused
-    # elementwise chain instead of two table gathers per step)
-    inv = 1.0 / (c.rope_theta ** (
-        jnp.arange(0, c.head_dim, 2, jnp.float32) / c.head_dim))
-    freqs = posw.astype(jnp.float32)[:, None, None] * inv  # [B, 1, hd/2]
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]
+        # rope angles computed directly at the ragged positions
+        # (identical floats to a rope_tables row: same product, same cos
+        # — but a fused elementwise chain instead of two table gathers
+        # per step)
+        inv = 1.0 / (c.rope_theta ** (
+            jnp.arange(0, c.head_dim, 2, jnp.float32) / c.head_dim))
+        freqs = posw.astype(jnp.float32)[:, None, None] * inv  # [B,1,hd/2]
+        cos, sin = jnp.cos(freqs), jnp.sin(freqs)
 
     page_idx = posw // ps
     off = posw % ps
@@ -640,25 +659,24 @@ def paged_decode_step(family, params, pool_k, pool_v, block_tables,
     def step(carry, xs):
         x = carry
         lp, kpl, vpl = xs                              # [P, kv, ps, hd]
-        h = _rms(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv_proj(h, lp, c)
-        q = rope_raw(q, cos, sin)
-        k = rope_raw(k, cos, sin)
+        q, k, v = _qkv_rope(x, lp, c, cos, sin)
         kpl = _kv_page_append(kpl, rows, off, k[:, 0], P)
         vpl = _kv_page_append(vpl, rows, off, v[:, 0], P)
-        if quant:
-            a = dispatched_paged_attention(
-                q[:, 0], kpl["q"], vpl["q"], block_tables, n,
-                k_scales=kpl["s"], v_scales=vpl["s"])
-        else:
-            a = dispatched_paged_attention(q[:, 0], kpl, vpl,
-                                           block_tables, n)
-        x = x + _mm(a.reshape(B, 1, -1).astype(x.dtype), lp["wo"])
+        with jax.named_scope("attn.kernel"):
+            if quant:
+                a = dispatched_paged_attention(
+                    q[:, 0], kpl["q"], vpl["q"], block_tables, n,
+                    k_scales=kpl["s"], v_scales=vpl["s"])
+            else:
+                a = dispatched_paged_attention(q[:, 0], kpl, vpl,
+                                               block_tables, n)
+        x = _attn_out(x, a.reshape(B, 1, -1), lp)
         return family.decode_mlp(x, lp, c), (kpl, vpl)
 
     x, (kc, vc) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
-    x = _rms(x, params["ln_f"], c.rms_norm_eps)
-    logits = _head_logits(x[:, 0, :], family._head(params, c))
+    with jax.named_scope("head"):
+        x = _rms(x, params["ln_f"], c.rms_norm_eps)
+        logits = _head_logits(x[:, 0, :], family._head(params, c))
     return kc, vc, logits
 
 
@@ -683,9 +701,10 @@ def paged_prefill_shared(family, params, ids, config, pool_k, pool_v,
               f"page_size {ps}")
     E.enforce(ncp >= 1, "shared prefill needs a cached prefix")
     ctx = ncp * ps
-    x = jnp.take(params["embed"], ids, axis=0)
-    cos, sin = rope_tables(ctx + S, c.head_dim, theta=c.rope_theta)
-    cos, sin = cos[ctx:], sin[ctx:]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0)
+        cos, sin = rope_tables(ctx + S, c.head_dim, theta=c.rope_theta)
+        cos, sin = cos[ctx:], sin[ctx:]
     # key t (prefix ++ tail token-major) visible to tail query i iff
     # t <= ctx + i: the whole prefix, causal within the tail
     mask = (jnp.arange(ctx + S)[None, :]
@@ -696,33 +715,33 @@ def paged_prefill_shared(family, params, ids, config, pool_k, pool_v,
     def step(carry, xs):
         x = carry
         lp, kpl, vpl = xs
-        h = _rms(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv_proj(h, lp, c)
-        q = rope_raw(q, cos, sin)
-        k = rope_raw(k, cos, sin)
-        # cached prefix pages, token-major: [G, ncp, kv, ps, hd] ->
-        # [G, ctx, kv, hd] (rope already applied when they were
-        # written; quantized pools dequantize in the gather)
-        ck = jnp.swapaxes(_kv_pool_gather(kpl, ctx_rows, k.dtype),
-                          2, 3).reshape(G, ctx, kv, hd)
-        cv = jnp.swapaxes(_kv_pool_gather(vpl, ctx_rows, v.dtype),
-                          2, 3).reshape(G, ctx, kv, hd)
-        ka = jnp.concatenate([ck, k], axis=1)
-        va = jnp.concatenate([cv, v], axis=1)
-        a = sdpa_raw(q, ka, va, attn_mask=mask).reshape(G, S, -1)
-        x = x + _mm(a.astype(x.dtype), lp["wo"])
+        q, k, v = _qkv_rope(x, lp, c, cos, sin)
+        with jax.named_scope("attn.kernel"):
+            # cached prefix pages, token-major: [G, ncp, kv, ps, hd] ->
+            # [G, ctx, kv, hd] (rope already applied when they were
+            # written; quantized pools dequantize in the gather)
+            ck = jnp.swapaxes(_kv_pool_gather(kpl, ctx_rows, k.dtype),
+                              2, 3).reshape(G, ctx, kv, hd)
+            cv = jnp.swapaxes(_kv_pool_gather(vpl, ctx_rows, v.dtype),
+                              2, 3).reshape(G, ctx, kv, hd)
+            ka = jnp.concatenate([ck, k], axis=1)
+            va = jnp.concatenate([cv, v], axis=1)
+            a = sdpa_raw(q, ka, va, attn_mask=mask).reshape(G, S, -1)
+        x = _attn_out(x, a, lp)
         return family.decode_mlp(x, lp, c), (k, v)
 
     x, (ks, vs) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
     npad = S // ps
-    ks = jnp.moveaxis(ks.reshape(L, G, npad, ps, kv, hd), 4, 3)
-    vs = jnp.moveaxis(vs.reshape(L, G, npad, ps, kv, hd), 4, 3)
+    with jax.named_scope("attn.kv_write"):
+        ks = jnp.moveaxis(ks.reshape(L, G, npad, ps, kv, hd), 4, 3)
+        vs = jnp.moveaxis(vs.reshape(L, G, npad, ps, kv, hd), 4, 3)
     pool_k = _kv_pool_write(pool_k, ks, page_rows)
     pool_v = _kv_pool_write(pool_v, vs, page_rows)
-    x = _rms(x, params["ln_f"], c.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
-    logits = _head_logits(last, family._head(params, c))
+    with jax.named_scope("head"):
+        x = _rms(x, params["ln_f"], c.rms_norm_eps)
+        last = jnp.take_along_axis(
+            x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
+        logits = _head_logits(last, family._head(params, c))
     return pool_k, pool_v, logits
 
 
@@ -745,11 +764,12 @@ def paged_verify_window(family, params, tokens, config, pool_k, pool_v,
     L, P, kv, ps, hd = (pool_k["q"] if quant else pool_k).shape
     maxp = block_tables.shape[1]
     pos = kv_len[:, None] + jnp.arange(C)[None, :]          # [B, C]
-    x = jnp.take(params["embed"], tokens, axis=0)
-    inv = 1.0 / (c.rope_theta ** (
-        jnp.arange(0, c.head_dim, 2, jnp.float32) / c.head_dim))
-    freqs = pos.astype(jnp.float32)[:, :, None] * inv[None, None, :]
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        inv = 1.0 / (c.rope_theta ** (
+            jnp.arange(0, c.head_dim, 2, jnp.float32) / c.head_dim))
+        freqs = pos.astype(jnp.float32)[:, :, None] * inv[None, None, :]
+        cos, sin = jnp.cos(freqs), jnp.sin(freqs)
 
     page_idx = pos // ps
     off = pos % ps
@@ -774,6 +794,7 @@ def paged_verify_window(family, params, tokens, config, pool_k, pool_v,
     lpi = page_idx - wstart[:, None]                        # [B, C] local
     bi = jnp.arange(B)[:, None]
 
+    @jax.named_scope("attn.kv_write")
     def _window_rewrite(leaf, val):
         """Gather the window's nwp pages, dequantize, zero the
         not-yet-written tail (stale codes must not inflate the
@@ -803,30 +824,32 @@ def paged_verify_window(family, params, tokens, config, pool_k, pool_v,
     def step(carry, xs):
         x = carry
         lp, kpl, vpl = xs
-        h = _rms(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv_proj(h, lp, c)
-        q = rope_raw(q, cos, sin)
-        k = rope_raw(k, cos, sin)
+        q, k, v = _qkv_rope(x, lp, c, cos, sin)
         if quant:
             kpl = _window_rewrite(kpl, k)
             vpl = _window_rewrite(vpl, v)
         else:
-            kpl = kpl.at[rows[:, :, None], kvi[None, None, :],
-                         off[:, :, None]].set(
-                k.astype(kpl.dtype), mode="drop", unique_indices=True)
-            vpl = vpl.at[rows[:, :, None], kvi[None, None, :],
-                         off[:, :, None]].set(
-                v.astype(vpl.dtype), mode="drop", unique_indices=True)
-        ck = jnp.swapaxes(_kv_pool_gather(kpl, block_tables, q.dtype),
-                          2, 3).reshape(B, maxp * ps, kv, hd)
-        cv = jnp.swapaxes(_kv_pool_gather(vpl, block_tables, q.dtype),
-                          2, 3).reshape(B, maxp * ps, kv, hd)
-        a = sdpa_raw(q, ck, cv,
-                     attn_mask=mask[:, None]).reshape(B, C, -1)
-        x = x + _mm(a.astype(x.dtype), lp["wo"])
+            with jax.named_scope("attn.kv_write"):
+                kpl = kpl.at[rows[:, :, None], kvi[None, None, :],
+                             off[:, :, None]].set(
+                    k.astype(kpl.dtype), mode="drop",
+                    unique_indices=True)
+                vpl = vpl.at[rows[:, :, None], kvi[None, None, :],
+                             off[:, :, None]].set(
+                    v.astype(vpl.dtype), mode="drop",
+                    unique_indices=True)
+        with jax.named_scope("attn.kernel"):
+            ck = jnp.swapaxes(_kv_pool_gather(kpl, block_tables, q.dtype),
+                              2, 3).reshape(B, maxp * ps, kv, hd)
+            cv = jnp.swapaxes(_kv_pool_gather(vpl, block_tables, q.dtype),
+                              2, 3).reshape(B, maxp * ps, kv, hd)
+            a = sdpa_raw(q, ck, cv,
+                         attn_mask=mask[:, None]).reshape(B, C, -1)
+        x = _attn_out(x, a, lp)
         return family.decode_mlp(x, lp, c), (kpl, vpl)
 
     x, (kc, vc) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
-    x = _rms(x, params["ln_f"], c.rms_norm_eps)
-    logits = _head_logits(x, family._head(params, c))
+    with jax.named_scope("head"):
+        x = _rms(x, params["ln_f"], c.rms_norm_eps)
+        logits = _head_logits(x, family._head(params, c))
     return kc, vc, logits

@@ -100,6 +100,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           offset=sk - sq, block_q=block_q, block_k=block_k,
                           num_k_blocks=nk),
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -243,6 +244,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           offset=sk - sq, block_q=block_q, block_k=block_k,
                           num_k_blocks=nk),
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -268,6 +270,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           offset=sk - sq, block_q=block_q, block_k=block_k,
                           num_q_blocks=nq),
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
@@ -672,6 +675,7 @@ def _seg_fwd(q, k, v, segq, segk, posq, posk, stats, stride, nh, *, scale,
     out, lse = pl.pallas_call(
         functools.partial(_seg_fwd_kernel, scale=scale, causal=causal,
                           nh=nh, stride=stride, num_k_blocks=nk),
+        name="flash_seg_fwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
@@ -705,6 +709,7 @@ def _seg_bwd(res, g, *, scale, causal, block_q, block_k, interpret):
     dq = pl.pallas_call(
         functools.partial(_seg_bwd_dq_kernel, scale=scale, causal=causal,
                           nh=nh, stride=stride, num_k_blocks=nk),
+        name="flash_seg_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, nk),
@@ -731,6 +736,7 @@ def _seg_bwd(res, g, *, scale, causal, block_q, block_k, interpret):
     dk_full, dv_full = pl.pallas_call(
         functools.partial(_seg_bwd_dkv_kernel, scale=scale, causal=causal,
                           nh=nh, stride=stride, num_q_blocks=nq),
+        name="flash_seg_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nk, nq),

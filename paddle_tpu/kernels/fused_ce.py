@@ -42,6 +42,7 @@ _NEG = -1e30   # large-negative instead of -inf: keeps XLA's max/exp exact
                # for masked lanes without generating inf-inf = nan paths
 
 
+@jax.named_scope("ce")
 def masked_xent_from_logits(logits, labels, *, ignore_index: int = -100,
                             reduction: str = "mean"):
     """Materialising xent with the SAME ignore_index semantics as the
@@ -95,6 +96,7 @@ def _blockwise_ce(x, headc, labels, valid_v):
     return loss
 
 
+@jax.named_scope("ce")
 def _blockwise_ce_fwd(x, headc, labels, valid_v):
     n = x.shape[0]
     k, vb, _ = headc.shape
@@ -123,6 +125,7 @@ def _blockwise_ce_fwd(x, headc, labels, valid_v):
     return loss, (x, headc, labels, lse)
 
 
+@jax.named_scope("ce")
 def _blockwise_ce_bwd(valid_v, res, g):
     x, headc, labels, lse = res
     k, vb, d = headc.shape
@@ -151,6 +154,7 @@ def _blockwise_ce_bwd(valid_v, res, g):
 _blockwise_ce.defvjp(_blockwise_ce_fwd, _blockwise_ce_bwd)
 
 
+@jax.named_scope("ce")
 def fused_cross_entropy(x, head, labels, *, vocab_chunk: int = 4096,
                         reduction: str = "mean", ignore_index: int = -100):
     """Softmax cross-entropy of ``x @ head.T`` against integer ``labels``

@@ -203,6 +203,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=ps,
                           max_pages=maxp, quant=quant),
+        name="paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv, gp, hd), q.dtype),
         interpret=interpret,

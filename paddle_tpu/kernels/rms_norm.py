@@ -78,6 +78,7 @@ def _call_fwd(x2, w, eps, br, interpret):
     grid = (pl.cdiv(n, br),)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="rms_norm_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
@@ -125,6 +126,7 @@ def _rms_bwd(eps, block_rows, _interp_unused, res, dy):
     grid = (pl.cdiv(n, br),)
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
+        name="rms_norm_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),

@@ -216,6 +216,7 @@ def _mm(x, w):
     return x @ w
 
 
+@jax.named_scope("head")
 def _head_logits(x2d, head):
     """lm-head logits [.., V] from hidden [.., D]; head is [V, D] (or
     its weight-only form {"q": int8 [V, D], "s": f32 [V]})."""
@@ -321,6 +322,7 @@ def unpack_int4(q4, in_axis: int):
     return jnp.stack([lo, hi], axis=in_axis + 1).reshape(shape)
 
 
+@jax.named_scope("attn.proj")
 def _qkv_proj(h, lp, config: LlamaConfig, constrain=_noc):
     """Attention input projections [B,S,D] -> q/k/v head grids (no rope;
     callers position-encode: training uses the full table, decode the
@@ -338,6 +340,7 @@ def _qkv_proj(h, lp, config: LlamaConfig, constrain=_noc):
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _ffn(x, lp, config: LlamaConfig, sp: bool = False, constrain=_noc):
     """Post-attention half of a decoder layer (ln2 + SwiGLU + residual)."""
     c = config
@@ -357,6 +360,7 @@ def decode_mlp(x, lp, config: LlamaConfig):
     return _ffn(x, lp, config)
 
 
+@jax.named_scope("attn.kernel")
 def _causal_attention(q, k, v, mesh, segment_ids, positions):
     """Causal attention through the kernel seam (``sdpa_raw``).
 
@@ -399,17 +403,19 @@ def _block(x, lp, cos, sin, config: LlamaConfig, sp: bool, mesh,
     constrain = (lambda a, spec: lax.with_sharding_constraint(
         a, NamedSharding(mesh, spec))) if mesh is not None else _noc
 
-    h = _rms(x, lp["ln1"], c.rms_norm_eps)
-    q, k, v = _qkv_proj(h, lp, c, constrain)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn.proj"):
+        h = _rms(x, lp["ln1"], c.rms_norm_eps)
+        q, k, v = _qkv_proj(h, lp, c, constrain)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     a = _causal_attention(q, k, v, mesh, segment_ids, positions)
     # Named so remat_policy="attn" can pin exactly this value: the one
     # tensor whose recompute (a full flash-attention forward) dominates
     # the backward pass under full remat, at 2*B*S*D bytes per layer.
     a = checkpoint_name(a, "attn_out")
-    a = a.reshape(B, S, -1)
-    x = x + constrain(_mm(a, lp["wo"]), _act_spec(sp))
+    with jax.named_scope("attn.proj"):
+        a = a.reshape(B, S, -1)
+        x = x + constrain(_mm(a, lp["wo"]), _act_spec(sp))
     return _ffn(x, lp, c, sp, constrain)
 
 
@@ -422,12 +428,13 @@ def forward_hidden(params, ids, config: LlamaConfig, *, sp: bool = False,
     semantics: rope positions restart per document and attention is
     segment-masked (see nn.functional.attention.sdpa_raw)."""
     c = config
-    x = jnp.take(params["embed"], ids, axis=0)
-    cos, sin = rope_tables(c, ids.shape[1])
-    if positions is not None:
-        # segment-local rope rows (sequence packing) via the shared
-        # position_ids gather seam
-        cos, sin = _gather_rope_rows(cos, sin, positions)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0)
+        cos, sin = rope_tables(c, ids.shape[1])
+        if positions is not None:
+            # segment-local rope rows (sequence packing) via the shared
+            # position_ids gather seam
+            cos, sin = _gather_rope_rows(cos, sin, positions)
 
     def step(carry, lp):
         return _block(carry, lp, cos, sin, c, sp, mesh,
@@ -437,7 +444,8 @@ def forward_hidden(params, ids, config: LlamaConfig, *, sp: bool = False,
         step = jax.checkpoint(step, prevent_cse=False,
                               policy=remat_policy(c.remat_policy))
     x, _ = lax.scan(step, x, params["layers"])
-    return _rms(x, params["ln_f"], c.rms_norm_eps)
+    with jax.named_scope("head"):
+        return _rms(x, params["ln_f"], c.rms_norm_eps)
 
 
 def _head(params, config: LlamaConfig):
@@ -900,6 +908,7 @@ def adamw_init(params, moment_dtype=jnp.float32):
     }
 
 
+@jax.named_scope("optim")
 def _adamw_update(params, grads, opt_state, lr, *, b1=0.9, b2=0.95,
                   eps=1e-8, wd=0.1):
     step = opt_state["step"] + 1

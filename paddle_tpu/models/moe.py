@@ -230,6 +230,7 @@ def moe_capacity(config: MoEConfig, n_tokens: int) -> int:
                       else cap))
 
 
+@jax.named_scope("moe.route")
 def _route(x, lp, config: MoEConfig):
     """Shared router head: (topv [T,k] normalized f32, topi [T,k], aux)."""
     c = config
@@ -247,6 +248,7 @@ def _route(x, lp, config: MoEConfig):
     return topv, topi, aux
 
 
+@jax.named_scope("moe.experts")
 def _expert_ffn(xe, lp):
     """Batched per-expert SwiGLU on [E, C|T, D] slot grids."""
     g = jnp.einsum("ecd,edf->ecf", xe, _edeq(lp["e_gate"], xe.dtype))
@@ -263,32 +265,36 @@ def _moe_mlp_capacity(x, lp, config: MoEConfig, T):
     C = moe_capacity(c, T)
     topv, topi, aux = _route(x, lp, c)
 
-    # Slot bookkeeping in token-major priority order (GShard): pos[t,k] =
-    # how many earlier slots chose the same expert == position in that
-    # expert's buffer. Over-capacity slots drop.
-    oh = jax.nn.one_hot(topi.reshape(-1), E, dtype=jnp.int32)  # [T*k, E]
-    pos = jnp.sum((jnp.cumsum(oh, axis=0) - oh) * oh, axis=-1)  # [T*k]
-    expert = topi.reshape(-1)                                   # [T*k]
-    keep = pos < C
-    dest = expert * C + pos                                     # [T*k]
+    with jax.named_scope("moe.dispatch"):
+        # Slot bookkeeping in token-major priority order (GShard):
+        # pos[t,k] = how many earlier slots chose the same expert ==
+        # position in that expert's buffer. Over-capacity slots drop.
+        oh = jax.nn.one_hot(topi.reshape(-1), E, dtype=jnp.int32)  # [T*k, E]
+        pos = jnp.sum((jnp.cumsum(oh, axis=0) - oh) * oh, axis=-1)  # [T*k]
+        expert = topi.reshape(-1)                                   # [T*k]
+        keep = pos < C
+        dest = expert * C + pos                                     # [T*k]
 
-    # Scatter each kept slot's TOKEN INDEX into the [E*C] grid; empty
-    # slots point at the appended zero row of xp (index T).
-    idx = jnp.full((E * C,), T, jnp.int32)
-    idx = idx.at[jnp.where(keep, dest, E * C)].set(
-        jnp.repeat(jnp.arange(T, dtype=jnp.int32), k), mode="drop")
-    xp = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
-    xe = jnp.take(xp, idx, axis=0).reshape(E, C, -1)            # [E, C, D]
+        # Scatter each kept slot's TOKEN INDEX into the [E*C] grid; empty
+        # slots point at the appended zero row of xp (index T).
+        idx = jnp.full((E * C,), T, jnp.int32)
+        idx = idx.at[jnp.where(keep, dest, E * C)].set(
+            jnp.repeat(jnp.arange(T, dtype=jnp.int32), k), mode="drop")
+        xp = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+        xe = jnp.take(xp, idx, axis=0).reshape(E, C, -1)        # [E, C, D]
 
     y = _expert_ffn(xe, lp)                                     # [E, C, D]
 
-    # Combine: each (t, k) slot gathers its expert output row, scaled by
-    # its (still-normalized) router weight; dropped slots contribute 0.
-    yk = jnp.take(y.reshape(E * C, -1), jnp.where(keep, dest, 0), axis=0)
-    w = (topv.reshape(-1) * keep).astype(jnp.float32)[:, None]
-    routed = jnp.sum((yk.astype(jnp.float32) * w).reshape(T, k, -1),
-                     axis=1)
-    return routed.astype(x.dtype), aux
+    with jax.named_scope("moe.combine"):
+        # Combine: each (t, k) slot gathers its expert output row, scaled
+        # by its (still-normalized) router weight; dropped slots
+        # contribute 0.
+        yk = jnp.take(y.reshape(E * C, -1), jnp.where(keep, dest, 0),
+                      axis=0)
+        w = (topv.reshape(-1) * keep).astype(jnp.float32)[:, None]
+        routed = jnp.sum((yk.astype(jnp.float32) * w).reshape(T, k, -1),
+                         axis=1)
+        return routed.astype(x.dtype), aux
 
 
 def _moe_mlp_dense(x, lp, config: MoEConfig, T, mesh):
@@ -296,8 +302,9 @@ def _moe_mlp_dense(x, lp, config: MoEConfig, T, mesh):
     expert compute is an einsum over the (sharded) expert axis."""
     c = config
     topv, topi, aux = _route(x, lp, c)
-    combine = jnp.zeros((T, c.num_experts), jnp.float32).at[
-        jnp.arange(T)[:, None], topi].set(topv)             # [T, E]
+    with jax.named_scope("moe.dispatch"):
+        combine = jnp.zeros((T, c.num_experts), jnp.float32).at[
+            jnp.arange(T)[:, None], topi].set(topv)         # [T, E]
 
     constrain = (lambda a, spec: lax.with_sharding_constraint(
         a, NamedSharding(mesh, spec))) if mesh is not None \
@@ -307,13 +314,15 @@ def _moe_mlp_dense(x, lp, config: MoEConfig, T, mesh):
     # unscaled token), combine with the router weights — gates scale
     # expert OUTPUTS, the DeepSeekMoE/GShard semantics (scaling the input
     # of a nonlinear expert would compute a different function)
-    dispatch = (combine > 0).astype(c.dtype)                # [T, E]
-    xe = jnp.einsum("td,te->etd", x.astype(c.dtype), dispatch)
-    xe = constrain(xe, P("ep", None, None))
+    with jax.named_scope("moe.dispatch"):
+        dispatch = (combine > 0).astype(c.dtype)            # [T, E]
+        xe = jnp.einsum("td,te->etd", x.astype(c.dtype), dispatch)
+        xe = constrain(xe, P("ep", None, None))
     y = constrain(_expert_ffn(xe, lp), P("ep", None, None))
-    routed = jnp.einsum("etd,te->td", y.astype(jnp.float32),
-                        combine)                            # weighted combine
-    return routed.astype(x.dtype), aux
+    with jax.named_scope("moe.combine"):
+        routed = jnp.einsum("etd,te->td", y.astype(jnp.float32),
+                            combine)                        # weighted combine
+        return routed.astype(x.dtype), aux
 
 
 def _moe_mlp(h, lp, config: MoEConfig, mesh):
@@ -332,11 +341,13 @@ def _moe_mlp(h, lp, config: MoEConfig, mesh):
     else:
         routed, aux = _moe_mlp_dense(x, lp, c, T, mesh)
 
-    sg = _mm(x, lp["s_gate"])
-    su = _mm(x, lp["s_up"])
-    shared = _mm(jax.nn.silu(sg) * su, lp["s_down"])
+    with jax.named_scope("moe.shared"):
+        sg = _mm(x, lp["s_gate"])
+        su = _mm(x, lp["s_up"])
+        shared = _mm(jax.nn.silu(sg) * su, lp["s_down"])
 
-    return (routed + shared).reshape(B, S, D).astype(h.dtype), aux
+    with jax.named_scope("moe.combine"):
+        return (routed + shared).reshape(B, S, D).astype(h.dtype), aux
 
 
 def decode_mlp(x, lp, config: MoEConfig):
@@ -344,9 +355,11 @@ def decode_mlp(x, lp, config: MoEConfig):
     MoE MLP + residual) — the family seam inference/paged.py composes
     with (see llama.decode_mlp). Router aux loss is dropped: serving
     never backprops."""
-    h2 = _rms(x, lp["ln2"], config.rms_norm_eps)
+    with jax.named_scope("moe.route"):
+        h2 = _rms(x, lp["ln2"], config.rms_norm_eps)
     out, _ = _moe_mlp(h2, lp, config, None)
-    return x + out
+    with jax.named_scope("moe.combine"):
+        return x + out
 
 
 def _head(params, config: MoEConfig):
@@ -361,19 +374,24 @@ def _block(x, lp, cos, sin, config: MoEConfig, mesh,
     B, S, D = x.shape
     nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
 
-    h = _rms(x, lp["ln1"], c.rms_norm_eps)
-    q = _mm(h, lp["wq"]).reshape(B, S, nh, hd)
-    k = _mm(h, lp["wk"]).reshape(B, S, nkv, hd)
-    v = _mm(h, lp["wv"]).reshape(B, S, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    a = sdpa_raw(q, k, v, is_causal=True, segment_ids=segment_ids,
-                 positions=positions).reshape(B, S, nh * hd)
-    x = x + _mm(a, lp["wo"])
+    with jax.named_scope("attn.proj"):
+        h = _rms(x, lp["ln1"], c.rms_norm_eps)
+        q = _mm(h, lp["wq"]).reshape(B, S, nh, hd)
+        k = _mm(h, lp["wk"]).reshape(B, S, nkv, hd)
+        v = _mm(h, lp["wv"]).reshape(B, S, nkv, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn.kernel"):
+        a = sdpa_raw(q, k, v, is_causal=True, segment_ids=segment_ids,
+                     positions=positions).reshape(B, S, nh * hd)
+    with jax.named_scope("attn.proj"):
+        x = x + _mm(a, lp["wo"])
 
-    h = _rms(x, lp["ln2"], c.rms_norm_eps)
+    with jax.named_scope("moe.route"):
+        h = _rms(x, lp["ln2"], c.rms_norm_eps)
     moe_out, aux = _moe_mlp(h, lp, c, mesh)
-    return x + moe_out, aux
+    with jax.named_scope("moe.combine"):
+        return x + moe_out, aux
 
 
 def forward_hidden(params, ids, config: MoEConfig, *,
@@ -384,11 +402,13 @@ def forward_hidden(params, ids, config: MoEConfig, *,
     semantics — segment-masked attention and per-document rope
     positions, exactly as in the llama family."""
     c = config
-    x = jnp.take(params["embed"], ids, axis=0)
-    cos, sin = _rope_tables(ids.shape[1], c.head_dim, theta=c.rope_theta)
-    if positions is not None:
-        from ..nn.functional.attention import gather_rope_rows
-        cos, sin = gather_rope_rows(cos, sin, positions)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0)
+        cos, sin = _rope_tables(ids.shape[1], c.head_dim,
+                                theta=c.rope_theta)
+        if positions is not None:
+            from ..nn.functional.attention import gather_rope_rows
+            cos, sin = gather_rope_rows(cos, sin, positions)
 
     def step(carry, lp):
         y, aux = _block(carry, lp, cos, sin, c, mesh,
@@ -399,7 +419,8 @@ def forward_hidden(params, ids, config: MoEConfig, *,
         step = jax.checkpoint(step, prevent_cse=False,
                               policy=remat_policy(c.remat_policy))
     x, auxes = lax.scan(step, x, params["layers"])
-    return _rms(x, params["ln_f"], c.rms_norm_eps), jnp.sum(auxes)
+    with jax.named_scope("head"):
+        return _rms(x, params["ln_f"], c.rms_norm_eps), jnp.sum(auxes)
 
 
 def forward(params, ids, config: MoEConfig, *,

@@ -18,12 +18,9 @@ overhead.
   ``<tmp>/paddle_tpu_profiles``); only the newest
   ``PADDLE_TPU_PROFILE_KEEP`` (default 4) are kept — oldest evicted,
   so a scrape-happy operator cannot fill the disk.
-- **Correlated**: while a capture is live, :func:`annotate_step`
-  wraps the engine's decode chunks and the sentinel loop's guarded
-  step in ``jax.profiler.StepTraceAnnotation`` (and
-  :func:`annotate` in ``TraceAnnotation``), so device events line up
-  with the host spans PR 5 already records. Outside a capture both
-  return a shared null context — one list read, no jax import.
+- **Correlated**: ``monitor.trace.span`` / ``step_span`` enter their
+  ``jax.profiler`` annotation in every session, this one included, so a
+  capture holds the program's host spans on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -35,8 +32,7 @@ import time
 from typing import Optional
 
 __all__ = ["CaptureBusy", "capture_sync", "capturing", "capture_root",
-           "keep_captures", "annotate", "annotate_step",
-           "list_captures"]
+           "keep_captures", "list_captures"]
 
 
 class CaptureBusy(RuntimeError):
@@ -50,19 +46,6 @@ _ACTIVE: list = [None]        # info dict while a capture window is open
 # Hard ceiling on one capture window: an operator typo'ing seconds=3600
 # must not pin the profiler (and its buffer growth) for an hour.
 MAX_SECONDS = 60.0
-
-
-class _Null:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _Null()
 
 
 def capture_root() -> str:
@@ -80,25 +63,6 @@ def keep_captures() -> int:
 
 def capturing() -> bool:
     return _ACTIVE[0] is not None
-
-
-def annotate(name: str, **attrs):
-    """``jax.profiler.TraceAnnotation`` while a capture is live, else a
-    shared null context (one list read, no jax import)."""
-    if _ACTIVE[0] is None:
-        return _NULL
-    import jax
-    return jax.profiler.TraceAnnotation(name, **attrs)
-
-
-def annotate_step(name: str, step_num):
-    """``jax.profiler.StepTraceAnnotation`` while a capture is live —
-    the wrapper that makes device trace steps line up with the host
-    spans (engine decode chunks, the guarded train step)."""
-    if _ACTIVE[0] is None:
-        return _NULL
-    import jax
-    return jax.profiler.StepTraceAnnotation(name, step_num=int(step_num))
 
 
 def list_captures(root: Optional[str] = None):

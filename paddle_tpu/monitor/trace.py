@@ -20,10 +20,19 @@ and instants (``instant(name, **attrs)``) with monotonic
   events plus a full ``monitor.snapshot()`` as JSON: what the system
   was doing in the seconds before it died.
 
-Gating: everything rides ``FLAGS_enable_monitor``. Flag off = every
-entry point is one cached-flag branch, the buffer stays empty, nothing
-is registered. Thread-safety: the ring buffer is a ``deque(maxlen=N)``
-— appends are GIL-atomic — with a lock around snapshots/clears.
+Gating: the RING rides ``FLAGS_enable_monitor``. Flag off = the buffer
+stays empty and nothing is registered. Thread-safety: the ring buffer
+is a ``deque(maxlen=N)`` — appends are GIL-atomic — with a lock around
+snapshots/clears.
+
+One primitive, two sinks: ``span`` (and its step form ``step_span``)
+ALSO enters a ``jax.profiler.TraceAnnotation`` — always, flag or no
+flag. With no profiler session that is a sub-microsecond no-op; inside
+ANY session (``/profile``'s, a benchmark's or a user's ``start_trace``)
+the span is in the device trace's own file, on its clock. Put a span at
+a boundary between layers, or between host work and waiting for the
+device — never inside a loop over slots or tokens. Instants stay
+ring-only.
 
 The flight-record DESTINATION is armed separately (a production launch
 script sets it once; tests arm it per-case):
@@ -43,10 +52,14 @@ import time
 from collections import deque
 from typing import List, Optional
 
+from jax.profiler import StepTraceAnnotation as _StepTraceAnnotation
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from ..core import flags as _flags
 
 __all__ = [
-    "span", "instant", "events", "clear", "capacity", "total_events",
+    "span", "step_span", "instant", "events", "clear", "capacity",
+    "total_events",
     "dump_flight_record", "flight_payload", "export_chrome_trace",
     "set_flight_record_path", "flight_record_path", "record_fault",
 ]
@@ -118,22 +131,28 @@ def enabled() -> bool:
 
 
 class span:
-    """Context manager recording one complete span into the ring when
-    the monitor is enabled — a single cached-flag branch otherwise.
+    """Context manager for one host span: a profiler annotation always
+    (module docstring), and one complete event in the ring when the
+    monitor is enabled.
 
     ``with trace.span("serving.prefill", group=4):`` — keyword attrs
-    land in the event's ``args`` and survive into flight records and
-    chrome traces. Reentrant and thread-safe; nesting is expressed by
-    timestamp containment (chrome's "X" events nest per tid)."""
+    land in the ring event's ``args`` and the annotation's metadata.
+    Reentrant and thread-safe; nesting is expressed by timestamp
+    containment (chrome's "X" events nest per tid)."""
 
-    __slots__ = ("name", "attrs", "_t0")
+    __slots__ = ("name", "attrs", "_t0", "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs or None
-        self._t0 = None
+        self._t0 = self._ann = None
+
+    def _annotation(self):
+        return _TraceAnnotation(self.name, **(self.attrs or {}))
 
     def __enter__(self):
+        self._ann = self._annotation()
+        self._ann.__enter__()
         # always (re)assign: a reused instance must not pair a stale t0
         self._t0 = time.perf_counter_ns() if _FLAG.value else None
         return self
@@ -143,7 +162,24 @@ class span:
             now = time.perf_counter_ns()
             _RING.add((self.name, "X", self._t0, now - self._t0,
                        threading.get_ident() & 0xFFFFFF, self.attrs))
+        self._ann.__exit__(*exc)
         return False
+
+
+class step_span(span):
+    """``span`` whose annotation is a ``StepTraceAnnotation``: the
+    profiler groups the device work it launches under step
+    ``step_num`` (engine decode chunks, the guarded train step)."""
+
+    __slots__ = ("step_num",)
+
+    def __init__(self, name: str, step_num, **attrs):
+        super().__init__(name, **attrs)
+        self.step_num = int(step_num)
+
+    def _annotation(self):
+        return _StepTraceAnnotation(self.name, step_num=self.step_num,
+                                    **(self.attrs or {}))
 
 
 def instant(name: str, **attrs):

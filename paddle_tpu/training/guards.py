@@ -132,6 +132,7 @@ def grad_numerics(grads):
     return out
 
 
+@jax.named_scope("optim")
 def step_health(loss, grads, inp, vocab_size: int, gnorm_cap):
     """(ok, health) of one guarded train step — the ONE anomaly
     definition shared by every family's guarded step. ``ok`` is True

@@ -63,7 +63,6 @@ import numpy as np
 
 from .. import monitor as _monitor
 from ..core import flags as _flags
-from ..monitor import profile_capture as _pcap
 from ..monitor import timeseries as _timeseries
 from ..monitor import trace as _trace
 from ..testing import faults as _faults
@@ -545,10 +544,9 @@ class SentinelLoop:
                 continue
             cap = jnp.asarray(self.sentinel.gnorm_cap(), jnp.float32)
             t_step = time.perf_counter()
-            # StepTraceAnnotation only while an on-demand profiler
-            # capture window is open (null context otherwise), so
-            # device trace steps correlate with the host spans
-            with _pcap.annotate_step("train.step", self.step):
+            # a step annotation in any open profiler session (a no-op
+            # outside one), so device trace steps line up with the host
+            with _trace.step_span("train.step", self.step):
                 params, opt, loss, health = self.step_fn(
                     self.params, self.opt_state, batch, cap)
                 if "numerics" in health and _monitor.enabled():

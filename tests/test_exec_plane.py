@@ -533,12 +533,48 @@ class TestProfileCapture:
         assert _get(f"{srv.url}/profile?seconds=0")[0] == 400
         assert _get(f"{srv.url}/profile?seconds=999")[0] == 400
 
-    def test_annotations_null_outside_capture(self):
-        a = pcap.annotate("x")
-        b = pcap.annotate_step("x", 3)
-        with a, b:
-            pass                        # null contexts, no jax import
-        assert not pcap.capturing()
+    def test_spans_annotate_inside_and_outside_capture(
+            self, mon, tmp_path, monkeypatch, fake_profiler):
+        """The merged primitive: ``trace.span`` / ``step_span`` enter
+        their profiler annotation whether or not a ``/profile`` capture
+        is live (the capture module has no annotation API of its own
+        and spans never ask it), and record in the ring beside it."""
+        assert not hasattr(pcap, "annotate")
+        assert not hasattr(pcap, "annotate_step")
+        seen = []
+
+        class Ann:
+            def __init__(self, name, **kw):
+                self.name, self.kw = name, kw
+
+            def __enter__(self):
+                seen.append((self.name, self.kw, pcap.capturing()))
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(trace, "_TraceAnnotation", Ann)
+        monkeypatch.setattr(trace, "_StepTraceAnnotation", Ann)
+
+        def spans():
+            with trace.span("x"), trace.step_span("y", 3):
+                pass
+
+        spans()                                   # no capture live
+        t = threading.Thread(target=pcap.capture_sync, args=(0.4,),
+                             kwargs={"base_dir": str(tmp_path)})
+        t.start()
+        deadline = time.time() + 2
+        while not pcap.capturing() and time.time() < deadline:
+            time.sleep(0.01)
+        spans()                                   # inside the window
+        t.join(timeout=10)
+        assert not t.is_alive() and not pcap.capturing()
+        assert seen == [("x", {}, False), ("y", {"step_num": 3}, False),
+                        ("x", {}, True), ("y", {"step_num": 3}, True)]
+        names = [e["name"] for e in trace.events()
+                 if e["name"] in ("x", "y")]
+        assert names == ["y", "x", "y", "x"]
 
     def test_bad_seconds_rejected(self):
         with pytest.raises(ValueError):

@@ -181,3 +181,85 @@ class TestDispatchGuards:
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
         finally:
             kernels.unregister()
+
+
+# ---------------------------------------------------------------------------
+# every pallas_call of the main paths carries its stable name
+# ---------------------------------------------------------------------------
+
+def _pallas_names(jaxpr, out=None):
+    """``name=`` of every ``pallas_call`` in a jaxpr, nested ones (scan
+    bodies, remat, custom_vjp, pjit) included."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_names(sub, out)
+    return out
+
+
+def _trace_train(packed):
+    from paddle_tpu.models import llama as L
+    cfg = L.llama_tiny(dtype=jnp.bfloat16, hidden_size=256,
+                       num_attention_heads=2, num_key_value_heads=2,
+                       max_position_embeddings=256, remat=True)
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+    batch = (ids, ids, ids, ids) if packed \
+        else jax.ShapeDtypeStruct((1, 129), jnp.int32)
+    return jax.make_jaxpr(lambda p, b: jax.value_and_grad(
+        lambda q: L.loss_fn(q, b, cfg))(p))(params, batch)
+
+
+def _trace_decode(_):
+    from paddle_tpu.inference.paged import init_pool, paged_decode_step
+    from paddle_tpu.models import llama as L
+    cfg = L.llama_tiny(dtype=jnp.bfloat16, hidden_size=256,
+                       num_attention_heads=2, num_key_value_heads=2)
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: init_pool(cfg, 8, 16))
+    bt = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    vec = jax.ShapeDtypeStruct((2,), jnp.int32)
+    return jax.make_jaxpr(lambda p, pk, pv, b, n, t: paged_decode_step(
+        L, p, pk, pv, b, n, t, cfg))(params, pool["k"], pool["v"], bt,
+                                     vec, vec)
+
+
+def _trace_rms(_):
+    from paddle_tpu.nn.functional import norm
+    x = jax.ShapeDtypeStruct((16, 128), jnp.float32)
+    w = jax.ShapeDtypeStruct((128,), jnp.float32)
+    return jax.make_jaxpr(lambda a, b: jax.value_and_grad(
+        lambda c: norm._FUSED_RMS_IMPL(c, b, 1e-6).sum())(a))(x, w)
+
+
+@pytest.mark.parametrize("trace,arg,want", [
+    (_trace_train, False, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    (_trace_train, True, {"flash_seg_fwd", "flash_seg_bwd_dq",
+                          "flash_seg_bwd_dkv"}),
+    (_trace_decode, None, {"paged_decode_attn"}),
+    (_trace_rms, None, {"rms_norm_fwd", "rms_norm_bwd"}),
+], ids=["train_step", "packed_train_step", "decode_step", "rms_norm"])
+def test_every_pallas_call_of_the_main_paths_is_named(monkeypatch, trace,
+                                                      arg, want):
+    """Jaxpr level (nothing compiles): with the dispatchers believing
+    they are on a TPU, every ``pallas_call`` the program traces carries
+    the kernel's stable name — what the compiled instruction is called
+    in a device trace, and what ``benchmark/layer_metrics/kern.*``
+    match. A kernel without ``name=`` would show the kernel function's
+    own name here and ``closed_call``/``checkpoint`` on the chip."""
+    from paddle_tpu import kernels
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "cached")
+    kernels.register()
+    try:
+        names = _pallas_names(trace(arg).jaxpr)
+    finally:
+        kernels.unregister()
+    assert names and set(names) == want, names

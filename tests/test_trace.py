@@ -118,6 +118,109 @@ class TestRing:
 
 
 # ---------------------------------------------------------------------------
+# the one span primitive: ring + profiler annotation
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Stub the two ``jax.profiler`` annotation classes ``trace.span``
+    enters; returns the log of ``(what, kind, name, kwargs)``. No test
+    opens a real profiler session in this process (after
+    test_device_plugin's fake PJRT plugin ``start_trace`` segfaults)."""
+    log = []
+
+    def stub(kind):
+        class Ann:
+            def __init__(self, name, **kw):
+                self.name, self.kw = name, kw
+
+            def __enter__(self):
+                log.append(("enter", kind, self.name, self.kw))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", kind, self.name, self.kw))
+                return False
+        return Ann
+
+    monkeypatch.setattr(trace, "_TraceAnnotation", stub("span"))
+    monkeypatch.setattr(trace, "_StepTraceAnnotation", stub("step"))
+    return log
+
+
+def span_tree(log):
+    """(name, depth) of every annotation in entry order, after checking
+    that they nest properly (each exit closes the innermost open)."""
+    out, stack = [], []
+    for what, _kind, name, _kw in log:
+        if what == "enter":
+            out.append((name, len(stack)))
+            stack.append(name)
+        else:
+            assert stack and stack[-1] == name, (name, stack)
+            stack.pop()
+    assert not stack, stack
+    return out
+
+
+class TestSpanAnnotation:
+    @pytest.mark.parametrize("flag", [False, True])
+    def test_span_enters_and_leaves_annotation(self, annotations, flag):
+        """The profiler annotation is entered flag or no flag; the ring
+        records only with the flag on, as before."""
+        monitor.reset()
+        pt.set_flags({"FLAGS_enable_monitor": flag})
+        try:
+            with trace.span("unit.outer", k=1):
+                with trace.step_span("unit.step", 7, chunk=4):
+                    pass
+            evs = trace.events()
+        finally:
+            pt.set_flags({"FLAGS_enable_monitor": False})
+            monitor.reset()
+        assert annotations == [
+            ("enter", "span", "unit.outer", {"k": 1}),
+            ("enter", "step", "unit.step", {"step_num": 7, "chunk": 4}),
+            ("exit", "step", "unit.step", {"step_num": 7, "chunk": 4}),
+            ("exit", "span", "unit.outer", {"k": 1})]
+        if flag:
+            assert [(e["name"], e.get("args")) for e in evs] == [
+                ("unit.step", {"chunk": 4}), ("unit.outer", {"k": 1})]
+        else:
+            assert evs == []
+
+    def test_annotation_leaves_when_the_body_raises(self, annotations):
+        with pytest.raises(KeyError):
+            with trace.span("unit.raises"):
+                raise KeyError("x")
+        assert [a[0] for a in annotations] == ["enter", "exit"]
+
+    def test_no_session_nothing_beyond_the_annotation(self):
+        """With no profiler session and the flag off a span is the real
+        ``jax.profiler`` annotation and nothing else: the classes are
+        JAX's own (no second system), the ring stays empty, nothing is
+        registered, and a span costs microseconds (a generous bound:
+        an inactive TraceAnnotation measures ~1 us)."""
+        import jax
+        assert trace._TraceAnnotation is jax.profiler.TraceAnnotation
+        assert trace._StepTraceAnnotation \
+            is jax.profiler.StepTraceAnnotation
+        monitor.reset()
+        n = 20_000
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with trace.span("unit.idle"):
+                pass
+        per_span = (time.perf_counter() - t0) / n
+        assert per_span < 50e-6, per_span
+        assert trace.events() == [] and trace.total_events() == 0
+        assert monitor.snapshot() == {}
+        from paddle_tpu.monitor import profile_capture
+        assert not profile_capture.capturing()
+        assert not hasattr(profile_capture, "annotate")
+
+
+# ---------------------------------------------------------------------------
 # flight recorder
 # ---------------------------------------------------------------------------
 
@@ -421,6 +524,101 @@ class TestServingLatency:
         eng.run(self._reqs(cfg, rng, lens=(3, 4), new=(3, 3)))
         assert monitor.snapshot() == {}
         assert trace.events() == []
+
+
+@pytest.mark.serving
+class TestServingSpans:
+    """The span tree of ``ServingEngine.step()`` as a profiler session
+    would record it (stubbed annotations, monitor off)."""
+
+    STEP = {"serving.step.retire", "serving.step.compact",
+            "serving.step.admit", "serving.step.reserve",
+            "serving.decode_chunk", "serving.spec_chunk"}
+    PHASES = ("build", "dispatch", "fetch", "emit")
+
+    def _run(self, annotations, **kw):
+        import jax
+        from paddle_tpu.inference import Request, ServingEngine
+        from paddle_tpu.models import llama as L
+        cfg = L.llama_tiny()
+        params = L.init_params(cfg, jax.random.PRNGKey(3))
+        eng = ServingEngine(L, params, cfg, num_slots=2, max_len=32,
+                            page_size=4, decode_chunk=2, **kw)
+        rng = np.random.default_rng(2)
+        # two same-bucket prompts (one prefill group), then a third
+        # that joins later through the same prefill program
+        for rid, n in enumerate((3, 4, 3)):
+            eng.submit(Request(
+                rid=rid, prompt=rng.integers(0, cfg.vocab_size,
+                                             (n,)).astype(np.int32),
+                max_new_tokens=5))
+        steps, self.raw = [], []
+        while True:
+            del annotations[:]
+            busy = eng.step()
+            steps.append(span_tree(annotations))
+            self.raw += annotations
+            if not busy:
+                break
+        assert sorted(eng.outputs) == [0, 1, 2]
+        assert trace.events() == []          # monitor off: ring empty
+        return steps
+
+    def test_step_span_names_and_nesting(self, annotations):
+        steps = self._run(annotations)
+        first = steps[0]
+        # every step is one serving.step root; its children are the
+        # phases, in order
+        for tree in steps:
+            assert tree[0] == ("serving.step", 0)
+            assert {n for n, d in tree if d == 1} <= self.STEP
+            assert all(n.startswith("serving.") for n, _ in tree)
+        assert [n for n, d in first if d == 1] == [
+            "serving.step.retire", "serving.step.compact",
+            "serving.step.admit", "serving.step.reserve",
+            "serving.decode_chunk"]
+        # the prefill of the admitted group sits inside admit, its four
+        # phases inside it, and the first call compiles
+        i = first.index(("serving.prefill", 2))
+        assert first[i - 1] == ("serving.step.admit", 1)
+        assert first[i + 1:i + 6] == [
+            ("serving.prefill.build", 3), ("serving.prefill.dispatch", 3),
+            ("serving.compile", 4), ("serving.prefill.fetch", 3),
+            ("serving.prefill.emit", 3)]
+        j = first.index(("serving.decode_chunk", 1))
+        assert first[j + 1:] == [
+            ("serving.decode_chunk.build", 2),
+            ("serving.decode_chunk.dispatch", 2), ("serving.compile", 3),
+            ("serving.decode_chunk.fetch", 2),
+            ("serving.decode_chunk.emit", 2)]
+
+    def test_compile_span_on_first_use_only(self, annotations):
+        steps = self._run(annotations)
+        flat = [n for tree in steps for n, _ in tree]
+        chunks = flat.count("serving.decode_chunk")
+        prefills = flat.count("serving.prefill")
+        assert chunks >= 3 and prefills == 2
+        # one program per (group, bucket) prefill shape and one decode
+        # chunk program ran: each compiled once, on its first call
+        programs = 2 + 1       # prefill g2 and g1, one chunk length
+        assert flat.count("serving.compile") == programs
+        for phase in self.PHASES:
+            assert flat.count(f"serving.decode_chunk.{phase}") == chunks
+            assert flat.count(f"serving.prefill.{phase}") == prefills
+        # a later step of the warm engine has no compile in it
+        assert "serving.compile" not in [n for n, _ in steps[-2]]
+
+    def test_decode_chunk_is_a_step_annotation(self, annotations):
+        """The chunk carries the profiler's step number (the decode
+        steps made so far), so a device trace groups by chunk."""
+        self._run(annotations)
+        chunks = [a for a in self.raw if a[0] == "enter"
+                  and a[2] == "serving.decode_chunk"]
+        assert len(chunks) >= 3
+        nums = [kw["step_num"] for _, _, _, kw in chunks]
+        assert nums == sorted(nums) and nums[0] == 0 and nums[1] == 2
+        for _what, kind, _name, kw in chunks:
+            assert kind == "step" and kw["chunk"] == 2
 
 
 # ---------------------------------------------------------------------------
