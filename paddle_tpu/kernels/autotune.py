@@ -430,11 +430,13 @@ def ce_chunk(n_tokens, hidden, vocab, dtype,
 
 # --------------------------------------------------------------------------
 # paged-attention page-size tuning (same cache/policy machinery). The page
-# is the KV block the ragged decode kernel processes per grid step: small
-# pages waste less pool memory on ragged tails but pay more grid steps
-# and DMA descriptors per token; large pages amortise the DMA but strand
-# capacity. Like the flash blocks, the right point is measured on the
-# real chip, not guessed.
+# is the unit the pool allocates and the unit the decode kernel copies
+# out of HBM (one DMA a page, all KV heads); what the kernel computes on
+# is a block of pages whose size it derives itself from the shapes, so
+# the page no longer sets its step count. Small pages waste less pool
+# memory on ragged tails but cost more DMA descriptors per token; large
+# pages amortise the descriptors but strand capacity. Like the flash
+# blocks, the right point is measured on the real chip, not guessed.
 # --------------------------------------------------------------------------
 
 PAGED_DEFAULT_PAGE = 16

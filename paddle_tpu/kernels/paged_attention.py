@@ -8,25 +8,38 @@ block table, and one decode query attends over exactly its own ragged
 length — no batch-uniform max-length padding in either HBM traffic or
 FLOPs.
 
-TPU-native design (follows flash_attention.py's canonical pattern):
-- Grid ``(batch, kv_heads, max_pages)`` with the page axis sequential per
-  core, carrying the online-softmax running max/denominator in VMEM
-  scratch exactly like the flash forward.
-- The block table and per-request lengths ride a
-  ``PrefetchScalarGridSpec`` scalar prefetch: the K/V BlockSpec index
-  maps read ``block_table[b, p]`` to aim the automatic HBM->VMEM DMA at
-  the right page — the gather IS the BlockSpec, no in-kernel DMA code.
-- Pages past a sequence's length are predicated off (``pl.when``), so a
-  short sequence in a long-batch grid costs control flow only; the
-  final partial page is masked per-position. A length of 0 (empty slot
-  in the serving engine's fixed slot grid) produces a zero output row.
+TPU-native design: the kernel's work follows the live tokens.
+- Grid ``(batch,)``, sequential. The block table and the lengths ride a
+  ``PrefetchScalarGridSpec`` scalar prefetch; the two page pools stay in
+  HBM (``memory_space=pl.ANY``) and the kernel fetches pages itself.
+- A sequence is read in BLOCKS of N pages: one ``make_async_copy`` a
+  page carries all its KV heads (``kv_heads * page_size * head_dim``
+  contiguous elements of the pool) into a double-buffered VMEM scratch
+  laid out ``[kv_heads, N, page_size, head_dim]``, so a head's pages of
+  a block are ``[N * page_size, head_dim]`` without moving data.
+- The loop over a sequence's blocks is a ``fori_loop`` of
+  ``cdiv(length, N * page_size)`` trips. A table entry past the
+  sequence's live pages never becomes an index, a copy or a trip; a
+  length of 0 (an empty slot of the serving engine's fixed slot grid)
+  makes no trip and writes a zero row.
+- The next block's copies are started before the current block is
+  computed on; after a sequence's last block that is the first block of
+  the next sequence that holds a token, so the hand-over between grid
+  steps leaves no bubble (which buffer is in flight rides in SMEM).
+- A trip computes on the whole block, all KV heads batched: one
+  ``[kv, gp, hd] x [kv, N*ps, hd]`` score product, the positions past
+  ``length`` masked, ONE online-softmax update (float32 scores, running
+  max, sum and accumulator in VMEM scratch), then the PV product with
+  ``p`` cast to the pages' dtype.
 - GQA: queries reshape to [B, kv_heads, group, head_dim]; the group dim
   is zero-padded to the sublane tile so every matmul is legal.
+- N follows from the shapes (``_pages_per_block``): what a fixed VMEM
+  budget holds of K and V, two buffers each, capped in tokens.
 
-Layouts: pages are ``[num_pages, kv_heads, page_size, head_dim]`` (the
-kv-head axis OUTSIDE the page axis so a (1, 1, page, hd) block satisfies
-Mosaic's last-two-dims tiling rule for any page size); q is
-``[batch, num_heads, head_dim]`` — one decode position per sequence.
+Layouts: pages are ``[num_pages, kv_heads, page_size, head_dim]`` (one
+page of every KV head is contiguous: 32 KiB at 8 heads of 128 and pages
+of 16 in bf16); q is ``[batch, num_heads, head_dim]`` — one decode
+position per sequence.
 
 ``paged_attention_ref`` is the pure-jnp gather fallback — identical
 math, runs on every backend — which tier-1 exercises on CPU and the
@@ -53,83 +66,154 @@ def _sublane(dtype) -> int:
 # kernel
 # ---------------------------------------------------------------------------
 
-def _page_scale(s_ref, pi):
-    """This page's scale, as a [1, 1] tile, out of the sequence's
-    [1, max_pages] row of per-page scales: a masked lane reduction
-    (Mosaic has no dynamic lane index)."""
-    row = s_ref[0, 0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    return jnp.sum(jnp.where(lane == pi, row, 0.0), axis=1, keepdims=True)
+# What one block may hold. The block's K and V, two buffers each, fit
+# _VMEM_BUDGET: half of the 16 MiB of VMEM a kernel gets by default, the
+# rest left to q, the output, the score tiles and Mosaic's temporaries.
+# _BLOCK_TOKENS caps the block: the arithmetic of a trip is paid on the
+# whole block, and a sequence's last block is on average half dead. On
+# the v5e blocks of 512 tokens read 36k live tokens of 64 sequences 2%
+# faster than blocks of 256 and a full pool 16% faster (PERF.md, PR 25).
+_VMEM_BUDGET = 8 * 1024 * 1024
+_BLOCK_TOKENS = 512
+
+
+def _pages_per_block(kv, ps, hd, itemsize, maxp) -> int:
+    """N, the pages one fetch and one online-softmax update cover: what
+    the VMEM budget and the token cap allow, at least one page and at
+    most a slot's whole table."""
+    by_vmem = _VMEM_BUDGET // (4 * kv * ps * hd * itemsize)
+    return max(1, min(by_vmem, _BLOCK_TOKENS // ps, maxp))
 
 
 def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
-                   quant):
+                   pages_per_block, quant):
     if quant:
-        # int8 pages ride with this (sequence, kv-head)'s row of per-page
-        # scales: dequant is one multiply FOLDED into the dots — the page
-        # DMA itself stays int8
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc, m_s, l_s = refs
+        (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
+         kbuf, vbuf, sems, slot_ref, acc, m_s, l_s) = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s = refs
+        (q_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sems, slot_ref, acc, m_s, l_s) = refs
+    ps, npb = page_size, pages_per_block
+    tb = npb * ps                                    # tokens a block
+    kv, hd = k_hbm.shape[1], k_hbm.shape[3]
+    gp = q_ref.shape[2]
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    nb = pl.num_programs(0)
     length = len_ref[b]
-    npages = (length + page_size - 1) // page_size
+    nblk = pl.cdiv(length, tb)
 
-    @pl.when(pi == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
+    def block_dma(seq, blk, slot, start):
+        """Start, or wait for, the copies of one block's LIVE pages: page
+        ``blk * npb + j`` of sequence ``seq`` into row ``j`` of buffer
+        ``slot``, all KV heads of the page in one copy. A table entry
+        past the sequence's pages is never read."""
+        live = pl.cdiv(len_ref[seq], ps) - blk * npb
+        for j in range(npb):
+            @pl.when(j < live)
+            def _(j=j):
+                # a wait needs the copy's shape only, not its source
+                page = bt_ref[seq * max_pages + blk * npb + j] if start else 0
+                for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[page], buf.at[slot, :, j], sems.at[s, slot])
+                    cp.start() if start else cp.wait()
 
-    # a page wholly past this sequence's length contributes nothing —
-    # the ragged skip that makes mixed-length batches cheap
-    @pl.when(pi < npages)
-    def _body():
-        q = q_ref[0, 0]                                  # [gp, hd]
-        k = k_ref[0, 0]                                  # [ps, hd]
+    def next_live(seq):
+        """The first sequence after ``seq`` that holds a token, or nb."""
+        return jax.lax.while_loop(
+            lambda s: jnp.logical_and(
+                s < nb, len_ref[jnp.minimum(s, nb - 1)] == 0),
+            lambda s: s + 1, seq + 1)
+
+    @pl.when(b == 0)
+    def _first():
+        # a row of a buffer that no copy has filled yet is multiplied by
+        # p == 0 in a last block's PV product: it must not hold a NaN.
+        # (K needs no such care: its scores are replaced, not scaled.)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        first = next_live(-1)
+
+        @pl.when(first < nb)
+        def _():
+            block_dma(first, 0, 0, start=True)
+
+    if quant:
+        # a block's per-page scales, [kv, npb], spread over the pages'
+        # columns by a 0/1 product (exact in float32), then over the
+        # query rows: [kv, gp, tb]
+        col = jax.lax.broadcasted_iota(jnp.int32, (npb, tb), 1) // ps
+        row = jax.lax.broadcasted_iota(jnp.int32, (npb, tb), 0)
+        spread = (col == row).astype(jnp.float32)
+
+        def cols(s_ref, i):
+            c = jax.lax.dot_general(
+                s_ref[0, i], spread, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            return jnp.stack([jnp.broadcast_to(c[h:h + 1], (gp, tb))
+                              for h in range(kv)])
+
+    acc[...] = jnp.zeros_like(acc)
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    slot0 = slot_ref[0]
+
+    def trip(i, carry):
+        cur = (slot0 + i) % 2
+        # the next block's copies go out before this block is computed
+        # on: this sequence's next block, or after its last block the
+        # first block of the next sequence that holds a token
+        last = i + 1 == nblk
+        seq_n = jax.lax.cond(last, lambda: next_live(b), lambda: b)
+        blk_n = jnp.where(last, 0, i + 1)
+
+        @pl.when(seq_n < nb)
+        def _():
+            block_dma(seq_n, blk_n, 1 - cur, start=True)
+
+        block_dma(b, i, cur, start=False)
+
+        pos = i * tb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb), 2)
+        valid = pos < length
+        q = q_ref[0]                                     # [kv, gp, hd]
+        # ps is a multiple of the sublane tile: a head's pages of the
+        # block are [tb, hd] without moving data
+        k = kbuf[cur].reshape(kv, tb, hd)
+        v = vbuf[cur].reshape(kv, tb, hd)
         if quant:
-            # every code in this (page, head) block shares ONE scale,
-            # so dot(q, codes) * (ks*scale) == dot(q, deq(codes)) * scale
-            s = jax.lax.dot_general(
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) \
-                * (_page_scale(ks_ref, pi) * scale)      # [gp, ps]
-        else:
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-        pos = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, _NEG_INF)         # partial last page
+            # every code of a (page, head) shares ONE scale, so
+            # dot(q, codes) * ks == dot(q, deq(codes)): the pages stay
+            # int8 in HBM and in VMEM
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [kv, gp, tb]
+        if quant:
+            s = s * cols(ks_ref, i)
+        s = jnp.where(valid, s, _NEG_INF)                # last block's tail
 
-        m_prev = m_s[:, :1]
-        l_prev = l_s[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_prev = m_s[:, :, :1]
+        l_prev = l_s[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_s[:] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_s.shape)
+        l_s[...] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=2, keepdims=True), l_s.shape)
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
         if quant:
-            acc[:] = acc[:] * alpha + jax.lax.dot_general(
-                p, v_ref[0, 0].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) \
-                * _page_scale(vs_ref, pi)
-        else:
-            acc[:] = acc[:] * alpha + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, 0],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+            p = p * cols(vs_ref, i)                      # V's scale, into p
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return carry
 
-    @pl.when(pi == max_pages - 1)
-    def _finalize():
-        l = l_s[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)                  # empty slot -> 0
-        o_ref[0, 0] = (acc[:] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, nblk, trip, 0)
+    slot_ref[0] = (slot0 + nblk) % 2
+
+    l = l_s[:, :, :1]
+    l = jnp.where(l == 0.0, 1.0, l)                      # empty slot -> 0
+    o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -138,15 +222,15 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """Paged decode attention. q: [B, num_heads, head_dim]; k_pages /
     v_pages: [num_pages, kv_heads, page_size, head_dim]; block_tables:
     [B, max_pages] page ids (entries past a sequence's pages may hold
-    any value — they are clamped and masked); lengths: [B] valid KV
-    positions per sequence (0 = empty slot -> zero output row).
+    any value — the kernel never reads them); lengths: [B] valid KV
+    positions per sequence (0 = empty slot -> zero output row; more than
+    the table holds counts as the whole table).
 
     With ``k_scales``/``v_scales`` ([num_pages, kv_heads] f32, both or
     neither) the pages are int8 codes (FLAGS_serving_kv_quant): the
-    block table gathers each sequence's scales into a [max_pages] row
-    per kv head (a tiny XLA gather), the row is fetched once per
-    (sequence, kv head), and dequantization folds into the two dots —
-    HBM page traffic stays int8. Returns [B, num_heads, head_dim]."""
+    block table gathers each sequence's scales block by block (a tiny
+    XLA gather), and dequantization folds into the two dots — page
+    traffic stays int8. Returns [B, num_heads, head_dim]."""
     quant = k_scales is not None
     B, nh, hd = q.shape
     P, kv, ps, _ = k_pages.shape
@@ -156,58 +240,68 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     gp = max(sub, (g + sub - 1) // sub * sub)
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
+    npb = _pages_per_block(kv, ps, hd, jnp.dtype(k_pages.dtype).itemsize,
+                           maxp)
 
     qg = q.reshape(B, kv, g, hd)
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    # clamp: padded/garbage table entries must still name a real page for
-    # the BlockSpec DMA; their contribution is masked by ``lengths``
-    bt = jnp.clip(block_tables, 0, P - 1).reshape(-1).astype(jnp.int32)
+    # a live entry off the pool would be a DMA out of bounds; the dead
+    # ones are clamped with them and read by nothing but the scale gather
+    bt = jnp.clip(block_tables, 0, P - 1).astype(jnp.int32)
+    lengths = jnp.minimum(lengths.astype(jnp.int32), maxp * ps)
 
-    def _page_map(b, h, p, bt_, ln_, mp=maxp):
-        return (bt_[b * mp + p], h, 0, 0)
+    def per_seq(b, bt_, ln_):
+        return (b, 0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, gp, hd),
-                     lambda b, h, p, bt_, ln_: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, ps, hd), _page_map),
-        pl.BlockSpec((1, 1, ps, hd), _page_map),
-    ]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, kv, gp, hd), per_seq), hbm, hbm]
     operands = [qg, k_pages, v_pages]
     if quant:
-        # Mosaic refuses a (1, 1) block of the [P, kv] scale plane (the
-        # last two block dims must divide (8, 128) or equal the array's),
-        # so the plane is gathered per sequence here: [B, kv, 1, maxp],
-        # whose (1, 1, 1, maxp) block is legal by the "equal" arm
-        def rows(scales):
-            per_seq = scales.astype(jnp.float32)[bt.reshape(B, maxp)]
-            return jnp.swapaxes(per_seq, 1, 2)[:, :, None, :]
+        # the scale planes, gathered per sequence and cut into the
+        # kernel's blocks here: [B, blocks, kv, npb], one block's
+        # [kv, npb] tile a dynamic index on a leading dim away. A dead
+        # entry's scale is whatever its clamped index names: it reads 0,
+        # since the spreading product would carry a NaN to live columns
+        nblocks = -(-maxp // npb)
+        padded = jnp.pad(bt, ((0, 0), (0, nblocks * npb - maxp)))
+        live = (jnp.arange(nblocks * npb)[None, :] * ps
+                < lengths[:, None])[:, :, None]
 
-        row_spec = pl.BlockSpec((1, 1, 1, maxp),
-                                lambda b, h, p, bt_, ln_: (b, h, 0, 0))
+        def rows(scales):
+            per = jnp.where(live, scales.astype(jnp.float32)[padded], 0.0)
+            return jnp.swapaxes(per.reshape(B, nblocks, npb, kv), 2, 3)
+
+        row_spec = pl.BlockSpec((1, nblocks, kv, npb), per_seq)
         in_specs += [row_spec, row_spec]
         operands += [rows(k_scales), rows(v_scales)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, kv, maxp),
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, gp, hd),
-                               lambda b, h, p, bt_, ln_: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, kv, gp, hd), per_seq),
         scratch_shapes=[
-            pltpu.VMEM((gp, hd), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
+            pltpu.VMEM((2, kv, npb, ps, hd), k_pages.dtype),
+            pltpu.VMEM((2, kv, npb, ps, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),     # (K | V, buffer)
+            pltpu.SMEM((1,), jnp.int32),         # the buffer in flight
+            pltpu.VMEM((kv, gp, hd), jnp.float32),
+            pltpu.VMEM((kv, gp, 128), jnp.float32),
+            pltpu.VMEM((kv, gp, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=ps,
-                          max_pages=maxp, quant=quant),
+                          max_pages=maxp, pages_per_block=npb, quant=quant),
         name="paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv, gp, hd), q.dtype),
+        # the buffer in flight is handed from one sequence to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(bt, lengths.astype(jnp.int32), *operands)
+    )(bt.reshape(-1), lengths, *operands)
     return out[:, :, :g, :].reshape(B, nh, hd)
 
 
@@ -272,12 +366,13 @@ def supported(q, k_pages, block_tables, quant=False) -> bool:
                                   jnp.dtype(jnp.bfloat16)):
         return False
     if quant:
-        # int8 pages: the K/V block's sublane tile is 32 rows (1-byte
-        # dtype), and only int8 codes are a valid quantized pool
+        # int8 pages: a page's sublane tile is 32 rows (1-byte dtype),
+        # and only int8 codes are a valid quantized pool
         if jnp.dtype(k_pages.dtype) != jnp.dtype(jnp.int8) or ps % 32:
             return False
     elif jnp.dtype(k_pages.dtype) == jnp.dtype(jnp.int8):
         return False     # int8 pool without scales is a contract breach
-    # page rows must cover the dtype's sublane tile (16 for bf16) and
-    # the lane dim should fill VREGs; anything smaller falls back
-    return hd % 8 == 0 and ps % _sublane(q.dtype) == 0 and P >= 1
+    # a page is copied out of HBM whole, so its rows fill lane tiles
+    # (Mosaic: "Slice shape along dimension 3 must be aligned to tiling
+    # (128)") and cover the dtype's sublane tile (16 for bf16)
+    return hd % 128 == 0 and ps % _sublane(q.dtype) == 0 and P >= 1

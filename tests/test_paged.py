@@ -96,10 +96,135 @@ class TestKernel:
                                    rtol=1e-5, atol=1e-5)
 
     def test_supported_guard(self):
-        q, kp, _, bt, _ = self._case(jnp.float32)
+        q, kp, _, bt, _ = self._case(jnp.float32, hd=128)
         assert PA.supported(q, kp, bt)
         assert not PA.supported(q.astype(jnp.int8), kp, bt)
         assert not PA.supported(q[:, :3], kp, bt)      # nh % kv != 0
+        # a page leaves HBM whole: its rows must fill the 128 lanes
+        q, kp, _, bt, _ = self._case(jnp.float32, hd=64)
+        assert not PA.supported(q, kp, bt)
+
+
+_BLOCK = 64     # tokens a block in TestKernelBlocks: 4 pages of 16, 2 of 32
+_MAXP = {16: 10, 32: 5}    # a table of 160 tokens: two blocks and a half
+
+
+def _paged_case(arm, g, hd, ps, lengths, seed=0, kv=2):
+    """q, pools, table, lengths and the scale keywords of one case; every
+    sequence owns exactly the pages its length needs, from a shuffled
+    pool with pages to spare, and the table's dead entries name one of
+    the spare pages."""
+    rng = np.random.default_rng(seed)
+    B, maxp = len(lengths), _MAXP[ps]
+    P = B * maxp + 7
+    q = jnp.asarray(rng.normal(size=(B, g * kv, hd)), jnp.bfloat16)
+    kw = {}
+    if arm == "int8":
+        kp, vp = (jnp.asarray(rng.integers(-127, 128, (P, kv, ps, hd)),
+                              jnp.int8) for _ in range(2))
+        kw = {n: jnp.asarray(rng.uniform(0.004, 0.02, (P, kv)), jnp.float32)
+              for n in ("k_scales", "v_scales")}
+    else:
+        kp, vp = (jnp.asarray(rng.normal(size=(P, kv, ps, hd)),
+                              jnp.bfloat16) for _ in range(2))
+    perm, at = rng.permutation(P), 0
+    bt = np.full((B, maxp), perm[-1], np.int32)
+    for b, n in enumerate(lengths):
+        need = -(-n // ps)
+        bt[b, :need] = perm[at:at + need]
+        at += need
+    owned = np.zeros(P, bool)
+    owned[perm[:at]] = True
+    return (q, kp, vp, jnp.asarray(bt), jnp.asarray(lengths, jnp.int32),
+            kw, owned)
+
+
+def _f32(x):
+    return np.asarray(x).astype(np.float32)
+
+
+class TestKernelBlocks:
+    """The kernel (interpret mode) against the gather reference where its
+    loop turns: no trip, one token, a page, a block, a block and a token,
+    the whole table; and that nothing past a sequence's live pages is
+    ever read."""
+
+    # 0 | 1 | one page | one block | one block + 1 | the full table
+    LENGTHS = {16: (0, 1, 16, _BLOCK, _BLOCK + 1, 160),
+               32: (0, 1, 32, _BLOCK, _BLOCK + 1, 160)}
+
+    @pytest.fixture(autouse=True)
+    def _small_blocks(self, monkeypatch):
+        monkeypatch.setattr(PA, "_BLOCK_TOKENS", _BLOCK)
+
+    def _check(self, case):
+        q, kp, vp, bt, ln, kw, _ = case
+        got = PA.ragged_paged_attention(q, kp, vp, bt, ln, interpret=True,
+                                        **kw)
+        want = PA.paged_attention_ref(q, kp, vp, bt, ln, **kw)
+        np.testing.assert_allclose(_f32(got), _f32(want), rtol=2e-2,
+                                   atol=2e-2)
+        empty = np.asarray(ln) == 0
+        np.testing.assert_array_equal(_f32(got)[empty], 0.0)
+        return got
+
+    @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("bf16", 32),
+                                        ("int8", 32)])
+    @pytest.mark.parametrize("hd", [64, 128])
+    @pytest.mark.parametrize("g", [1, 4, 8])
+    def test_every_turn_of_the_loop(self, arm, ps, hd, g):
+        """All six lengths in one call, so a sequence's last block hands
+        over to the next sequence's first in every way it can."""
+        self._check(_paged_case(arm, g, hd, ps, self.LENGTHS[ps]))
+
+    @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("int8", 32)])
+    @pytest.mark.parametrize("which", range(6), ids=[
+        "empty", "one-token", "one-page", "one-block", "one-block-plus-1",
+        "full-table"])
+    def test_one_length_between_empty_slots(self, arm, ps, which):
+        n = self.LENGTHS[ps][which]
+        self._check(_paged_case(arm, 4, 128, ps, (0, n, 0), seed=which))
+
+    @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("int8", 32)])
+    def test_at_the_default_block(self, arm, ps, monkeypatch):
+        monkeypatch.undo()
+        self._check(_paged_case(arm, 4, 128, ps, (160, 0, 33, 1)))
+
+    @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("int8", 32)])
+    def test_dead_table_entries_are_never_indices(self, arm, ps):
+        """Entries past a sequence's live pages hold -1 and P + 5: the
+        result is the clean table's, bit for bit."""
+        case = _paged_case(arm, 4, 128, ps, self.LENGTHS[ps], seed=5)
+        q, kp, vp, bt, ln, kw, _ = case
+        clean = self._check(case)
+        live = (np.arange(bt.shape[1])[None, :] * ps
+                < np.asarray(ln)[:, None])
+        junk = np.where(np.arange(bt.size).reshape(bt.shape) % 2,
+                        -1, kp.shape[0] + 5)
+        dirty = PA.ragged_paged_attention(
+            q, kp, vp, jnp.asarray(np.where(live, bt, junk), jnp.int32),
+            ln, interpret=True, **kw)
+        np.testing.assert_array_equal(_f32(dirty), _f32(clean))
+
+    @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("int8", 32)])
+    def test_pages_no_sequence_owns_are_never_read(self, arm, ps):
+        """Every page no sequence owns is NaN (for int8 codes: its
+        scales), and the dead entries name such pages: the output is
+        finite and the clean pool's."""
+        case = _paged_case(arm, 4, 128, ps, self.LENGTHS[ps], seed=6)
+        q, kp, vp, bt, ln, kw, owned = case
+        clean = self._check(case)
+        assert not owned[np.asarray(bt)[0, -1]]    # a dead entry's page
+        if arm == "int8":
+            kw = {n: jnp.where(jnp.asarray(owned)[:, None], x, jnp.nan)
+                  for n, x in kw.items()}
+        else:
+            own = jnp.asarray(owned)[:, None, None, None]
+            kp, vp = jnp.where(own, kp, jnp.nan), jnp.where(own, vp, jnp.nan)
+        got = PA.ragged_paged_attention(q, kp, vp, bt, ln, interpret=True,
+                                        **kw)
+        assert np.isfinite(_f32(got)).all()
+        np.testing.assert_array_equal(_f32(got), _f32(clean))
 
 
 class TestAllocator:

@@ -219,7 +219,7 @@ class TestKVQuantKernel:
                                    rtol=1e-6, atol=1e-6)
 
     def test_supported_quant_guard(self):
-        q, kq, vq, ks, vs, bt, ln = self._case()
+        q, kq, vq, ks, vs, bt, ln = self._case(hd=128)
         assert PA.supported(q, kq, bt, quant=True)
         # int8 pages without the scales arm are a contract breach
         assert not PA.supported(q, kq, bt)
