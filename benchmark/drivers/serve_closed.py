@@ -71,6 +71,8 @@ def run(r) -> dict:
         "attempted": attempted, "failed": failed,
         "end_to_end": {"serve_tok_s": tokens / t_close},
         "counters": sv.window_counters(c0, c1, t_close),
+        "compared": {**sv.check["numbers"], "requests_failed": [failed, 0],
+                     "requests_rejected": [len(sv.rejected), 0]},
         "info": {"check": sv.check, "warm": sv.warmed,
                  "rejected": sv.rejected[:5]},
     }

@@ -26,7 +26,7 @@ def run(r) -> dict:
     r.mark("weights")
     # checked before the moments exist, so that the reference's float32
     # copies have the room
-    chk = check.train_check(family, cfg, r.conf, params, r.seed)
+    chk = check.train_check(r.arch, family, cfg, r.conf, params, r.seed)
     say(f"reference check: {chk}")
     r.mark("reference_check")
     opt = jax.jit(lambda p: L.adamw_init(
@@ -78,10 +78,11 @@ def run(r) -> dict:
         flops = r.conf["model_flops_per_token"]["flops"]
         say(f"model FLOPs a token {flops:.4g} (at {mix['seq_len']} tokens): "
             f"{tokens / elapsed * flops / 1e12:.2f} TFLOP/s a job")
+    failed = sum(not math.isfinite(x) for x in losses)
     return {
         "correct": chk["ok"] and finite and steps > 0,
-        "attempted": steps, "failed": sum(not math.isfinite(x)
-                                          for x in losses),
+        "attempted": steps, "failed": failed,
+        "compared": {**chk["numbers"], "losses_not_finite": [failed, 0]},
         "end_to_end": {"train_tok_s": tokens / elapsed},
         "counters": {"train.steps": steps, "train.tokens": tokens,
                      "window_s": elapsed},

@@ -2,6 +2,11 @@
 reference, during set-up, at the widths the cell runs. Logits and losses
 are compared, never sampled tokens: with random weights the largest
 logit changes on rounding.
+
+The reference is the configuration's own: ``arch`` is the module its
+file names (``benchmark/architectures/<name>.py``), which gives the
+reference's ``layer``, ``logits_at`` and ``loss`` and, for serving, the
+three calls into the program over a cache this file never opens.
 """
 from __future__ import annotations
 
@@ -9,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import reference as R
 from .traffic import seed_of
 
 
@@ -20,19 +24,17 @@ def _leaf_norms(tree) -> dict:
         for k, g in jax.tree_util.tree_leaves_with_path(tree)}
 
 
-def serve_check(family, cfg, conf: dict, params, page_size: int,
+def serve_check(arch, family, cfg, conf: dict, params, page_size: int,
                 seed: int, reference_params=None) -> dict:
-    """The engine's own programs (``inference/paged.py``: prefill, then
-    decode steps through a paged pool, greedy) against the reference's
-    full forward pass over prompt plus the tokens decoded. Two errors,
-    each with its band: the largest logit difference over the largest
-    reference logit (one logit far off), and the difference's rms over
-    the logits' rms (the whole pass a little off; the steadier of the
-    two). ``reference_params`` (a test's) gives the reference other
+    """The engine's own programs (``arch.prefill``, then greedy
+    ``arch.decode_step``s, through the cache ``arch.make_cache`` makes:
+    one pytree, handed back in and donated, never opened) against the
+    reference's full forward pass over prompt plus the tokens decoded.
+    Two errors, each with its band: the largest logit difference over the
+    largest reference logit (one logit far off), and the difference's rms
+    over the logits' rms (the whole pass a little off; the steadier of
+    the two). ``reference_params`` (a test's) gives the reference other
     weights than the program."""
-    from paddle_tpu.inference.paged import (init_pool, paged_decode_step,
-                                            paged_prefill)
-
     chk = conf["serve"]["check"]
     n, plen, steps = chk["prompts"], chk["prompt_len"], chk["decode_steps"]
     ps = page_size
@@ -43,35 +45,34 @@ def serve_check(family, cfg, conf: dict, params, page_size: int,
                                     dtype=np.int32)
     padded = np.zeros((n, s_pad), np.int32)
     padded[:, :plen] = ids
-    pool = init_pool(cfg, n * per_seq, ps)
+    cache = arch.make_cache(cfg, n * per_seq, ps, n)
 
     prefill = jax.jit(
-        lambda p, i, pk, pv, r, sl: paged_prefill(family, p, i, cfg, pk, pv,
-                                                  r, sl),
-        donate_argnums=(2, 3))
+        lambda p, i, c, r, sl: arch.prefill(family, p, i, cfg, c, r, sl),
+        donate_argnums=(2,))
     decode = jax.jit(
-        lambda p, pk, pv, bt, ln, tok: paged_decode_step(
-            family, p, pk, pv, bt, ln, tok, cfg),
-        donate_argnums=(1, 2))
-    pk, pv, logits = prefill(params, jnp.asarray(padded), pool["k"],
-                             pool["v"], jnp.asarray(rows[:, :s_pad // ps]),
-                             jnp.full((n,), plen, jnp.int32))
+        lambda p, c, bt, ln, tok: arch.decode_step(family, p, c, bt, ln,
+                                                   tok, cfg),
+        donate_argnums=(1,))
+    cache, logits = prefill(params, jnp.asarray(padded), cache,
+                            jnp.asarray(rows[:, :s_pad // ps]),
+                            jnp.full((n,), plen, jnp.int32))
     got, toks = [np.asarray(logits, np.float32)], []
     for t in range(steps):
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         toks.append(np.asarray(tok))
-        pk, pv, logits = decode(params, pk, pv, jnp.asarray(rows),
-                                jnp.full((n,), plen + t + 1, jnp.int32), tok)
+        cache, logits = decode(params, cache, jnp.asarray(rows),
+                               jnp.full((n,), plen + t + 1, jnp.int32), tok)
         got.append(np.asarray(logits, np.float32))
     got = np.stack(got, 1)                                # [n, steps+1, V]
-    del pk, pv, pool
+    del cache
 
-    layer_fn = jax.jit(lambda x, w: R.layer(x, w, conf))
+    layer_fn = jax.jit(lambda x, w: arch.layer(x, w, conf))
     want = []
     with jax.default_matmul_precision("highest"):
         for j in range(n):
             full = np.concatenate([ids[j], [tk[j] for tk in toks]])
-            want.append(np.asarray(R.logits_at(
+            want.append(np.asarray(arch.logits_at(
                 reference_params or params, jnp.asarray(full), conf,
                 np.arange(plen - 1, plen + steps), layer_fn)))
     want = np.stack(want)
@@ -83,11 +84,13 @@ def serve_check(family, cfg, conf: dict, params, page_size: int,
     return {"ok": ok, "logit_err_over_max": err, "max_ref_logit": scale,
             "rms_err_over_rms": rms, "tolerance": chk["tolerance"],
             "rms_tolerance": chk["rms_tolerance"],
+            "numbers": {"logit_err_over_max": [err, chk["tolerance"]],
+                        "rms_err_over_rms": [rms, chk["rms_tolerance"]]},
             "compared": f"{n} prompts x {plen} tokens, prefill + {steps} "
                         f"decode steps"}
 
 
-def train_check(family, cfg, conf: dict, params, seed: int) -> dict:
+def train_check(arch, family, cfg, conf: dict, params, seed: int) -> dict:
     """First-step loss and the gradient on one sequence: the program's
     ``loss_fn`` (kernels, fused CE, capacity dispatch) against the
     reference's loss under the same capacity rule. The gradient is
@@ -106,7 +109,7 @@ def train_check(family, cfg, conf: dict, params, seed: int) -> dict:
     loss, norms = measured(lambda q, b: family.loss_fn(q, b, cfg))(
         params, jnp.asarray(ids))
     with jax.default_matmul_precision("highest"):
-        rloss, rnorms = measured(lambda q, b: R.loss(q, b, conf))(
+        rloss, rnorms = measured(lambda q, b: arch.loss(q, b, conf))(
             params, jnp.asarray(ids[0]))
     loss, rloss = float(loss), float(rloss)
     norms, rnorms = ({k: float(v) for k, v in t.items()}
@@ -129,5 +132,10 @@ def train_check(family, cfg, conf: dict, params, seed: int) -> dict:
             "grad_norm_rel_diff": dg, "worst_leaf": worst,
             "worst_leaf_norm_rel_diff": leaf[worst],
             "worst_leaf_band": band[worst],
+            "numbers": {"loss_abs_diff": [dl, chk["loss_tolerance"]],
+                        "grad_norm_rel_diff": [dg,
+                                               chk["grad_norm_tolerance"]],
+                        "worst_leaf_norm_rel_diff": [leaf[worst],
+                                                     band[worst]]},
             "leaf_norm_rel_diff": {k: round(v, 6) for k, v in leaf.items()},
             "compared": f"one sequence of {chk['seq_len']} tokens"}

@@ -1,18 +1,25 @@
 """Where everything is: ``BENCHMARK.json`` and the data files it names.
 
-No Python file lists cells, configurations, mixes or metrics. A cell's
-entry in ``BENCHMARK.json`` names its configuration and its traffic mix;
-this module finds the configuration's ``file``, ``traffic/<traffic>.json``
-and ``layer_metrics/<metric>.json`` under the benchmark's own directory by
-those names, so a later PR adds a cell, a
-configuration, a mix or a per-layer metric by adding files and appending
-entries, and edits no file that is there.
+No Python file lists cells, configurations, mixes, metrics or
+architectures. A cell's entry in ``BENCHMARK.json`` names its
+configuration and its traffic mix; this module finds the configuration's
+``file``, ``traffic/<traffic>.json`` and ``layer_metrics/<metric>.json``
+under the benchmark's own directory by those names, and four kinds of
+code by a name in a data file: a driver by a mix's ``kind``, a reader by
+a per-layer metric's ``reader``, and an architecture (its plain
+reference, its counts, the serve check's calls into the program, its
+kernels' work) by a configuration's ``architecture``. So a later PR adds
+a cell, a configuration, a mix, a per-layer metric or an architecture by
+adding files and appending entries, and edits no file that is there.
+``BENCHMARK.json`` alone says which cells report a metric.
 """
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -58,11 +65,25 @@ class Manifest:
     def layer_metric(self, name: str) -> dict:
         return _load(self.path("layer_metrics", name))
 
+    def architecture(self, conf: dict):
+        """The module a configuration's file names: no default."""
+        return plugin("architectures", conf["architecture"], self.bench_dir)
 
-def plugin(package: str, name: str):
+
+def plugin(package: str, name: str, bench_dir: str = BENCH_DIR):
     """``benchmark.<package>.<name>``: drivers by a mix's ``kind``,
-    readers by a per-layer metric's ``reader``. A new kind is a new file."""
-    return importlib.import_module(f"benchmark.{package}.{name}")
+    readers by a per-layer metric's ``reader``, architectures by a
+    configuration's ``architecture``. A new kind is a new file. A
+    manifest elsewhere than this checkout (a test's copy) may bring a
+    file of its own, which is found before this checkout's."""
+    full = f"benchmark.{package}.{name}"
+    own = os.path.join(bench_dir, package, name + ".py")
+    if full not in sys.modules and os.path.isfile(own) \
+            and os.path.abspath(bench_dir) != BENCH_DIR:
+        spec = importlib.util.spec_from_file_location(full, own)
+        sys.modules[full] = module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    return importlib.import_module(full)
 
 
 def seeded_params(family, cfg, seed: int):

@@ -43,8 +43,8 @@ class Serving:
             blk["num_slots"], cfg.num_attention_heads,
             cfg.num_key_value_heads, cfg.head_dim, -(-max_len // 16) * 16,
             cfg.dtype)
-        self.check = check.serve_check(self.family, cfg, conf, self.params,
-                                       page, run.seed)
+        self.check = check.serve_check(run.arch, self.family, cfg, conf,
+                                       self.params, page, run.seed)
         say(f"reference check: {self.check}")
         run.mark("reference_check")
 
@@ -65,6 +65,7 @@ class Serving:
         self.delivered = 0               # tokens seen by clients, total
         self.kv_token_steps = 0.0        # while tracing: see stamp()
         self.traced_decode_steps = 0
+        self.traced_tokens_decoded = 0
         self._kv_seen = {}
         self.warm(min_prompt, max_prompt)
         run.mark("warm_up")
@@ -174,6 +175,7 @@ class Serving:
             if tracing:
                 # what the paged kernel had to read for it: kv-e+1 .. kv
                 self.kv_token_steps += e * kv - e * (e - 1) / 2
+                self.traced_tokens_decoded += e
             if rid not in self.want:
                 continue
             seen = self.n_seen.get(rid, 0)
@@ -208,11 +210,13 @@ class Serving:
         return len(rids), failed
 
     def counters(self) -> dict:
+        """Every public numeric attribute of ``engine.stats`` under
+        ``engine.<name>``: a counter the program adds reaches a
+        ``counter`` reader with no edit here."""
         st = self.eng.stats
-        out = {f"engine.{k}": getattr(st, k) for k in (
-            "admitted", "completed", "preempted", "decode_steps",
-            "tokens_generated", "tokens_decoded", "tokens_prefilled",
-            "tokens_discarded", "peak_pages_in_use")}
+        out = {f"engine.{k}": v for k, v in vars(st).items()
+               if not k.startswith("_") and isinstance(v, (int, float))
+               and not isinstance(v, bool)}
         out["engine.slot_steps"] = st.decode_steps * self.slots
         return out
 
@@ -223,4 +227,5 @@ class Serving:
                 "engine.peak_pages_in_use": c1["engine.peak_pages_in_use"],
                 "kv_token_steps": self.kv_token_steps,
                 "traced_decode_steps": self.traced_decode_steps,
+                "traced_tokens_decoded": self.traced_tokens_decoded,
                 "window_s": window_s}

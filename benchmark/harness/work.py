@@ -1,7 +1,8 @@
-"""The yardstick's arithmetic: peaks, parameter counts, model FLOPs a
-token, cache bytes a token, and what each kernel call has to compute or
-read. Everything here is a function of shapes, so that a program PR
-cannot move it. Peaks are the published figures of the part, keyed by
+"""The yardstick's arithmetic: peaks, what the architectures' counts
+share (attention's parameters, a decoder's tables, FLOPs a trained token,
+cache bytes a token), and what each kernel call has to compute or read.
+Everything here is a function of shapes, so that a program PR cannot
+move it. Peaks are the published figures of the part, keyed by
 the ``device_kind`` JAX reports; a device that is not in the table is an
 error, never a default.
 """
@@ -23,57 +24,49 @@ def peaks(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-# -- parameters --------------------------------------------------------
+# -- what architectures count with ----------------------------------------
+# (an architecture's own counts are in ``benchmark/architectures/<name>.py``:
+# ``param_count``, ``kv_bytes_per_token``, ``model_flops_per_token``,
+# ``state_bytes_per_slot``; these are the parts several of them share)
 
-def _attn_params(c: dict) -> int:
+def head_dim(c: dict) -> int:
+    """A head's size: the file's ``head_dim`` where it states one, else
+    ``hidden_size // num_attention_heads``."""
+    return c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
+
+
+def attn_params(c: dict) -> int:
+    """The four projections of grouped-query attention, no bias."""
     d, nh, nkv = c["hidden_size"], c["num_attention_heads"], \
         c["num_key_value_heads"]
-    hd = d // nh
+    hd = head_dim(c)
     return d * hd * (nh + 2 * nkv) + nh * hd * d
 
 
-def dense_layer_params(c: dict) -> int:
-    """One decoder layer of a dense SwiGLU model, norms included."""
-    return (_attn_params(c) + 3 * c["hidden_size"] * c["intermediate_size"]
-            + 2 * c["hidden_size"])
-
-
-def moe_layer_params(c: dict, active: bool = False) -> int:
-    """One DeepSeekMoE expert layer: attention, router, routed experts
-    (all of them, or the ``num_experts_per_tok`` a token uses), shared
-    experts, norms."""
-    d, fe = c["hidden_size"], c["moe_intermediate_size"]
-    routed = c["num_experts_per_tok"] if active else c["n_routed_experts"]
-    return (_attn_params(c) + d * c["n_routed_experts"]
-            + routed * 3 * d * fe
-            + 3 * d * c["n_shared_experts"] * fe + 2 * d)
-
-
-def param_count(c: dict, active: bool = False) -> int:
-    """Parameters of the configuration as run (its file's own keys). With
-    ``active``: those a token's forward pass multiplies by, the embedding
-    lookup left out."""
-    layer = moe_layer_params(c, active) if "n_routed_experts" in c \
-        else dense_layer_params(c)
+def decoder_params(c: dict, layer: int, active: bool = False) -> int:
+    """``num_hidden_layers`` layers of ``layer`` parameters each, the
+    embedding and the head (one table where they are tied), the last
+    norm. With ``active``: those a token's forward pass multiplies by,
+    the embedding lookup left out."""
     table = c["vocab_size"] * c["hidden_size"]
     tables = table if active or c.get("tie_word_embeddings") else 2 * table
     return c["num_hidden_layers"] * layer + tables + c["hidden_size"]
 
 
-def model_flops_per_token(c: dict, seq_len: int) -> float:
+def train_flops_per_token(c: dict, active_params: int, seq_len: int) -> float:
     """Forward and backward FLOPs a trained token requires: 6 a
     multiplied parameter, plus causal attention's two products at
     ``seq_len`` (forward 4*S/2*heads*head_dim a layer, backward twice
     that). Recompute is not counted."""
-    d = c["hidden_size"]
-    attn = 6.0 * c["num_hidden_layers"] * seq_len * d
-    return 6.0 * param_count(c, active=True) + attn
+    attn = 6.0 * c["num_hidden_layers"] * seq_len \
+        * c["num_attention_heads"] * head_dim(c)
+    return 6.0 * active_params + attn
 
 
 def kv_bytes_per_token(c: dict, bytes_per_value: int = 2) -> int:
-    hd = c["hidden_size"] // c["num_attention_heads"]
-    return (2 * c["num_hidden_layers"] * c["num_key_value_heads"] * hd
-            * bytes_per_value)
+    """Keys and values of one cached token where every layer attends."""
+    return (2 * c["num_hidden_layers"] * c["num_key_value_heads"]
+            * head_dim(c) * bytes_per_value)
 
 
 # -- kernels: what one call has to do -----------------------------------
@@ -87,8 +80,7 @@ def flash_shape(c: dict, mix: dict) -> dict:
     """B, H, S, d of a training step's flash calls: the mix's batch and
     sequence length, the configuration's heads."""
     return {"batch": mix["batch"], "seq": mix["seq_len"],
-            "heads": c["num_attention_heads"],
-            "head_dim": c["hidden_size"] // c["num_attention_heads"]}
+            "heads": c["num_attention_heads"], "head_dim": head_dim(c)}
 
 
 def flash_flops(calls: dict, *, batch, heads, seq, head_dim) -> float:
@@ -100,8 +92,9 @@ def flash_flops(calls: dict, *, batch, heads, seq, head_dim) -> float:
     return (2 * calls.get("fwd", 0) + 5 * calls.get("bwd", 0)) * u
 
 
-def paged_attn_bytes(kv_token_steps: float, c: dict) -> float:
-    """Bytes the paged decode kernel had to read: every live cached token
-    of every slot, K and V, once a layer a decode step.
-    ``kv_token_steps`` is the sum over decode steps of live tokens."""
-    return kv_token_steps * kv_bytes_per_token(c)
+def decode_attn_flops(kv_token_steps: float, c: dict) -> float:
+    """FLOPs of decode attention's two products (q K^T and p V, two a
+    multiply-add) over ``kv_token_steps`` live cached tokens read, every
+    query head a layer."""
+    return (4.0 * kv_token_steps * c["num_hidden_layers"]
+            * c["num_attention_heads"] * head_dim(c))

@@ -8,7 +8,9 @@ and inputs from ``--seed``, checks the program against the plain
 reference, warms up every shape, measures for ``--seconds`` and prints
 one JSON object as the last line of its output: with ``--trace 0`` the
 cell's end-to-end metrics (profiler and monitor off), with ``--trace 1``
-its per-layer metrics and the breakdown of a traced slice.
+its per-layer metrics and the breakdown of a traced slice. Its last key,
+``compared``, holds each number ``correct`` was decided by beside its
+limit; the same are the last lines on standard error.
 
 Off the chip it exits non-zero and prints no result. ``--rehearse`` runs
 the same code at the tiny sizes of each file's ``rehearse`` block on the
@@ -35,6 +37,8 @@ class Run:
         self.manifest = manifest
         self.cell = manifest.cell(args.workload)
         self.conf = manifest.config(self.cell["config"])
+        # the configuration's own reference, counts, cache calls, kernel work
+        self.arch = manifest.architecture(self.conf)
         self.mix = manifest.traffic(self.cell["traffic"])
         if self.rehearse:
             self.conf = rehearsal_of(self.conf)
@@ -93,7 +97,8 @@ def layer_metrics(run: Run, result: dict, trace, devices) -> dict:
     from benchmark.harness import work
     from benchmark.harness.manifest import plugin
 
-    ctx = {"counters": result["counters"], "trace": trace, "config": run.conf, "mix": run.mix,
+    ctx = {"counters": result["counters"], "trace": trace,
+           "config": run.conf, "mix": run.mix, "architecture": run.arch,
            "peaks": None if run.rehearse
            else work.peaks(devices[0].device_kind)}
     out = {}
@@ -133,7 +138,8 @@ def main(argv=None) -> int:
     import paddle_tpu  # noqa: F401  (the system under test; absent, no run)
 
     run = Run(args, manifest, t_device)
-    say(f"cell {cell['name']}: config {cell['config']}, traffic "
+    say(f"cell {cell['name']}: config {cell['config']} (architecture "
+        f"{run.conf['architecture']}: {run.arch.__name__}), traffic "
         f"{cell['traffic']} ({run.mix['kind']}), seed {args.seed}, "
         f"{args.seconds} s, trace {args.trace}; compile cache {cache}"
         + ("; REHEARSAL on the CPU, no device metric" if args.rehearse
@@ -187,6 +193,14 @@ def main(argv=None) -> int:
         line["breakdown"] = trace.breakdown()
     if args.rehearse:
         line["rehearsal"] = True
+    # each number ``correct`` compared, beside its limit: the result's
+    # last key, and the last lines on standard error
+    line["compared"] = {k: {"value": v, "limit": lim}
+                        for k, (v, lim) in result["compared"].items()}
+    for k, c in line["compared"].items():
+        print(f"compared {k}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -1,7 +1,8 @@
 """``BENCHMARK.json`` against the files it names and the contract's
-limits, each configuration against what its shapes give, and the proof
-that the harness is driven by data: one more configuration, mix, cell and
-per-layer metric, added as files and appended entries in a copy, run."""
+limits, each configuration against what its architecture's module counts,
+and the proof that the harness is driven by data: one more configuration,
+mix, cell and per-layer metric, added as files and appended entries in a
+copy, run (one more architecture: ``test_architectures.py``)."""
 import importlib
 import json
 import os
@@ -99,7 +100,9 @@ def test_layer_metric_resolves_and_its_moves_is_reported(name):
     spec = MAN.layer_metric(name)
     for key in ("name", "unit", "layer", "moves"):
         assert spec[key] == entry[key]
-    assert spec.get("workloads") == entry.get("workloads")
+    # which cells report it is BENCHMARK.json's to say, and its alone: a
+    # new cell joins a metric by an appended name, with no file edited
+    assert "workloads" not in spec
     reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
     assert callable(reader.read)
     e2e = {m["name"]: m for m in DOC["end_to_end"]}
@@ -129,12 +132,18 @@ def test_config_file_builds_and_counts(name):
     tree = jax.eval_shape(
         lambda: family.init_params(cfg, jax.random.PRNGKey(0)))
     counted = int(sum(np.prod(x.shape) for x in jax.tree.leaves(tree)))
-    assert counted == conf["param_count"] == work.param_count(conf)
-    assert conf["kv_bytes_per_token"] == work.kv_bytes_per_token(conf)
+    # ... against the count of the architecture the file names
+    arch = MAN.architecture(conf)
+    assert arch.__name__ == f"benchmark.architectures.{conf['architecture']}"
+    assert counted == conf["param_count"] == arch.param_count(conf)
+    assert conf["kv_bytes_per_token"] == arch.kv_bytes_per_token(conf)
+    assert conf.get("state_bytes_per_slot", 0) \
+        == arch.state_bytes_per_slot(conf)
     mf = conf["model_flops_per_token"]
-    assert mf["flops"] == work.model_flops_per_token(conf, mf["seq_len"])
+    assert mf["flops"] == arch.model_flops_per_token(conf, mf["seq_len"])
     if "active_param_count" in conf:
-        assert conf["active_param_count"] == work.param_count(conf, True)
+        assert conf["active_param_count"] == arch.param_count(conf, True)
+    assert work.head_dim(conf) == cfg.head_dim
     # the rehearsal's tiny widths build too
     from benchmark.run import rehearsal_of
 
@@ -156,42 +165,46 @@ def test_kernel_work_from_shapes():
     c = {"hidden_size": 8, "num_attention_heads": 4,
          "num_key_value_heads": 2, "num_hidden_layers": 3}
     assert work.kv_bytes_per_token(c) == 2 * 3 * 2 * 2 * 2
-    assert work.paged_attn_bytes(10, c) == 10 * 48
+    # q K^T and p V, two FLOPs a multiply-add, 3 layers x 4 heads of 2
+    assert work.decode_attn_flops(10, c) == 4 * 10 * 3 * 4 * 2
 
 
 def copy_of_the_data(root: str) -> str:
     bench = os.path.join(root, "benchmark")
-    for sub in ("configs", "traffic", "layer_metrics"):
+    for sub in ("configs", "traffic", "layer_metrics", "architectures"):
         shutil.copytree(os.path.join(ROOT, "benchmark", sub),
-                        os.path.join(bench, sub))
+                        os.path.join(bench, sub),
+                        ignore=shutil.ignore_patterns("__pycache__"))
     return bench
 
 
 def test_every_data_file_is_registered():
     """Nothing is parked under the benchmark's directories: every mix,
-    per-layer metric, configuration and reader there is one that a
-    registered cell runs."""
+    per-layer metric, configuration, reader, driver and architecture
+    there is one that a registered cell runs."""
     mixes = {w["traffic"] for w in DOC["workloads"]}
     readers = {MAN.layer_metric(m)["reader"] for m in LAYER} | {"__init__"}
     drivers = {MAN.traffic(m)["kind"] for m in mixes} | {"__init__"}
+    archs = {MAN.config(c)["architecture"] for c in CONFIGS} | {"__init__"}
     for kind, names, ext in (("traffic", mixes, ".json"),
                              ("layer_metrics", set(LAYER), ".json"),
                              ("configs", set(CONFIGS), ".json"),
                              ("readers", readers, ".py"),
-                             ("drivers", drivers, ".py")):
+                             ("drivers", drivers, ".py"),
+                             ("architectures", archs, ".py")):
         files = {f[:-len(ext)]
                  for f in os.listdir(os.path.join(MAN.bench_dir, kind))
                  if f.endswith(ext)}
         assert files == names, kind
 
 
-def test_one_more_of_each_is_files_and_appended_entries(tmp_path):
-    """A configuration, a traffic mix, a cell and a per-layer metric added
-    to a copy of the benchmark without editing any file that was there
-    (only appending to BENCHMARK.json), then rehearsed."""
-    from test_traffic import check_last_line, rehearse
-
-    root = str(tmp_path)
+def one_more_of_each(root: str, architecture: tuple = None) -> tuple:
+    """In a copy of the data under ``root``: a configuration, a mix, a
+    cell, a ``counter`` metric over an attribute of ``engine.stats`` that
+    no file lists, and the cell's name appended to ``serve_tok_s`` and to
+    an existing per-layer metric; with ``architecture`` (name, source)
+    also that module, named by the configuration. Returns (the cell's
+    name, the files that were there with their modification times)."""
     bench = copy_of_the_data(root)
     before = {os.path.join(b, f): os.path.getmtime(os.path.join(b, f))
               for b, _, fs in os.walk(bench) for f in fs}
@@ -202,6 +215,15 @@ def test_one_more_of_each_is_files_and_appended_entries(tmp_path):
 
     conf = MAN.config("mistral-7b-v0.3")
     conf["rehearse"]["override"]["num_hidden_layers"] = 3
+    # (float32 on the CPU agrees to 3e-7; at the rehearsal's width scores
+    # spread little, and a position fault moves the logits by ~1e-3)
+    conf["rehearse"]["serve"]["check"].update(tolerance=1e-5,
+                                              rms_tolerance=1e-5)
+    if architecture:
+        conf["architecture"], source = architecture
+        with open(os.path.join(bench, "architectures",
+                               conf["architecture"] + ".py"), "w") as f:
+            f.write(source)
     add("configs", "another-dense", conf)
     mix = MAN.traffic("decode-sat")
     mix["rehearse"]["output"] = {"median": 8, "sigma": 0.3, "min": 4,
@@ -209,30 +231,51 @@ def test_one_more_of_each_is_files_and_appended_entries(tmp_path):
     add("traffic", "short-answers", mix)
     cell = {"name": "another-dense.short-answers", "config": "another-dense",
             "traffic": "short-answers", "chips": 1, "why": "a test's cell"}
-    metric = {"name": "sched.preempted", "unit": "requests",
-              "layer": "scheduler", "moves": "serve_tok_s",
-              "workloads": [cell["name"]], "reader": "counter",
-              "params": {"counter": "engine.preempted"}}
+    metric = {"name": "sched.shed", "unit": "requests", "layer": "scheduler",
+              "moves": "serve_tok_s", "reader": "counter",
+              "params": {"counter": "engine.shed"}}
     add("layer_metrics", metric["name"], metric)
 
     doc = json.loads(json.dumps(DOC))
     doc["configs"].append({**DOC["configs"][0], "name": "another-dense",
                            "file": "benchmark/configs/another-dense.json"})
     doc["workloads"].append(cell)
-    for m in doc["end_to_end"]:
-        if m["name"] == "serve_tok_s":
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        if m["name"] in ("serve_tok_s", "sched.occupancy_pct"):
             m["workloads"] = m["workloads"] + [cell["name"]]
     doc["per_layer"].append({k: metric[k] for k in (
-        "name", "unit", "layer", "moves", "workloads")}
-        | {"better": "lower", "source": "program_counter"})
+        "name", "unit", "layer", "moves")}
+        | {"better": "lower", "source": "program_counter",
+           "workloads": [cell["name"]]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
+    return cell["name"], before
 
-    _, last = rehearse(cell["name"], 1, root=root)
-    check_last_line(last, Manifest(root), cell["name"], 1)
-    assert last["metrics"]["rehearse.sched.preempted"]["value"] == 0.0
-    _, last = rehearse(cell["name"], 0, root=root)
+
+def rehearsed_both_ways(root: str, cell: str, before: dict) -> list:
+    """The copy's new cell rehearsed with ``--trace 1`` and ``0``, and no
+    file that was there edited. Returns the traced run's lines."""
+    from test_traffic import check_last_line, rehearse
+
+    lines, last = rehearse(cell, 1, root=root)
+    check_last_line(last, Manifest(root), cell, 1)
+    # the new counter metric, and the existing one the cell joined
+    assert last["metrics"]["rehearse.sched.shed"]["value"] == 0.0
+    assert 0 < last["metrics"]["rehearse.sched.occupancy_pct"]["value"] <= 100
+    _, last = rehearse(cell, 0, root=root)
     assert set(last["metrics"]) == {"rehearse.serve_tok_s",
                                     "rehearse.setup_s"}
+    assert last["correct"] is True
     after = {p: os.path.getmtime(p) for p in before}
     assert after == before, "a file that was there was edited"
+    return lines
+
+
+def test_one_more_of_each_is_files_and_appended_entries(tmp_path):
+    """A configuration, a traffic mix, a cell and a per-layer metric added
+    to a copy of the benchmark, and an existing per-layer metric joined,
+    without editing any file that was there (only appending to
+    BENCHMARK.json), then rehearsed."""
+    cell, before = one_more_of_each(str(tmp_path))
+    lines = rehearsed_both_ways(str(tmp_path), cell, before)
+    assert "(architecture dense_decoder: " in lines[0]

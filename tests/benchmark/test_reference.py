@@ -1,6 +1,7 @@
 """The plain reference against the program's own forward pass and loss at
-tiny widths on the CPU, float32, for both families: the dense block, and
-the expert block with the capacity rule and its drops."""
+tiny widths on the CPU, float32, for both architectures, each through the
+module its configuration's file names: the dense block, and the expert
+block with the capacity rule and its drops."""
 import copy
 
 import jax
@@ -8,11 +9,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.architectures import deepseek_moe, dense_decoder
 from benchmark.harness import check, reference as R
 from benchmark.harness.manifest import Manifest, build_config
 from benchmark.run import rehearsal_of
 
 MAN = Manifest()
+DENSE, MOE = (MAN.architecture(MAN.config(c))
+              for c in ("mistral-7b-v0.3", "deepseek-moe-16b"))
 
 
 def tiny(name, block):
@@ -22,12 +26,17 @@ def tiny(name, block):
     return conf, family, cfg, params
 
 
+def test_each_configuration_names_its_architecture():
+    assert DENSE is dense_decoder and MOE is deepseek_moe
+
+
 def test_dense_forward_matches_models_llama():
     conf, family, cfg, params = tiny("mistral-7b-v0.3", "serve")
     ids = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 40))
     want = np.asarray(family.forward(params, jnp.asarray(ids), cfg))
     for b in range(2):
-        got = R.logits_at(params, jnp.asarray(ids[b]), conf, np.arange(40))
+        got = DENSE.logits_at(params, jnp.asarray(ids[b]), conf,
+                              np.arange(40))
         np.testing.assert_allclose(np.asarray(got), want[b], atol=2e-5)
 
 
@@ -52,7 +61,7 @@ def test_grouped_query_heads_are_grouped_as_published():
 def test_moe_loss_and_gradient_match_models_moe_with_drops():
     conf, family, cfg, params = tiny("deepseek-moe-16b", "train")
     assert cfg.dispatch_mode is None          # capacity dispatch, as run
-    out = check.train_check(family, cfg, conf, params, seed=5)
+    out = check.train_check(MOE, family, cfg, conf, params, seed=5)
     assert out["ok"], out
     assert out["loss_abs_diff"] < 1e-5 and out["grad_norm_rel_diff"] < 1e-4
     assert out["worst_leaf_norm_rel_diff"] < 1e-4
@@ -62,16 +71,16 @@ def test_moe_loss_and_gradient_match_models_moe_with_drops():
     # about the drops too: with room for every slot the loss differs
     roomy = dict(conf, capacity_factor=8.0)
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, 33)
-    tight = float(R.loss(params, jnp.asarray(ids), conf))
-    loose = float(R.loss(params, jnp.asarray(ids), roomy))
+    tight = float(MOE.loss(params, jnp.asarray(ids), conf))
+    loose = float(MOE.loss(params, jnp.asarray(ids), roomy))
     assert abs(tight - loose) > 1e-6
     # likewise the renormalised weights of the chosen experts: the file
     # keeps the source's norm_topk_prob (false) and states what the
     # program does beside it; by the source's key alone the loss differs
     assert not conf["norm_topk_prob"] and conf["renormalise_routed_weights"]
     as_source = dict(conf, renormalise_routed_weights=False)
-    assert abs(tight - float(R.loss(params, jnp.asarray(ids),
-                                    as_source))) > 1e-6
+    assert abs(tight - float(MOE.loss(params, jnp.asarray(ids),
+                                      as_source))) > 1e-6
 
 
 @pytest.mark.parametrize("tokens,want", [(16384, 1920), (4096, 512),
@@ -81,14 +90,15 @@ def test_capacity_rule_is_the_programs(tokens, want):
 
     conf = MAN.config("deepseek-moe-16b")
     _, cfg = build_config(conf, "train")
-    assert R.capacity(conf, tokens) == want == moe.moe_capacity(cfg, tokens)
+    assert MOE.capacity(conf, tokens) == want \
+        == moe.moe_capacity(cfg, tokens)
 
 
 def test_serving_check_catches_a_wrong_program():
     """The paged programs against the reference: right as they are, and
     wrong (over the tolerance) once a weight is disturbed under them."""
     conf, family, cfg, params = tiny("mistral-7b-v0.3", "serve")
-    good = check.serve_check(family, cfg, conf, params, 16, seed=7)
+    good = check.serve_check(DENSE, family, cfg, conf, params, 16, seed=7)
     assert good["ok"] and good["logit_err_over_max"] < 1e-4
 
     class Skewed:
@@ -100,7 +110,7 @@ def test_serving_check_catches_a_wrong_program():
         def decode_mlp(x, lp, c):
             return x + (x @ lp["up"]) @ lp["down"]
 
-    bad = check.serve_check(Skewed(), cfg, conf, params, 16, seed=7)
+    bad = check.serve_check(DENSE, Skewed(), cfg, conf, params, 16, seed=7)
     assert not bad["ok"]
 
 
@@ -119,14 +129,15 @@ def test_training_check_catches_a_fault_in_one_small_leaf():
                 p["layers"]["router"]))
             return family.loss_fn(dict(p, layers=layers), batch, c)
 
-    out = check.train_check(NoRouterGradient(), cfg, conf, params, seed=5)
+    out = check.train_check(MOE, NoRouterGradient(), cfg, conf, params,
+                            seed=5)
     assert not out["ok"]
     assert out["worst_leaf"] == "['layers']['router']"
     assert out["worst_leaf_norm_rel_diff"] == pytest.approx(1.0)
     # a leaf with a band of its own is held to that one
     own = copy.deepcopy(conf)
     own["train"]["check"]["leaf_norm_tolerance_of"] = {out["worst_leaf"]: 2.0}
-    assert check.train_check(NoRouterGradient(), cfg, own, params,
+    assert check.train_check(MOE, NoRouterGradient(), cfg, own, params,
                              seed=5)["ok"]
     assert out["loss_abs_diff"] < 1e-5
     assert out["grad_norm_rel_diff"] < 0.02     # the band PR 23 first had
@@ -153,12 +164,12 @@ def test_a_band_a_quarter_over_bf16_fails_lower_precision():
                  tolerance=1.0, rms_tolerance=1.0)
     family, cfg = build_config(conf, "serve")
     params = family.init_params(cfg, jax.random.PRNGKey(3))
-    bf16 = check.serve_check(family, cfg, conf, params, 16, seed=7)
+    bf16 = check.serve_check(DENSE, family, cfg, conf, params, 16, seed=7)
     assert 1e-3 < bf16["rms_err_over_rms"] < 0.05
     conf["serve"]["check"].update(
         tolerance=1.25 * bf16["logit_err_over_max"],
         rms_tolerance=1.25 * bf16["rms_err_over_rms"])
-    assert check.serve_check(family, cfg, conf, params, 16, seed=7)["ok"]
+    assert check.serve_check(DENSE, family, cfg, conf, params, 16, seed=7)["ok"]
 
     def int8(w):
         step = jnp.max(jnp.abs(w.astype(jnp.float32)), -2, keepdims=True) / 127
@@ -170,8 +181,8 @@ def test_a_band_a_quarter_over_bf16_fails_lower_precision():
     for lower in (int8, fp8):
         rounded = jax.tree.map(lambda w: lower(w) if w.ndim >= 2 else w,
                                params)
-        out = check.serve_check(family, cfg, conf, rounded, 16, seed=7,
-                                reference_params=params)
+        out = check.serve_check(DENSE, family, cfg, conf, rounded, 16,
+                                seed=7, reference_params=params)
         assert not out["ok"], lower.__name__
         assert out["rms_err_over_rms"] > 2 * bf16["rms_err_over_rms"]
 
@@ -189,11 +200,11 @@ def test_random_weights_do_not_hide_a_rope_or_mask_fault(fault, monkeypatch):
                           if w.ndim >= 2 else w, params)   # 256 x .08^2 = 1.6
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 512, 48))
     at = np.arange(20, 28)          # (the last row sees every token anyway)
-    good = np.asarray(R.logits_at(params, ids, conf, at))
+    good = np.asarray(DENSE.logits_at(params, ids, conf, at))
     if fault == "no_rope":
         monkeypatch.setattr(R, "rotary", lambda x, theta: x)
     else:
         monkeypatch.setattr(jnp, "tril", jnp.ones_like)
-    bad = np.asarray(R.logits_at(params, ids, conf, at))
+    bad = np.asarray(DENSE.logits_at(params, ids, conf, at))
     rms = np.sqrt(np.mean((bad - good) ** 2) / np.mean(good ** 2))
     assert rms > 0.5, rms                   # against a band of 0.05

@@ -114,6 +114,10 @@ def check_last_line(last: dict, manifest, cell: str, trace: int):
     if not trace:
         assert "rehearse.setup_s" in last["metrics"]
     assert "breakdown" not in last and "busy_s" not in last["device"]
+    # what ``correct`` was decided by, each number beside its limit, last
+    assert list(last)[-1] == "compared" and len(last["compared"]) >= 3
+    for c in last["compared"].values():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
 
 
 @pytest.mark.parametrize("cell,trace", [
@@ -124,6 +128,8 @@ def check_last_line(last: dict, manifest, cell: str, trace: int):
 def test_rehearsal_of_each_driver(cell, trace):
     lines, last = rehearse(cell, trace)
     check_last_line(last, MAN, cell, trace)
+    conf = MAN.config(MAN.cell(cell)["config"])
+    assert f"(architecture {conf['architecture']}: " in lines[0]
     window = [x for x in lines if "INSIDE THE WINDOW" in x]
     assert window and "compiled 0, from the cache 0" in window[0]
 
