@@ -7,10 +7,14 @@ new request enters the running batch without draining it, retirement
 freeing pages the moment a sequence finishes — rebuilt TPU-native:
 
 - The decode data plane is ONE jitted program over the static
-  ``[num_slots]`` grid (paged_decode_step + vectorised sampling inside
+  ``[num_slots]`` grid (cache_decode_step + vectorised sampling inside
   a ``lax.scan`` of ``decode_chunk`` steps), so continuous batching
   never retraces: joins/retires only permute host-side block tables
-  between chunks. One device round-trip per chunk, not per token.
+  between chunks. One device round-trip per chunk, not per token. Every
+  program takes and returns the cache WHOLE, one donated pytree: the two
+  page pools and, for a family that keeps one, the recurrent state a
+  sequence, which slots reach through a row table uploaded with the
+  block tables.
 - Admission policy: a request is admitted when a slot is free AND the
   pool keeps >= ``watermark`` free pages after its prompt allocation —
   the page headroom that lets RUNNING requests keep appending without
@@ -60,7 +64,8 @@ gap reads as what the host was doing. With no session a span is a
 no-op of about a microsecond, ten to fifteen a step. Add one only at a
 boundary between layers or between host work and waiting, never inside
 a loop over slots. The device programs are named to match:
-``jit_decode_chunk``, ``jit_spec_verify``, ``jit__pf``.
+``jit_decode_chunk``, ``jit_spec_verify``, ``jit__pf``,
+``jit__join_first``.
 
 Token accounting contract (pinned by tests/test_trace.py):
 ``serving.tokens.generated`` counts every SAMPLED token (prefill's
@@ -137,9 +142,9 @@ from ..monitor import trace as _trace
 from ..monitor import slo as _slo
 from ..monitor import forensics as _forensics
 from ..monitor.registry import LATENCY_BUCKETS_MS as _LATENCY_BUCKETS_MS
-from .paged import (PagedKVCache, PrefixCache, paged_decode_step,
-                    paged_prefill, paged_prefill_shared,
-                    paged_verify_window)
+from .paged import (PagedKVCache, PrefixCache, cache_decode_step,
+                    cache_prefill, cache_prefill_shared,
+                    cache_verify_window)
 
 _NO_SPAN = contextlib.nullcontext()     # what _first_call gives after the first
 
@@ -331,6 +336,10 @@ class EngineStats:
         self.tokens_prefilled = 0
         self.tokens_discarded = 0    # thrown away by preemption recompute
         self.peak_pages_in_use = 0
+        # rows of recurrent state (a family that keeps one; else all 0)
+        self.state_rows_in_use = 0
+        self.peak_state_rows_in_use = 0
+        self.state_rows_assigned = 0     # rows handed out, re-admissions too
         self._occ_steps = 0      # decode steps weighted by slot count
         # shared-prefix radix cache (FLAGS_serving_prefix_cache)
         self.prefix_lookups = 0
@@ -385,18 +394,27 @@ def _sample_rows(logits, temps, keys, sampled=True):
     return jnp.where(temps > 0, drawn.astype(jnp.int32), greedy)
 
 
-def _decode_chunk(family, config, chunk, sampled, params, pool_k, pool_v,
-                  block_tables, tokens, kv_len, done, gen, keys, temps,
-                  max_new, eos):
+def _join_first(tokens, at, tok):
+    """A prefill group's first tokens put at their slots among the decode
+    chunk's pending tokens, on the device (``at`` names the slot grid's
+    length for a dummy row, which is dropped)."""
+    return tokens.at[at].set(tok, mode="drop")
+
+
+def _decode_chunk(family, config, chunk, sampled, params, cache,
+                  block_tables, state_rows, tokens, kv_len, done, gen, keys,
+                  temps, max_new, eos):
     """``chunk`` decode steps as one program: write the pending token's
     KV, attend, sample the next. Done slots coast (writes dropped via
-    length 0, outputs masked to -1)."""
+    length 0, outputs masked to -1; a recurrent state's row untouched).
+    ``cache`` is the whole cache, one donated pytree; ``state_rows`` is
+    the slots' row table (None where the family keeps no state)."""
 
     def body(carry, key_t):
-        pool_k, pool_v, tok, kvl, done, gen = carry
+        cache, tok, kvl, done, gen = carry
         n = jnp.where(done, 0, kvl + 1)
-        pool_k, pool_v, logits = paged_decode_step(
-            family, params, pool_k, pool_v, block_tables, n, tok, config)
+        cache, logits = cache_decode_step(
+            family, params, cache, block_tables, n, tok, config, state_rows)
         kvl = jnp.where(done, kvl, kvl + 1)
         nxt = _sample_rows(logits, temps, key_t, sampled)
         emitted = jnp.where(done, -1, nxt)
@@ -404,20 +422,21 @@ def _decode_chunk(family, config, chunk, sampled, params, pool_k, pool_v,
         hit_eos = (~done) & (nxt == eos)
         done = done | hit_eos | (gen >= max_new)
         tok = jnp.where(emitted >= 0, nxt, tok)
-        return (pool_k, pool_v, tok, kvl, done, gen), emitted
+        return (cache, tok, kvl, done, gen), emitted
 
-    (pool_k, pool_v, tok, kvl, done, gen), emitted = jax.lax.scan(
-        body, (pool_k, pool_v, tokens, kv_len, done, gen), keys,
-        length=chunk)
-    return pool_k, pool_v, tok, kvl, done, gen, emitted
+    (cache, tok, kvl, done, gen), emitted = jax.lax.scan(
+        body, (cache, tokens, kv_len, done, gen), keys, length=chunk)
+    return cache, tok, kvl, done, gen, emitted
 
 
 class ServingEngine:
     """Continuous-batching decode over a paged KV cache.
 
     ``family`` is a model module exposing the decoder seam
-    (models.llama / models.moe); ``params`` may be the bf16 tree or the
-    weight-only int8 tree from ``family.quantize_weights``."""
+    (models.llama / models.moe; models.falcon_h1, which also keeps a
+    recurrent state a sequence beside the pages); ``params`` may be the
+    bf16 tree or the weight-only int8 tree from
+    ``family.quantize_weights``."""
 
     def __init__(self, family, params, config, *, num_slots: int = 8,
                  max_len: Optional[int] = None,
@@ -499,9 +518,28 @@ class ServingEngine:
                   f"pool of {num_pages} pages cannot hold even one "
                   f"max-length sequence ({self.max_pages_per_seq} pages)")
         self.watermark_pages = int(watermark * num_pages)
-        self.cache = PagedKVCache(config, num_pages, self.page_size,
-                                  self.max_pages_per_seq, kv_dtype,
-                                  kv_quant=self._kv_quant)
+        # A family that declares a recurrent state gets a row of it a
+        # slot, beside the pages. What shares or rewinds pages has no
+        # counterpart for a state yet, and a guess is worse than a refusal.
+        shapes = getattr(family, "state_shapes", None)
+        self._recurrent = shapes is not None
+        if self._recurrent:
+            for on, what, missing in (
+                    (self._prefix_on, "serving_prefix_cache",
+                     "a snapshot of the state at the shared prefix's end"),
+                    (self._spec_decode, "serving_spec_decode",
+                     "a rollback of the state past the rejected drafts"),
+                    (self._kv_quant, "serving_kv_quant",
+                     "a quantized form of the state beside int8 pages")):
+                E.enforce(not on, f"FLAGS_{what} with {family.__name__}: "
+                          f"its sequences keep a recurrent state beside "
+                          f"their pages, and {missing} is not written",
+                          error=E.UnimplementedError)
+        self.cache = PagedKVCache(
+            config, num_pages, self.page_size, self.max_pages_per_seq,
+            kv_dtype, kv_quant=self._kv_quant,
+            state_shapes=shapes(config) if self._recurrent else None,
+            state_rows=self.num_slots)
         # radix shared-prefix cache over the pool's committed pages;
         # None (flag off) short-circuits every hook to the original code
         self._prefix = PrefixCache(self.cache.alloc) if self._prefix_on \
@@ -528,7 +566,7 @@ class ServingEngine:
             # program's (XLA module jit_decode_chunk) in every trace
             def decode_chunk(*args):
                 return _decode_chunk(family, config, c, s, *args)
-            return jax.jit(decode_chunk, donate_argnums=(1, 2))
+            return jax.jit(decode_chunk, donate_argnums=(1,))
 
         self._chunk_fns = {
             (c, s): chunk_fn(c, s)
@@ -558,6 +596,13 @@ class ServingEngine:
         self._dev: dict = {}
         self._state_dirty = True
         self._bt_dirty = True
+        # prefills dispatched whose first tokens are still on the device
+        # (_prefill_group: what reads them, the tokens, their slots);
+        # empty whenever step() has returned
+        self._unfetched = deque()
+        self._join = jax.jit(_join_first)
+        self._joins = set()      # group sizes whose join is compiled
+        self._zero_rows = {}     # a greedy prefill group's temp and key, by g
         self._sampled = False
         self._zero_keys = {
             c: jnp.zeros((c, self.num_slots, 2), jnp.uint32)
@@ -1158,17 +1203,17 @@ class ServingEngine:
         if fn is None:
             family, config = self.family, self.config
 
-            def _pf(params, ids, pool_k, pool_v, page_rows, slen, temp,
-                    key):
-                pk, pv, logits = paged_prefill(family, params, ids,
-                                               config, pool_k, pool_v,
-                                               page_rows, slen)
+            def _pf(params, ids, cache, page_rows, slen, temp, key,
+                    state_rows=None):
+                cache, logits = cache_prefill(family, params, ids, config,
+                                              cache, page_rows, slen,
+                                              state_rows)
                 # the first tokens sample INSIDE the prefill program —
                 # one dispatch per admission GROUP, not two per request
                 tok = _sample_rows(logits, temp, key, sampled)
-                return pk, pv, tok
+                return cache, tok
 
-            fn = jax.jit(_pf, donate_argnums=(2, 3))
+            fn = jax.jit(_pf, donate_argnums=(2,))
             self._prefill_fns[(g, s_pad, sampled)] = fn
         return fn
 
@@ -1183,15 +1228,15 @@ class ServingEngine:
         if fn is None:
             family, config = self.family, self.config
 
-            def _pf(params, ids, pool_k, pool_v, page_rows, slen, temp,
-                    key, ctx_rows):
-                pk, pv, logits = paged_prefill_shared(
-                    family, params, ids, config, pool_k, pool_v,
-                    page_rows, slen, ctx_rows)
+            def _pf(params, ids, cache, page_rows, slen, temp, key,
+                    ctx_rows):
+                cache, logits = cache_prefill_shared(
+                    family, params, ids, config, cache, page_rows, slen,
+                    ctx_rows)
                 tok = _sample_rows(logits, temp, key, sampled)
-                return pk, pv, tok
+                return cache, tok
 
-            fn = jax.jit(_pf, donate_argnums=(2, 3))
+            fn = jax.jit(_pf, donate_argnums=(2,))
             self._prefill_shared_fns[(g, s_eff, ncp, sampled)] = fn
         return fn
 
@@ -1203,15 +1248,14 @@ class ServingEngine:
         if fn is None:
             family, config = self.family, self.config
 
-            def spec_verify(params, pool_k, pool_v, bt, drafts, kv_len,
-                            live):
-                pk, pv, logits = paged_verify_window(
-                    family, params, drafts, config, pool_k, pool_v,
-                    bt, kv_len, live)
-                return pk, pv, jnp.argmax(
+            def spec_verify(params, cache, bt, drafts, kv_len, live):
+                cache, logits = cache_verify_window(
+                    family, params, drafts, config, cache, bt, kv_len,
+                    live)
+                return cache, jnp.argmax(
                     logits, axis=-1).astype(jnp.int32)
 
-            fn = jax.jit(spec_verify, donate_argnums=(1, 2))
+            fn = jax.jit(spec_verify, donate_argnums=(1,))
             self._spec_fns[C] = fn
         return fn
 
@@ -1314,7 +1358,8 @@ class ServingEngine:
     def _compact(self):
         """Slot compaction: pack live slots into the low indices (block
         tables and device slot state are rebuilt on the next chunk, so
-        this is a pure host permutation)."""
+        this is a pure host permutation: a recurrent state stays in its
+        row, which the rebuilt row table finds)."""
         live = [s for s in self.slots if s is not None]
         packed = live + [None] * (self.num_slots - len(live))
         if packed != self.slots:
@@ -1460,6 +1505,7 @@ class ServingEngine:
         request requeued at the FRONT so it re-runs before newcomers);
         the victim is :meth:`_preempt_victim_idx`'s. False when
         nothing can be evicted."""
+        self._first_tokens()
         idx = self._preempt_victim_idx()
         if idx is None:
             return False
@@ -1802,14 +1848,43 @@ class ServingEngine:
                 sampled = any(r.temperature > 0 for r in group)
                 pf = self._prefill_shared_fn(g, s_eff, ncp, sampled) \
                     if cached else self._prefill_fn(g, s_pad, sampled)
-                pf_args = (self.params, jnp.asarray(ids), self.cache.pool["k"],
-                           self.cache.pool["v"])
-                pf_kwargs = dict(page_rows=jnp.asarray(rows),
-                                 slen=jnp.asarray(slen),
-                                 temp=jnp.asarray(temps),
-                                 key=jnp.asarray(keys))
+                up = dict(ids=ids, page_rows=rows, slen=slen)
+                # Where no request of the group can end on its first token
+                # (none names an EOS), nothing the scheduler decides before
+                # the next chunk's dispatch reads that token: it goes to its
+                # slot on the device (_join_first), the chunk's inputs are
+                # built while the device prefills, the chunk starts where the
+                # prefill ends, and the host reads the token after that.
+                later = all(r.eos_token_id is None for r in group)
+                if later:
+                    up["at"] = np.full(g, self.num_slots, np.int32)
+                    up["at"][:len(group)] = free[:len(group)]
+                    if g not in self._joins:
+                        # compiled here, beside the group's prefill program,
+                        # not at a first join in the middle of serving
+                        self._joins.add(g)
+                        with _trace.span("serving.compile"):
+                            self._join(*jax.device_put((
+                                np.zeros(self.num_slots, np.int32),
+                                up["at"], np.zeros(g, np.int32))))
+                if sampled:
+                    up.update(temp=temps, key=keys)
                 if cached:
-                    pf_kwargs["ctx_rows"] = jnp.asarray(ctx_rows)
+                    up["ctx_rows"] = ctx_rows
+                if self._recurrent:
+                    with _trace.span("serving.step.state"):
+                        # each request's own row; a dummy names nobody's
+                        up["state_rows"] = self.cache.state_row_table(
+                            [r.rid for r in group]
+                            + [None] * (g - len(group)))
+                pf_kwargs = jax.device_put(up)       # one call for them all
+                if not sampled:     # greedy: neither is read, nor sent again
+                    if g not in self._zero_rows:
+                        self._zero_rows[g] = jax.device_put(
+                            dict(temp=temps, key=keys))
+                    pf_kwargs.update(self._zero_rows[g])
+                at = pf_kwargs.pop("at", None)
+                pf_args = (self.params, pf_kwargs.pop("ids"), self.cache.pool)
             exec_rec = None
             pf_flops_share = None
             if mon:
@@ -1820,7 +1895,7 @@ class ServingEngine:
                     if cached else ("serving.prefill", g, s_pad, sampled),
                     f"serving.prefill_shared[g{g},s{s_eff},ctx{ncp}]"
                     if cached else f"serving.prefill[g{g},s{s_pad}]",
-                    pf, pf_args, pf_kwargs, donated=(2, 3))
+                    pf, pf_args, pf_kwargs, donated=(2,))
                 from ..monitor import exectime as _exectime
                 exec_rec = _exectime.maybe_sample(key, feed_last=False)
                 # modeled-FLOPs attribution: the registered program's
@@ -1831,71 +1906,84 @@ class ServingEngine:
                     pf_flops_share = pf_flops / len(group)
             with _trace.span("serving.prefill.dispatch"), \
                     self._first_call(pf):
-                pk, pv, tok_a = pf(*pf_args, **pf_kwargs)
-            self.cache.pool = {"k": pk, "v": pv}
-            with _trace.span("serving.prefill.fetch"):
-                # the np.asarray download syncs the device — the span ends
-                # (and TTFT is stamped) when the first token actually EXISTS
-                # on the host, not when the dispatch returned
-                toks = np.asarray(tok_a)
-            if exec_rec is not None:
-                # the download above already synchronized: rec(None) adds
-                # ZERO extra block_until_ready calls at this seam
-                exec_rec(None)
-            t_first = None
-            if mon:
-                # TTFT is NOT observed here: a preemption would discard
-                # this run's tokens and re-prefill, double-sampling the
-                # histogram with a first token the client never saw. The
-                # slot carries t_first to _retire, which observes once per
-                # completed request. The lifecycle instant still marks
-                # every prefill (preempted runs included) in the trace.
-                t_first = time.perf_counter()
-                for r in group:
-                    _trace.instant("serving.first_token", rid=r.rid)
-                    # pure host bookkeeping AFTER the np.asarray download
-                    # above already synchronized: zero added device syncs
-                    _forensics.note(r.rid, "first_token", t=t_first)
-            with _trace.span("serving.prefill.emit"):
-                for j, (r, slot) in enumerate(zip(group, slots)):
-                    self.cache.alloc.advance(r.rid, int(slen[j]) + cached)
-                    tok = int(toks[j])
-                    slot.tokens.append(tok)
-                    slot.pending = tok
-                    slot.gen = 1
-                    slot.t_first = slot.t_last = t_first
-                    if mon:
-                        slot.cost = getattr(r, "_cost", None)
-                        # page-seconds integrate from admission (pages were
-                        # allocated in _admit) at chunk-edge resolution
-                        slot.t_tick = t_admit
-                        slot.steps0 = self.stats.decode_steps
-                        if slot.cost is not None:
-                            slot.cost.prefill_tokens += int(slen[j])
-                            if cached:
-                                slot.cost.prefix_cached_tokens += cached
+                self.cache.pool, tok_a = pf(*pf_args, **pf_kwargs)
+            # the slots are taken now, with all that no token decides
+            for j, (r, slot) in enumerate(zip(group, slots)):
+                self.cache.alloc.advance(r.rid, int(slen[j]) + cached)
+                slot.gen = 1
+                slot.done = slot.gen >= r.max_new_tokens
+                self.slots[free[j]] = slot
+                self.stats.admitted += 1
+                self.stats.tokens_generated += 1
+                self.stats.tokens_prefilled += int(slen[j])
+                _monitor.inc("serving.requests.admitted")
+                # the prefill-sampled first token counts here so the
+                # counter agrees with stats.tokens_generated
+                _monitor.inc("serving.tokens.generated")
+                _monitor.inc("serving.tokens.prefilled", int(slen[j]))
+            self._state_dirty = self._bt_dirty = True
+
+            def first_tokens():
+                with _trace.span("serving.prefill.fetch"):
+                    # the np.asarray download syncs the device — the span
+                    # ends (and TTFT is stamped) when the first token actually
+                    # EXISTS on the host, not when the dispatch returned
+                    toks = np.asarray(tok_a)
+                if exec_rec is not None:
+                    # the download above already synchronized: rec(None) adds
+                    # ZERO extra block_until_ready calls at this seam
+                    exec_rec(None)
+                t_first = None
+                if mon:
+                    # TTFT is NOT observed here: a preemption would discard
+                    # this run's tokens and re-prefill, double-sampling the
+                    # histogram with a first token the client never saw. The
+                    # slot carries t_first to _retire, which observes once per
+                    # completed request. The lifecycle instant still marks
+                    # every prefill (preempted runs included) in the trace.
+                    t_first = time.perf_counter()
+                    for r in group:
+                        _trace.instant("serving.first_token", rid=r.rid)
+                        # pure host bookkeeping AFTER the np.asarray download
+                        # above already synchronized: zero added device syncs
+                        _forensics.note(r.rid, "first_token", t=t_first)
+                with _trace.span("serving.prefill.emit"):
+                    for j, (r, slot) in enumerate(zip(group, slots)):
+                        tok = int(toks[j])
+                        slot.tokens.append(tok)
+                        slot.pending = tok
+                        slot.t_first = slot.t_last = t_first
+                        if mon:
+                            slot.cost = getattr(r, "_cost", None)
+                            # page-seconds integrate from admission (pages were
+                            # allocated in _admit) at chunk-edge resolution
+                            slot.t_tick = t_admit
+                            slot.steps0 = self.stats.decode_steps
+                            if slot.cost is not None:
+                                slot.cost.prefill_tokens += int(slen[j])
+                                if cached:
+                                    slot.cost.prefix_cached_tokens += cached
+                                    if pf_flops_share:
+                                        # modeled: the tail program's per-
+                                        # padded-token cost scaled by the
+                                        # tokens the cache served — what a
+                                        # full prefill would have added, to
+                                        # first order
+                                        slot.cost.prefill_flops_saved += (
+                                            pf_flops_share / s_eff * cached)
                                 if pf_flops_share:
-                                    # modeled: the tail program's per-padded-
-                                    # token cost scaled by the tokens the cache
-                                    # served — what a full prefill would have
-                                    # added, to first order
-                                    slot.cost.prefill_flops_saved += (
-                                        pf_flops_share / s_eff * cached)
-                            if pf_flops_share:
-                                slot.cost.model_flops += pf_flops_share
-                    slot.done = (tok == r.eos_token_id
-                                 if r.eos_token_id is not None else False) \
-                        or slot.gen >= r.max_new_tokens
-                    self.slots[free[j]] = slot
-                    self.stats.admitted += 1
-                    self.stats.tokens_generated += 1
-                    self.stats.tokens_prefilled += int(slen[j])
-                    _monitor.inc("serving.requests.admitted")
-                    # the prefill-sampled first token counts here so the
-                    # counter agrees with stats.tokens_generated
-                    _monitor.inc("serving.tokens.generated")
-                    _monitor.inc("serving.tokens.prefilled", int(slen[j]))
-                self._state_dirty = self._bt_dirty = True
+                                    slot.cost.model_flops += pf_flops_share
+                        slot.done = slot.done or tok == r.eos_token_id
+
+            self._unfetched.append((first_tokens, tok_a, at))
+            if not later:
+                self._first_tokens()
+
+    def _first_tokens(self):
+        """Wait for the first tokens of every prefill dispatched and not
+        yet read, and give them to their slots."""
+        while self._unfetched:
+            self._unfetched.popleft()[0]()
 
     def _pick_chunk(self, live_idx: List[int]) -> int:
         """Turbo chunk when no retire/join/EOS could land mid-chunk:
@@ -1959,10 +2047,13 @@ class ServingEngine:
                   .dispatch               the jitted call
                     serving.compile       first call of a program only
                   .fetch                  the download that waits
-                  .emit                   per-request bookkeeping
+                  .emit                   first tokens to their slots
               serving.step.reserve        chunk length, pages, preemption
               serving.decode_chunk | serving.spec_chunk
                 .build | .dispatch [serving.compile] | .fetch | .emit
+
+        A prefill's ``.fetch`` and ``.emit`` come after the chunk's
+        ``.dispatch`` where they were put off (``_prefill_group``).
         """
         with _trace.span("serving.step"):
             if self._deadlines_seen:
@@ -1984,6 +2075,12 @@ class ServingEngine:
                 self.stats.peak_pages_in_use, in_use)
             _monitor.set_gauge("serving.pages.in_use", in_use,
                                doc="KV pages currently allocated")
+            if self._recurrent:
+                st, alloc = self.stats, self.cache.alloc
+                st.state_rows_in_use = alloc.used_rows
+                st.peak_state_rows_in_use = max(st.peak_state_rows_in_use,
+                                                alloc.used_rows)
+                st.state_rows_assigned = alloc.rows_assigned
 
             live_idx = [i for i, s in enumerate(self.slots)
                         if s is not None and not s.done]
@@ -2000,12 +2097,14 @@ class ServingEngine:
                 # inside; pure host state — zero device syncs)
                 self._frame_pub.maybe_publish(self)
             if not live_idx:
+                self._first_tokens()
                 return bool(self.queue) or any(
                     s is not None for s in self.slots)
             with _trace.span("serving.step.reserve"):
                 C = self._pick_chunk(live_idx)
                 live_idx = self._ensure_chunk_capacity(live_idx, C)
             if not live_idx:
+                self._first_tokens()
                 return True
             if (self._spec_decode and C == self.turbo_chunk
                     and not any(self.slots[i].req.temperature > 0
@@ -2025,43 +2124,63 @@ class ServingEngine:
                                   live=len(live_idx)):
                 return self._chunk_step(live_idx, C)
 
+    def _block_tables(self, live_idx: List[int]) -> np.ndarray:
+        """The slot grid's block table; a slot that is not live reads no
+        page."""
+        live = set(live_idx)
+        return self.cache.block_tables(
+            [self.slots[i].req.rid if i in live else None
+             for i in range(self.num_slots)])
+
     def _chunk_step(self, live_idx: List[int], C: int) -> bool:
         """``C`` sequential decode steps over the slot grid as one
         program, and the one download that brings its tokens back."""
         with _trace.span("serving.decode_chunk.build"):
             B = self.num_slots
+            up = {}             # what this chunk uploads, in one call
+            if self._bt_dirty:
+                up["bt"] = self._block_tables(live_idx)
+                self._bt_dirty = False
             if self._state_dirty:
                 # (re)build the device-side slot state. The steady state —
                 # chunk after chunk with no join/retire/new-page — reuses the
                 # PREVIOUS chunk's returned device arrays untouched: the
                 # scheduler's host work then stays off the per-token path.
-                tokens = np.zeros(B, np.int32)
-                kv_len = np.zeros(B, np.int32)
-                done = np.ones(B, bool)
-                gen = np.zeros(B, np.int32)
-                temps = np.zeros(B, np.float32)
-                max_new = np.zeros(B, np.int32)
-                eos = np.full(B, -1, np.int32)
-                for i in live_idx:
-                    s = self.slots[i]
-                    tokens[i], kv_len[i], done[i] = s.pending, s.kv_len, False
-                    gen[i], temps[i] = s.gen, s.req.temperature
-                    max_new[i] = s.req.max_new_tokens
-                    if s.req.eos_token_id is not None:
-                        eos[i] = s.req.eos_token_id
-                self._dev.update(
-                    tokens=jnp.asarray(tokens), kv_len=jnp.asarray(kv_len),
-                    done=jnp.asarray(done), gen=jnp.asarray(gen),
-                    temps=jnp.asarray(temps), max_new=jnp.asarray(max_new),
-                    eos=jnp.asarray(eos))
-                self._sampled = any(self.slots[i].req.temperature > 0
-                                    for i in live_idx)
+                live = [self.slots[i] for i in live_idx]
+                at = np.asarray(live_idx, np.intp)
+
+                def col(values, dtype, fill=0):
+                    a = np.full(B, fill, dtype)
+                    a[at] = values
+                    return a
+
+                up.update(
+                    tokens=col([s.pending for s in live], np.int32),
+                    kv_len=col([s.kv_len for s in live], np.int32),
+                    done=col(False, bool, True),
+                    gen=col([s.gen for s in live], np.int32),
+                    temps=col([s.req.temperature for s in live], np.float32),
+                    max_new=col([s.req.max_new_tokens for s in live],
+                                np.int32),
+                    eos=col([-1 if s.req.eos_token_id is None
+                             else s.req.eos_token_id for s in live],
+                            np.int32, -1))
+                self._sampled = any(s.req.temperature > 0 for s in live)
+                if self._recurrent:
+                    # where each slot's sequence keeps its state: after a
+                    # compaction or a join this table moves, no state does
+                    with _trace.span("serving.step.state"):
+                        up["rows"] = self.cache.state_row_table(
+                            [s.req.rid if s is not None and not s.done
+                             else None for s in self.slots])
                 self._state_dirty = False
-            if self._bt_dirty:
-                seq_ids = [self.slots[i].req.rid
-                           if i in set(live_idx) else None for i in range(B)]
-                self._dev["bt"] = jnp.asarray(self.cache.block_tables(seq_ids))
-                self._bt_dirty = False
+            if up:
+                self._dev.update(jax.device_put(up))
+            for _, first, where in self._unfetched:
+                # a slot admitted in this step: its pending token is still
+                # on the device, and goes to its place there
+                self._dev["tokens"] = self._join(self._dev["tokens"], where,
+                                                 first)
             if self._sampled:
                 keys = np.zeros((C, B, 2), np.uint32)
                 for i in live_idx:
@@ -2074,10 +2193,9 @@ class ServingEngine:
 
         d = self._dev
         ck = self._chunk_fns[(C, self._sampled)]
-        ck_args = (self.params, self.cache.pool["k"],
-                   self.cache.pool["v"], d["bt"], d["tokens"],
-                   d["kv_len"], d["done"], d["gen"], keys, d["temps"],
-                   d["max_new"], d["eos"])
+        ck_args = (self.params, self.cache.pool, d["bt"], d.get("rows"),
+                   d["tokens"], d["kv_len"], d["done"], d["gen"], keys,
+                   d["temps"], d["max_new"], d["eos"])
         exec_rec = None
         ck_flops_share = None
         if _monitor.enabled():
@@ -2085,7 +2203,7 @@ class ServingEngine:
                 ("serving.decode_chunk", C, self._sampled),
                 f"serving.decode_chunk[c{C}"
                 f"{',sampled' if self._sampled else ''}]",
-                ck, ck_args, None, donated=(1, 2))
+                ck, ck_args, None, donated=(1,))
             from ..monitor import exectime as _exectime
             exec_rec = _exectime.maybe_sample(key, feed_last=False)
             # modeled-FLOPs attribution: the chunk program's registered
@@ -2099,9 +2217,9 @@ class ServingEngine:
                 ck_flops_share = ck_flops / len(live_idx)
         with _trace.span("serving.decode_chunk.dispatch"), \
                 self._first_call(ck):
-            pk, pv, tok, kvl, done_a, gen_a, emitted = ck(*ck_args)
-        self.cache.pool = {"k": pk, "v": pv}
+            self.cache.pool, tok, kvl, done_a, gen_a, emitted = ck(*ck_args)
         self._dev.update(tokens=tok, kv_len=kvl, done=done_a, gen=gen_a)
+        self._first_tokens()     # the prefills are done before the chunk is
         with _trace.span("serving.decode_chunk.fetch"):
             # ONE device->host transfer per chunk: every host-side fact
             # is derivable from the emitted grid (-1 = slot was done at
@@ -2118,10 +2236,11 @@ class ServingEngine:
         t_chunk = time.perf_counter() if _monitor.enabled() else None
         with _trace.span("serving.decode_chunk.emit"):
             new_tokens = 0
+            cols = emitted.T.tolist()        # a slot's steps, a row each
+            whole = bool((emitted >= 0).all())
             for i in live_idx:
                 s = self.slots[i]
-                toks = emitted[:, i]
-                toks = toks[toks >= 0].tolist()
+                toks = cols[i] if whole else [t for t in cols[i] if t >= 0]
                 if toks:
                     s.tokens.extend(toks)
                     new_tokens += len(toks)
@@ -2201,12 +2320,11 @@ class ServingEngine:
         precision an argmax near-tie can flip — exact in f32.)
         Rejected positions' KV stays in the pool as garbage masked out
         by sequence length and overwritten by later commits."""
+        self._first_tokens()           # a draft starts at the pending token
         with _trace.span("serving.spec_chunk.build"):
             B = self.num_slots
             if self._bt_dirty:
-                seq_ids = [self.slots[i].req.rid
-                           if i in set(live_idx) else None for i in range(B)]
-                self._dev["bt"] = jnp.asarray(self.cache.block_tables(seq_ids))
+                self._dev["bt"] = jnp.asarray(self._block_tables(live_idx))
                 self._bt_dirty = False
             drafts = np.zeros((B, C), np.int32)
             kv_len = np.zeros(B, np.int32)
@@ -2217,8 +2335,7 @@ class ServingEngine:
                 kv_len[i] = s.kv_len
                 live_m[i] = True
             vf = self._spec_fn(C)
-            vf_args = (self.params, self.cache.pool["k"],
-                       self.cache.pool["v"], self._dev["bt"],
+            vf_args = (self.params, self.cache.pool, self._dev["bt"],
                        jnp.asarray(drafts), jnp.asarray(kv_len),
                        jnp.asarray(live_m))
         exec_rec = None
@@ -2227,7 +2344,7 @@ class ServingEngine:
             key = self._record_serving_program(
                 ("serving.spec_chunk", C),
                 f"serving.spec_chunk[c{C}]", vf, vf_args, None,
-                donated=(1, 2))
+                donated=(1,))
             from ..monitor import exectime as _exectime
             exec_rec = _exectime.maybe_sample(key, feed_last=False)
             vf_flops = self._program_flops(key)
@@ -2235,8 +2352,7 @@ class ServingEngine:
                 vf_flops_share = vf_flops / len(live_idx)
         with _trace.span("serving.spec_chunk.dispatch"), \
                 self._first_call(vf):
-            pk, pv, preds_a = vf(*vf_args)
-        self.cache.pool = {"k": pk, "v": pv}
+            self.cache.pool, preds_a = vf(*vf_args)
         with _trace.span("serving.spec_chunk.fetch"):
             preds = np.asarray(preds_a)                  # [B, C]
         if exec_rec is not None:

@@ -14,12 +14,27 @@ Three layers:
   advance / fork / free). Pure Python+numpy; never touches the device.
 - ``PagedKVCache`` — the pool tensors (one page grid per layer) married
   to an allocator; owns layout and the block-table/length device views.
-- ``paged_prefill`` / ``paged_decode_step`` — pure-jax data plane with
-  the same (params, ..., config) shape as the ring-buffer
-  ``(init_cache, prefill, decode_step)`` contract in models/llama.py,
-  but generic over the model family: any module exposing the decoder
-  seam (``_qkv_proj``-compatible layers, ``decode_mlp``, ``_head``)
-  plugs in — llama and the MoE families both do.
+- ``cache_prefill`` / ``cache_decode_step`` / ``cache_prefill_shared`` /
+  ``cache_verify_window`` — pure-jax data plane over the WHOLE cache (one
+  pytree, taken and returned), generic over the model family: any module
+  exposing the decoder seam (``_qkv_proj``-compatible layers,
+  ``decode_mlp``, ``_head``) plugs in — llama and the MoE families both
+  do. A family whose block is not "attention, then MLP" composes it
+  itself round the program's attention core (``paged_block``, see
+  ``_block``); one that keeps a recurrent state a sequence declares its
+  leaves (``state_shapes``) and gives the mixer in two forms
+  (``mixer_prefill``, ``mixer_decode``): models/falcon_h1.py does both.
+  ``paged_prefill`` / ``paged_decode_step`` are the same two programs for
+  a caller that holds the two pool halves and nothing else.
+
+Two kinds of state under one manager: K/V pages a token, and (for a
+family that declares one) a recurrent state a SEQUENCE: leaves
+``cache["state"][name]`` of shape ``[L, rows + 1, ...]``. A sequence owns
+one row from ``alloc`` to ``free``; slots reach their rows through a row
+table (a block table of width 1), so moving a sequence to another slot
+copies nothing. The last row belongs to nobody: a prefill group's dummy
+rows and a decode grid's idle slots name it, and what lands there is
+never read.
 
 Pool layout: ``[L, num_pages, kv_heads, page_size, head_dim]``. The
 ISSUE/vLLM order puts page_size before kv_heads; the kv-head axis is
@@ -34,6 +49,7 @@ page 0 — the allocator owns the sentinel discipline.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -46,8 +62,8 @@ from ..models.llama import _head_logits, _mm, _qkv_proj, _rms
 from ..nn.functional.attention import rope_raw, rope_tables
 
 __all__ = ["PageAllocator", "PagedKVCache", "PrefixCache", "init_pool",
-           "paged_prefill", "paged_prefill_shared", "paged_decode_step",
-           "paged_verify_window"]
+           "cache_prefill", "cache_decode_step", "cache_prefill_shared",
+           "cache_verify_window", "paged_prefill", "paged_decode_step"]
 
 
 # ---------------------------------------------------------------------------
@@ -62,12 +78,18 @@ class PageAllocator:
     exception."""
 
     def __init__(self, num_pages: int, page_size: int,
-                 max_pages_per_seq: int):
+                 max_pages_per_seq: int, state_rows: int = 0):
         E.enforce(num_pages >= 1, f"num_pages must be >= 1, got {num_pages}")
         E.enforce(page_size >= 1, f"page_size must be >= 1, got {page_size}")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.max_pages_per_seq = int(max_pages_per_seq)
+        # rows of recurrent state (0: the family keeps none). A sequence
+        # takes one with its first pages and gives it back with them, so
+        # every path that frees a sequence frees its row.
+        self.state_rows = int(state_rows)
+        self._free_rows: List[int] = list(range(self.state_rows - 1, -1, -1))
+        self.rows_assigned = 0
         self._free: List[int] = list(range(num_pages - 1, -1, -1))
         self._ref = np.zeros(num_pages, np.int32)
         # prefix-cache pins: each held page carries exactly one extra
@@ -86,6 +108,14 @@ class PageAllocator:
     @property
     def used_pages(self) -> int:
         return self.num_pages - len(self._free)
+
+    @property
+    def used_rows(self) -> int:
+        return self.state_rows - len(self._free_rows)
+
+    def state_row(self, seq_id: int) -> int:
+        """The row of recurrent state this sequence owns."""
+        return self._seqs[seq_id]["row"]
 
     def pages_for(self, n_tokens: int) -> int:
         return -(-int(n_tokens) // self.page_size)
@@ -138,6 +168,11 @@ class PageAllocator:
             raise AssertionError("referenced page on the free list")
         if len(free) + int((self._ref > 0).sum()) != self.num_pages:
             raise AssertionError("leaked page: neither free nor referenced")
+        if self.state_rows:
+            held = [s["row"] for s in self._seqs.values()]
+            if sorted(held + self._free_rows) != list(range(self.state_rows)):
+                raise AssertionError(
+                    f"state rows drift: held={held} free={self._free_rows}")
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -149,6 +184,12 @@ class PageAllocator:
             self._ref[p] += 1
         return taken
 
+    def _new_seq(self, seq_id: int, pages: List[int]):
+        self._seqs[seq_id] = {"pages": pages, "len": 0}
+        if self.state_rows:
+            self._seqs[seq_id]["row"] = self._free_rows.pop()
+            self.rows_assigned += 1
+
     def alloc(self, seq_id: int, n_tokens: int) -> Optional[List[int]]:
         """Create a sequence with capacity for ``n_tokens`` (its written
         length starts at 0 — ``advance`` after the KV lands). None = OOM."""
@@ -158,10 +199,12 @@ class PageAllocator:
         E.enforce(need <= self.max_pages_per_seq,
                   f"{n_tokens} tokens need {need} pages > "
                   f"max_pages_per_seq {self.max_pages_per_seq}")
+        if self.state_rows and not self._free_rows:
+            return None
         pages = self._take(need)
         if pages is None:
             return None
-        self._seqs[seq_id] = {"pages": pages, "len": 0}
+        self._new_seq(seq_id, pages)
         return pages
 
     def alloc_prefix(self, seq_id: int, shared_pages: List[int],
@@ -177,6 +220,7 @@ class PageAllocator:
         covers any later aliasing. None = OOM, state unchanged."""
         E.enforce(seq_id not in self._seqs,
                   f"sequence {seq_id} already allocated")
+        self._no_state("alloc_prefix")
         need = self.pages_for(n_tokens)
         E.enforce(need <= self.max_pages_per_seq,
                   f"{n_tokens} tokens need {need} pages > "
@@ -228,9 +272,10 @@ class PageAllocator:
         unchanged)."""
         s = self._seqs[seq_id]
         need_total = self.pages_for(total_tokens)
-        E.enforce(need_total <= self.max_pages_per_seq,
-                  f"{total_tokens} tokens need {need_total} pages > "
-                  f"max_pages_per_seq {self.max_pages_per_seq}")
+        if need_total > self.max_pages_per_seq:   # (no message built else)
+            raise E.PreconditionNotMetError(
+                f"{total_tokens} tokens need {need_total} pages > "
+                f"max_pages_per_seq {self.max_pages_per_seq}")
         grow = max(0, need_total - len(s["pages"]))
         first_written = s["len"] // self.page_size
         cow_idx = [i for i in range(first_written,
@@ -253,9 +298,10 @@ class PageAllocator:
         """Record ``n_tokens`` written; capacity must already exist."""
         s = self._seqs[seq_id]
         new_len = s["len"] + int(n_tokens)
-        E.enforce(new_len <= len(s["pages"]) * self.page_size,
-                  f"advance past capacity: {new_len} tokens > "
-                  f"{len(s['pages'])} pages")
+        if new_len > len(s["pages"]) * self.page_size:
+            raise E.PreconditionNotMetError(
+                f"advance past capacity: {new_len} tokens > "
+                f"{len(s['pages'])} pages")
         s["len"] = new_len
 
     def fork(self, src_id: int, dst_id: int) -> List[int]:
@@ -264,14 +310,27 @@ class PageAllocator:
         side copy-on-writes the tail page."""
         E.enforce(dst_id not in self._seqs,
                   f"sequence {dst_id} already allocated")
+        self._no_state("fork")
         s = self._seqs[src_id]
         for p in s["pages"]:
             self._ref[p] += 1
         self._seqs[dst_id] = {"pages": list(s["pages"]), "len": s["len"]}
         return list(s["pages"])
 
+    def _no_state(self, what: str):
+        """Pages are shared by refcount; a recurrent state is one row a
+        sequence, and sharing a prefix or forking would need a snapshot
+        of it at the shared position, which nothing takes yet."""
+        E.enforce(not self.state_rows,
+                  f"{what}: sequences here keep a recurrent state beside "
+                  f"their pages, and there is no state snapshot to share "
+                  f"or copy (pages alone can be)",
+                  error=E.UnimplementedError)
+
     def free(self, seq_id: int):
         s = self._seqs.pop(seq_id)
+        if "row" in s:
+            self._free_rows.append(s["row"])
         for p in s["pages"]:
             self._ref[p] -= 1
             E.enforce(self._ref[p] >= 0, f"double free of page {p}")
@@ -420,11 +479,16 @@ class PrefixCache:
 # ---------------------------------------------------------------------------
 
 def init_pool(config, num_pages: int, page_size: int, dtype=None,
-              kv_quant: bool = False) -> dict:
+              kv_quant: bool = False, state_shapes: Optional[dict] = None,
+              state_rows: int = 0) -> dict:
     """Fresh page pools, one [P, kv, ps, hd] grid per layer (stacked on
     a leading layer axis to ride the decode lax.scan, like the ring
-    cache). With ``kv_quant`` (FLAGS_serving_kv_quant) each pool leaf
-    is the quantized pair {"q": int8 codes, "s": f32 [L, P, kv] scale
+    cache). With ``state_shapes`` (a recurrent family's
+    ``state_shapes(config)``: leaf name -> (shape a row a layer, type))
+    the cache also holds ``"state"``: each leaf ``[L, state_rows + 1,
+    ...]`` of zeros, the last row owned by no sequence. With
+    ``kv_quant`` (FLAGS_serving_kv_quant) each pool leaf is the
+    quantized pair {"q": int8 codes, "s": f32 [L, P, kv] scale
     plane} — per-page per-kv-head write-time absmax scales ride the
     SAME page axis as their codes, so every page-granular operation
     (CoW copy, fork refcount, scatter-with-drop) moves code and scale
@@ -436,8 +500,14 @@ def init_pool(config, num_pages: int, page_size: int, dtype=None,
         def leaf():
             return {"q": jnp.zeros(shape, jnp.int8),
                     "s": jnp.zeros(shape[:3], jnp.float32)}
-        return {"k": leaf(), "v": leaf()}
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+        pool = {"k": leaf(), "v": leaf()}
+    else:
+        pool = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    if state_shapes:
+        pool["state"] = {
+            name: jnp.zeros((shape[0], state_rows + 1) + tuple(row), t)
+            for name, (row, t) in state_shapes.items()}
+    return pool
 
 
 class PagedKVCache:
@@ -447,22 +517,28 @@ class PagedKVCache:
 
     def __init__(self, config, num_pages: int, page_size: int,
                  max_pages_per_seq: int, dtype=None,
-                 kv_quant: bool = False):
+                 kv_quant: bool = False,
+                 state_shapes: Optional[dict] = None, state_rows: int = 0):
         self.config = config
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         self.max_pages_per_seq = int(max_pages_per_seq)
         self.kv_quant = bool(kv_quant)
+        self.state_rows = int(state_rows) if state_shapes else 0
         self.pool = init_pool(config, num_pages, page_size, dtype,
-                              kv_quant=self.kv_quant)
-        self.alloc = PageAllocator(num_pages, page_size, max_pages_per_seq)
-        # page-row copy over EVERY pool leaf: the quantized pool's
-        # scale planes share the page axis (axis 1) with their codes,
-        # so one tree_map mirrors CoW onto codes and scales exactly —
-        # the invariant the fork/CoW scale tests pin
+                              kv_quant=self.kv_quant,
+                              state_shapes=state_shapes,
+                              state_rows=self.state_rows)
+        self.alloc = PageAllocator(num_pages, page_size, max_pages_per_seq,
+                                   state_rows=self.state_rows)
+        # page-row copy over EVERY leaf of the two page pools: the
+        # quantized pool's scale planes share the page axis (axis 1) with
+        # their codes, so one tree_map mirrors CoW onto codes and scales
+        # exactly — the invariant the fork/CoW scale tests pin
         self._copy1 = jax.jit(
-            lambda pool, src, dst: jax.tree.map(
-                lambda a: a.at[:, dst].set(a[:, src]), pool),
+            lambda pool, src, dst: {**pool, **jax.tree.map(
+                lambda a: a.at[:, dst].set(a[:, src]),
+                {"k": pool["k"], "v": pool["v"]})},
             donate_argnums=(0,))
 
     def apply_cow(self, pairs):
@@ -477,10 +553,25 @@ class PagedKVCache:
         become all-sentinel rows."""
         width = self.max_pages_per_seq if width is None else width
         rows = np.full((len(seq_ids), width), self.num_pages, np.int32)
-        for i, sid in enumerate(seq_ids):
-            if sid is not None:
-                rows[i] = self.alloc.block_row(sid, width)
+        # one scatter for the whole table, a row's pages into its first
+        # columns: the engine builds it anew for every chunk in which a
+        # slot or a page changed, while the device waits
+        pages = [() if sid is None else self.alloc._seqs[sid]["pages"]
+                 for sid in seq_ids]
+        lens = np.fromiter(map(len, pages), np.int64, len(pages))
+        E.enforce(int(lens.max(initial=0)) <= width,
+                  f"a sequence holds more pages than the table's width "
+                  f"{width}")
+        rows[np.arange(width) < lens[:, None]] = np.fromiter(
+            itertools.chain.from_iterable(pages), np.int32, int(lens.sum()))
         return rows
+
+    def state_row_table(self, seq_ids) -> np.ndarray:
+        """[len(seq_ids)] rows of recurrent state, a slot each; None
+        entries (empty slots) name the last row, which nobody owns."""
+        return np.asarray([self.state_rows if sid is None
+                           else self.alloc.state_row(sid)
+                           for sid in seq_ids], np.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -576,68 +667,169 @@ def _attn_out(x, a, lp):
     return x + _mm(a.astype(x.dtype), lp["wo"])
 
 
-def paged_prefill(family, params, ids, config, pool_k, pool_v, page_rows,
-                  slen):
+def _block(family, x, lp, c, cos, sin, attend, mix=None):
+    """One decoder block round the program's attention core: the ONE seam
+    of the four programs below. ``attend(q, k, v) -> (a [B, S, heads *
+    head_dim], extra)`` is the program's own (plain causal attention, the
+    paged kernel behind a page append, a gather of cached pages);
+    ``extra`` is whatever it has to hand on (fresh K/V, updated pool
+    halves). Returns (x', attend's extra, the mixer's extra or None).
+
+    A family whose block is "ln1, q/k/v, rope, attention, wo, residual,
+    then ``decode_mlp``" needs nothing more (llama, moe). Another gives
+    ``paged_block(x, lp, config, cos, sin, attend, mix)`` and composes the
+    same pieces itself; ``mix(h, lp) -> (m [B, S, D], extra)`` is then the
+    program's way to the family's mixer, carrying the recurrent state."""
+    compose = getattr(family, "paged_block", None)
+    if compose is not None:
+        return compose(x, lp, c, cos, sin, attend, mix)
+    q, k, v = _qkv_rope(x, lp, c, cos, sin)
+    a, extra = attend(q, k, v)
+    x = _attn_out(x, a, lp)
+    return family.decode_mlp(x, lp, c), extra, None
+
+
+def _embed(family, params, ids, c):
+    scaled = getattr(family, "embed_tokens", None)
+    return jnp.take(params["embed"], ids, axis=0) if scaled is None \
+        else scaled(params, ids, c)
+
+
+def _logits(family, params, x, c):
+    """Float32 logits of hidden states that passed the last norm."""
+    scaled = getattr(family, "head_logits", None)
+    return _head_logits(x, family._head(params, c)) if scaled is None \
+        else scaled(params, x, c)
+
+
+def _pool_shape(pool_k):
+    return (pool_k["q"] if isinstance(pool_k, dict) else pool_k).shape
+
+
+def _no_state(cache, what: str):
+    E.enforce("state" not in cache,
+              f"{what}: this cache holds a recurrent state a sequence, "
+              f"and {what} would need a snapshot of it at the shared "
+              f"position or a rollback of rejected tokens; neither is "
+              f"written", error=E.UnimplementedError)
+
+
+# Rows of a recurrent family's prefill group that go through the blocks at
+# once. A program keeps each row's state of every layer until its last
+# write (4 MiB a row a layer at Falcon-H1-34B's widths), so a group wider
+# than this is taken in passes: a group of all 128 slots at once (a cold
+# start, a benchmark's warm-up) would otherwise ask for 3 GiB of states and
+# 2 GiB of feed-forward activations beside a full chip. The scheduler's own
+# groups (1, 2, 4, 8 wide) are one pass.
+_PREFILL_PASS_ROWS = 8
+
+
+def _rows_a_pass(G: int) -> int:
+    """The largest divisor of ``G`` that is at most ``_PREFILL_PASS_ROWS``:
+    passes are equal, so that one ``lax.scan`` runs them."""
+    return max(d for d in range(1, min(G, _PREFILL_PASS_ROWS) + 1)
+               if G % d == 0)
+
+
+def cache_prefill(family, params, ids, config, cache, page_rows, slen,
+                  state_rows=None):
     """Consume a batch of padded prompts [G, S_pad] (S_pad a page
     multiple; rows are INDEPENDENT requests): writes every covered page
     of K/V into ``page_rows`` [G, S_pad/ps] (sentinel rows drop —
     padding beyond a request's owned pages never lands; an all-sentinel
-    row is a group-padding dummy) and returns (pool_k', pool_v', logits
-    [G, V] at each row's position ``slen[g]``-1). Identical layer math
-    to the family's ring-buffer prefill, so greedy decode parity holds
-    token-for-token."""
+    row is a group-padding dummy) and returns (cache', logits [G, V] at
+    each row's position ``slen[g]``-1). Identical layer math to the
+    family's ring-buffer prefill, so greedy decode parity holds
+    token-for-token. A recurrent family's state lands in ``state_rows``
+    [G] as it is after ``slen[g]`` tokens, not after the padding; a
+    dummy row names the row nobody owns. Rows being independent, a group
+    of more than ``_PREFILL_PASS_ROWS`` rows with a state is taken in
+    equal passes, one after another in the same program."""
+    G = ids.shape[0]
+    if "state" in cache:
+        per = _rows_a_pass(G)
+        if per < G:
+            def one_pass(cache, xs):
+                return _prefill_pass(family, params, xs[0], config, cache,
+                                     *xs[1:])
+
+            cache, logits = lax.scan(one_pass, cache, jax.tree.map(
+                lambda a: a.reshape(G // per, per, *a.shape[1:]),
+                (ids, page_rows, slen, state_rows)))
+            return cache, logits.reshape(G, -1)
+    return _prefill_pass(family, params, ids, config, cache, page_rows,
+                         slen, state_rows)
+
+
+def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
+                  state_rows):
     c = config
     G, S = ids.shape
-    quant = isinstance(pool_k, dict)
-    L, P, kv, ps, hd = (pool_k["q"] if quant else pool_k).shape
+    pool_k, pool_v = cache["k"], cache["v"]
+    L, P, kv, ps, hd = _pool_shape(pool_k)
     E.enforce(S % ps == 0, f"padded prompt {S} not a multiple of "
               f"page_size {ps}")
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], ids, axis=0)
+        x = _embed(family, params, ids, c)
         cos, sin = rope_tables(S, c.head_dim, theta=c.rope_theta)
 
     from ..nn.functional.attention import sdpa_raw
 
-    def step(carry, lp):
-        x = carry
-        q, k, v = _qkv_rope(x, lp, c, cos, sin)
+    def attend(q, k, v):
         with jax.named_scope("attn.kernel"):
-            a = sdpa_raw(q, k, v, is_causal=True).reshape(G, S, -1)
-        x = _attn_out(x, a, lp)
-        return family.decode_mlp(x, lp, c), (k, v)
+            return sdpa_raw(q, k, v, is_causal=True).reshape(G, S, -1), \
+                (k, v)
 
-    x, (ks, vs) = lax.scan(step, x, params["layers"])
+    mix = None
+    if "state" in cache:
+        def mix(h, lp):
+            return family.mixer_prefill(h, lp, c, slen)
+
+    def step(carry, lp):
+        x, kvs, st = _block(family, carry, lp, c, cos, sin, attend, mix)
+        return x, (kvs, st)
+
+    x, ((ks, vs), st) = lax.scan(step, x, params["layers"])
     npad = S // ps
     with jax.named_scope("attn.kv_write"):
         # [L, G, S, kv, hd] -> [L, G, npad, kv, ps, hd] page grids
         ks = jnp.moveaxis(ks.reshape(L, G, npad, ps, kv, hd), 4, 3)
         vs = jnp.moveaxis(vs.reshape(L, G, npad, ps, kv, hd), 4, 3)
-    pool_k = _kv_pool_write(pool_k, ks, page_rows)
-    pool_v = _kv_pool_write(pool_v, vs, page_rows)
+    out = {"k": _kv_pool_write(pool_k, ks, page_rows),
+           "v": _kv_pool_write(pool_v, vs, page_rows)}
+    if st is not None:
+        with jax.named_scope("ssm.scan"):
+            out["state"] = jax.tree.map(
+                lambda a, n: a.at[:, state_rows].set(n.astype(a.dtype)),
+                cache["state"], st)
     with jax.named_scope("head"):
         x = _rms(x, params["ln_f"], c.rms_norm_eps)
         last = jnp.take_along_axis(
             x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
-        logits = _head_logits(last, family._head(params, c))
-    return pool_k, pool_v, logits
+        logits = _logits(family, params, last, c)
+    return out, logits
 
 
-def paged_decode_step(family, params, pool_k, pool_v, block_tables,
-                      lengths, tokens, config):
+def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
+                      config, state_rows=None):
     """One incremental step over the fixed slot grid. ``tokens`` [B]
     sit at position ``lengths``-1 of their sequences (``lengths`` is the
     valid KV count INCLUDING each new token; 0 marks an inactive slot —
     its write is dropped and its logits row is garbage the caller
-    masks). Returns (pool_k', pool_v', logits [B, V])."""
+    masks). Returns (cache', logits [B, V]). A recurrent family's state
+    is the layer scan's CARRY, whole, and each layer updates its rows in
+    place (``state_rows`` [B]; an inactive slot is sent to the row nobody
+    owns, so its own row is untouched): the pools ride the scan as
+    ``xs``/``ys`` and are held twice, the state must not be."""
     c = config
     B = tokens.shape[0]
+    pool_k, pool_v = cache["k"], cache["v"]
     quant = isinstance(pool_k, dict)
-    L, P, kv, ps, hd = (pool_k["q"] if quant else pool_k).shape
-    maxp = block_tables.shape[1]
+    L, P, kv, ps, hd = _pool_shape(pool_k)
     n = lengths
     posw = jnp.maximum(n - 1, 0)                       # [B] write position
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]
+        x = _embed(family, params, tokens, c)[:, None, :]
         # rope angles computed directly at the ragged positions
         # (identical floats to a rope_tables row: same product, same cos
         # — but a fused elementwise chain instead of two table gathers
@@ -652,57 +844,96 @@ def paged_decode_step(family, params, pool_k, pool_v, block_tables,
     rows = jnp.take_along_axis(block_tables, page_idx[:, None],
                                axis=1)[:, 0]
     rows = jnp.where(n > 0, rows, P)                   # inactive: drop
-    kvi = jnp.arange(kv)
 
     from ..kernels import dispatched_paged_attention
 
-    def step(carry, xs):
-        x = carry
-        lp, kpl, vpl = xs                              # [P, kv, ps, hd]
-        q, k, v = _qkv_rope(x, lp, c, cos, sin)
-        kpl = _kv_page_append(kpl, rows, off, k[:, 0], P)
-        vpl = _kv_page_append(vpl, rows, off, v[:, 0], P)
-        with jax.named_scope("attn.kernel"):
-            if quant:
-                a = dispatched_paged_attention(
-                    q[:, 0], kpl["q"], vpl["q"], block_tables, n,
-                    k_scales=kpl["s"], v_scales=vpl["s"])
-            else:
-                a = dispatched_paged_attention(q[:, 0], kpl, vpl,
-                                               block_tables, n)
-        x = _attn_out(x, a.reshape(B, 1, -1), lp)
-        return family.decode_mlp(x, lp, c), (kpl, vpl)
+    state = cache.get("state")
+    xs = (params["layers"], pool_k, pool_v)
+    if state is not None:
+        nobody = jax.tree.leaves(state)[0].shape[1] - 1
+        srows = jnp.where(n > 0, state_rows, nobody)
+        xs += (jnp.arange(L),)
 
-    x, (kc, vc) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
+    def step(carry, xs):
+        x, state = carry
+        lp, kpl, vpl, *layer = xs                      # [P, kv, ps, hd]
+
+        def attend(q, k, v):
+            kp = _kv_page_append(kpl, rows, off, k[:, 0], P)
+            vp = _kv_page_append(vpl, rows, off, v[:, 0], P)
+            with jax.named_scope("attn.kernel"):
+                if quant:
+                    a = dispatched_paged_attention(
+                        q[:, 0], kp["q"], vp["q"], block_tables, n,
+                        k_scales=kp["s"], v_scales=vp["s"])
+                else:
+                    a = dispatched_paged_attention(q[:, 0], kp, vp,
+                                                   block_tables, n)
+            return a.reshape(B, 1, -1), (kp, vp)
+
+        mix = None
+        if state is not None:
+            def mix(h, lp):
+                return family.mixer_decode(h, lp, c, state, layer[0], srows)
+
+        x, kvs, new = _block(family, x, lp, c, cos, sin, attend, mix)
+        return (x, new), kvs
+
+    (x, state), (kc, vc) = lax.scan(step, (x, state), xs)
+    out = {"k": kc, "v": vc}
+    if state is not None:
+        out["state"] = state
     with jax.named_scope("head"):
         x = _rms(x, params["ln_f"], c.rms_norm_eps)
-        logits = _head_logits(x[:, 0, :], family._head(params, c))
-    return kc, vc, logits
+        logits = _logits(family, params, x[:, 0, :], c)
+    return out, logits
 
 
-def paged_prefill_shared(family, params, ids, config, pool_k, pool_v,
-                         page_rows, slen, ctx_rows):
+def paged_prefill(family, params, ids, config, pool_k, pool_v, page_rows,
+                  slen):
+    """``cache_prefill`` for a caller that holds the two pool halves and
+    nothing else: (pool_k', pool_v', logits)."""
+    cache, logits = cache_prefill(family, params, ids, config,
+                                  {"k": pool_k, "v": pool_v}, page_rows,
+                                  slen)
+    return cache["k"], cache["v"], logits
+
+
+def paged_decode_step(family, params, pool_k, pool_v, block_tables,
+                      lengths, tokens, config):
+    """``cache_decode_step`` over the two pool halves alone: (pool_k',
+    pool_v', logits)."""
+    cache, logits = cache_decode_step(
+        family, params, {"k": pool_k, "v": pool_v}, block_tables, lengths,
+        tokens, config)
+    return cache["k"], cache["v"], logits
+
+
+def cache_prefill_shared(family, params, ids, config, cache, page_rows,
+                         slen, ctx_rows):
     """Tail-only prefill over a SHARED cached prefix: every row owns
     ``ctx_rows`` [G, ncp] pages of committed prefix KV (the radix
     cache's, forked by refcount — all rows share the same static
     cached length ncp*ps) and prefills only its uncached tail ``ids``
     [G, S_tail] into ``page_rows`` (sentinel drops, as in
-    ``paged_prefill``). Tail queries attend the gathered prefix pages
+    ``cache_prefill``). Tail queries attend the gathered prefix pages
     plus causally within the tail, with rope at the true absolute
     positions, so logits at ``slen``-1 (tail-local) are identical to a
-    full prefill at position ncp*ps+slen-1. Returns (pool_k', pool_v',
-    logits [G, V])."""
+    full prefill at position ncp*ps+slen-1. Returns (cache', logits
+    [G, V]). Pages alone can be shared: a cache that holds a recurrent
+    state is refused."""
     c = config
     G, S = ids.shape
-    quant = isinstance(pool_k, dict)
-    L, P, kv, ps, hd = (pool_k["q"] if quant else pool_k).shape
+    _no_state(cache, "a shared-prefix prefill")
+    pool_k, pool_v = cache["k"], cache["v"]
+    L, P, kv, ps, hd = _pool_shape(pool_k)
     ncp = ctx_rows.shape[1]
     E.enforce(S % ps == 0, f"padded tail {S} not a multiple of "
               f"page_size {ps}")
     E.enforce(ncp >= 1, "shared prefill needs a cached prefix")
     ctx = ncp * ps
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], ids, axis=0)
+        x = _embed(family, params, ids, c)
         cos, sin = rope_tables(ctx + S, c.head_dim, theta=c.rope_theta)
         cos, sin = cos[ctx:], sin[ctx:]
     # key t (prefix ++ tail token-major) visible to tail query i iff
@@ -713,39 +944,41 @@ def paged_prefill_shared(family, params, ids, config, pool_k, pool_v,
     from ..nn.functional.attention import sdpa_raw
 
     def step(carry, xs):
-        x = carry
         lp, kpl, vpl = xs
-        q, k, v = _qkv_rope(x, lp, c, cos, sin)
-        with jax.named_scope("attn.kernel"):
-            # cached prefix pages, token-major: [G, ncp, kv, ps, hd] ->
-            # [G, ctx, kv, hd] (rope already applied when they were
-            # written; quantized pools dequantize in the gather)
-            ck = jnp.swapaxes(_kv_pool_gather(kpl, ctx_rows, k.dtype),
-                              2, 3).reshape(G, ctx, kv, hd)
-            cv = jnp.swapaxes(_kv_pool_gather(vpl, ctx_rows, v.dtype),
-                              2, 3).reshape(G, ctx, kv, hd)
-            ka = jnp.concatenate([ck, k], axis=1)
-            va = jnp.concatenate([cv, v], axis=1)
-            a = sdpa_raw(q, ka, va, attn_mask=mask).reshape(G, S, -1)
-        x = _attn_out(x, a, lp)
-        return family.decode_mlp(x, lp, c), (k, v)
+
+        def attend(q, k, v):
+            with jax.named_scope("attn.kernel"):
+                # cached prefix pages, token-major: [G, ncp, kv, ps, hd]
+                # -> [G, ctx, kv, hd] (rope already applied when they
+                # were written; quantized pools dequantize in the gather)
+                ck = jnp.swapaxes(_kv_pool_gather(kpl, ctx_rows, k.dtype),
+                                  2, 3).reshape(G, ctx, kv, hd)
+                cv = jnp.swapaxes(_kv_pool_gather(vpl, ctx_rows, v.dtype),
+                                  2, 3).reshape(G, ctx, kv, hd)
+                ka = jnp.concatenate([ck, k], axis=1)
+                va = jnp.concatenate([cv, v], axis=1)
+                a = sdpa_raw(q, ka, va, attn_mask=mask).reshape(G, S, -1)
+            return a, (k, v)
+
+        x, kvs, _ = _block(family, carry, lp, c, cos, sin, attend)
+        return x, kvs
 
     x, (ks, vs) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
     npad = S // ps
     with jax.named_scope("attn.kv_write"):
         ks = jnp.moveaxis(ks.reshape(L, G, npad, ps, kv, hd), 4, 3)
         vs = jnp.moveaxis(vs.reshape(L, G, npad, ps, kv, hd), 4, 3)
-    pool_k = _kv_pool_write(pool_k, ks, page_rows)
-    pool_v = _kv_pool_write(pool_v, vs, page_rows)
+    out = {"k": _kv_pool_write(pool_k, ks, page_rows),
+           "v": _kv_pool_write(pool_v, vs, page_rows)}
     with jax.named_scope("head"):
         x = _rms(x, params["ln_f"], c.rms_norm_eps)
         last = jnp.take_along_axis(
             x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
-        logits = _head_logits(last, family._head(params, c))
-    return pool_k, pool_v, logits
+        logits = _logits(family, params, last, c)
+    return out, logits
 
 
-def paged_verify_window(family, params, tokens, config, pool_k, pool_v,
+def cache_verify_window(family, params, tokens, config, cache,
                         block_tables, kv_len, live):
     """Speculative-decode verify: process a drafted window ``tokens``
     [B, C] sitting at positions ``kv_len``..``kv_len``+C-1 of each
@@ -753,19 +986,23 @@ def paged_verify_window(family, params, tokens, config, pool_k, pool_v,
     block-table pages first (dropped where ``live`` is False), then
     every window query attends the sequence's full paged context plus
     causally within the window. C-fold fewer sequential model passes
-    than C ``paged_decode_step`` calls; identical math per position, so
+    than C ``cache_decode_step`` calls; identical math per position, so
     greedy argmax over the returned logits [B, C, V] reproduces the
     sequential chunk token-for-token. The host accepts the longest
     draft-matching run and simply does not ``advance`` past it —
-    rejected positions' KV is masked garbage until overwritten."""
+    rejected positions' KV is masked garbage until overwritten. A
+    recurrent state cannot be un-advanced that way: a cache that holds
+    one is refused. Returns (cache', logits)."""
     c = config
     B, C = tokens.shape
+    _no_state(cache, "a speculative verify window")
+    pool_k, pool_v = cache["k"], cache["v"]
     quant = isinstance(pool_k, dict)
-    L, P, kv, ps, hd = (pool_k["q"] if quant else pool_k).shape
+    L, P, kv, ps, hd = _pool_shape(pool_k)
     maxp = block_tables.shape[1]
     pos = kv_len[:, None] + jnp.arange(C)[None, :]          # [B, C]
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = _embed(family, params, tokens, c)
         inv = 1.0 / (c.rope_theta ** (
             jnp.arange(0, c.head_dim, 2, jnp.float32) / c.head_dim))
         freqs = pos.astype(jnp.float32)[:, :, None] * inv[None, None, :]
@@ -822,34 +1059,38 @@ def paged_verify_window(family, params, tokens, config, pool_k, pool_v,
     from ..nn.functional.attention import sdpa_raw
 
     def step(carry, xs):
-        x = carry
         lp, kpl, vpl = xs
-        q, k, v = _qkv_rope(x, lp, c, cos, sin)
-        if quant:
-            kpl = _window_rewrite(kpl, k)
-            vpl = _window_rewrite(vpl, v)
-        else:
-            with jax.named_scope("attn.kv_write"):
-                kpl = kpl.at[rows[:, :, None], kvi[None, None, :],
-                             off[:, :, None]].set(
-                    k.astype(kpl.dtype), mode="drop",
-                    unique_indices=True)
-                vpl = vpl.at[rows[:, :, None], kvi[None, None, :],
-                             off[:, :, None]].set(
-                    v.astype(vpl.dtype), mode="drop",
-                    unique_indices=True)
-        with jax.named_scope("attn.kernel"):
-            ck = jnp.swapaxes(_kv_pool_gather(kpl, block_tables, q.dtype),
-                              2, 3).reshape(B, maxp * ps, kv, hd)
-            cv = jnp.swapaxes(_kv_pool_gather(vpl, block_tables, q.dtype),
-                              2, 3).reshape(B, maxp * ps, kv, hd)
-            a = sdpa_raw(q, ck, cv,
-                         attn_mask=mask[:, None]).reshape(B, C, -1)
-        x = _attn_out(x, a, lp)
-        return family.decode_mlp(x, lp, c), (kpl, vpl)
+
+        def attend(q, k, v):
+            if quant:
+                kp = _window_rewrite(kpl, k)
+                vp = _window_rewrite(vpl, v)
+            else:
+                with jax.named_scope("attn.kv_write"):
+                    kp = kpl.at[rows[:, :, None], kvi[None, None, :],
+                                off[:, :, None]].set(
+                        k.astype(kpl.dtype), mode="drop",
+                        unique_indices=True)
+                    vp = vpl.at[rows[:, :, None], kvi[None, None, :],
+                                off[:, :, None]].set(
+                        v.astype(vpl.dtype), mode="drop",
+                        unique_indices=True)
+            with jax.named_scope("attn.kernel"):
+                ck = jnp.swapaxes(
+                    _kv_pool_gather(kp, block_tables, q.dtype),
+                    2, 3).reshape(B, maxp * ps, kv, hd)
+                cv = jnp.swapaxes(
+                    _kv_pool_gather(vp, block_tables, q.dtype),
+                    2, 3).reshape(B, maxp * ps, kv, hd)
+                a = sdpa_raw(q, ck, cv,
+                             attn_mask=mask[:, None]).reshape(B, C, -1)
+            return a, (kp, vp)
+
+        x, kvs, _ = _block(family, carry, lp, c, cos, sin, attend)
+        return x, kvs
 
     x, (kc, vc) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
     with jax.named_scope("head"):
         x = _rms(x, params["ln_f"], c.rms_norm_eps)
-        logits = _head_logits(x, family._head(params, c))
-    return kc, vc, logits
+        logits = _logits(family, params, x, c)
+    return {"k": kc, "v": vc}, logits

@@ -3,7 +3,9 @@
 Reference capability: paddle/phi/kernels/fusion/ (52 fused CUDA kernels) and
 the flash-attn wrapper (gpu/flash_attn_kernel.cu). TPU-native: hand-written
 pallas kernels for the ops where XLA's automatic fusion is not enough —
-flash attention (tiled online softmax on the MXU) and fused RMSNorm; the
+flash attention (tiled online softmax on the MXU), paged decode attention,
+the in-place recurrent state update of a Mamba-2 layer
+(``ssm_state_update``) and fused RMSNorm; the
 rest of the reference's fused set (bias+act, rope, swiglu) is left to XLA
 fusion, which already emits single kernels for those elementwise chains.
 
@@ -24,6 +26,7 @@ from . import flash_attention as _fa
 from . import fused_ce as _fce
 from . import paged_attention as _pa
 from . import rms_norm as _rn
+from . import ssm as _ssm
 from .ring_attention import ring_attention  # noqa
 
 flash_attention = _fa.flash_attention
@@ -34,11 +37,16 @@ fused_rms_norm = _rn.rms_norm
 fused_cross_entropy = _fce.fused_cross_entropy
 ragged_paged_attention = _pa.ragged_paged_attention
 paged_attention_ref = _pa.paged_attention_ref
+ssm_state_update = _ssm.ssm_state_update
+ssm_state_update_ref = _ssm.ssm_state_update_ref
+ssd_chunked_scan = _ssm.ssd_chunked_scan
 
 __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "dispatched_fused_ce", "ring_attention",
            "ragged_paged_attention", "paged_attention_ref",
            "dispatched_paged_attention",
+           "ssm_state_update", "ssm_state_update_ref", "ssd_chunked_scan",
+           "dispatched_ssm_update",
            "flash_attention_segments", "segment_attention_ref",
            "count_skipped_blocks", "dispatched_segment_attention",
            "register", "unregister", "dispatch_stats", "reset_dispatch_stats"]
@@ -54,10 +62,21 @@ _DISPATCH_STATS = {"flash": 0, "flash_fallback": 0,
                    "fused_ce": 0, "fused_ce_fallback": 0,
                    "paged": 0, "paged_fallback": 0,
                    "paged_quant": 0, "paged_quant_fallback": 0,
-                   "varlen": 0, "varlen_fallback": 0}
+                   "varlen": 0, "varlen_fallback": 0,
+                   "ssm": 0, "ssm_fallback": 0}
 
 
 def dispatch_stats() -> dict:
+    """How often each dispatcher traced its kernel into a program, and
+    how often the plain-XLA fallback (``*_fallback``). The table, kernel
+    by counter: ``flash`` flash attention forward and backward
+    (``flash_attention.py``); ``rms`` fused RMSNorm; ``fused_ce`` the
+    blockwise cross entropy; ``paged`` / ``paged_quant`` the paged decode
+    attention ``paged_decode_attn`` and its int8-page arm; ``varlen`` the
+    segment (packed) flash kernels; ``ssm`` the in-place recurrent state
+    update ``ssm_state_update`` (``ssm.py``), whose fallback gathers the
+    rows, updates them and scatters them back. A serving cell asserts
+    ``paged_fallback`` and ``ssm_fallback`` stay 0."""
     return dict(_DISPATCH_STATS)
 
 
@@ -189,6 +208,22 @@ def dispatched_paged_attention(q, k_pages, v_pages, block_tables, lengths,
     return _pa.paged_attention_ref(
         q, k_pages, v_pages, block_tables, lengths, scale=scale,
         k_scales=k_scales, v_scales=v_scales)
+
+
+def dispatched_ssm_update(state, layer, rows, decay, dtx, b, c):
+    """One token a slot through a Mamba-2 layer's recurrence against the
+    state ``[L, rows, H, N, P]`` (``kernels/ssm.py``), with the counter
+    discipline of the others: the Pallas kernel ``ssm_state_update`` on a
+    TPU where the shapes are supported (the state updated in place, its
+    rows found through the row table), the gather / update / scatter in
+    plain XLA elsewhere (tier-1's CPU path, a state that is not float32).
+    Returns (state', y [B, H, P] float32)."""
+    if _on_tpu() and _ssm.supported(state, dtx, b):
+        _DISPATCH_STATS["ssm"] += 1
+        return _ssm.ssm_state_update(state, layer, rows, decay, dtx, b, c,
+                                     interpret=False)
+    _DISPATCH_STATS["ssm_fallback"] += 1
+    return _ssm.ssm_state_update_ref(state, layer, rows, decay, dtx, b, c)
 
 
 def register(flash: bool = True, rms: bool = True, interpret: bool = False):

@@ -536,7 +536,7 @@ class TestServingSpans:
             "serving.decode_chunk", "serving.spec_chunk"}
     PHASES = ("build", "dispatch", "fetch", "emit")
 
-    def _run(self, annotations, **kw):
+    def _run(self, annotations, eos=None, **kw):
         import jax
         from paddle_tpu.inference import Request, ServingEngine
         from paddle_tpu.models import llama as L
@@ -551,7 +551,7 @@ class TestServingSpans:
             eng.submit(Request(
                 rid=rid, prompt=rng.integers(0, cfg.vocab_size,
                                              (n,)).astype(np.int32),
-                max_new_tokens=5))
+                max_new_tokens=5, eos_token_id=eos))
         steps, self.raw = [], []
         while True:
             del annotations[:]
@@ -577,20 +577,40 @@ class TestServingSpans:
             "serving.step.retire", "serving.step.compact",
             "serving.step.admit", "serving.step.reserve",
             "serving.decode_chunk"]
-        # the prefill of the admitted group sits inside admit, its four
-        # phases inside it, and the first call compiles
+        # the prefill of the admitted group sits inside admit, and the
+        # first call compiles. No request names an EOS, so nothing before
+        # the chunk's dispatch reads the first tokens: they go to their
+        # slots on the device (the join's program compiles beside the
+        # group's prefill, under its build), the chunk follows the
+        # prefill at once, and the host reads them after its dispatch
         i = first.index(("serving.prefill", 2))
         assert first[i - 1] == ("serving.step.admit", 1)
+        assert first[i + 1:i + 6] == [
+            ("serving.prefill.build", 3), ("serving.compile", 4),
+            ("serving.prefill.dispatch", 3), ("serving.compile", 4),
+            ("serving.step.reserve", 1)]
+        j = first.index(("serving.decode_chunk", 1))
+        assert first[j + 1:] == [
+            ("serving.decode_chunk.build", 2),
+            ("serving.decode_chunk.dispatch", 2), ("serving.compile", 3),
+            ("serving.prefill.fetch", 2), ("serving.prefill.emit", 2),
+            ("serving.decode_chunk.fetch", 2),
+            ("serving.decode_chunk.emit", 2)]
+
+    def test_first_token_is_read_inside_the_prefill_when_it_may_end_a_request(
+            self, annotations):
+        # an EOS may end a request on its first token, which decides what
+        # the scheduler does next: the download is not put off
+        first = self._run(annotations, eos=10 ** 6)[0]
+        i = first.index(("serving.prefill", 2))
         assert first[i + 1:i + 6] == [
             ("serving.prefill.build", 3), ("serving.prefill.dispatch", 3),
             ("serving.compile", 4), ("serving.prefill.fetch", 3),
             ("serving.prefill.emit", 3)]
         j = first.index(("serving.decode_chunk", 1))
-        assert first[j + 1:] == [
+        assert first[j + 1:j + 3] == [
             ("serving.decode_chunk.build", 2),
-            ("serving.decode_chunk.dispatch", 2), ("serving.compile", 3),
-            ("serving.decode_chunk.fetch", 2),
-            ("serving.decode_chunk.emit", 2)]
+            ("serving.decode_chunk.dispatch", 2)]
 
     def test_compile_span_on_first_use_only(self, annotations):
         steps = self._run(annotations)
@@ -599,8 +619,10 @@ class TestServingSpans:
         prefills = flat.count("serving.prefill")
         assert chunks >= 3 and prefills == 2
         # one program per (group, bucket) prefill shape and one decode
-        # chunk program ran: each compiled once, on its first call
-        programs = 2 + 1       # prefill g2 and g1, one chunk length
+        # chunk program ran: each compiled once, on its first call; and
+        # with each group size's first prefill, the join of its first
+        # tokens (no request here names an EOS)
+        programs = 2 + 1 + 2   # prefill g2 and g1, one chunk length, joins
         assert flat.count("serving.compile") == programs
         for phase in self.PHASES:
             assert flat.count(f"serving.decode_chunk.{phase}") == chunks
