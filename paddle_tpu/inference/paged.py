@@ -481,9 +481,10 @@ class PrefixCache:
 def init_pool(config, num_pages: int, page_size: int, dtype=None,
               kv_quant: bool = False, state_shapes: Optional[dict] = None,
               state_rows: int = 0) -> dict:
-    """Fresh page pools, one [P, kv, ps, hd] grid per layer (stacked on
-    a leading layer axis to ride the decode lax.scan, like the ring
-    cache). With ``state_shapes`` (a recurrent family's
+    """Fresh page pools, one [P, kv, ps, hd] grid per layer, stacked on
+    a leading layer axis into ONE buffer a half (the decode step's layer
+    scan carries it whole and names a layer by index). With
+    ``state_shapes`` (a recurrent family's
     ``state_shapes(config)``: leaf name -> (shape a row a layer, type))
     the cache also holds ``"state"``: each leaf ``[L, state_rows + 1,
     ...]`` of zeros, the last row owned by no sequence. With
@@ -621,10 +622,13 @@ def _kv_pool_gather(pool, rows, dtype):
 
 
 @jax.named_scope("attn.kv_write")
-def _kv_page_append(leaf, rows, off, val, P):
+def _kv_page_append(leaf, layer, rows, off, val, P):
     """Append one token's [B, kv, hd] values at slot ``off`` of pages
-    ``rows`` (sentinel ``P`` drops) — the decode-step write. Quantized
-    pools rescale the whole touched page: gather, dequantize, zero the
+    ``rows`` of layer ``layer`` (sentinel ``P`` drops) — the decode-step
+    write. ``leaf`` is the WHOLE pool half [L, P, kv, ps, hd], the layer
+    scan's carry: the scatter names (layer, page) and touches nothing
+    else, so the pool stays one buffer, updated in place. Quantized pools
+    rescale the whole touched page: gather, dequantize, zero the
     not-yet-written tail slots (a reused page's stale codes must not
     inflate the scale), insert the token, requantize under the page's
     fresh absmax, and scatter codes + scale row under one drop mask.
@@ -633,10 +637,10 @@ def _kv_page_append(leaf, rows, off, val, P):
     B, kv = val.shape[0], val.shape[1]
     kvi = jnp.arange(kv)
     if isinstance(leaf, dict):
-        ps = leaf["q"].shape[2]
+        ps = leaf["q"].shape[3]
         rc = jnp.clip(rows, 0, P - 1)
-        page = (leaf["q"][rc].astype(jnp.float32)
-                * leaf["s"][rc][..., None, None])      # [B, kv, ps, hd]
+        page = (leaf["q"][layer, rc].astype(jnp.float32)
+                * leaf["s"][layer, rc][..., None, None])   # [B, kv, ps, hd]
         keep = jnp.arange(ps)[None, None, :, None] \
             <= off[:, None, None, None]
         page = jnp.where(keep, page, 0.0)
@@ -645,11 +649,11 @@ def _kv_page_append(leaf, rows, off, val, P):
                                          unique_indices=True)
         s = jnp.max(jnp.abs(page), axis=(-2, -1)) / _KV_QMAX
         q = _kv_quantize(page, s[..., None, None])
-        return {"q": leaf["q"].at[rows[:, None], kvi[None, :]].set(
+        return {"q": leaf["q"].at[layer, rows[:, None], kvi[None, :]].set(
                     q, mode="drop", unique_indices=True),
-                "s": leaf["s"].at[rows[:, None], kvi[None, :]].set(
+                "s": leaf["s"].at[layer, rows[:, None], kvi[None, :]].set(
                     s, mode="drop", unique_indices=True)}
-    return leaf.at[rows[:, None], kvi[None, :], off[:, None]].set(
+    return leaf.at[layer, rows[:, None], kvi[None, :], off[:, None]].set(
         val.astype(leaf.dtype), mode="drop", unique_indices=True)
 
 
@@ -816,11 +820,14 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
     sit at position ``lengths``-1 of their sequences (``lengths`` is the
     valid KV count INCLUDING each new token; 0 marks an inactive slot —
     its write is dropped and its logits row is garbage the caller
-    masks). Returns (cache', logits [B, V]). A recurrent family's state
-    is the layer scan's CARRY, whole, and each layer updates its rows in
-    place (``state_rows`` [B]; an inactive slot is sent to the row nobody
-    owns, so its own row is untouched): the pools ride the scan as
-    ``xs``/``ys`` and are held twice, the state must not be."""
+    masks). Returns (cache', logits [B, V]). The cache is the layer
+    scan's CARRY, whole: each pool half is one buffer that the KV write
+    and the kernel address by (layer, page), and a recurrent family's
+    state one buffer whose rows each layer updates in place
+    (``state_rows`` [B]; an inactive slot is sent to the row nobody
+    owns, so its own row is untouched). Nothing is sliced out of the
+    cache and nothing of its size is made, so a program that donates it
+    holds it once."""
     c = config
     B = tokens.shape[0]
     pool_k, pool_v = cache["k"], cache["v"]
@@ -848,38 +855,39 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
     from ..kernels import dispatched_paged_attention
 
     state = cache.get("state")
-    xs = (params["layers"], pool_k, pool_v)
     if state is not None:
         nobody = jax.tree.leaves(state)[0].shape[1] - 1
         srows = jnp.where(n > 0, state_rows, nobody)
-        xs += (jnp.arange(L),)
 
     def step(carry, xs):
-        x, state = carry
-        lp, kpl, vpl, *layer = xs                      # [P, kv, ps, hd]
+        x, pool_k, pool_v, state = carry
+        lp, layer = xs
 
         def attend(q, k, v):
-            kp = _kv_page_append(kpl, rows, off, k[:, 0], P)
-            vp = _kv_page_append(vpl, rows, off, v[:, 0], P)
+            kp = _kv_page_append(pool_k, layer, rows, off, k[:, 0], P)
+            vp = _kv_page_append(pool_v, layer, rows, off, v[:, 0], P)
             with jax.named_scope("attn.kernel"):
                 if quant:
                     a = dispatched_paged_attention(
                         q[:, 0], kp["q"], vp["q"], block_tables, n,
-                        k_scales=kp["s"], v_scales=vp["s"])
+                        k_scales=kp["s"], v_scales=vp["s"], layer=layer)
                 else:
-                    a = dispatched_paged_attention(q[:, 0], kp, vp,
-                                                   block_tables, n)
+                    a = dispatched_paged_attention(
+                        q[:, 0], kp, vp, block_tables, n, layer=layer)
             return a.reshape(B, 1, -1), (kp, vp)
 
         mix = None
         if state is not None:
             def mix(h, lp):
-                return family.mixer_decode(h, lp, c, state, layer[0], srows)
+                return family.mixer_decode(h, lp, c, state, layer, srows)
 
-        x, kvs, new = _block(family, x, lp, c, cos, sin, attend, mix)
-        return (x, new), kvs
+        x, (pool_k, pool_v), state = _block(family, x, lp, c, cos, sin,
+                                            attend, mix)
+        return (x, pool_k, pool_v, state), None
 
-    (x, state), (kc, vc) = lax.scan(step, (x, state), xs)
+    (x, kc, vc, state), _ = lax.scan(
+        step, (x, pool_k, pool_v, state),
+        (params["layers"], jnp.arange(L)))
     out = {"k": kc, "v": vc}
     if state is not None:
         out["state"] = state

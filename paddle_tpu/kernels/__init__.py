@@ -183,7 +183,7 @@ def dispatched_segment_attention(q, k, v, seg_q, seg_k, pos_q, pos_k, *,
 
 def dispatched_paged_attention(q, k_pages, v_pages, block_tables, lengths,
                                *, scale=None, k_scales=None,
-                               v_scales=None):
+                               v_scales=None, layer=None):
     """Ragged paged decode attention with the same counter discipline as
     flash/rms: the pallas kernel on TPU when the shapes are supported,
     the pure-jnp gather reference elsewhere (tier-1's CPU path). Both
@@ -195,7 +195,11 @@ def dispatched_paged_attention(q, k_pages, v_pages, block_tables, lengths,
     planes; both the kernel and the reference dequantize inline (page
     DMA stays int8, the scale folds into the attention dot), counted
     separately (``paged_quant[_fallback]``) so benchmarks can assert
-    which arm a quantized shape actually traced."""
+    which arm a quantized shape actually traced.
+
+    ``layer`` names one layer of pools (and scale planes) that carry a
+    leading layer axis, as the serving program's do; None is one layer's
+    pools alone. Either way both paths read the pool where it lies."""
     quant = k_scales is not None
     arm = "paged_quant" if quant else "paged"
     if _on_tpu() and _pa.supported(q, k_pages, block_tables,
@@ -203,11 +207,12 @@ def dispatched_paged_attention(q, k_pages, v_pages, block_tables, lengths,
         _DISPATCH_STATS[arm] += 1
         return _pa.ragged_paged_attention(
             q, k_pages, v_pages, block_tables, lengths, scale=scale,
-            k_scales=k_scales, v_scales=v_scales, interpret=False)
+            k_scales=k_scales, v_scales=v_scales, layer=layer,
+            interpret=False)
     _DISPATCH_STATS[arm + "_fallback"] += 1
     return _pa.paged_attention_ref(
         q, k_pages, v_pages, block_tables, lengths, scale=scale,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, layer=layer)
 
 
 def dispatched_ssm_update(state, layer, rows, decay, dtx, b, c):

@@ -11,7 +11,8 @@ FLOPs.
 TPU-native design: the kernel's work follows the live tokens.
 - Grid ``(batch,)``, sequential. The block table and the lengths ride a
   ``PrefetchScalarGridSpec`` scalar prefetch; the two page pools stay in
-  HBM (``memory_space=pl.ANY``) and the kernel fetches pages itself.
+  HBM (``memory_space=pl.ANY``), every layer of them, and the kernel
+  fetches pages itself.
 - A sequence is read in BLOCKS of N pages: one ``make_async_copy`` a
   page carries all its KV heads (``kv_heads * page_size * head_dim``
   contiguous elements of the pool) into a double-buffered VMEM scratch
@@ -36,10 +37,19 @@ TPU-native design: the kernel's work follows the live tokens.
 - N follows from the shapes (``_pages_per_block``): what a fixed VMEM
   budget holds of K and V, two buffers each, capped in tokens.
 
-Layouts: pages are ``[num_pages, kv_heads, page_size, head_dim]`` (one
-page of every KV head is contiguous: 32 KiB at 8 heads of 128 and pages
-of 16 in bf16); q is ``[batch, num_heads, head_dim]`` — one decode
-position per sequence.
+Layouts: a pool half is ``[layers, num_pages, kv_heads, page_size,
+head_dim]`` (one page of every KV head is contiguous: 32 KiB at 8 heads
+of 128 and pages of 16 in bf16) and the call names one ``layer`` of it,
+a traced scalar: the serving program holds the whole pool in ONE buffer
+and never cuts a layer out of it. The kernel sees that buffer as
+``[layers * num_pages, ...]`` (a bitcast) and a table of ``layer *
+num_pages + page`` (``_pages_of``), so a page's address is one index. On
+the v5e that form times as the kernel on one layer's pool does, and the
+layer as a third prefetched scalar with ``hbm.at[layer, page]`` 2-3%
+slower (PERF.md, PR 28). A caller that holds one layer alone passes
+``[num_pages, kv_heads, page_size, head_dim]`` and no ``layer``: the
+same body. q is ``[batch, num_heads, head_dim]`` — one decode position
+per sequence.
 
 ``paged_attention_ref`` is the pure-jnp gather fallback — identical
 math, runs on every backend — which tier-1 exercises on CPU and the
@@ -216,24 +226,46 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
     o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
 
 
+def _pages_of(layer, block_tables, *pools):
+    """(table, pools) with the layer folded into the page: a pool that
+    carries its layer axis, [L, P, ...], is read as [L * P, ...] (the same
+    bytes: a bitcast) through a table whose entries are ``layer * P +
+    page``, so a page's address is one index whatever the layer, and no
+    layer is ever cut out of the pool. Table entries are clamped to the
+    pool first: a live one off it would be a DMA out of bounds, and the
+    dead ones are clamped with them."""
+    P = pools[0].shape[0 if layer is None else 1]
+    bt = jnp.clip(block_tables, 0, P - 1).astype(jnp.int32)
+    if layer is None:
+        return bt, pools
+    return bt + layer * P, [None if p is None else
+                            p.reshape((-1,) + p.shape[2:]) for p in pools]
+
+
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            scale=None, k_scales=None, v_scales=None,
-                           interpret=False):
+                           layer=None, interpret=False):
     """Paged decode attention. q: [B, num_heads, head_dim]; k_pages /
-    v_pages: [num_pages, kv_heads, page_size, head_dim]; block_tables:
-    [B, max_pages] page ids (entries past a sequence's pages may hold
-    any value — the kernel never reads them); lengths: [B] valid KV
-    positions per sequence (0 = empty slot -> zero output row; more than
-    the table holds counts as the whole table).
+    v_pages: [layers, num_pages, kv_heads, page_size, head_dim] with
+    ``layer`` the int32 scalar (traced or not) that names the layer to
+    attend over, the rest of the pool untouched and uncopied; or, with
+    ``layer`` None, one layer alone, [num_pages, kv_heads, page_size,
+    head_dim]; block_tables: [B, max_pages] page ids (entries past a
+    sequence's pages may hold any value — the kernel never reads them);
+    lengths: [B] valid KV positions per sequence (0 = empty slot -> zero
+    output row; more than the table holds counts as the whole table).
 
-    With ``k_scales``/``v_scales`` ([num_pages, kv_heads] f32, both or
-    neither) the pages are int8 codes (FLAGS_serving_kv_quant): the
-    block table gathers each sequence's scales block by block (a tiny
-    XLA gather), and dequantization folds into the two dots — page
-    traffic stays int8. Returns [B, num_heads, head_dim]."""
+    With ``k_scales``/``v_scales`` ([layers, num_pages, kv_heads] f32,
+    or [num_pages, kv_heads] beside a 4-D pool; both or neither) the
+    pages are int8 codes (FLAGS_serving_kv_quant): the block table
+    gathers each sequence's scales block by block (a tiny XLA gather),
+    and dequantization folds into the two dots — page traffic stays
+    int8. Returns [B, num_heads, head_dim]."""
     quant = k_scales is not None
+    bt, (k_pages, v_pages, k_scales, v_scales) = _pages_of(
+        layer, block_tables, k_pages, v_pages, k_scales, v_scales)
     B, nh, hd = q.shape
-    P, kv, ps, _ = k_pages.shape
+    _, kv, ps, _ = k_pages.shape
     maxp = block_tables.shape[1]
     g = nh // kv
     sub = _sublane(q.dtype)
@@ -246,9 +278,6 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     qg = q.reshape(B, kv, g, hd)
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    # a live entry off the pool would be a DMA out of bounds; the dead
-    # ones are clamped with them and read by nothing but the scale gather
-    bt = jnp.clip(block_tables, 0, P - 1).astype(jnp.int32)
     lengths = jnp.minimum(lengths.astype(jnp.int32), maxp * ps)
 
     def per_seq(b, bt_, ln_):
@@ -310,19 +339,23 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 # ---------------------------------------------------------------------------
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
-                        scale=None, k_scales=None, v_scales=None):
+                        scale=None, k_scales=None, v_scales=None,
+                        layer=None):
     """Gather-based reference: same contract and masking semantics as the
     kernel (safe softmax — an empty sequence yields a zero row, never
-    NaN). This is the path tier-1 runs on CPU. ``k_scales``/``v_scales``
-    ([num_pages, kv_heads] f32) mark int8 pages: the gathered codes are
-    dequantized in f32 before the same einsum math."""
+    NaN), the pools with or without their layer axis as there. This is
+    the path tier-1 runs on CPU. ``k_scales``/``v_scales`` mark int8
+    pages: the gathered codes are dequantized in f32 before the same
+    einsum math."""
+    bt, (k_pages, v_pages, k_scales, v_scales) = _pages_of(
+        layer, block_tables, k_pages, v_pages, k_scales, v_scales)
     B, nh, hd = q.shape
-    P, kv, ps, _ = k_pages.shape
+    _, kv, ps, _ = k_pages.shape
     maxp = block_tables.shape[1]
     g = nh // kv
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
-    bt = jnp.clip(block_tables, 0, P - 1).reshape(-1)
+    bt = bt.reshape(-1)
     # flat gathers with in-bounds promise (clip above), consumed in page
     # layout directly — XLA:CPU's generic gather/transpose lowering is
     # this fallback's hot spot, so no moveaxis copies
@@ -354,12 +387,13 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
 
 def supported(q, k_pages, block_tables, quant=False) -> bool:
     """Whether the pallas kernel handles these shapes (else the
-    dispatcher uses paged_attention_ref). ``quant`` marks the int8-page
+    dispatcher uses paged_attention_ref); of a pool with its layer axis
+    the last four dimensions are judged. ``quant`` marks the int8-page
     arm (scale planes present)."""
-    if q.ndim != 3 or k_pages.ndim != 4 or block_tables.ndim != 2:
+    if q.ndim != 3 or k_pages.ndim not in (4, 5) or block_tables.ndim != 2:
         return False
     B, nh, hd = q.shape
-    P, kv, ps, hd2 = k_pages.shape
+    P, kv, ps, hd2 = k_pages.shape[-4:]
     if hd != hd2 or hd > 256 or nh % kv != 0:
         return False
     if jnp.dtype(q.dtype) not in (jnp.dtype(jnp.float32),

@@ -187,19 +187,25 @@ class TestDispatchGuards:
 # every pallas_call of the main paths carries its stable name
 # ---------------------------------------------------------------------------
 
-def _pallas_names(jaxpr, out=None):
-    """``name=`` of every ``pallas_call`` in a jaxpr, nested ones (scan
-    bodies, remat, custom_vjp, pjit) included."""
-    out = [] if out is None else out
+def _walk(jaxpr):
+    """Every equation of a jaxpr, nested ones (scan bodies, remat,
+    custom_vjp, pjit) included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            out.append(eqn.params["name"])
+        yield eqn
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _pallas_names(sub, out)
-    return out
+                    yield from _walk(sub)
+
+
+def _eqns(jaxpr, name):
+    return [e for e in _walk(jaxpr) if e.primitive.name == name]
+
+
+def _pallas_names(jaxpr):
+    """``name=`` of every ``pallas_call`` in a jaxpr."""
+    return [e.params["name"] for e in _eqns(jaxpr, "pallas_call")]
 
 
 def _trace_train(packed):
@@ -216,19 +222,46 @@ def _trace_train(packed):
         lambda q: L.loss_fn(q, b, cfg))(p))(params, batch)
 
 
-def _trace_decode(_):
+def _trace_decode(pages):
     from paddle_tpu.inference.paged import init_pool, paged_decode_step
     from paddle_tpu.models import llama as L
     cfg = L.llama_tiny(dtype=jnp.bfloat16, hidden_size=256,
                        num_attention_heads=2, num_key_value_heads=2)
     params = jax.eval_shape(lambda k: L.init_params(cfg, k),
                             jax.random.PRNGKey(0))
-    pool = jax.eval_shape(lambda: init_pool(cfg, 8, 16))
+    pool = jax.eval_shape(lambda: init_pool(cfg, pages or 8, 16))
     bt = jax.ShapeDtypeStruct((2, 4), jnp.int32)
     vec = jax.ShapeDtypeStruct((2,), jnp.int32)
     return jax.make_jaxpr(lambda p, pk, pv, b, n, t: paged_decode_step(
         L, p, pk, pv, b, n, t, cfg))(params, pool["k"], pool["v"], bt,
                                      vec, vec)
+
+
+@pytest.mark.parametrize("on_tpu", [True, False],
+                         ids=["kernel", "gather-reference"])
+def test_decode_step_carries_the_pool_and_never_cuts_a_layer_out(
+        monkeypatch, on_tpu):
+    """Jaxpr level: the decode step's layer scan has both pool halves in
+    its carry and no stacked output (a scan's ys is a fresh buffer: a
+    second pool and a copy of it every step); the kernel's operands are
+    the pool halves whole, [L, P, kv, ps, hd] read as [L * P, kv, ps, hd];
+    and no equation makes one layer [P, kv, ps, hd] of a half, on either
+    arm of the dispatcher."""
+    from paddle_tpu import kernels
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: on_tpu)
+    jaxpr = _trace_decode(12).jaxpr    # (the tables name 8 pages: a gather)
+    half = (2, 12, 2, 16, 128)               # llama_tiny here, 12 pages of 16
+    scan, = _eqns(jaxpr, "scan")
+    carried = scan.outvars[:scan.params["num_carry"]]
+    assert [v.aval.shape for v in carried].count(half) == 2
+    assert len(scan.outvars) == scan.params["num_carry"]       # no ys
+    if on_tpu:
+        call, = _eqns(jaxpr, "pallas_call")
+        whole = (half[0] * half[1],) + half[2:]
+        assert [v.aval.shape for v in call.invars].count(whole) == 2
+
+    assert half[1:] not in {v.aval.shape for e in _walk(jaxpr)
+                            for v in e.outvars}
 
 
 def _trace_rms(_):

@@ -8,6 +8,8 @@ granularity — plus allocator refcount invariants (nothing leaks, OOM is
 admission refusal, fork is copy-on-write) and scheduler behavior under
 a randomized arrival/length trace.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -227,6 +229,123 @@ class TestKernelBlocks:
         np.testing.assert_array_equal(_f32(got), _f32(clean))
 
 
+_LAYERS = 3
+_LAYER_LENGTHS = (0, 33, 160, 1)    # an empty slot; a token past a page's end
+
+
+@functools.lru_cache(maxsize=None)
+def _layered_case(arm, ps):
+    """``_paged_case`` with pools (and scale planes) of ``_LAYERS`` layers,
+    every layer its own random pages under ONE table."""
+    cases = [_paged_case(arm, 4, 128, ps, _LAYER_LENGTHS, seed=s)
+             for s in range(_LAYERS)]
+    q, _, _, bt, ln, _, _ = cases[0]
+    k5, v5 = (jnp.stack([c[i] for c in cases]) for i in (1, 2))
+    kw5 = {n: jnp.stack([c[5][n] for c in cases]) for n in cases[0][5]}
+    return q, k5, v5, bt, ln, kw5
+
+
+class TestKernelLayers:
+    """The pool with its layer axis and ``layer`` a traced scalar, against
+    the same call on that layer cut out: the kernel (interpret mode) and the
+    gather reference, bf16 and int8 pages."""
+
+    IMPLS = {"kernel": functools.partial(PA.ragged_paged_attention,
+                                         interpret=True),
+             "ref": PA.paged_attention_ref}
+
+    @pytest.mark.parametrize("layer", range(_LAYERS))
+    @pytest.mark.parametrize("impl", IMPLS)
+    @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("int8", 32)])
+    def test_a_layer_of_the_whole_pool_is_that_layer_alone(self, arm, ps,
+                                                           impl, layer):
+        fn = self.IMPLS[impl]
+        q, k5, v5, bt, ln, kw5 = _layered_case(arm, ps)
+        whole = jax.jit(lambda l: fn(q, k5, v5, bt, ln, layer=l, **kw5))(
+            jnp.int32(layer))
+        cut = jax.jit(lambda k, v, kw: fn(q, k, v, bt, ln, **kw))(
+            k5[layer], v5[layer], {n: x[layer] for n, x in kw5.items()})
+        np.testing.assert_array_equal(_f32(whole), _f32(cut))
+        np.testing.assert_array_equal(_f32(whole)[0], 0.0)   # the empty slot
+        # and it is not another layer's answer
+        other = jax.jit(lambda l: fn(q, k5, v5, bt, ln, layer=l, **kw5))(
+            jnp.int32((layer + 1) % _LAYERS))
+        assert not np.allclose(_f32(whole)[1:], _f32(other)[1:], atol=1e-3)
+
+    def test_supported_judges_the_last_four_dimensions(self):
+        q, k5, _, bt, _, _ = _layered_case("bf16", 16)
+        assert PA.supported(q, k5, bt) and PA.supported(q, k5[0], bt)
+        assert not PA.supported(q, k5[None], bt)
+        q8, c5, _, bt8, _, _ = _layered_case("int8", 32)
+        assert PA.supported(q8, c5, bt8, quant=True)
+        assert not PA.supported(q8, c5, bt8)         # codes without scales
+
+
+@functools.lru_cache(maxsize=None)
+def _one_decode_step(kv_quant):
+    """A pool of random pages before and after ONE decode step over three
+    slots, the middle one idle: (before, after, pages written, offsets)."""
+    from paddle_tpu.inference.paged import cache_decode_step, init_pool
+    cfg = L.llama_tiny(num_hidden_layers=3)
+    params = L.init_params(cfg, jax.random.PRNGKey(2))
+    rng = np.random.default_rng(4)
+    pool = init_pool(cfg, 12, 8, kv_quant=kv_quant)
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        if a.ndim == 3:                                # a scale plane
+            return jnp.asarray(rng.uniform(0.004, 0.02, a.shape), a.dtype)
+        return jnp.asarray(rng.normal(size=a.shape), a.dtype)
+
+    pool = jax.tree.map(fill, pool)
+    before = jax.tree.map(np.asarray, pool)
+    bt = jnp.asarray(rng.permutation(12).reshape(3, 4), jnp.int32)
+    lengths = np.array([6, 0, 19])
+    after, _ = jax.jit(lambda c: cache_decode_step(
+        L, params, c, bt, jnp.asarray(lengths), jnp.array([5, 7, 11]),
+        cfg))(pool)
+    pos = lengths[[0, 2]] - 1
+    pages = np.asarray(bt)[[0, 2], pos // 8]
+    return before, jax.tree.map(np.asarray, after), pages, pos % 8
+
+
+class TestDecodeStepWrite:
+    """One decode step appends layer l's token to layer l's page, and every
+    other element of the pool (the carry the layer scan updates in place)
+    is bit-equal to what it was."""
+
+    @pytest.mark.parametrize("layer", range(3))
+    @pytest.mark.parametrize("half", ["k", "v"])
+    def test_bf16_pool_changes_at_the_token_alone(self, half, layer):
+        before, after, pages, off = _one_decode_step(False)
+        b, a = before[half], after[half]
+        touched = np.zeros(b.shape, bool)
+        touched[layer, pages, :, off] = True
+        diff = (a != b)[layer]
+        np.testing.assert_array_equal(diff & ~touched[layer], False)
+        assert diff[pages, :, off].any(axis=-1).all()   # each row landed
+        # not the row another layer wrote
+        nxt = (layer + 1) % 3
+        assert not np.array_equal(a[layer][pages, :, off],
+                                  a[nxt][pages, :, off])
+
+    @pytest.mark.parametrize("layer", range(3))
+    @pytest.mark.parametrize("half", ["k", "v"])
+    def test_int8_pool_changes_at_the_pages_alone(self, half, layer):
+        """The int8 arm rewrites a touched page whole under its fresh
+        scale: codes and scale row of (layer, page), nothing else."""
+        before, after, pages, off = _one_decode_step(True)
+        for leaf in ("q", "s"):
+            b, a = before[half][leaf], after[half][leaf]
+            touched = np.zeros(b.shape[1], bool)
+            touched[pages] = True
+            diff = (a != b)[layer].reshape(b.shape[1], -1).any(axis=1)
+            np.testing.assert_array_equal(diff & ~touched, False)
+            # (a page's absmax, so its scale, may come out as it was)
+            assert leaf == "s" or diff[pages].all()
+
+
 class TestAllocator:
     def test_alloc_advance_free_roundtrip(self):
         a = PageAllocator(num_pages=8, page_size=4, max_pages_per_seq=4)
@@ -389,9 +508,9 @@ class TestPagedDecodeParity:
         orig = K.dispatched_paged_attention
         import paddle_tpu.inference.paged as paged_mod  # noqa: F401
 
-        def interp(q, kp, vp, bt, ln, *, scale=None):
-            return PA.ragged_paged_attention(q, kp, vp, bt, ln,
-                                             scale=scale, interpret=True)
+        def interp(q, kp, vp, bt, ln, *, scale=None, layer=None):
+            return PA.ragged_paged_attention(q, kp, vp, bt, ln, scale=scale,
+                                             layer=layer, interpret=True)
 
         K.dispatched_paged_attention = interp
         try:

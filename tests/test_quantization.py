@@ -341,10 +341,10 @@ class TestKVQuantDecodeParity:
         orig = K.dispatched_paged_attention
 
         def interp(q, kp, vp, bt, ln, *, scale=None, k_scales=None,
-                   v_scales=None):
+                   v_scales=None, layer=None):
             return PA.ragged_paged_attention(
                 q, kp, vp, bt, ln, scale=scale, k_scales=k_scales,
-                v_scales=v_scales, interpret=True)
+                v_scales=v_scales, layer=layer, interpret=True)
 
         K.dispatched_paged_attention = interp
         try:
