@@ -1,5 +1,4 @@
-"""Train a Llama slice on one chip — the bench.py recipe as a readable
-example.
+"""Train a Llama slice on one chip, as a readable example.
 
 Run:  python examples/train_llama_single_chip.py  (TPU or CPU)
 

@@ -28,7 +28,7 @@ __all__ = ["Config", "Predictor", "create_predictor", "Tensor",
 
 _SERVING = {"PageAllocator": "paged", "PagedKVCache": "paged",
             "EngineOverloaded": "engine",
-            "Request": "engine", "RequestCost": "engine",
+            "Request": "engine", "RequestCost": "accounting",
             "RequestOutput": "engine", "RequestRejected": "engine",
             "ServingEngine": "engine"}
 

@@ -27,28 +27,12 @@ freeing pages the moment a sequence finishes — rebuilt TPU-native:
   low indices before each admission pass, so occupancy accounting and
   the admission scan touch a dense prefix.
 
-Instrumentation (paddle_tpu.monitor, FLAGS_enable_monitor-gated):
-``serving.pages.in_use|total``, ``serving.batch.occupancy``,
-``serving.queue.depth`` gauges; ``serving.requests.admitted|completed|
-preempted``, ``serving.tokens.generated|prefilled|discarded`` counters.
-The same numbers are always available unconditionally on
-``engine.stats``.
-
-SLO latency (monitor-gated, one cached-flag branch when off): each
-request's lifecycle is stamped enqueue -> admit -> prefill -> first
-token -> retire, feeding the ``serving.latency.*`` histograms —
-``queue_wait_ms`` (latest enqueue to admission; a preempted request
-re-queues and waits again — each wait observed once, while the
-per-request cost record keeps the CUMULATIVE sum), ``ttft_ms``
-(ORIGINAL enqueue to the
-prefill-sampled first token of the run the client KEEPS — observed
-once per request at retirement, so a preempted run's discarded first
-token never biases the histogram),
-``tpot_ms`` (mean inter-token time over the decode phase, chunk-edge
-resolution), ``e2e_ms`` (original enqueue to retire). All carry
-bucket-interpolated p50/p90/p95/p99 in their snapshots. The same
-milestones land in the ``monitor.trace`` ring as lifecycle events, so
-a flight record shows which requests were in flight at a crash.
+What happened is reported, once an event, to ``inference/accounting.py``
+(``self._acct``), which alone knows the planes that consume it (counters,
+latency histograms, the per-request cost record on
+``RequestOutput.cost``, the SLO window, forensics, the failover journal,
+federation frames): docs/serving.md has the events. This module imports
+nothing of them; ``engine.stats`` holds the same counts unconditionally.
 
 Spans (always on; ``monitor.trace.span``): every phase of ``step()`` —
 expire, retire, compact, admit with each group's prefill, the page
@@ -56,67 +40,36 @@ reservation, the decode or verify chunk — and inside prefill and chunk
 the host's work apart from its waiting (``.build``, ``.dispatch``,
 ``.fetch``, ``.emit``; a program's first call under
 ``serving.compile``) is a span under the prefix ``serving.`` (the tree
-is in ``ServingEngine.step``'s docstring). In the ring they ride the
-monitor flag like everything above; as ``jax.profiler`` annotations
-they are in ANY open profiler session (``/profile``, a benchmark's, a
-user's ``start_trace``) on the device trace's clock, so a device idle
-gap reads as what the host was doing. With no session a span is a
-no-op of about a microsecond, ten to fifteen a step. Add one only at a
-boundary between layers or between host work and waiting, never inside
-a loop over slots. The device programs are named to match:
-``jit_decode_chunk``, ``jit_spec_verify``, ``jit__pf``,
-``jit__join_first``.
+is in ``ServingEngine.step``'s docstring). As ``jax.profiler``
+annotations they are in ANY open profiler session on the device trace's
+clock, so a device idle gap reads as what the host was doing. With no
+session a span is a no-op of about a microsecond, ten to fifteen a step.
+Add one only at a boundary between layers or between host work and
+waiting, never inside a loop over slots. The device programs are named
+to match: ``jit_decode_chunk``, ``jit_spec_verify``, ``jit__pf``,
+``jit__join_first``. The jitted calls stay inline in ``_prefill_group``,
+``_chunk_step`` and ``_spec_step``, accounting calls go beside them, never
+round them, and those frames keep their size: a program's first call costs
+0.2-0.6 s more or less by the BYTES of Python frame above it (a hot call
+of the tracer that straddles the end of one of CPython's 16 KiB
+frame-stack chunks maps and unmaps one each time; PERF.md section 6, PRs
+24 and 29; frames and bytes are held by tests/test_engine_layering.py).
 
-Token accounting contract (pinned by tests/test_trace.py):
-``serving.tokens.generated`` counts every SAMPLED token (prefill's
-first token + decode emissions — work done, including work later
-thrown away); ``serving.tokens.discarded`` counts tokens a preemption
-discarded for recompute. On a drained engine
-``generated - discarded == sum(len(output.tokens))`` exactly.
-
-Cost attribution (monitor-gated, PR 12): requests carry a ``tenant``
-(default ``"default"``) and ``priority``, validated/coerced at submit
-with the rest of the isolation screening, and every request
-accumulates a :class:`RequestCost` record across its lifecycle —
-prefill/decode/discarded tokens, CUMULATIVE queue wait across
-preemption re-queues (the ``queue_wait_ms`` histogram still observes
-each individual wait once), page-seconds (pages held x wall,
-integrated at the chunk boundaries the emitted-grid download already
-synchronizes — the cost plane adds ZERO device synchronizations at
-any rate), slot steps + occupancy share, and modeled FLOPs (the
-chunk/prefill program's registered cost-analysis FLOPs from
-``monitor/programs.py``, split evenly across the live slots/group
-rows that shared the dispatch). The record rides out on
-``RequestOutput.cost`` and folds into ``monitor/slo.py``'s windowed
-SLO accounting + bounded per-tenant aggregates at retirement; each
-scheduler step also feeds the autoscale tick
-(``slo.note_sched_tick``). Monitor off: ``cost`` is None and none of
-this exists — byte-identical emitted tokens either way.
-
-Overload control (PR 13, the ACTING half of ROADMAP item 5 — all
-flag-gated, every flag default OFF, flags-off scheduling byte-identical
-to the accounting-only engine; see docs/overload.md):
-
-- **Priority admission** (``FLAGS_serving_priority_admission``): the
-  admission scan orders the queue by (priority desc, arrival) and
-  enforces ``FLAGS_serving_tenant_inflight_cap`` live slots per tenant.
-- **Bounded queue + shedding** (``FLAGS_serving_max_queue``,
-  ``FLAGS_serving_shed_on_burn``): a full queue — or an SLO
-  fast-burn, for priority<=0 work — sheds submissions with a typed
-  :class:`EngineOverloaded` carrying a ``retry_after_s`` hint from the
-  autoscale demand model; a higher-priority arrival displaces the
-  lowest-priority queued request instead.
-- **Deadlines** (per-request ``Request.deadline_s``, default off):
-  a spent TTL expires the request in queue or evicts it from the
-  running batch (partial tokens delivered, ``finish_reason="expired"``,
-  cost recorded).
-- **SLO-aware preemption** (``FLAGS_serving_slo_preemption``): page
-  pressure evicts the lowest-(priority, prior preemptions, accumulated
-  work) request instead of youngest-first.
-- **Drain lifecycle** (:meth:`ServingEngine.begin_drain`): stop
-  admitting, shed the queue with retry hints, finish live decodes;
-  ``drain_complete`` gates the elastic controller's scale-in
-  (``distributed/fleet/elastic.py``).
+Overload control (docs/overload.md; every flag default OFF, flags-off
+scheduling byte-identical to the policy-free engine): priority
+admission with a per-tenant in-flight cap
+(``FLAGS_serving_priority_admission``,
+``FLAGS_serving_tenant_inflight_cap``); a bounded queue that sheds, or
+displaces for a higher priority, with a typed :class:`EngineOverloaded`
+carrying a ``retry_after_s`` hint (``FLAGS_serving_max_queue``; an SLO
+fast-burn sheds priority<=0 work under ``FLAGS_serving_shed_on_burn``);
+per-request deadlines (``Request.deadline_s``: expired in the queue, or
+evicted from the batch with the tokens it had); SLO-aware preemption
+(``FLAGS_serving_slo_preemption``: the lowest (priority, prior
+preemptions, accumulated work) goes first, not the youngest); and the
+drain lifecycle (:meth:`ServingEngine.begin_drain`: stop admitting, shed
+the queue, finish live decodes; ``drain_complete`` gates the elastic
+controller's scale-in).
 
 Every submitted request ends in exactly one of completed / rejected /
 expired / shed, with a typed reason — nothing is dropped silently.
@@ -127,7 +80,6 @@ import contextlib
 import dataclasses
 import math
 import time
-import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -135,42 +87,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import monitor as _monitor
 from ..core import enforce as E
-from ..monitor import server as _mserver
 from ..monitor import trace as _trace
-from ..monitor import slo as _slo
-from ..monitor import forensics as _forensics
-from ..monitor.registry import LATENCY_BUCKETS_MS as _LATENCY_BUCKETS_MS
+from .accounting import EngineAccounting, RequestCost
 from .paged import (PagedKVCache, PrefixCache, cache_decode_step,
                     cache_prefill, cache_prefill_shared,
                     cache_verify_window)
 
-_NO_SPAN = contextlib.nullcontext()     # what _first_call gives after the first
-
-
-def _engine_health_provider(ref):
-    """``/healthz`` contributor over a weakly-held engine: queue depth,
-    slot occupancy, page-pool pressure. Returns None once the engine is
-    garbage-collected (the server prunes the entry). Always ``ok`` —
-    a deep queue is backpressure, not a liveness failure."""
-    def provide():
-        eng = ref()
-        if eng is None:
-            return None
-        return {
-            "ok": True,
-            "queue_depth": len(eng.queue),
-            "slots_live": sum(1 for s in eng.slots if s is not None),
-            "num_slots": eng.num_slots,
-            "pages_free": eng.cache.alloc.free_pages,
-            "pages_total": eng.cache.num_pages,
-            "requests_completed": eng.stats.completed,
-        }
-    return provide
-
-def _observe_latency(name: str, ms: float, doc: str):
-    _monitor.observe(name, ms, doc=doc, buckets=_LATENCY_BUCKETS_MS)
+_NO_SPAN = contextlib.nullcontext()     # _first_call's, after the first
 
 __all__ = ["EngineOverloaded", "Request", "RequestCost", "RequestOutput",
            "RequestRejected", "ServingEngine"]
@@ -238,45 +162,6 @@ class Request:
 
 
 @dataclasses.dataclass
-class RequestCost:
-    """Per-request resource attribution, accumulated at the engine's
-    existing host-sync seams (monitor-gated; see the module
-    docstring). Cumulative across preemption re-queues — the record
-    follows the REQUEST, not one run of it."""
-
-    tenant: str = "default"
-    priority: int = 0
-    prefill_tokens: int = 0      # prompt tokens prefilled (re-prefills
-    #                              after preemption included; tokens a
-    #                              cached prefix skipped are NOT here —
-    #                              they were not work done)
-    prefix_cached_tokens: int = 0    # prompt tokens served from the
-    #                              radix prefix cache instead of
-    #                              prefill (cumulative across re-runs)
-    prefill_flops_saved: float = 0.0  # modeled FLOPs the cached prefix
-    #                              skipped (tail program's registered
-    #                              per-padded-token rate x cached)
-    decode_tokens: int = 0       # decode emissions (work done, incl.
-    #                              tokens a preemption later discarded)
-    discarded_tokens: int = 0    # thrown away by preemption recompute
-    queue_wait_ms: float = 0.0   # SUM of every enqueue->admission wait
-    page_seconds: float = 0.0    # KV pages held x wall (chunk edges)
-    slot_steps: int = 0          # decode-grid steps a slot was held
-    grid_steps: int = 0          # grid capacity (steps x slots) that
-    #                              elapsed during the residencies
-    slot_share: Optional[float] = None   # slot_steps / grid_steps
-    model_flops: float = 0.0     # registered program FLOPs, split
-    #                              across the dispatch's live slots
-    preemptions: int = 0
-    ttft_ms: Optional[float] = None
-    tpot_ms: Optional[float] = None
-    e2e_ms: Optional[float] = None
-
-    def as_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
-
-@dataclasses.dataclass
 class RequestOutput:
     rid: int
     tokens: np.ndarray                   # generated ids (<= max_new_tokens)
@@ -301,8 +186,7 @@ class RequestOutput:
 
 class _Slot:
     __slots__ = ("req", "kv_len", "gen", "tokens", "pending", "done",
-                 "keys", "preemptions", "t_first", "t_last",
-                 "cost", "t_tick", "steps0", "ng", "ng_n")
+                 "keys", "preemptions", "ng", "ng_n")
 
     def __init__(self, req: Request, keys: np.ndarray):
         self.req = req
@@ -313,11 +197,6 @@ class _Slot:
         self.done = False
         self.keys = keys         # [max_new, 2] uint32 sampling keys
         self.preemptions = 0
-        self.t_first = None      # first-token wall stamp (monitor on)
-        self.t_last = None       # latest-token wall stamp (monitor on)
-        self.cost = None         # the request's RequestCost (monitor on)
-        self.t_tick = None       # last page-seconds integration stamp
-        self.steps0 = 0          # engine decode_steps at admission
         self.ng = None           # spec decode: bigram draft table over
         #                          this request's own context (lazy)
         self.ng_n = 0            # context tokens folded into ng so far
@@ -477,7 +356,6 @@ class ServingEngine:
         # Exactly-once failover (inference/failover.py): the flag only
         # OFFERS durability — journaling starts when a controller (or
         # test) calls attach_journal, the publish_frames opt-in shape.
-        # Flag off and unattached: one None check per terminal event.
         self._failover = bool(_opt(failover, "serving_failover"))
         # Per-token-latency optimizations (ROADMAP item 2): both
         # default off; flags-off scheduling and emitted tokens are
@@ -489,7 +367,6 @@ class ServingEngine:
         # with per-page per-kv-head scale planes. Off = full-precision
         # pools, byte-identical contents and tokens.
         self._kv_quant = bool(_opt(kv_quant, "serving_kv_quant"))
-        self._journal = None
         self._draining = False
         self._deadlines_seen = False   # sticky: first deadline request
         #                                arms the per-step expiry scan
@@ -575,22 +452,6 @@ class ServingEngine:
         # programs already called once: the first call of each compiles
         # (or loads), and runs under a serving.compile span
         self._called: set = set()
-        # KV-page absmax sampling (monitor/numerics.py): 1-in-N decode
-        # chunks dispatch a tiny per-layer per-page |K|/|V| max over
-        # the pool AFTER the chunk's emitted-grid download has already
-        # synchronized the device — zero added block_until_ready calls
-        # at any rate (PR 9's pattern, pinned by test)
-        self._kv_chunks = 0
-        self._kv_absmax_fn = None
-        # Fleet SLO federation (monitor/federation.py): an attached
-        # FramePublisher rides the per-scheduler-step host tick — one
-        # None check per step when unattached, pure host reads when
-        # attached (zero added device synchronizations at any rate)
-        self._frame_pub = None
-        # registered-program FLOPs, cached per registry key: the cost
-        # plane reads it once per chunk, not once per slot, and the
-        # cached value keeps the per-dispatch cost at one dict lookup
-        self._flops_by_key: dict = {}
         # device-side slot state, reused across chunks until a
         # join/retire/preempt (state) or page-table change (bt) dirties it
         self._dev: dict = {}
@@ -607,75 +468,7 @@ class ServingEngine:
         self._zero_keys = {
             c: jnp.zeros((c, self.num_slots, 2), jnp.uint32)
             for c in (self.decode_chunk, self.turbo_chunk)}
-        _monitor.set_gauge("serving.pages.total",
-                           self.cache.num_pages,
-                           doc="KV page pool capacity")
-        # Operator plane: start the telemetry server when its flag is
-        # set (one cached branch otherwise) and contribute this
-        # engine's scheduler state to /healthz. The provider holds the
-        # engine WEAKLY — a retired engine prunes itself, never pins —
-        # and registers only while some plane could read it (monitor on
-        # or server flag/running): a fully-off process must not grow
-        # the provider map one entry per engine, ever.
-        # Process-unique uid (GIL-atomic counter, monitor/programs.py)
-        # keys both the /healthz provider name ("serving:<n>" — two
-        # engines must not evict each other's view) and the
-        # introspection-registry records (which outlive the engine —
-        # id(self) reuse must not alias a successor onto stale ones).
-        _mserver.maybe_start()
-        self._engine_uid = _monitor.programs.next_uid()
-        if _monitor.enabled() or _mserver.plane_active():
-            _mserver.register_health_provider(
-                f"serving:{self._engine_uid}",
-                _engine_health_provider(weakref.ref(self)))
-        # Sharding inspector (distributed/introspect.py): the param
-        # tree's per-leaf layout for /sharding — pure serving runs
-        # populate the view with no training loop in sight. Self-gated
-        # on the monitor flag (off path computes + registers nothing).
-        from ..distributed import introspect as _introspect
-        _introspect.register_sharded_tree(
-            f"serving:{self._engine_uid}.params", self.params)
-
-    def _record_serving_program(self, spec_key, name, jitted, args,
-                                kwargs, donated=()):
-        """Register a serving program with the introspection registry
-        (monitor/programs.py) once per specialization — signature,
-        donation map, cost-analysis FLOPs (one re-trace), and a lazy
-        memory analyzer the ``/programs`` endpoint resolves. The
-        registry ITSELF is the dedup (not an engine-local set): after
-        a ``monitor.reset()`` mid-run the next dispatch re-registers,
-        so the scrape endpoints and the headroom estimate's temp
-        reservation recover instead of staying empty forever. The
-        per-dispatch cost after the first is one locked dict lookup,
-        monitor-on only. The params sharding tree rides the same
-        reset-recovery seam (ensure_sharded_tree): a mid-run
-        ``monitor.reset()`` repopulates ``/sharding`` on the next
-        dispatch, like the program registry itself."""
-        from ..distributed import introspect as _introspect
-        from ..monitor import programs as _programs
-        _introspect.ensure_sharded_tree(
-            f"serving:{self._engine_uid}.params", lambda: self.params)
-        key = ("engine", self._engine_uid) + spec_key
-        if _programs.has_record(key):
-            _programs.note_hit(key)
-            return key
-        _programs.record_jit_call(key, name, jitted, args,
-                                  kwargs=kwargs, source="serving",
-                                  donated=donated)
-        return key
-
-    def _program_flops(self, key):
-        """Cached ``monitor/programs.flops_of`` read (None when the
-        backend never reported a count). An unknown key is NOT cached
-        as None: a ``monitor.reset()`` mid-run re-registers on the
-        next dispatch and the lookup must recover with it."""
-        v = self._flops_by_key.get(key)
-        if v is None:
-            from ..monitor import programs as _programs
-            v = _programs.flops_of(key)
-            if v is not None:
-                self._flops_by_key[key] = v
-        return v
+        self._acct = EngineAccounting(self)
 
     # -- submission ---------------------------------------------------------
 
@@ -783,24 +576,7 @@ class ServingEngine:
         ``finish_reason="shed"``)."""
         reason, norm = self._reject_reason(req)
         if reason is not None:
-            _monitor.inc("serving.requests.rejected",
-                         doc="malformed submissions refused at the "
-                             "door (engine state untouched)")
-            _trace.instant("serving.reject", rid=req.rid, reason=reason)
-            if _monitor.enabled():
-                # availability = non-rejected fraction: the refusal
-                # must enter the SLO window, attributed to whatever
-                # tenant the submission claimed (best-effort — the
-                # rejection may be ABOUT the tenant field)
-                try:
-                    tenant = str(req.tenant or "default")[:128]
-                except Exception:
-                    tenant = "default"
-                _slo.record_rejected(tenant or "default")
-                _forensics.note_terminal(req.rid, "rejected",
-                                         reason=reason,
-                                         tenant=tenant or "default")
-            raise RequestRejected(req.rid, reason)
+            raise self._finish(req, None, "rejected", reason, entered=False)
         # the scheduler consumes the NORMALIZED values it was screened
         # on — the original coercible-but-wrong-typed fields must not
         # ride into the loop
@@ -808,18 +584,15 @@ class ServingEngine:
          req.tenant, req.priority, req.deadline_s) = norm
         if getattr(req, "_submitted", False):
             # re-admission of a previously-submitted object (the client
-            # kept it): per-run mutable state must not carry over — the
-            # cost record restarts, TTFT/e2e re-anchor, a stale
-            # deadline anchor must not expire the new run, and the
-            # preemption count is the new run's. (Preemption re-queues
-            # re-enter via appendleft, not submit, and deliberately
-            # keep all of it — the record follows the request across
-            # ONE run.) The PRNG key is the exception: _keys_for pinned
-            # the first run's key onto req.key, so a resubmission
-            # replays byte-identical tokens.
-            req._t0 = None
-            req._t_enqueue = None
-            req._cost = None
+            # kept it): per-run mutable state must not carry over — a
+            # stale deadline anchor must not expire the new run, the
+            # preemption count is the new run's, and the accounting
+            # starts afresh at ``submitted`` below. (Preemption
+            # re-queues re-enter via appendleft, not submit, and
+            # deliberately keep all of it — the record follows the
+            # request across ONE run.) The PRNG key is the exception:
+            # _keys_for pinned the first run's key onto req.key, so a
+            # resubmission replays byte-identical tokens.
             req._t_deadline = None
             req._preempt_count = 0
         # overload gates, in severity order: a draining replica refuses
@@ -827,67 +600,36 @@ class ServingEngine:
         # bounded queue sheds (or displaces for higher priority). All
         # three raise BEFORE the request touches any engine state.
         if self._draining:
-            self._shed_submit(req, "engine is draining")
+            raise self._finish(req, None, "shed", "engine is draining",
+                               entered=False)
         if (self._shed_on_burn and req.priority <= 0
-                and _monitor.enabled()
-                and _slo.burn_alerting(load_only=True)):
-            # load_only: the trigger reads the LATENCY burn — the
-            # sheds this gate produces are availability-bad records,
-            # and feeding them back would lock best-effort traffic
-            # out long after the real overload cleared
-            self._shed_submit(req, "SLO fast-burn alerting; "
-                                   "priority<=0 work shed")
+                and self._acct.burning()):
+            raise self._finish(req, None, "shed", "SLO fast-burn alerting; "
+                               "priority<=0 work shed", entered=False)
         if self._max_queue and len(self.queue) >= self._max_queue:
             victim = self._displaceable_pos(req.priority)
             if victim is None:
-                self._shed_submit(
-                    req, f"queue full ({self._max_queue}) and no "
-                         f"lower-priority request to displace")
-            else:
-                shed = self.queue[victim]
-                del self.queue[victim]
-                _forensics.decision(
-                    "displace", rid=shed.rid, reason="queue_full",
-                    queue_depth=len(self.queue) + 1,
-                    max_queue=self._max_queue, by_rid=req.rid,
-                    by_priority=req.priority,
-                    victim_priority=getattr(shed, "priority", 0))
-                self._finish_shed(
-                    shed, "displaced by higher-priority request "
-                          f"{req.rid!r}")
+                raise self._finish(
+                    req, None, "shed", f"queue full ({self._max_queue}) "
+                    f"and no lower-priority request to displace",
+                    entered=False)
+            shed = self.queue[victim]
+            del self.queue[victim]
+            self._acct.displaced(shed, req, self._max_queue)
+            self._finish(shed, None, "shed", "displaced by "
+                         f"higher-priority request {req.rid!r}")
         if req.deadline_s is not None:
             req._t_deadline = time.perf_counter() + req.deadline_s
             self._deadlines_seen = True
-        plen = int(req.prompt.shape[0])
-        if _monitor.enabled():
-            now = time.perf_counter()
-            # t0 anchors TTFT/e2e (first submission wins); t_enqueue is
-            # refreshed by preemption re-queues and anchors queue_wait
-            req._t0 = getattr(req, "_t0", None) or now
-            req._t_enqueue = now
-            # the cost record follows the REQUEST across preemption
-            # re-queues (they re-enter via appendleft, not submit —
-            # but a client resubmitting the same object keeps it too)
-            if getattr(req, "_cost", None) is None:
-                req._cost = RequestCost(tenant=req.tenant,
-                                        priority=req.priority)
-            _trace.instant("serving.enqueue", rid=req.rid, prompt=plen,
-                           max_new=req.max_new_tokens,
-                           tenant=req.tenant)
-            _forensics.note(req.rid, "enqueue", t=now,
-                            tenant=req.tenant, priority=req.priority,
-                            prompt=plen, max_new=req.max_new_tokens)
         req._submitted = True
-        if self._journal is not None:
-            # journal AFTER every gate that could still refuse the
-            # request (a shed/rejected submission never entered the
-            # engine and must not be re-dispatched), and pin the
-            # sampling key BEFORE the record is written so a
-            # re-dispatch replays byte-identical tokens
-            if req.temperature > 0.0 and req.key is None:
-                self._rng_fallback += 1
-                req.key = jax.random.PRNGKey(self._rng_fallback)
-            self._journal.admit(req)
+        if (self._acct.journal is not None and req.temperature > 0.0
+                and req.key is None):
+            # a journaled request's sampling key is pinned BEFORE its
+            # record is written, so a re-dispatch replays byte-identical
+            # tokens
+            self._rng_fallback += 1
+            req.key = jax.random.PRNGKey(self._rng_fallback)
+        self._acct.submitted(req)
         self.queue.append(req)
 
     # -- overload policy: shedding, deadlines, drain ------------------------
@@ -904,14 +646,7 @@ class ServingEngine:
         stop a replica while an output is still trapped in a slot.
         (The ``serving.autoscale.*`` gauges tick inside ``step`` after
         retirement, where the two notions coincide.)"""
-        resident = sum(1 for s in self.slots if s is not None)
-        return _slo.demand_model(
-            len(self.queue), resident, self.num_slots,
-            self.cache.alloc.free_pages / self.cache.num_pages
-            if self.cache.num_pages else 0.0)
-
-    def _retry_after(self) -> float:
-        return _slo.retry_after_hint(self.autoscale_payload())
+        return self._acct.autoscale_payload()
 
     def publish_frames(self, name: str, dir_path: Optional[str] = None,
                        *, min_interval_s: float = 0.25, client=None,
@@ -926,13 +661,9 @@ class ServingEngine:
         the frame IS the liveness beat). Pure host reads; zero added
         device synchronizations at any publish rate. Returns the
         publisher (one per engine; re-attaching replaces it)."""
-        from ..monitor import federation as _fed
-        self._frame_pub = _fed.FramePublisher(
-            name, dir_path=dir_path, client=client,
-            local_only=local_only,
+        return self._acct.publish_frames(
+            name, dir_path, client=client, local_only=local_only,
             min_interval_s=min_interval_s, slo_fn=slo_fn)
-        self._frame_pub.maybe_publish(self, force=True)
-        return self._frame_pub
 
     def attach_journal(self, name: str, dir_path: Optional[str] = None,
                        *, client=None):
@@ -945,36 +676,8 @@ class ServingEngine:
         controller can re-dispatch work stranded by a crash without
         ever double-serving a finished request. Returns the journal
         (one per engine; re-attaching replaces it)."""
-        if not self._failover:
-            return None
-        from .failover import AdmissionJournal
-        self._journal = AdmissionJournal(name, dir_path=dir_path,
-                                         client=client)
-        return self._journal
-
-    def _shed_submit(self, req: Request, why: str):
-        """Refuse a WELL-FORMED submission by overload policy: typed
-        :class:`EngineOverloaded` with the demand-model backoff hint,
-        before the request touches any engine state."""
-        hint = self._retry_after()
-        self.stats.shed += 1
-        _monitor.inc("serving.requests.shed",
-                     doc="admissible work refused by overload policy "
-                         "(bounded queue, SLO burn, displacement, "
-                         "drain) with a retry_after_s hint")
-        tenant = getattr(req, "tenant", "default") or "default"
-        _trace.instant("serving.shed", rid=req.rid, reason=why,
-                       retry_after_s=hint, tenant=tenant)
-        if _monitor.enabled():
-            _slo.record_shed(tenant)
-            _forensics.decision("shed", rid=req.rid, reason=why,
-                                queue_depth=len(self.queue),
-                                priority=getattr(req, "priority", 0),
-                                draining=self._draining)
-            _forensics.note_terminal(req.rid, "shed", reason=why,
-                                     tenant=tenant,
-                                     retry_after_s=round(hint, 3))
-        raise EngineOverloaded(req.rid, why, hint)
+        return self._acct.attach_journal(name, dir_path, client) \
+            if self._failover else None
 
     def _displaceable_pos(self, priority: int) -> Optional[int]:
         """Queue position of the displacement victim for an arriving
@@ -994,51 +697,59 @@ class ServingEngine:
                 pos, lowest = j, p
         return pos
 
-    def _finish_shed(self, req: Request, why: str):
-        """End a QUEUED request as shed (displacement or drain): it
-        leaves through ``outputs`` with ``finish_reason="shed"`` and
-        the backoff hint — never silently dropped (its submitter
-        already returned from ``submit``)."""
-        hint = self._retry_after()
-        self.stats.shed += 1
-        _monitor.inc("serving.requests.shed")
-        mon = _monitor.enabled()
-        cost = getattr(req, "_cost", None) if mon else None
-        if cost is not None:
-            t_enq = getattr(req, "_t_enqueue", None)
-            if t_enq is not None:
-                cost.queue_wait_ms += (time.perf_counter() - t_enq) * 1e3
-        if mon:
-            if cost is not None:
-                # the shed rides availability like a rejection, but
-                # its consumption (prefill before a preemption,
-                # page-seconds, the queue wait above) folds into the
-                # tenant aggregates — the tenant PAID for it
-                _slo.record_request(dict(cost.as_dict(),
-                                         rejected=True, shed=True))
-            else:
-                _slo.record_shed(getattr(req, "tenant", "default")
-                                 or "default")
+    def _finish(self, req: Request, idx: Optional[int], state: str,
+                reason: Optional[str] = None, *, entered: bool = True):
+        """The ONE way a request ends: ``completed`` (from slot
+        ``idx``), ``expired`` (from its slot with the tokens it had —
+        they were sampled and are the client's to keep, so the
+        generated-discarded==emitted token contract holds — or from the
+        queue with none), ``shed`` by overload policy, or ``rejected``
+        as malformed. A request that ``entered`` the engine leaves
+        through ``outputs`` — never silently dropped (its submitter
+        already returned from ``submit``); for one refused at ``submit``
+        (``entered`` False: it touched no engine state) the typed error
+        comes back for ``submit`` to raise, a shed one's with the
+        demand-model backoff hint."""
+        slot = None
+        if idx is not None:
+            slot = self.slots[idx]
+            self.slots[idx] = None
+            self._state_dirty = self._bt_dirty = True
+        hint = self._acct.retry_after() if state == "shed" else None
+        # before the free: the final page-seconds tick reads the pages
+        cost = self._acct.finished(req, slot, idx, state, reason, hint,
+                                   entered)
+        if state != "rejected":
+            setattr(self.stats, state, getattr(self.stats, state) + 1)
+        if not entered:
+            return RequestRejected(req.rid, reason) if hint is None \
+                else EngineOverloaded(req.rid, reason, hint)
+        tokens = np.zeros(0, np.int32)
+        if slot is not None:
+            tokens = np.asarray(slot.tokens, np.int32)
+            if (state == "completed" and self._prefix is not None
+                    and slot.kv_len >= self.page_size):
+                # retirement insertion: only COMMITTED positions enter the
+                # radix — the prompt plus the generated tokens whose KV is
+                # already written (kv_len worth; the final pending token's
+                # KV never was). insert() takes a cache hold on each newly
+                # shared page BEFORE the free below, so the pages survive
+                # the sequence's release with ref >= 1.
+                prompt = np.asarray(req.prompt, np.int32)
+                gen_committed = slot.kv_len - int(prompt.shape[0])
+                stream = prompt if gen_committed <= 0 else np.concatenate(
+                    [prompt, tokens[:gen_committed]])
+                self._prefix.insert(stream,
+                                    self.cache.alloc.seq_pages(req.rid))
+            self.cache.alloc.free(req.rid)
         self.outputs[req.rid] = RequestOutput(
-            rid=req.rid, tokens=np.zeros(0, np.int32),
+            rid=req.rid, tokens=tokens,
             prompt_len=int(np.asarray(req.prompt).shape[0]),
-            preemptions=getattr(req, "_preempt_count", 0),
-            tenant=getattr(req, "tenant", "default"),
-            cost=cost, finish_reason="shed", retry_after_s=hint,
-            shed_reason=why)
-        if self._journal is not None:
-            self._journal.finish(req.rid, "shed")
-        tenant = getattr(req, "tenant", "default") or "default"
-        _trace.instant("serving.shed", rid=req.rid, reason=why,
-                       retry_after_s=hint, tenant=tenant)
-        if mon:
-            _forensics.decision("shed", rid=req.rid, reason=why,
-                                queued=True,
-                                priority=getattr(req, "priority", 0),
-                                draining=self._draining)
-            _forensics.note_terminal(req.rid, "shed", reason=why,
-                                     tenant=tenant,
-                                     retry_after_s=round(hint, 3))
+            preemptions=slot.preemptions if slot is not None
+            else getattr(req, "_preempt_count", 0),
+            tenant=getattr(req, "tenant", "default"), cost=cost,
+            finish_reason=state, retry_after_s=hint,
+            shed_reason=reason if state == "shed" else None)
 
     def begin_drain(self, shed_queued: bool = True):
         """Enter the drain lifecycle: stop admitting new work (submit
@@ -1052,8 +763,7 @@ class ServingEngine:
         _faults.hit("serving.drain")
         already = self._draining
         self._draining = True
-        _trace.instant("serving.drain.begin", queued=len(self.queue),
-                       again=already)
+        self._acct.drain_begun(again=already)
         if shed_queued:
             keep: deque = deque()
             while self.queue:
@@ -1067,15 +777,9 @@ class ServingEngine:
                     # re-queues can enter the queue.
                     keep.append(r)
                 else:
-                    self._finish_shed(r, "engine is draining")
+                    self._finish(r, None, "shed", "engine is draining")
             self.queue = keep
-        if self._frame_pub is not None:
-            # drain state must reach the federation controller now,
-            # not a rate-limit later — but only the TRANSITION forces:
-            # the controller re-invokes begin_drain every retry tick
-            # of a slow drain, and forcing each call would bypass the
-            # rate limit into per-tick transport I/O
-            self._frame_pub.maybe_publish(self, force=not already)
+        self._acct.drain_queue_shed(again=already)
 
     @property
     def draining(self) -> bool:
@@ -1105,7 +809,7 @@ class ServingEngine:
             for r in self.queue:
                 t = getattr(r, "_t_deadline", None)
                 if t is not None and now >= t:
-                    self._finish_expired(r, slot_idx=None, now=now)
+                    self._finish(r, None, "expired")
                 else:
                     keep.append(r)
             self.queue = keep
@@ -1115,77 +819,7 @@ class ServingEngine:
                 continue
             t = getattr(slot.req, "_t_deadline", None)
             if t is not None and now >= t:
-                self._finish_expired(slot.req, slot_idx=idx, now=now)
-
-    def _finish_expired(self, req: Request, slot_idx: Optional[int],
-                        now: float):
-        """End ``req`` as deadline-expired: from the queue (no tokens)
-        or evicted from a running slot (partial tokens delivered —
-        they were sampled and are the client's to keep, so the
-        generated-discarded==emitted token contract holds)."""
-        mon = _monitor.enabled()
-        cost = getattr(req, "_cost", None) if mon else None
-        tokens = np.zeros(0, np.int32)
-        preemptions = getattr(req, "_preempt_count", 0)
-        if slot_idx is not None:
-            slot = self.slots[slot_idx]
-            self.slots[slot_idx] = None
-            self._state_dirty = self._bt_dirty = True
-            if cost is not None and slot.t_tick is not None:
-                # final page-seconds tick, read before the free
-                cost.page_seconds += (
-                    self.cache.alloc.page_count(req.rid)
-                    * (now - slot.t_tick))
-            self.cache.alloc.free(req.rid)
-            tokens = np.asarray(slot.tokens, np.int32)
-            preemptions = slot.preemptions
-            if cost is not None:
-                cost.grid_steps += (self.stats.decode_steps
-                                    - slot.steps0) * self.num_slots
-        elif cost is not None:
-            t_enq = getattr(req, "_t_enqueue", None)
-            if t_enq is not None:
-                cost.queue_wait_ms += (now - t_enq) * 1e3
-        self.stats.expired += 1
-        _monitor.inc("serving.requests.expired",
-                     doc="requests retired by their submit-time "
-                         "deadline (expired in queue or evicted from "
-                         "the running batch)")
-        if cost is not None:
-            cost.preemptions = preemptions
-            t0 = getattr(req, "_t0", None)
-            if t0 is not None:
-                cost.e2e_ms = (now - t0) * 1e3
-            if cost.grid_steps > 0:
-                cost.slot_share = round(
-                    cost.slot_steps / cost.grid_steps, 6)
-            # the SLO window counts an expiry BAD for availability and
-            # excludes it from the latency objectives (monitor/slo.py)
-            _slo.record_request(dict(cost.as_dict(), expired=True))
-        self.outputs[req.rid] = RequestOutput(
-            rid=req.rid, tokens=tokens,
-            prompt_len=int(np.asarray(req.prompt).shape[0]),
-            preemptions=preemptions,
-            tenant=getattr(req, "tenant", "default"),
-            cost=cost, finish_reason="expired")
-        if self._journal is not None:
-            self._journal.finish(req.rid, "expired",
-                                 tokens=int(tokens.shape[0]))
-        tenant = getattr(req, "tenant", "default") or "default"
-        _trace.instant("serving.expire", rid=req.rid,
-                       tokens=int(tokens.shape[0]),
-                       in_slot=slot_idx is not None, tenant=tenant)
-        if mon:
-            if slot_idx is not None:
-                _forensics.decision("evict", rid=req.rid,
-                                    reason="deadline", slot=slot_idx,
-                                    tokens=int(tokens.shape[0]))
-            _forensics.note_terminal(
-                req.rid, "expired", t=now,
-                e2e_ms=(cost.e2e_ms if cost is not None
-                        and cost.e2e_ms else None),
-                tenant=tenant, tokens=int(tokens.shape[0]),
-                in_slot=slot_idx is not None)
+                self._finish(slot.req, idx, "expired")
 
     # -- scheduling ---------------------------------------------------------
 
@@ -1264,8 +898,8 @@ class ServingEngine:
         serving program (it compiles, or loads from the cache), a null
         context after: a compile in the middle of serving is a named gap
         in a trace, not a slow step. The call itself stays inline at its
-        site: a Python frame between the scheduler and a jitted call is
-        not free while the program is traced (PERF.md section 6, PR 24)."""
+        site: what lies on Python's frame stack above a jitted call is
+        not free while the program is traced (the module's docstring)."""
         if id(fn) in self._called:
             return _NO_SPAN
         self._called.add(id(fn))
@@ -1292,8 +926,7 @@ class ServingEngine:
         dropped = self._prefix.evicted_nodes - before
         if dropped:
             self.stats.prefix_evictions += dropped
-            _monitor.inc("serving.prefix_cache.evictions", dropped,
-                         doc="radix nodes dropped under pool pressure")
+            self._acct.prefix("evictions", dropped)
         return freed
 
     def _match_len(self, req: Request) -> int:
@@ -1314,8 +947,7 @@ class ServingEngine:
         if self._prefix is None:
             return alloc.alloc(req.rid, s_pad)
         self.stats.prefix_lookups += 1
-        _monitor.inc("serving.prefix_cache.lookups",
-                     doc="admission prompt-prefix radix probes")
+        self._acct.prefix("lookups")
         need = alloc.pages_for(s_pad)
         while True:
             cached, pages = self._prefix.match(np.asarray(req.prompt))
@@ -1332,12 +964,8 @@ class ServingEngine:
             if cached:
                 self.stats.prefix_hits += 1
                 self.stats.prefix_tokens_saved += cached
-                _monitor.inc("serving.prefix_cache.hits",
-                             doc="admissions that forked cached "
-                                 "prefix pages")
-                _monitor.inc("serving.prefix_cache.tokens_saved", cached,
-                             doc="prompt tokens served from cached KV "
-                                 "instead of prefill")
+                self._acct.prefix("hits")
+                self._acct.prefix("tokens_saved", cached)
             return got
 
     def _keys_for(self, req: Request) -> np.ndarray:
@@ -1366,109 +994,6 @@ class ServingEngine:
             self.slots = packed
             self._state_dirty = self._bt_dirty = True
 
-    def _retire(self, idx: int):
-        slot = self.slots[idx]
-        self.slots[idx] = None
-        self._state_dirty = self._bt_dirty = True
-        mon = _monitor.enabled()
-        cost = slot.cost if mon else None
-        if cost is not None and slot.t_tick is not None:
-            # final page-seconds tick: pages held from the last chunk
-            # edge until this retirement, read BEFORE the free below
-            now_t = time.perf_counter()
-            cost.page_seconds += (
-                self.cache.alloc.page_count(slot.req.rid)
-                * (now_t - slot.t_tick))
-            slot.t_tick = now_t
-        if self._prefix is not None and slot.kv_len >= self.page_size:
-            # retirement insertion: only COMMITTED positions enter the
-            # radix — the prompt plus the generated tokens whose KV is
-            # already written (kv_len worth; the final pending token's
-            # KV never was). insert() takes a cache hold on each newly
-            # shared page BEFORE the free below, so the pages survive
-            # the sequence's release with ref >= 1.
-            prompt = np.asarray(slot.req.prompt, np.int32)
-            plen = int(prompt.shape[0])
-            gen_committed = slot.kv_len - plen
-            stream = prompt if gen_committed <= 0 else np.concatenate(
-                [prompt, np.asarray(slot.tokens[:gen_committed],
-                                    np.int32)])
-            self._prefix.insert(stream,
-                                self.cache.alloc.seq_pages(slot.req.rid))
-        self.cache.alloc.free(slot.req.rid)
-        self.outputs[slot.req.rid] = RequestOutput(
-            rid=slot.req.rid,
-            tokens=np.asarray(slot.tokens, np.int32),
-            prompt_len=int(np.asarray(slot.req.prompt).shape[0]),
-            preemptions=slot.preemptions,
-            tenant=getattr(slot.req, "tenant", "default"),
-            cost=cost)
-        if self._journal is not None:
-            # the completion marker lands BEFORE the output can be
-            # harvested: a crash after this point re-dispatches
-            # nothing for this rid (exactly-once dedup)
-            self._journal.finish(slot.req.rid, "completed",
-                                 tokens=int(len(slot.tokens)))
-        self.stats.completed += 1
-        _monitor.inc("serving.requests.completed")
-        if mon:
-            now = time.perf_counter()
-            t0 = getattr(slot.req, "_t0", None)
-            if t0 is not None:
-                e2e = (now - t0) * 1e3
-                _observe_latency(
-                    "serving.latency.e2e_ms", e2e,
-                    "request lifetime: original enqueue to retirement")
-                if cost is not None:
-                    cost.e2e_ms = e2e
-                if slot.t_first is not None:
-                    # observed at retirement, not at prefill: a
-                    # preempted request re-prefills, and only the
-                    # surviving run's first token — the one the client
-                    # keeps — counts. One sample per completed request.
-                    ttft = (slot.t_first - t0) * 1e3
-                    _observe_latency(
-                        "serving.latency.ttft_ms", ttft,
-                        "original enqueue to the prefill-sampled "
-                        "first token the client keeps")
-                    if cost is not None:
-                        cost.ttft_ms = ttft
-            if slot.gen > 1 and slot.t_first is not None \
-                    and slot.t_last is not None:
-                # mean inter-token time over the decode phase; t_last
-                # is the arrival of the final emitted token (chunk-edge
-                # resolution), t_first the prefill-sampled token
-                tpot = (slot.t_last - slot.t_first) / (slot.gen - 1) * 1e3
-                _observe_latency(
-                    "serving.latency.tpot_ms", tpot,
-                    "mean time per output token after the first")
-                if cost is not None:
-                    cost.tpot_ms = tpot
-            if cost is not None:
-                cost.preemptions = slot.preemptions
-                # slot-occupancy share: fraction of the decode grid's
-                # capacity this request held over its residencies
-                # (cumulative across preemption re-runs; None when it
-                # retired without a decode chunk in between)
-                cost.grid_steps += (self.stats.decode_steps
-                                    - slot.steps0) * self.num_slots
-                cost.slot_share = round(
-                    cost.slot_steps / cost.grid_steps, 6) \
-                    if cost.grid_steps > 0 else None
-                _slo.record_request(cost.as_dict())
-            _trace.instant("serving.retire", rid=slot.req.rid,
-                           tokens=slot.gen,
-                           preemptions=slot.preemptions,
-                           tenant=getattr(slot.req, "tenant", "default"))
-            _forensics.note_terminal(
-                slot.req.rid, "completed", t=now,
-                e2e_ms=(cost.e2e_ms if cost is not None
-                        and cost.e2e_ms else None),
-                ttft_ms=(cost.ttft_ms if cost is not None
-                         and cost.ttft_ms else None),
-                tenant=getattr(slot.req, "tenant", "default"),
-                tokens=slot.gen, preemptions=slot.preemptions)
-
     def _preempt_victim_idx(self) -> Optional[int]:
         """Pick the eviction victim. Default: the YOUNGEST live request
         (highest slot index — the original recompute policy). With
@@ -1476,10 +1001,9 @@ class ServingEngine:
         cost, ordered by (priority, prior preemptions, accumulated
         work) — evict the least important class first; within a class
         protect repeat victims (anti-starvation) and then evict the
-        request that is cheapest to recompute. Work comes from the
-        per-request cost record (prefill+decode tokens, cumulative
-        across re-runs) when the monitor keeps one, else the current
-        run's KV length — the monitor-off proxy of the same quantity."""
+        request that is cheapest to recompute (the accounting's
+        ``work_done``: the cost record's tokens when the monitor keeps
+        one, else the current run's KV length)."""
         if not self._slo_preemption:
             for idx in range(self.num_slots - 1, -1, -1):
                 slot = self.slots[idx]
@@ -1491,11 +1015,8 @@ class ServingEngine:
             slot = self.slots[idx]
             if slot is None or slot.done:
                 continue
-            work = slot.kv_len
-            if slot.cost is not None:
-                work = slot.cost.prefill_tokens + slot.cost.decode_tokens
             key = (getattr(slot.req, "priority", 0), slot.preemptions,
-                   work, -idx)       # final tie-break: youngest
+                   self._acct.work_done(slot), -idx)   # tie-break: youngest
             if best_key is None or key < best_key:
                 best_idx, best_key = idx, key
         return best_idx
@@ -1512,15 +1033,9 @@ class ServingEngine:
         slot = self.slots[idx]
         self.slots[idx] = None
         self._state_dirty = self._bt_dirty = True
-        now = time.perf_counter() if _monitor.enabled() else None
-        cost = slot.cost if now is not None else None
-        if cost is not None and slot.t_tick is not None:
-            # final page-seconds tick for this run, read before
-            # the free — an evicted request PAID for the pages
-            # it held even though the work is recomputed
-            cost.page_seconds += (
-                self.cache.alloc.page_count(slot.req.rid)
-                * (now - slot.t_tick))
+        # before the free: the evicted request PAID for the pages
+        self._acct.preempted(slot, idx, "slo" if self._slo_preemption
+                             else "youngest")
         self.cache.alloc.free(slot.req.rid)
         slot.req._preempt_count = getattr(
             slot.req, "_preempt_count", 0) + 1
@@ -1530,52 +1045,7 @@ class ServingEngine:
         # recomputed from scratch: move them to the discarded
         # column so generated - discarded stays == emitted
         self.stats.tokens_discarded += slot.gen
-        _monitor.inc("serving.requests.preempted")
-        _monitor.inc("serving.tokens.discarded", slot.gen,
-                     doc="sampled tokens thrown away by "
-                         "preemption recompute")
-        if now is not None:
-            # the re-queue refreshes t_enqueue: the NEXT wait
-            # accumulates onto the record's cumulative
-            # queue_wait_ms at re-admission (the histogram
-            # observes each wait once, the record keeps the sum)
-            slot.req._t_enqueue = now
-            if cost is not None:
-                cost.discarded_tokens += slot.gen
-                cost.grid_steps += (self.stats.decode_steps
-                                    - slot.steps0) \
-                    * self.num_slots
-            tenant = getattr(slot.req, "tenant", "default") \
-                or "default"
-            _trace.instant("serving.preempt", rid=slot.req.rid,
-                           discarded=slot.gen, tenant=tenant)
-            # the victim-selection inputs that chose this slot — the
-            # _preempt_victim_idx key, recorded so the eviction is
-            # auditable (forensics decision ring + the victim's own
-            # timeline)
-            work = slot.kv_len
-            if cost is not None:
-                work = (cost.prefill_tokens + cost.decode_tokens)
-            policy = "slo" if self._slo_preemption else "youngest"
-            victim = dict(policy=policy, slot=idx,
-                          priority=getattr(slot.req, "priority", 0),
-                          prior_preemptions=slot.preemptions,
-                          work=int(work))
-            _forensics.decision("preempt", rid=slot.req.rid,
-                                discarded=slot.gen, **victim)
-            _forensics.note(slot.req.rid, "preempt", t=now,
-                            tenant=tenant, discarded=slot.gen,
-                            **victim)
         return True
-
-    def _defer(self, req: "Request", reason: str, **inputs):
-        """Record one admission-scan deferral (forensics timeline +
-        decision ring, both self-gated and coalescing — a head request
-        blocked on the same reason for many steps is ONE record with a
-        count, not a flood)."""
-        _forensics.note_defer(req.rid, reason, **inputs)
-        _forensics.decision("defer", rid=req.rid, reason=reason,
-                            **inputs)
 
     def _admit(self):
         # PAIRED SCANS: this FIFO body and _admit_policy below share
@@ -1589,7 +1059,7 @@ class ServingEngine:
         while self.queue:
             free = [i for i, s in enumerate(self.slots) if s is None]
             if not free:
-                self._defer(self.queue[0], "no_free_slot",
+                self._acct.deferred(self.queue[0], "no_free_slot",
                             queue_depth=len(self.queue))
                 break
             req = self.queue[0]
@@ -1600,7 +1070,7 @@ class ServingEngine:
                            for s in self.slots)
             if (self._free_slack() - need < self.watermark_pages
                     and not idle):        # head-of-line admission control
-                self._defer(req, "watermark",
+                self._acct.deferred(req, "watermark",
                             free_slack=self._free_slack(), need=need,
                             watermark_pages=self.watermark_pages,
                             queue_depth=len(self.queue))
@@ -1608,7 +1078,7 @@ class ServingEngine:
             self.queue.popleft()
             if self._alloc_for(req, s_pad) is None:
                 self.queue.appendleft(req)
-                self._defer(req, "alloc_failed", need=need,
+                self._acct.deferred(req, "alloc_failed", need=need,
                             free_pages=self.cache.alloc.free_pages,
                             queue_depth=len(self.queue))
                 # an idle engine that cannot place its head request will
@@ -1679,7 +1149,7 @@ class ServingEngine:
         while self.queue:
             free = [i for i, s in enumerate(self.slots) if s is None]
             if not free:
-                self._defer(self.queue[0], "no_free_slot",
+                self._acct.deferred(self.queue[0], "no_free_slot",
                             queue_depth=len(self.queue))
                 break
             pos = None
@@ -1695,7 +1165,7 @@ class ServingEngine:
                     pos = j
             if pos is None:
                 # every waiter's tenant is at cap
-                self._defer(self.queue[0], "tenant_cap", cap=cap,
+                self._acct.deferred(self.queue[0], "tenant_cap", cap=cap,
                             queue_depth=len(self.queue))
                 break
             req = self.queue[pos]
@@ -1706,7 +1176,7 @@ class ServingEngine:
                            for s in self.slots)
             if (self._free_slack() - need < self.watermark_pages
                     and not idle):
-                self._defer(req, "watermark",
+                self._acct.deferred(req, "watermark",
                             free_slack=self._free_slack(), need=need,
                             watermark_pages=self.watermark_pages,
                             queue_depth=len(self.queue))
@@ -1714,7 +1184,7 @@ class ServingEngine:
             del self.queue[pos]
             if self._alloc_for(req, s_pad) is None:
                 self.queue.insert(pos, req)
-                self._defer(req, "alloc_failed", need=need,
+                self._acct.deferred(req, "alloc_failed", need=need,
                             free_pages=self.cache.alloc.free_pages,
                             queue_depth=len(self.queue))
                 E.enforce(not idle,
@@ -1775,70 +1245,41 @@ class ServingEngine:
         power-of-two group size (bounds compiles at log2(slots) per
         bucket); dummy rows carry all-sentinel page tables and never
         touch the pool."""
-        need = s_pad // self.page_size
-        mon = _monitor.enabled()
-        t_admit = None
-        if mon:
-            t_admit = time.perf_counter()
-            _forensics.decision(
-                "admit", rid=group[0].rid, group=len(group),
-                bucket=s_pad, free_slots=len(free),
-                queue_depth=len(self.queue),
-                pfx_cached=int(getattr(group[0], "_pfx_cached", 0)))
-            for r in group:
-                wait_ms = None
-                t_enq = getattr(r, "_t_enqueue", None)
-                if t_enq is not None:
-                    wait_ms = (t_admit - t_enq) * 1e3
-                    _observe_latency(
-                        "serving.latency.queue_wait_ms", wait_ms,
-                        "enqueue (or preemption re-queue) to admission")
-                    cost = getattr(r, "_cost", None)
-                    if cost is not None:
-                        # CUMULATIVE across preemption re-queues: the
-                        # histogram above observes each wait once; the
-                        # record answers "how long did this request
-                        # spend queued in total"
-                        cost.queue_wait_ms += wait_ms
-                _trace.instant("serving.admit", rid=r.rid)
-                # the admit event carries the prefix-cache match result
-                # (cached prefix length this group was grouped on)
-                _forensics.note(
-                    r.rid, "admit", t=t_admit, bucket=s_pad,
-                    group=len(group),
-                    wait_ms=round(wait_ms, 3)
-                    if wait_ms is not None else None,
-                    pfx_cached=int(getattr(r, "_pfx_cached", 0)))
-        with _trace.span("serving.prefill", group=len(group),
-                         s_pad=s_pad):
+        n, page, B = len(group), self.page_size, self.num_slots
+        cache, stats, rids = self.cache, self.stats, [r.rid for r in group]
+        alloc, sentinel = cache.alloc, cache.num_pages  # a dummy row's page
+        need = s_pad // page
+        # with the prefix cache on, every member of this group shares
+        # the same cached page-aligned prefix length (admission grouped
+        # by it): the program prefills only the uncached tail, reading
+        # the shared context pages without ever writing them
+        cached = int(getattr(group[0], "_pfx_cached", 0)) \
+            if self._prefix is not None else 0
+        self._acct.admitted(group, s_pad, cached, len(free))
+        with _trace.span("serving.prefill", group=n, s_pad=s_pad):
             with _trace.span("serving.prefill.build"):
                 g = 1
-                while g < len(group):
+                while g < n:
                     g *= 2
-                # with the prefix cache on, every member of this group shares
-                # the same cached page-aligned prefix length (admission grouped
-                # by it): the program prefills only the uncached tail, reading
-                # the shared context pages without ever writing them
-                cached = int(getattr(group[0], "_pfx_cached", 0)) \
-                    if self._prefix is not None else 0
-                ncp = cached // self.page_size
+                ncp = cached // page
                 s_eff = s_pad - cached
                 need_eff = need - ncp
                 ids = np.zeros((g, s_eff), np.int32)
-                rows = np.full((g, need_eff), self.cache.num_pages, np.int32)
-                ctx_rows = np.full((g, ncp), self.cache.num_pages, np.int32)
+                rows = np.full((g, need_eff), sentinel, np.int32)
+                ctx_rows = np.full((g, ncp), sentinel, np.int32)
                 slen = np.ones(g, np.int32)
                 temps = np.zeros(g, np.float32)
                 keys = np.zeros((g, 2), np.uint32)
                 slots = []
                 for j, r in enumerate(group):
-                    plen = int(np.asarray(r.prompt).shape[0])
-                    ids[j, :plen - cached] = np.asarray(r.prompt,
-                                                        np.int32)[cached:]
-                    brow = self.cache.alloc.block_row(r.rid, need)
+                    prompt = np.asarray(r.prompt, np.int32)
+                    plen = int(prompt.shape[0])
+                    tail = plen - cached
+                    ids[j, :tail] = prompt[cached:]
+                    brow = alloc.block_row(rids[j], need)
                     ctx_rows[j] = brow[:ncp]
                     rows[j] = brow[ncp:]
-                    slen[j] = plen - cached
+                    slen[j] = tail
                     temps[j] = r.temperature
                     slot = _Slot(r, self._keys_for(r))
                     slot.kv_len = plen
@@ -1846,8 +1287,11 @@ class ServingEngine:
                     keys[j] = slot.keys[0]
                     slots.append(slot)
                 sampled = any(r.temperature > 0 for r in group)
-                pf = self._prefill_shared_fn(g, s_eff, ncp, sampled) \
-                    if cached else self._prefill_fn(g, s_pad, sampled)
+                # the program's name; its tail is what it is specialized on
+                spec_key = ("serving.prefill_shared", g, s_eff, ncp, sampled) \
+                    if cached else ("serving.prefill", g, s_pad, sampled)
+                pf = (self._prefill_shared_fn if cached
+                      else self._prefill_fn)(*spec_key[1:])
                 up = dict(ids=ids, page_rows=rows, slen=slen)
                 # Where no request of the group can end on its first token
                 # (none names an EOS), nothing the scheduler decides before
@@ -1857,16 +1301,16 @@ class ServingEngine:
                 # prefill ends, and the host reads the token after that.
                 later = all(r.eos_token_id is None for r in group)
                 if later:
-                    up["at"] = np.full(g, self.num_slots, np.int32)
-                    up["at"][:len(group)] = free[:len(group)]
+                    where = up["at"] = np.full(g, B, np.int32)
+                    where[:n] = free[:n]
                     if g not in self._joins:
                         # compiled here, beside the group's prefill program,
                         # not at a first join in the middle of serving
                         self._joins.add(g)
                         with _trace.span("serving.compile"):
                             self._join(*jax.device_put((
-                                np.zeros(self.num_slots, np.int32),
-                                up["at"], np.zeros(g, np.int32))))
+                                np.zeros(B, np.int32), where,
+                                np.zeros(g, np.int32))))
                 if sampled:
                     up.update(temp=temps, key=keys)
                 if cached:
@@ -1874,9 +1318,8 @@ class ServingEngine:
                 if self._recurrent:
                     with _trace.span("serving.step.state"):
                         # each request's own row; a dummy names nobody's
-                        up["state_rows"] = self.cache.state_row_table(
-                            [r.rid for r in group]
-                            + [None] * (g - len(group)))
+                        up["state_rows"] = cache.state_row_table(
+                            rids + [None] * (g - n))
                 pf_kwargs = jax.device_put(up)       # one call for them all
                 if not sampled:     # greedy: neither is read, nor sent again
                     if g not in self._zero_rows:
@@ -1884,43 +1327,22 @@ class ServingEngine:
                             dict(temp=temps, key=keys))
                     pf_kwargs.update(self._zero_rows[g])
                 at = pf_kwargs.pop("at", None)
-                pf_args = (self.params, pf_kwargs.pop("ids"), self.cache.pool)
-            exec_rec = None
-            pf_flops_share = None
-            if mon:
-                # introspection-registry record, BEFORE the dispatch that
-                # donates the pool buffers (once per specialization)
-                key = self._record_serving_program(
-                    ("serving.prefill_shared", g, s_eff, ncp, sampled)
-                    if cached else ("serving.prefill", g, s_pad, sampled),
-                    f"serving.prefill_shared[g{g},s{s_eff},ctx{ncp}]"
-                    if cached else f"serving.prefill[g{g},s{s_pad}]",
-                    pf, pf_args, pf_kwargs, donated=(2,))
-                from ..monitor import exectime as _exectime
-                exec_rec = _exectime.maybe_sample(key, feed_last=False)
-                # modeled-FLOPs attribution: the registered program's
-                # cost-analysis count split across the real requests that
-                # shared this dispatch (dummy pad rows attribute nowhere)
-                pf_flops = self._program_flops(key)
-                if pf_flops:
-                    pf_flops_share = pf_flops / len(group)
+                pf_args = (self.params, pf_kwargs.pop("ids"), cache.pool)
+            run = self._acct.dispatching(spec_key, pf, pf_args, pf_kwargs,
+                                         (2,), n)
             with _trace.span("serving.prefill.dispatch"), \
                     self._first_call(pf):
-                self.cache.pool, tok_a = pf(*pf_args, **pf_kwargs)
+                cache.pool, tok_a = pf(*pf_args, **pf_kwargs)
             # the slots are taken now, with all that no token decides
             for j, (r, slot) in enumerate(zip(group, slots)):
-                self.cache.alloc.advance(r.rid, int(slen[j]) + cached)
+                tail = int(slen[j])
+                alloc.advance(rids[j], tail + cached)
                 slot.gen = 1
                 slot.done = slot.gen >= r.max_new_tokens
                 self.slots[free[j]] = slot
-                self.stats.admitted += 1
-                self.stats.tokens_generated += 1
-                self.stats.tokens_prefilled += int(slen[j])
-                _monitor.inc("serving.requests.admitted")
-                # the prefill-sampled first token counts here so the
-                # counter agrees with stats.tokens_generated
-                _monitor.inc("serving.tokens.generated")
-                _monitor.inc("serving.tokens.prefilled", int(slen[j]))
+                stats.admitted += 1
+                stats.tokens_generated += 1
+                stats.tokens_prefilled += tail
             self._state_dirty = self._bt_dirty = True
 
             def first_tokens():
@@ -1929,50 +1351,13 @@ class ServingEngine:
                     # ends (and TTFT is stamped) when the first token actually
                     # EXISTS on the host, not when the dispatch returned
                     toks = np.asarray(tok_a)
-                if exec_rec is not None:
-                    # the download above already synchronized: rec(None) adds
-                    # ZERO extra block_until_ready calls at this seam
-                    exec_rec(None)
-                t_first = None
-                if mon:
-                    # TTFT is NOT observed here: a preemption would discard
-                    # this run's tokens and re-prefill, double-sampling the
-                    # histogram with a first token the client never saw. The
-                    # slot carries t_first to _retire, which observes once per
-                    # completed request. The lifecycle instant still marks
-                    # every prefill (preempted runs included) in the trace.
-                    t_first = time.perf_counter()
-                    for r in group:
-                        _trace.instant("serving.first_token", rid=r.rid)
-                        # pure host bookkeeping AFTER the np.asarray download
-                        # above already synchronized: zero added device syncs
-                        _forensics.note(r.rid, "first_token", t=t_first)
+                self._acct.downloaded(run, kv_sample=False)
+                self._acct.first_tokens(run, group, s_eff, cached)
                 with _trace.span("serving.prefill.emit"):
                     for j, (r, slot) in enumerate(zip(group, slots)):
                         tok = int(toks[j])
                         slot.tokens.append(tok)
                         slot.pending = tok
-                        slot.t_first = slot.t_last = t_first
-                        if mon:
-                            slot.cost = getattr(r, "_cost", None)
-                            # page-seconds integrate from admission (pages were
-                            # allocated in _admit) at chunk-edge resolution
-                            slot.t_tick = t_admit
-                            slot.steps0 = self.stats.decode_steps
-                            if slot.cost is not None:
-                                slot.cost.prefill_tokens += int(slen[j])
-                                if cached:
-                                    slot.cost.prefix_cached_tokens += cached
-                                    if pf_flops_share:
-                                        # modeled: the tail program's per-
-                                        # padded-token cost scaled by the
-                                        # tokens the cache served — what a
-                                        # full prefill would have added, to
-                                        # first order
-                                        slot.cost.prefill_flops_saved += (
-                                            pf_flops_share / s_eff * cached)
-                                if pf_flops_share:
-                                    slot.cost.model_flops += pf_flops_share
                         slot.done = slot.done or tok == r.eos_token_id
 
             self._unfetched.append((first_tokens, tok_a, at))
@@ -2063,18 +1448,14 @@ class ServingEngine:
                 for idx in range(self.num_slots):
                     if self.slots[idx] is not None \
                             and self.slots[idx].done:
-                        self._retire(idx)
+                        self._finish(self.slots[idx].req, idx, "completed")
             with _trace.span("serving.step.compact"):
                 self._compact()
             with _trace.span("serving.step.admit"):
                 self._admit()
-            _monitor.set_gauge("serving.queue.depth", len(self.queue),
-                               doc="requests waiting for admission")
             in_use = self.cache.alloc.used_pages
             self.stats.peak_pages_in_use = max(
                 self.stats.peak_pages_in_use, in_use)
-            _monitor.set_gauge("serving.pages.in_use", in_use,
-                               doc="KV pages currently allocated")
             if self._recurrent:
                 st, alloc = self.stats, self.cache.alloc
                 st.state_rows_in_use = alloc.used_rows
@@ -2084,18 +1465,7 @@ class ServingEngine:
 
             live_idx = [i for i, s in enumerate(self.slots)
                         if s is not None and not s.done]
-            if _monitor.enabled():
-                # autoscale feed (monitor/slo.py): one host tick per
-                # scheduling step — queue depth, live slots, page slack.
-                # The gauges themselves are recomputed at scrape time.
-                _slo.note_sched_tick(
-                    len(self.queue), len(live_idx), self.num_slots,
-                    self.cache.alloc.free_pages / self.cache.num_pages
-                    if self.cache.num_pages else 0.0)
-            if self._frame_pub is not None:
-                # federation frame on the same host tick (rate-limited
-                # inside; pure host state — zero device syncs)
-                self._frame_pub.maybe_publish(self)
+            self._acct.tick(len(live_idx), in_use)
             if not live_idx:
                 self._first_tokens()
                 return bool(self.queue) or any(
@@ -2191,33 +1561,16 @@ class ServingEngine:
             else:
                 keys = self._zero_keys[C]  # greedy: keys are never read
 
-        d = self._dev
-        ck = self._chunk_fns[(C, self._sampled)]
-        ck_args = (self.params, self.cache.pool, d["bt"], d.get("rows"),
+        d, cache, sampled = self._dev, self.cache, self._sampled
+        ck = self._chunk_fns[(C, sampled)]
+        ck_args = (self.params, cache.pool, d["bt"], d.get("rows"),
                    d["tokens"], d["kv_len"], d["done"], d["gen"], keys,
                    d["temps"], d["max_new"], d["eos"])
-        exec_rec = None
-        ck_flops_share = None
-        if _monitor.enabled():
-            key = self._record_serving_program(
-                ("serving.decode_chunk", C, self._sampled),
-                f"serving.decode_chunk[c{C}"
-                f"{',sampled' if self._sampled else ''}]",
-                ck, ck_args, None, donated=(1,))
-            from ..monitor import exectime as _exectime
-            exec_rec = _exectime.maybe_sample(key, feed_last=False)
-            # modeled-FLOPs attribution: the chunk program's registered
-            # cost-analysis count split across the live slots sharing
-            # this dispatch (done/empty slots ride along for free in
-            # the static grid; the work exists because of the live
-            # ones). None/0 when the backend never reported — skipped,
-            # not fabricated.
-            ck_flops = self._program_flops(key)
-            if ck_flops:
-                ck_flops_share = ck_flops / len(live_idx)
+        run = self._acct.dispatching(("serving.decode_chunk", C, sampled),
+                                     ck, ck_args, None, (1,), len(live_idx))
         with _trace.span("serving.decode_chunk.dispatch"), \
                 self._first_call(ck):
-            self.cache.pool, tok, kvl, done_a, gen_a, emitted = ck(*ck_args)
+            cache.pool, tok, kvl, done_a, gen_a, emitted = ck(*ck_args)
         self._dev.update(tokens=tok, kv_len=kvl, done=done_a, gen=gen_a)
         self._first_tokens()     # the prefills are done before the chunk is
         with _trace.span("serving.decode_chunk.fetch"):
@@ -2225,56 +1578,48 @@ class ServingEngine:
             # is derivable from the emitted grid (-1 = slot was done at
             # that step; a write and a sample happen exactly on non -1
             # steps). The download syncs, so the span's end — and the
-            # t_chunk stamp below — is when the tokens reached the host.
+            # accounting's stamp in ``downloaded`` — is when the tokens
+            # reached the host.
             emitted = np.asarray(emitted)                # [C, B]
-        if exec_rec is not None:
-            # the emitted-grid download already synchronized this
-            # chunk: rec(None) adds zero block_until_ready calls
-            exec_rec(None)
-        if _monitor.enabled():
-            self._maybe_sample_kv_absmax()
-        t_chunk = time.perf_counter() if _monitor.enabled() else None
+        self._acct.downloaded(run)
         with _trace.span("serving.decode_chunk.emit"):
-            new_tokens = 0
+            counts = []
             cols = emitted.T.tolist()        # a slot's steps, a row each
             whole = bool((emitted >= 0).all())
+            advance = cache.alloc.advance
             for i in live_idx:
                 s = self.slots[i]
+                req, eos = s.req, s.req.eos_token_id
                 toks = cols[i] if whole else [t for t in cols[i] if t >= 0]
-                if toks:
+                n = len(toks)
+                counts.append(n)
+                if n:
                     s.tokens.extend(toks)
-                    new_tokens += len(toks)
-                    self.cache.alloc.advance(s.req.rid, len(toks))
-                    s.kv_len += len(toks)
-                    s.gen += len(toks)
+                    advance(req.rid, n)
+                    s.kv_len += n
+                    s.gen += n
                     s.pending = toks[-1]
-                    s.t_last = t_chunk if t_chunk is not None else s.t_last
-                if t_chunk is not None and s.cost is not None:
-                    # cost attribution at the chunk edge the emitted-grid
-                    # download above already synchronized: pure host reads
-                    # (allocator page counts, the cached program FLOPs) —
-                    # zero added device synchronizations at any rate
-                    if s.t_tick is not None:
-                        s.cost.page_seconds += (
-                            self.cache.alloc.page_count(s.req.rid)
-                            * (t_chunk - s.t_tick))
-                    s.t_tick = t_chunk
-                    s.cost.slot_steps += C
-                    s.cost.decode_tokens += len(toks)
-                    if ck_flops_share:
-                        s.cost.model_flops += ck_flops_share
-                s.done = s.gen >= s.req.max_new_tokens or (
-                    s.req.eos_token_id is not None and bool(toks)
-                    and toks[-1] == s.req.eos_token_id)
+                s.done = s.gen >= req.max_new_tokens or (
+                    eos is not None and n > 0 and toks[-1] == eos)
+        self._chunk_done(run, live_idx, C, counts)
+        return True
+
+    def _chunk_done(self, run, live_idx: List[int], C: int, counts,
+                    accepted=None):
+        """A chunk's (or a verify window's) ``C`` steps into the stats,
+        and its one hand-over to the accounting, outside the per-slot
+        loop: the live slots, the chunk length, each slot's tokens."""
+        new_tokens = sum(counts)
         self.stats.decode_steps += C
         self.stats.tokens_generated += new_tokens
         self.stats.tokens_decoded += new_tokens
         self.stats._occ_steps += C * self.num_slots
-        occ = self.stats.occupancy()
-        _monitor.set_gauge("serving.batch.occupancy", round(occ, 4),
-                           doc="generated tokens / (decode steps x slots)")
-        _monitor.inc("serving.tokens.generated", new_tokens)
-        return True
+        if accepted is not None:
+            self.stats.spec_rounds += len(live_idx)
+            self.stats.spec_drafted += (C - 1) * len(live_idx)
+            self.stats.spec_accepted += sum(accepted)
+        self._acct.chunk_done(run, [self.slots[i] for i in live_idx], C,
+                              counts, accepted)
 
     def _draft_for(self, s: "_Slot", C: int) -> np.ndarray:
         """Draft a C-token verify window for one sequence: position 0
@@ -2322,9 +1667,9 @@ class ServingEngine:
         by sequence length and overwritten by later commits."""
         self._first_tokens()           # a draft starts at the pending token
         with _trace.span("serving.spec_chunk.build"):
-            B = self.num_slots
+            B, dev = self.num_slots, self._dev
             if self._bt_dirty:
-                self._dev["bt"] = jnp.asarray(self._block_tables(live_idx))
+                dev["bt"] = jnp.asarray(self._block_tables(live_idx))
                 self._bt_dirty = False
             drafts = np.zeros((B, C), np.int32)
             kv_len = np.zeros(B, np.int32)
@@ -2334,149 +1679,47 @@ class ServingEngine:
                 drafts[i] = self._draft_for(s, C)
                 kv_len[i] = s.kv_len
                 live_m[i] = True
-            vf = self._spec_fn(C)
-            vf_args = (self.params, self.cache.pool, self._dev["bt"],
+            vf, cache = self._spec_fn(C), self.cache
+            vf_args = (self.params, cache.pool, dev["bt"],
                        jnp.asarray(drafts), jnp.asarray(kv_len),
                        jnp.asarray(live_m))
-        exec_rec = None
-        vf_flops_share = None
-        if _monitor.enabled():
-            key = self._record_serving_program(
-                ("serving.spec_chunk", C),
-                f"serving.spec_chunk[c{C}]", vf, vf_args, None,
-                donated=(1,))
-            from ..monitor import exectime as _exectime
-            exec_rec = _exectime.maybe_sample(key, feed_last=False)
-            vf_flops = self._program_flops(key)
-            if vf_flops:
-                vf_flops_share = vf_flops / len(live_idx)
+        run = self._acct.dispatching(("serving.spec_chunk", C), vf, vf_args,
+                                     None, (1,), len(live_idx))
         with _trace.span("serving.spec_chunk.dispatch"), \
                 self._first_call(vf):
-            self.cache.pool, preds_a = vf(*vf_args)
+            cache.pool, preds_a = vf(*vf_args)
         with _trace.span("serving.spec_chunk.fetch"):
             preds = np.asarray(preds_a)                  # [B, C]
-        if exec_rec is not None:
-            exec_rec(None)
-        if _monitor.enabled():
-            self._maybe_sample_kv_absmax()
-        t_chunk = time.perf_counter() if _monitor.enabled() else None
+        self._acct.downloaded(run)
         with _trace.span("serving.spec_chunk.emit"):
-            new_tokens = 0
-            accepted_total = 0
+            counts, accepted = [], []
+            advance = cache.alloc.advance
             for i in live_idx:
                 s = self.slots[i]
+                req = s.req
                 dr = drafts[i]
                 col = preds[i]
                 a = 0
                 while a < C - 1 and dr[a + 1] == col[a]:
                     a += 1
                 emitted = [int(t) for t in col[:a + 1]]
+                n = a + 1
                 s.tokens.extend(emitted)
-                new_tokens += len(emitted)
-                accepted_total += a
-                self.cache.alloc.advance(s.req.rid, len(emitted))
-                s.kv_len += len(emitted)
-                s.gen += len(emitted)
+                counts.append(n)
+                accepted.append(a)
+                advance(req.rid, n)
+                s.kv_len += n
+                s.gen += n
                 s.pending = emitted[-1]
-                s.t_last = t_chunk if t_chunk is not None else s.t_last
-                if t_chunk is not None and s.cost is not None:
-                    if s.t_tick is not None:
-                        s.cost.page_seconds += (
-                            self.cache.alloc.page_count(s.req.rid)
-                            * (t_chunk - s.t_tick))
-                    s.t_tick = t_chunk
-                    s.cost.slot_steps += C
-                    s.cost.decode_tokens += len(emitted)
-                    if vf_flops_share:
-                        s.cost.model_flops += vf_flops_share
-                if t_chunk is not None:
-                    # aggregate fold, no event append: spec rounds are
-                    # per-chunk-rate and would flood the bounded timeline
-                    _forensics.note_spec(s.req.rid, C - 1, a)
                 # turbo preconditions rule out EOS; only the length bound
                 # can finish a sequence here
-                s.done = s.gen >= s.req.max_new_tokens
-        self.stats.decode_steps += C
-        self.stats.tokens_generated += new_tokens
-        self.stats.tokens_decoded += new_tokens
-        self.stats._occ_steps += C * self.num_slots
-        self.stats.spec_rounds += len(live_idx)
-        self.stats.spec_drafted += (C - 1) * len(live_idx)
-        self.stats.spec_accepted += accepted_total
-        occ = self.stats.occupancy()
-        _monitor.set_gauge("serving.batch.occupancy", round(occ, 4),
-                           doc="generated tokens / (decode steps x slots)")
-        _monitor.inc("serving.tokens.generated", new_tokens)
-        _monitor.inc("serving.spec.rounds", len(live_idx),
-                     doc="per-sequence speculative verify rounds")
-        _monitor.inc("serving.spec.drafted", (C - 1) * len(live_idx),
-                     doc="n-gram draft tokens proposed for verification")
-        _monitor.inc("serving.spec.accepted", accepted_total,
-                     doc="draft tokens confirmed by the greedy verify")
+                s.done = s.gen >= req.max_new_tokens
+        self._chunk_done(run, live_idx, C, counts, accepted)
         # the device-side sequential slot state is stale after a spec
         # round (tokens/kv_len/gen advanced on the host): rebuild it
         # before the next sequential chunk
         self._state_dirty = True
         return True
-
-    def _maybe_sample_kv_absmax(self):
-        """KV-page absmax distribution feed (numerics plane): every
-        1-in-N chunks (``PADDLE_TPU_KV_SAMPLE``; 0 disables) compute
-        per-layer per-page max|K| / max|V| over the pool, keep only
-        the pages the allocator holds live (free pages are zeros that
-        would drown the distribution), and record them. Runs right
-        after the chunk's token download — the device is idle, so the
-        small [L, P] compute + transfer rides the existing seam with
-        zero extra synchronizations of in-flight work."""
-        from ..monitor import numerics as _numerics
-        rate = _numerics.kv_sample_rate()
-        if rate <= 0:
-            return
-        self._kv_chunks += 1
-        if self._kv_chunks < rate:
-            return
-        self._kv_chunks = 0
-        in_use = np.flatnonzero(self.cache.alloc._ref > 0)
-        if in_use.size == 0:
-            return
-        if self._kv_absmax_fn is None:
-            if self._kv_quant:
-                # quantized pool: codes [L, P, kv, page, hd] + scales
-                # [L, P, kv]. absmax = max|code|·scale; also surface the
-                # quantizer's own health — the scale magnitudes and the
-                # fraction of codes pinned at the clip rail (±127)
-                def _q_absmax(k, v):
-                    def one(leaf):
-                        am = jnp.max(jnp.abs(leaf["q"]), axis=(3, 4))
-                        return jnp.max(am.astype(jnp.float32)
-                                       * leaf["s"], axis=2)
-                    clip = (
-                        jnp.mean((jnp.abs(k["q"]) == 127),
-                                 axis=(0, 2, 3, 4)).astype(jnp.float32)
-                        + jnp.mean((jnp.abs(v["q"]) == 127),
-                                   axis=(0, 2, 3, 4)).astype(jnp.float32)
-                    ) * 0.5                               # [P]
-                    scales = jnp.maximum(jnp.max(k["s"], axis=2),
-                                         jnp.max(v["s"], axis=2))
-                    return one(k), one(v), scales, clip
-                self._kv_absmax_fn = jax.jit(_q_absmax)
-            else:
-                # pool layout [L, P, kv, page, hd] -> per-layer per-page
-                self._kv_absmax_fn = jax.jit(
-                    lambda k, v: (
-                        jnp.max(jnp.abs(k), axis=(2, 3, 4)
-                                ).astype(jnp.float32),
-                        jnp.max(jnp.abs(v), axis=(2, 3, 4)
-                                ).astype(jnp.float32)))
-        out = self._kv_absmax_fn(self.cache.pool["k"],
-                                 self.cache.pool["v"])
-        km = np.asarray(out[0])[:, in_use]
-        vm = np.asarray(out[1])[:, in_use]
-        _numerics.record_kv_absmax(km, vm)
-        if self._kv_quant:
-            scales = np.asarray(out[2])[:, in_use]
-            clip = float(np.mean(np.asarray(out[3])[in_use]))
-            _numerics.record_kv_quant(scales, clip)
 
     def run(self, requests=None, max_steps: int = 1_000_000
             ) -> Dict[int, RequestOutput]:

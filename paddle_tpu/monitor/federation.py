@@ -34,9 +34,8 @@ seams that already exist:
 
 - **Surfaces.** ``/fleet/serving`` on ``monitor/server.py`` (frames +
   federated verdict + attribution), ``slo.fleet.*`` gauges plus
-  ``{replica="..."}``-labeled exposition through the PR 7 escaping, a
-  guarded ``federation`` block in ``trace.flight_payload``, and
-  ``bench.py extra.metrics.federation``.
+  ``{replica="..."}``-labeled exposition through the PR 7 escaping, and
+  a guarded ``federation`` block in ``trace.flight_payload``.
 
 Actuation lives in ``fleet/elastic.py`` behind
 ``FLAGS_serving_fleet_burn_scaling`` (default OFF — flags-off

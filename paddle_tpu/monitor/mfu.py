@@ -7,8 +7,9 @@ sustains. Two inputs:
 - **Program FLOPs**: XLA's own ``cost_analysis()`` of the compiled
   program — the MEASURED flop count of one step, not the 6ND
   estimate (which misses remat recompute, attention, and fused-loss
-  flops; bench.py still reports 6ND-based MFU alongside for
-  comparability with the literature).
+  flops). On the TPU the analysis answers for none of the repo's
+  programs, so the benchmark counts FLOPs from shapes (PERF.md
+  section 3, ``prog.mfu.*``).
 - **Peak FLOP/s**: a per-backend table (bf16 peak per chip by TPU
   generation), env-overridable with ``PADDLE_TPU_PEAK_FLOPS`` — which
   is also how the CPU smoke path gets a meaningful denominator.
@@ -19,8 +20,6 @@ Capture seams:
   cache miss (monitor-gated), accumulating ``jit.program.flops`` so a
   snapshot shows the total analyzed FLOPs footprint of the process's
   compiled programs and ``jit.program.last_flops`` the newest one.
-- ``bench.py`` uses :func:`lowered_flops` on its own jitted train step
-  and reports ``extra.metrics.mfu``.
 
 ``lowered_flops`` costs one re-trace + lowering (NO XLA compile:
 ``jax.stages.Lowered.cost_analysis`` runs the HLO-level analyzer), so

@@ -37,7 +37,7 @@ Three consumers feed it:
 
 Served at ``/numerics`` (``monitor/server.py``), embedded in the
 flight record (``trace.flight_payload``), exported as ``numerics.*``
-gauges, condensed into ``bench.py extra.metrics.numerics``.
+gauges.
 
 Gating: every record path is one cached ``FLAGS_enable_monitor``
 branch when the monitor is off — nothing registers, every store stays

@@ -10,7 +10,7 @@ number that composes with the packing efficiency of
 ``io/packing.py`` (tokens already exclude padding there) and against
 which MFU (``monitor/mfu.py``) is the FLOPs-side twin.
 
-Usage (the hapi fit loop and bench.py both ride this)::
+Usage (the hapi fit loop rides this)::
 
     st = monitor.StepTimer("train")
     for batch in st.iter_data(loader):        # data-wait timed per next()

@@ -6,7 +6,7 @@ The observability doc's "what is instrumented" tables are the contract
 operators build dashboards against; a metric added in code but not in
 the doc is invisible drift. This script:
 
-1. scans ``paddle_tpu/`` (plus ``bench.py``) for string-literal metric
+1. scans ``paddle_tpu/`` for string-literal metric
    names passed to the registration/observation calls
    (``inc/observe/set_gauge/counter/gauge/histogram/timed`` and the
    latency helper) — f-string templated names are skipped (they are
@@ -49,10 +49,10 @@ _DOC_TOKEN_RE = re.compile(r"`([a-zA-Z0-9_.<>|{}-]+)`")
 
 
 def registered_names(root: str = None) -> set:
-    """Literal metric names registered under paddle_tpu/ + bench.py."""
+    """Literal metric names registered under paddle_tpu/."""
     root = root or REPO
     names = set()
-    files = [os.path.join(root, "bench.py")]
+    files = []
     for dirpath, dirnames, filenames in os.walk(
             os.path.join(root, "paddle_tpu")):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]

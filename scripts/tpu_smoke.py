@@ -1614,7 +1614,7 @@ def main():
         # varlen (segment-kernel) blocks at the packed-training rung's
         # shape: the rung's packed row count is a deterministic function
         # of the shared heavy-tailed trace (io.packing), so the sweep
-        # here lands on exactly the key bench.py will look up
+        # here lands on exactly the key a packed training run looks up
         from paddle_tpu.io import packing as pk
         lens = pk.heavy_tailed_lengths(2048, 24, seed=7)
         pb = pk.pack_documents(

@@ -495,7 +495,7 @@ class TestOpRegistry:
 
 
 class TestAdviceR3Fixes:
-    """Regressions for the round-3 advisor findings (ADVICE.md r3)."""
+    """Regressions for the round-3 advisor findings."""
 
     def test_worker_seed_differs_across_epochs(self):
         # WorkerInfo.seed must be base_seed + wid with a fresh base per

@@ -109,7 +109,7 @@ class TestFlashBlocks:
     def test_env_path_resolves_after_construction(self, tmp_path,
                                                   monkeypatch):
         # The module-level cache is built at import time, BEFORE the
-        # harness (bench.py) exports PADDLE_TPU_AUTOTUNE_CACHE. The path
+        # harness exports PADDLE_TPU_AUTOTUNE_CACHE. The path
         # must resolve lazily or the tuned repo cache is silently
         # ignored (the round-5 on-chip bench ran default blocks this
         # way).

@@ -653,89 +653,3 @@ class TestEngineExec:
         # slot — a decode-chunk sample between two train steps would
         # otherwise be misattributed as that train step's exec time
         assert exectime.take_last_sample_ms() is None
-
-
-# ---------------------------------------------------------------------------
-# bench guard: lower-is-better exec rungs
-# ---------------------------------------------------------------------------
-
-def _load_guard():
-    import importlib.util
-    path = os.path.join(REPO, "scripts", "check_bench_regression.py")
-    spec = importlib.util.spec_from_file_location(
-        "check_bench_regression_exec", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_blob(value, exec_block=None):
-    rec = {"metric": "llama_train_tokens_per_sec_per_chip",
-           "value": value, "unit": "tokens/s"}
-    if exec_block is not None:
-        rec["extra"] = {"metrics": {"exec": exec_block}}
-    return {"n": 5, "rc": 0, "tail": json.dumps(rec) + "\n",
-            "parsed": rec}
-
-
-class TestExecBenchGuard:
-    def _write(self, root, rnd, blob):
-        with open(os.path.join(root, f"BENCH_r{rnd:02d}.json"),
-                  "w") as f:
-            json.dump(blob, f)
-
-    def test_absence_on_old_files_skipped_not_zero_floored(self,
-                                                           tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        # old rounds predate the exec block entirely
-        self._write(root, 1, _bench_blob(1000.0))
-        self._write(root, 2, _bench_blob(1010.0))
-        self._write(root, 3, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 120.0}}))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)     # no prior ceiling -> no guard
-
-    def test_exec_slowdown_beyond_tolerance_fails(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 100.0}}))
-        self._write(root, 2, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 130.0}}))
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("headline_exec_ms_p50" in l and "REGRESSION" in l
-                   for l in lines)
-
-    def test_exec_within_tolerance_passes(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 100.0}}))
-        self._write(root, 2, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 110.0}}))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-
-    def test_exec_improvement_passes_and_newest_absence_reported(
-            self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 100.0}}))
-        self._write(root, 2, _bench_blob(
-            1000.0, exec_block={"headline": {"p50_ms": 60.0}}))
-        ok, _ = guard.check(root)
-        assert ok
-        # newest run dropped the block: reported, not a failure
-        self._write(root, 3, _bench_blob(1000.0))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-        assert any("headline_exec_ms_p50" in l and "absent" in l
-                   for l in lines)
-
-    def test_checked_in_trajectory_still_green(self):
-        guard = _load_guard()
-        ok, lines = guard.check(REPO)
-        assert ok, "\n".join(lines)

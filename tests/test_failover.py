@@ -372,7 +372,7 @@ class TestEngineJournalSeam:
         eng = _mk_engine()                      # failover defaults off
         assert eng._failover is False
         assert eng.attach_journal("rB", str(tmp_path)) is None
-        assert eng._journal is None
+        assert eng._acct.journal is None
 
     def test_resubmission_resets_state_and_pins_tokens(self, tmp_path):
         # satellite contract: a Request object re-admitted after a
@@ -391,17 +391,16 @@ class TestEngineJournalSeam:
         _drain(a)
         first = list(a.outputs[1].tokens)
         # simulate the state a monitored/preempted run leaves behind
-        # (the timing anchors are only stamped with the monitor on)
-        req._t0 = 123.0
-        req._t_enqueue = 124.0
-        req._cost = object()
+        # (the accounting's record, timing anchors and cost, exists
+        # only with the monitor on: inference/accounting.py keeps it
+        # on the request as ``_acct``)
+        req._acct = object()
         req._t_deadline = 125.0
         req._preempt_count = 2
 
         b = _mk_engine(failover=True)
         b.submit(req)                           # re-admission resets
-        assert req._t0 is None and req._cost is None
-        assert req._t_enqueue is None and req._t_deadline is None
+        assert req._acct is None and req._t_deadline is None
         assert req._preempt_count == 0
         np.testing.assert_array_equal(np.asarray(req.key), key0)
         _drain(b)

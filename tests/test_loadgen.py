@@ -1,14 +1,12 @@
-"""Loadgen harness: deterministic trace generation, open-loop replay,
-SLO scorecard, and the guarded bench rungs (paddle_tpu/loadgen/).
+"""Loadgen harness: deterministic trace generation, open-loop replay
+and the SLO scorecard (paddle_tpu/loadgen/).
 
 The determinism contract under test: same seed ⇒ byte-identical
 serialized trace AND identical terminal-state/token counts across two
 replays on fresh engines (the scorecard's ``deterministic`` block is
 diffed wholesale); wall-clock data stays quarantined in ``timing``.
 """
-import importlib.util
 import json
-import os
 import urllib.error
 import urllib.request
 
@@ -23,8 +21,6 @@ from paddle_tpu.loadgen import (ArrivalTrace, Episode, TenantSpec,
                                 replay_fleet, replay_trace)
 from paddle_tpu.loadgen import scorecard as sc
 from paddle_tpu.loadgen.traces import TRACE_VERSION
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the draw sequence the packed-training bench rung and the smoke
 # pre-tuning were swept under (autotune cache keys depend on it) —
@@ -532,145 +528,3 @@ class TestFleetReplay:
         with pytest.raises(ValueError, match="heartbeat"):
             replay_fleet(lambda name: _mk_engine(), _small_trace(),
                          episodes=[Episode("kill", at_s=0.1)])
-
-
-# ---------------------------------------------------------------------------
-# bench-guard wiring for the serving_trace_replay rung
-# ---------------------------------------------------------------------------
-
-def _load_guard():
-    path = os.path.join(REPO, "scripts", "check_bench_regression.py")
-    spec = importlib.util.spec_from_file_location(
-        "check_bench_regression_loadgen", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_blob(value, extra=None):
-    rec = {"metric": "llama_train_tokens_per_sec_per_chip",
-           "value": value, "unit": "tokens/s"}
-    if extra:
-        rec["extra"] = extra
-    return {"n": 5, "cmd": "python bench.py", "rc": 0,
-            "tail": json.dumps(rec) + "\n", "parsed": rec}
-
-
-def _replay_extra(goodput, ttft_p99):
-    return {"serving_trace_replay": {
-        "goodput_tokens_per_sec": goodput, "ttft_p99_ms": ttft_p99}}
-
-
-def _failover_extra(lost, recovery_p99):
-    return {"serving_failover_replay": {
-        "lost": lost, "recovery_s_p99": recovery_p99}}
-
-
-class TestReplayBenchGuard:
-    def _write(self, root, rnd, blob):
-        with open(os.path.join(root, f"BENCH_r{rnd:02d}.json"),
-                  "w") as f:
-            json.dump(blob, f)
-
-    def test_rungs_in_allowlists(self):
-        guard = _load_guard()
-        assert guard.ALLOWLIST[
-            "serving_replay_goodput_tokens_per_sec"] \
-            == "extra.serving_trace_replay.goodput_tokens_per_sec"
-        assert guard.ALLOWLIST_LOWER["serving_replay_ttft_ms_p99"] \
-            == "extra.serving_trace_replay.ttft_p99_ms"
-
-    def test_goodput_regression_fails(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0,
-                                         _replay_extra(300.0, 50.0)))
-        self._write(root, 2, _bench_blob(1000.0,
-                                         _replay_extra(200.0, 50.0)))
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("serving_replay_goodput" in l and "REGRESSION" in l
-                   for l in lines)
-
-    def test_goodput_noise_within_tolerance_passes(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0,
-                                         _replay_extra(300.0, 50.0)))
-        self._write(root, 2, _bench_blob(1000.0,
-                                         _replay_extra(270.0, 52.0)))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-
-    def test_ttft_p99_increase_fails_lower_is_better(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0,
-                                         _replay_extra(300.0, 50.0)))
-        self._write(root, 2, _bench_blob(1000.0,
-                                         _replay_extra(300.0, 80.0)))
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("serving_replay_ttft" in l and "REGRESSION" in l
-                   for l in lines)
-
-    def test_absence_on_old_rounds_is_skip_not_floor(self, tmp_path):
-        # rounds predating the rung contribute no floor/ceiling, and a
-        # newest round without it reports absence, never failure
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0))
-        self._write(root, 2, _bench_blob(1000.0,
-                                         _replay_extra(300.0, 50.0)))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-        self._write(root, 3, _bench_blob(1000.0))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-        assert any("serving_replay_goodput" in l and "absent" in l
-                   for l in lines)
-
-    def test_failover_rungs_in_allowlists(self):
-        guard = _load_guard()
-        assert guard.ALLOWLIST_LOWER["serving_failover_recovery_s_p99"] \
-            == "extra.serving_failover_replay.recovery_s_p99"
-        assert guard.ALLOWLIST_ZERO["serving_failover_lost"] \
-            == "extra.serving_failover_replay.lost"
-
-    def test_failover_lost_nonzero_fails_even_on_first_run(self,
-                                                           tmp_path):
-        # the invariant has no baseline: one run with a positive lost
-        # count is already a failure (and zero passes)
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0,
-                                         _failover_extra(1, 0.5)))
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("serving_failover_lost" in l and "REGRESSION" in l
-                   for l in lines)
-        self._write(root, 1, _bench_blob(1000.0,
-                                         _failover_extra(0, 0.5)))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-
-    def test_failover_recovery_p99_ceiling(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0,
-                                         _failover_extra(0, 1.0)))
-        self._write(root, 2, _bench_blob(1000.0,
-                                         _failover_extra(0, 2.0)))
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("serving_failover_recovery" in l
-                   and "REGRESSION" in l for l in lines)
-        self._write(root, 2, _bench_blob(1000.0,
-                                         _failover_extra(0, 1.05)))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-
-    def test_checked_in_trajectory_is_green(self):
-        guard = _load_guard()
-        ok, lines = guard.check(REPO)
-        assert ok, "\n".join(lines)

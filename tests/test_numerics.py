@@ -595,7 +595,7 @@ class TestKVPageSampling:
         NM.set_kv_sample_rate(1)
         eng = _run_engine()
         assert NM.kv_snapshot()["samples"] == 0
-        assert eng._kv_absmax_fn is None     # never even built
+        assert eng._acct._kv_absmax_fn is None   # never even built
 
     def test_free_pages_excluded_and_values_plausible(self):
         """Sampled absmax values come from live pages only: all finite

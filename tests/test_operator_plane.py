@@ -1,5 +1,5 @@
 """Operator plane (monitor/server.py + programs.py + memory.py +
-fleet.py, scripts/check_bench_regression.py).
+fleet.py).
 
 The load-bearing contracts:
 
@@ -21,10 +21,7 @@ The load-bearing contracts:
 - **Fleet aggregation**: min/max/sum/per-host views + divergence, the
   same on every rank (2-process launch CLI, slow lane), served from
   rank 0's /metrics?scope=fleet without peers joining the scrape.
-- **Bench guard**: the checked-in BENCH_r*.json trajectory passes;
-  synthetic regressions beyond the noise tolerance fail.
 """
-import importlib.util
 import json
 import math
 import os
@@ -746,116 +743,3 @@ class TestFleetAggregation:
         digests = sorted(l.split()[-1] for l in blob.splitlines()
                          if l.startswith("DIGEST"))
         assert len(digests) == 2 and digests[0] == digests[1]
-
-
-# ---------------------------------------------------------------------------
-# bench-trajectory regression guard
-# ---------------------------------------------------------------------------
-
-def _load_guard():
-    path = os.path.join(REPO, "scripts", "check_bench_regression.py")
-    spec = importlib.util.spec_from_file_location(
-        "check_bench_regression", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_blob(value, extra=None, error=None):
-    rec = {"metric": "llama_train_tokens_per_sec_per_chip",
-           "value": value, "unit": "tokens/s"}
-    if extra:
-        rec["extra"] = extra
-    if error:
-        rec["error"] = error
-    return {"n": 5, "cmd": "python bench.py", "rc": 0,
-            "tail": json.dumps(rec) + "\n", "parsed": rec}
-
-
-class TestBenchRegressionGuard:
-    def test_checked_in_trajectory_is_green(self):
-        """The tier-1 guard itself: the repo's own bench trajectory
-        must pass (this is what keeps future rounds honest)."""
-        guard = _load_guard()
-        ok, lines = guard.check(REPO)
-        assert ok, "\n".join(lines)
-
-    def _write(self, root, rnd, blob):
-        with open(os.path.join(root, f"BENCH_r{rnd:02d}.json"),
-                  "w") as f:
-            json.dump(blob, f)
-
-    def test_regression_beyond_tolerance_fails(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0))
-        self._write(root, 2, _bench_blob(800.0))    # -20% > 15% tol
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("REGRESSION" in l for l in lines)
-
-    def test_noise_within_tolerance_passes(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0))
-        self._write(root, 2, _bench_blob(900.0))    # -10% < 15% tol
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-
-    def test_failed_runs_are_skipped_not_zero(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0))
-        self._write(root, 2, _bench_blob(
-            0.0, error="backend init failed"))
-        self._write(root, 3, _bench_blob(990.0))
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)    # r02 must not read as a 0 floor
-        traj = guard.load_trajectory(root)
-        assert [rnd for rnd, _ in traj] == [1, 3]
-
-    def test_sub_rungs_guarded_via_allowlist(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(
-            1000.0, extra={"decode": {"decode_tokens_per_sec": 500.0}}))
-        self._write(root, 2, _bench_blob(
-            1000.0, extra={"decode": {"decode_tokens_per_sec": 300.0}}))
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("decode_tokens_per_sec" in l and "REGRESSION" in l
-                   for l in lines)
-        # a metric OUTSIDE the allowlist never fails the guard
-        self._write(root, 2, _bench_blob(
-            1000.0, extra={"decode": {"ms_per_token": 99999.0}}))
-        ok, _ = guard.check(root)
-        assert ok
-
-    def test_missing_rung_in_newest_is_not_failure(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(
-            1000.0, extra={"moe": {"tokens_per_sec": 100.0}}))
-        self._write(root, 2, _bench_blob(1005.0))   # moe rung dropped
-        ok, lines = guard.check(root)
-        assert ok, "\n".join(lines)
-        assert any("absent" in l for l in lines)
-
-    def test_published_floor_from_baseline_json(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        with open(os.path.join(root, "BASELINE.json"), "w") as f:
-            json.dump({"published": {
-                "llama_train_tokens_per_sec_per_chip": 2000.0}}, f)
-        self._write(root, 1, _bench_blob(1000.0))   # half the published
-        ok, lines = guard.check(root)
-        assert not ok
-        assert any("REGRESSION" in l for l in lines)
-
-    def test_cli_exit_codes(self, tmp_path):
-        guard = _load_guard()
-        root = str(tmp_path)
-        self._write(root, 1, _bench_blob(1000.0))
-        assert guard.main(["--root", root]) == 0
-        self._write(root, 2, _bench_blob(500.0))
-        assert guard.main(["--root", root]) == 1

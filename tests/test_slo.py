@@ -1,5 +1,5 @@
 """SLO accounting plane (monitor/slo.py + engine cost attribution,
-/slo route, tenant exposition, autoscale signals, bench-guard rungs).
+/slo route, tenant exposition, autoscale signals).
 
 The load-bearing contracts:
 
@@ -623,64 +623,6 @@ class TestSurfaces:
         assert slo.tenants_snapshot()["tenants"] == {}
         assert slo.update_autoscale_gauges() == {"available": False}
         assert slo.tenant_exposition_text() == ""
-
-
-# ---------------------------------------------------------------------------
-# bench-guard lower rungs
-# ---------------------------------------------------------------------------
-
-def _load_guard():
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "check_bench_regression",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))),
-            "scripts", "check_bench_regression.py"))
-    m = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(m)
-    return m
-
-
-class TestBenchGuardSloRungs:
-    def test_slo_rungs_in_lower_allowlist(self):
-        g = _load_guard()
-        assert g.ALLOWLIST_LOWER["serving_ttft_ms_p99"] == \
-            "extra.metrics.slo.ttft_p99_ms"
-        assert g.ALLOWLIST_LOWER["serving_tpot_ms_p99"] == \
-            "extra.metrics.slo.tpot_p99_ms"
-
-    def test_extraction_and_absence_skip(self, tmp_path):
-        g = _load_guard()
-        blob = {"parsed": {"metric": "x", "value": 100.0, "extra": {
-            "metrics": {"slo": {"ttft_p99_ms": 12.5,
-                                "tpot_p99_ms": 3.25}}}}}
-        rungs = g.extract_rungs(blob, g.ALLOWLIST_LOWER)
-        assert rungs["serving_ttft_ms_p99"] == 12.5
-        assert rungs["serving_tpot_ms_p99"] == 3.25
-        # absence on an old blob contributes nothing (skip, not zero)
-        old = {"parsed": {"metric": "x", "value": 100.0, "extra": {}}}
-        assert g.extract_rungs(old, g.ALLOWLIST_LOWER) is None
-        # trajectory: old round without the block + new round with it
-        # -> no ceiling yet, guard passes
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps(old))
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps(blob))
-        ok, lines = g.check(str(tmp_path))
-        assert ok, lines
-        # a later round regressing TTFT beyond tolerance FAILS
-        worse = {"parsed": {"metric": "x", "value": 100.0, "extra": {
-            "metrics": {"slo": {"ttft_p99_ms": 20.0,
-                                "tpot_p99_ms": 3.30}}}}}
-        (tmp_path / "BENCH_r03.json").write_text(json.dumps(worse))
-        ok, lines = g.check(str(tmp_path))
-        assert not ok
-        assert any("serving_ttft_ms_p99" in ln and "REGRESSION" in ln
-                   for ln in lines)
-
-    def test_checked_in_trajectory_still_green(self):
-        g = _load_guard()
-        ok, lines = g.check()
-        assert ok, "\n".join(lines)
 
 
 # ---------------------------------------------------------------------------
