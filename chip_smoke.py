@@ -309,7 +309,8 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args(argv)
 
-    # Blocks come from the tracked autotune_cache.json and are never
+    # Blocks come from the tracked autotune_cache.json or, where it has
+    # no entry, from the shape (flash) or the default, and are never
     # measured here, so what runs is a function of the committed tree.
     os.environ["PADDLE_TPU_AUTOTUNE"] = "cached"
     cache_dir = enable_compile_cache()
@@ -400,8 +401,8 @@ def main(argv=None) -> int:
         require(len(served["buckets"]) <= 2,
                 f"more than two prefill buckets: {served['buckets']}")
 
-    say("autotune (mode 'cached'; source 'default' = no entry in "
-        "autotune_cache.json):")
+    say("autotune (mode 'cached'; source 'shape-rule' or 'default' = no "
+        "entry in autotune_cache.json):")
     for key, used in sorted(autotune.used_blocks().items()):
         say(f"  {key}: {used}")
     print(json.dumps({"ok": True, "device": {

@@ -22,7 +22,9 @@ TPU-native design:
 - Measurement only runs on a real TPU backend (timing interpret-mode
   pallas on CPU is meaningless); elsewhere the defaults return
   immediately. FLAGS use_autotune=False (or env PADDLE_TPU_AUTOTUNE=0)
-  freezes everything at the defaults.
+  freezes everything at the defaults. The dense flash kernels' default
+  is no constant but a rule over the call's shape
+  (tiling.flash_blocks_for), so no tracked file has to foresee a shape.
 - A sweep in which NO candidate compiles raises with the compiler's
   message: a kernel that cannot run at a shape its ``supported()`` gate
   admitted is a bug to fix at the gate, not a default to run on.
@@ -48,14 +50,16 @@ _flags.define_flag("use_autotune", True,
                    "Measure+cache pallas kernel block sizes per shape "
                    "(reference: phi/kernels/autotune).")
 
+# The segment (packed) kernels' default and sweep set. The dense kernels'
+# default is a rule over the shape (tiling.flash_blocks_for) and their sweep
+# set the rule's answer first, so a timing tie keeps it, then CANDIDATES:
+# a measuring run can still beat the rule.
 DEFAULT_BLOCKS = (128, 128)
-# VERDICT-r3 sweep set: {128,256,512} x {128,256}. Ordered with the
-# known-good default first so a timing tie keeps it.
-CANDIDATES = ((128, 128), (256, 128), (128, 256), (256, 256),
-              (512, 128), (512, 256))
-# VMEM working-set bound per candidate (scratch + operand blocks, f32):
-# stay well under the ~16M/core budget so Mosaic never has to spill.
-_VMEM_BUDGET = 12 * 1024 * 1024
+VARLEN_CANDIDATES = ((128, 128), (256, 128), (128, 256), (256, 256),
+                     (512, 128), (512, 256))
+CANDIDATES = VARLEN_CANDIDATES + (
+    (256, 512), (512, 512), (1024, 256), (256, 1024), (1024, 512),
+    (512, 1024), (1024, 1024), (512, 2048))
 
 
 def _cache_path() -> str:
@@ -190,32 +194,29 @@ def _mode() -> str:
     return os.environ.get("PADDLE_TPU_AUTOTUNE", "1")
 
 
-def _vmem_bytes(bq: int, bk: int, d: int) -> int:
-    # fwd: acc[bq,d] + m/l[bq,128] + q[bq,d] + k/v[bk,d] + s/p[bq,bk]
-    # bwd dkv: dk/dv acc[bk,d]*2 + blocks. Take the max-ish superset.
-    return 4 * (bq * d * 2 + bq * 128 * 2 + bk * d * 3 + bq * bk * 2)
+def _dividing(among, sq, sk):
+    """``among`` clamped to the sequences, those that divide them."""
+    out = []
+    for bq, bk in among:
+        c = (min(bq, sq), min(bk, sk))
+        if c not in out and not (sq % c[0] or sk % c[1]
+                                 or c[0] % 8 or c[1] % 8):
+            out.append(c)
+    return out
 
 
 def flash_candidates(bh, sq, sk, d, dtype):
-    """Legal (block_q, block_k) candidates for a flash shape, default
-    first."""
-    from .tiling import flash_specs_legal
+    """Legal (block_q, block_k) candidates for a dense flash shape, the
+    shape rule's answer first."""
+    from .tiling import (FLASH_VMEM_BUDGET, flash_blocks_for,
+                         flash_specs_legal, flash_vmem_bytes)
 
-    out = []
-    for bq, bk in CANDIDATES:
-        bq_, bk_ = min(bq, sq), min(bk, sk)
-        if (bq_, bk_) in out:
-            continue
-        if sq % bq_ or sk % bk_ or bq_ % 8 or bk_ % 8:
-            continue
-        if _vmem_bytes(bq_, bk_, d) > _VMEM_BUDGET:
-            continue
-        if not flash_specs_legal(bh, sq, sk, d, bq_, bk_, dtype):
-            continue
-        out.append((bq_, bk_))
-    if not out:
-        out.append(_default_blocks(sq, sk))
-    return out
+    rule = flash_blocks_for(sq, sk, d, dtype)
+    return [rule] + [
+        c for c in _dividing(CANDIDATES, sq, sk)
+        if c != rule
+        and flash_vmem_bytes(sq, sk, d, *c, dtype) <= FLASH_VMEM_BUDGET
+        and flash_specs_legal(bh, sq, sk, d, *c, dtype)]
 
 
 def _rand(rng, shape, dtype, scale=1.0):
@@ -287,7 +288,7 @@ def _in_trace() -> bool:
 
 
 def _tuned(key, field, default, candidates, measure, make_measure, cache,
-           label=str, warm_start=False):
+           label=str, warm_start=False, default_source="default"):
     """The one tuning policy behind every knob (flash/varlen blocks, CE
     chunk, page size): returns the value to use for ``key`` and records
     it with its ``source`` in ``used_blocks()``.
@@ -299,7 +300,8 @@ def _tuned(key, field, default, candidates, measure, make_measure, cache,
     ``measure`` (``make_measure()`` builds the real one lazily — it
     materialises operands), persist the winner and return it. Failing
     candidates drop out; if ALL fail the last compiler message is
-    raised. ``label`` names a candidate in the persisted timings."""
+    raised. ``label`` names a candidate in the persisted timings,
+    ``default_source`` what ``default`` is in ``used_blocks()``."""
     def stored(value):              # block pairs persist as JSON lists
         return list(value) if isinstance(value, tuple) else value
 
@@ -318,7 +320,7 @@ def _tuned(key, field, default, candidates, measure, make_measure, cache,
         return use(default, "off")
     cache = cache or _CACHE
     if measure is None and mode != "cached" and not _tuning_backend():
-        return cannot_measure("default-not-tpu")
+        return cannot_measure(f"{default_source}-not-tpu")
     hit = cache.get(key)
     _monitor.inc("autotune.cache.hit" if hit else "autotune.cache.miss")
     if hit:
@@ -326,9 +328,9 @@ def _tuned(key, field, default, candidates, measure, make_measure, cache,
         return use(tuple(value) if isinstance(value, list) else value,
                    "cache")
     if mode == "cached":
-        return cannot_measure("default")
+        return cannot_measure(default_source)
     if measure is None and _in_trace():
-        return cannot_measure("default-in-trace")
+        return cannot_measure(f"{default_source}-in-trace")
     cands = candidates()
     if len(cands) == 1:
         cache.put(key, {field: stored(cands[0]), "us": None,
@@ -542,17 +544,15 @@ def paged_page_size(batch, num_heads, kv_heads, head_dim, max_len, dtype,
 # --------------------------------------------------------------------------
 
 def varlen_candidates(b, bh, sq, sk, d, dtype):
-    """Legal (block_q, block_k) candidates for the segment kernels:
-    flash legality plus the segment-array specs (k-side lane rule)."""
-    from .tiling import segment_specs_legal
+    """Legal (block_q, block_k) candidates for the segment kernels,
+    default first: flash legality plus the segment-array specs (k-side
+    lane rule)."""
+    from .tiling import flash_specs_legal, segment_specs_legal
 
-    out = []
-    for bq, bk in flash_candidates(bh, sq, sk, d, dtype):
-        if segment_specs_legal(b, sq, sk, bq, bk):
-            out.append((bq, bk))
-    if not out:
-        out.append(_default_blocks(sq, sk))
-    return out
+    return [c for c in _dividing(VARLEN_CANDIDATES, sq, sk)
+            if flash_specs_legal(bh, sq, sk, d, *c, dtype)
+            and segment_specs_legal(b, sq, sk, *c)] \
+        or [_default_blocks(sq, sk)]
 
 
 def _varlen_measurer(b, sq, sk, h, kvh, d, dtype, causal):
@@ -616,16 +616,19 @@ def varlen_blocks(q_shape, k_shape, dtype, causal,
 def flash_blocks(q_shape, k_shape, dtype, causal,
                  measure: Optional[Callable] = None,
                  cache: Optional[AutotuneCache] = None):
-    """Tuned (block_q, block_k) for a flash call; measures once per shape
-    key and caches (memory + disk). ``measure``/``cache`` are injectable
-    for tests. Returns the defaults without measuring when autotune is
-    off or the backend isn't a real TPU (:func:`_tuned`)."""
+    """(block_q, block_k) for a dense flash call: a cache hit, else a
+    measured sweep where one can run (once per shape key, cached in memory
+    and on disk), else what the shape allows (tiling.flash_blocks_for,
+    source ``shape-rule``: autotune off, "cached" mode, under a trace,
+    not a TPU). ``measure``/``cache`` are injectable for tests."""
+    from .tiling import flash_blocks_for
+
     b, sq, h, d = q_shape
     sk, kvh = k_shape[1], k_shape[2]
     key = (f"flash:{jax.default_backend()}:{jnp.dtype(dtype).name}:"
            f"b{b}h{h}kv{kvh}:q{sq}k{sk}d{d}:c{int(bool(causal))}")
     return _tuned(
-        key, "blocks", _default_blocks(sq, sk),
+        key, "blocks", flash_blocks_for(sq, sk, d, dtype),
         lambda: flash_candidates(b * h, sq, sk, d, dtype), measure,
         lambda: _flash_measurer(b, sq, sk, h, kvh, d, dtype, causal),
-        cache, label=_blocks_label)
+        cache, label=_blocks_label, default_source="shape-rule")

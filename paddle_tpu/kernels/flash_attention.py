@@ -2,13 +2,23 @@
 
 Reference capability: paddle/phi/kernels/gpu/flash_attn_kernel.cu (wrapping
 third_party/flashattn) and nn/functional/flash_attention.py. TPU-native
-design: tiled online-softmax kernels on the MXU following the canonical
-pallas TPU pattern — a (batch*heads, q_blocks, k_blocks) grid whose minor
-axis iterates sequentially per core, carrying running max/denominator in
-VMEM scratch; causal blocks above the diagonal are skipped (predicated),
-GQA queries map to their kv head via the BlockSpec index map, and the
-backward pass recomputes probabilities blockwise from the saved
-log-sum-exp (no S×S materialisation anywhere).
+design: tiled online-softmax kernels on the MXU whose time is set by
+their products, not by their step count. A grid step of the forward and
+dq kernels owns one q block and holds a *span* of K and V resident in
+VMEM — the whole sequence where it fits, and then it is fetched once a
+kv head, because its block index does not change with the q block — and
+walks the span's ``block_k`` sub-blocks in a loop that ends at the
+diagonal: first the sub-blocks every row of the q block sees whole (no
+mask is built for them), then the ones the diagonal crosses. Nothing is
+run, and nothing fetched, for a sub-block wholly above the diagonal. The
+dkv kernel is the same loop turned round: a grid step owns one k block,
+holds a span of q and dO, starts at the diagonal and computes its tiles
+transposed ([block_k, block_q]) so that every product is one the MXU
+does without a transpose. GQA queries map to their kv head via the
+BlockSpec index map, the backward pass recomputes probabilities from the
+saved log-sum-exp (no S×S materialisation anywhere), and the softmax
+scale is folded into q once, in the copy that lays it out for the
+kernels.
 
 Layouts: public API is paddle's [B, S, H, D]; kernels run on [B*H, S, D].
 """
@@ -22,9 +32,81 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiling import flash_blocks_for, flash_span, flash_specs_legal
+
+# the segment kernels' blocks (the dense kernels take theirs from the
+# shape: tiling.flash_blocks_for)
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
+_LANES = 128
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T, the MXU's own form
+
+
+def _cols(x, n):
+    """A lane-replicated [rows, 128] statistic as [rows, n]: whole lane
+    tiles are repeated, which costs no broadcast."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _sub(i, block, count):
+    """Rows (or lanes) of sub-block ``i`` of a resident span."""
+    if count == 1:
+        return pl.ds(0, block)
+    return pl.ds(pl.multiple_of(i * block, block), block)
+
+
+def k_loop_bounds(qi, span0, *, offset, block_q, block_k, count):
+    """Which ``block_k`` sub-blocks of a K/V span that starts at column
+    ``span0`` the q block ``qi`` visits: ``[0, whole)`` are seen whole by
+    every row, ``[whole, end)`` are crossed by the diagonal, the rest is
+    hidden. Bottom-right-aligned causal (sdpa convention): row r sees
+    columns <= r + offset, offset = sk - sq."""
+    first = qi * block_q + offset - span0 + 1   # columns the first row sees
+    last = first + block_q - 1                  # ... and the last row
+    whole = jnp.minimum(jnp.maximum(first, 0) // block_k, count)
+    end = jnp.minimum(pl.cdiv(jnp.maximum(last, 0), block_k), count)
+    return whole, end
+
+
+def q_loop_bounds(ki, span0, *, offset, block_q, block_k, count):
+    """The dkv kernel's turn of :func:`k_loop_bounds`: of a q span that
+    starts at row ``span0``, sub-blocks ``[start, whole)`` are crossed by
+    the diagonal of k block ``ki`` and ``[whole, count)`` see it whole;
+    the ones before ``start`` see none of it."""
+    hidden = ki * block_k - offset - span0      # rows that see no column
+    partly = hidden + block_k - 1               # ... or not every column
+    start = jnp.minimum(jnp.maximum(hidden, 0) // block_q, count)
+    whole = jnp.minimum(pl.cdiv(jnp.maximum(partly, 0), block_q), count)
+    return start, whole
+
+
+def _row_minus_col(shape, q_axis):
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+
+
+def _loop(lo, hi, step):
+    """``step(i)`` for i in [lo, hi); the state lives in scratch refs."""
+    def body(i, carry):
+        step(i)
+        return carry
+    jax.lax.fori_loop(lo, hi, body, 0)
+
+
+def _walk_k(step, qi, span0, *, causal, count, **geometry):
+    """``step(c, crossed)`` for every sub-block of a K/V span that q block
+    ``qi`` sees, the ones it sees whole first."""
+    if causal:
+        whole, end = k_loop_bounds(qi, span0, count=count, **geometry)
+        _loop(0, whole, lambda c: step(c, False))
+        _loop(whole, end, lambda c: step(c, True))
+    else:
+        _loop(0, count, lambda c: step(c, False))
 
 
 # ---------------------------------------------------------------------------
@@ -32,103 +114,106 @@ _NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
-                scale, causal, offset, block_q, block_k, num_k_blocks):
+                causal, offset, block_k):
+    block_q, d = q_ref.shape[1:]
+    span = k_ref.shape[1]
+    count = span // block_k
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    # bottom-right-aligned causal (sdpa convention): row r sees cols
-    # <= r + offset, offset = sk - sq
-    last_ki = jnp.minimum(
-        (qi + 1) * block_q - 1 + offset,
-        (num_k_blocks * block_k) - 1) // block_k \
-        if causal else num_k_blocks - 1
+    kj = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    # causal: whole block above the diagonal contributes nothing
-    run = (ki * block_k <= (qi + 1) * block_q - 1 + offset) \
-        if causal else True
+    q = q_ref[0]                                        # [bq, d], scaled
+    diag0 = kj * span - qi * block_q - offset
+    if causal:
+        row_col = _row_minus_col((block_q, block_k), 0)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                                    # [bq, d]
-        k = k_ref[0]                                    # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + offset >= cols, s, _NEG_INF)
-
-        m_prev = m_s[:, :1]                             # [bq, 1]
-        l_prev = l_s[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)       # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
+    def step(c, crossed):
+        rows = _sub(c, block_k, count)
+        s = jax.lax.dot_general(q, k_ref[0, rows, :], _NT,
+                                preferred_element_type=jnp.float32)
+        if crossed:
+            s = jnp.where(row_col >= diag0 + c * block_k, s, _NEG_INF)
+        m_prev = m_s[:]                                 # [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                          # [bq, bk]
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+        p = jnp.exp(s - _cols(m_new, block_k))          # [bq, bk]
+        l_s[:] = l_s[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc[:] = acc[:] * _cols(alpha, d) + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, rows, :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_s[:] = m_new
 
-    @pl.when(ki == last_ki)
+    _walk_k(step, qi, kj * span, causal=causal, offset=offset,
+            block_q=block_q, block_k=block_k, count=count)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
-        l = l_s[:, :1]
+        l = l_s[:]
         l = jnp.where(l == 0.0, 1.0, l)                 # fully-masked rows
-        o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_s[:, :1] + jnp.log(l)            # [bq, 1]
+        o_ref[0] = (acc[:] / _cols(l, d)).astype(o_ref.dtype)
+        lse_ref[0] = (m_s[:] + jnp.log(l))[:, :1]       # [bq, 1]
 
 
-def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
-    """q: [BH, Sq, D]; k/v: [BKV, Sk, D] with BH = BKV * group."""
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _q_major(q, k, *, causal, block_q, block_k):
+    """Grid and BlockSpecs of the forward and dq kernels: (grid, a q
+    block's rows, its row statistics, a K or V span). A span wholly above
+    a q block's diagonal keeps the index of the last one that is not, so
+    that it is not fetched."""
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     group = bh // bkv
-    nq = pl.cdiv(sq, block_q)
-    nk = pl.cdiv(sk, block_k)
+    span = flash_span(sk, block_k, d, k.dtype)
+    spans = sk // span
+    if causal and spans > 1:
+        def at(i, j):
+            last = ((i + 1) * block_q - 1 + sk - sq) // span
+            return jnp.minimum(j, jnp.clip(last, 0, spans - 1))
+    else:
+        def at(i, j):
+            return j
+    row = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    # LSE rides a trailing singleton lane dim: Mosaic requires the last
+    # two block dims be (8, 128)-divisible OR equal to the array dims —
+    # (block_q, 1) over [bh, sq, 1] satisfies the "equal" arm (a bare
+    # (1, block_q) block over [bh, sq] is illegal and killed BENCH_r02).
+    stat = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    kv = pl.BlockSpec((1, span, d),
+                      lambda b, i, j: (b // group, at(i, j), 0))
+    return (bh, sq // block_q, spans), row, stat, kv
 
-    grid = (bh, nq, nk)
+
+def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
+    """q: [BH, Sq, D], already scaled; k/v: [BKV, Sk, D] with
+    BH = BKV * group."""
+    bh, sq, d = q.shape
+    grid, row, stat, kv = _q_major(q, k, causal=causal, block_q=block_q,
+                                   block_k=block_k)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          offset=sk - sq, block_q=block_q, block_k=block_k,
-                          num_k_blocks=nk),
+        functools.partial(_fwd_kernel, causal=causal,
+                          offset=k.shape[1] - sq, block_k=block_k),
         name="flash_fwd",
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j, g=group: (b // g, j, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j, g=group: (b // g, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            # LSE rides a trailing singleton lane dim: Mosaic requires the
-            # last two block dims be (8, 128)-divisible OR equal to the
-            # array dims — (block_q, 1) over [bh, sq, 1] satisfies the
-            # "equal" arm with zero padding waste (a bare (1, block_q)
-            # block over [bh, sq] is illegal and killed BENCH_r02).
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        in_specs=[row, kv, kv],
+        out_specs=[row, stat],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -139,91 +224,101 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale, causal, offset, block_q, block_k,
-                   num_k_blocks):
+                   dq_acc, *, scale, causal, offset, block_k):
+    block_q = q_ref.shape[1]
+    span = k_ref.shape[1]
+    count = span // block_k
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    last_ki = jnp.minimum(
-        (qi + 1) * block_q - 1 + offset,
-        (num_k_blocks * block_k) - 1) // block_k \
-        if causal else num_k_blocks - 1
+    kj = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = (ki * block_k <= (qi + 1) * block_q - 1 + offset) \
-        if causal else True
+    q = q_ref[0]
+    do = do_ref[0]
+    # the row statistics, lane-replicated once a q block
+    lse = jnp.broadcast_to(lse_ref[0], (block_q, _LANES))
+    delta = jnp.broadcast_to(delta_ref[0], (block_q, _LANES))
+    diag0 = kj * span - qi * block_q - offset
+    if causal:
+        row_col = _row_minus_col((block_q, block_k), 0)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        kk = k_ref[0]
-        s = jax.lax.dot_general(
-            q, kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + offset >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0])                     # lse_ref[0]: [bq, 1]
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
+    def step(c, crossed):
+        rows = _sub(c, block_k, count)
+        kk = k_ref[0, rows, :]
+        s = jax.lax.dot_general(q, kk, _NT,
+                                preferred_element_type=jnp.float32)
+        if crossed:
+            s = jnp.where(row_col >= diag0 + c * block_k, s, _NEG_INF)
+        p = jnp.exp(s - _cols(lse, block_k))
+        dp = jax.lax.dot_general(do, v_ref[0, rows, :], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - _cols(delta, block_k))
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(kk.dtype), kk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == last_ki)
+    _walk_k(step, qi, kj * span, causal=causal, offset=offset,
+            block_q=block_q, block_k=block_k, count=count)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        # q came scaled, so ds is the gradient of the scaled scores
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    offset, block_q, block_k, num_q_blocks):
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, causal, offset,
+                    block_q):
+    block_k = k_ref.shape[1]
+    span = q_ref.shape[1]
+    count = span // block_q
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qj = pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(qj == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # causal: q blocks strictly above the diagonal see none of this k block
-    run = ((qi + 1) * block_q - 1 + offset >= ki * block_k) \
-        if causal else True
+    kk = k_ref[0]
+    v = v_ref[0]
+    diag0 = ki * block_k - qj * span - offset
+    if causal:
+        row_col = _row_minus_col((block_k, block_q), 1)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        kk = k_ref[0]
-        s = jax.lax.dot_general(
-            q, kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + offset >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0])                     # lse_ref[0]: [bq, 1]
-        do = do_ref[0]
+    def step(i, crossed):
+        rows = _sub(i, block_q, count)
+        q = q_ref[0, rows, :]                           # scaled
+        do = do_ref[0, rows, :]
+        # every tile transposed, [bk, bq]: the row statistics lie along
+        # the lanes and broadcast over sublanes
+        s = jax.lax.dot_general(kk, q, _NT,
+                                preferred_element_type=jnp.float32)
+        if crossed:
+            s = jnp.where(row_col >= diag0 - i * block_q, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, :, rows])
         dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta_ref[0]) * scale
+        dp = jax.lax.dot_general(v, do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, :, rows])
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bk, d]
 
-    @pl.when(qi == num_q_blocks - 1)
+    if causal:
+        start, whole = q_loop_bounds(ki, qj * span, offset=offset,
+                                     block_q=block_q, block_k=block_k,
+                                     count=count)
+        _loop(start, whole, lambda i: step(i, True))
+        _loop(whole, count, lambda i: step(i, False))
+    else:
+        _loop(0, count, lambda i: step(i, False))
+
+    @pl.when(qj == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -234,58 +329,53 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     group = bh // bkv
-    nq = pl.cdiv(sq, block_q)
-    nk = pl.cdiv(sk, block_k)
+    offset = sk - sq
     do = g.astype(q.dtype)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)              # [BH, Sq, 1]
 
+    grid, row, stat, kv = _q_major(q, k, causal=causal, block_q=block_q,
+                                   block_k=block_k)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          offset=sk - sq, block_q=block_q, block_k=block_k,
-                          num_k_blocks=nk),
+                          offset=offset, block_k=block_k),
         name="flash_bwd_dq",
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j, g_=group: (b // g_, j, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j, g_=group: (b // g_, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        grid=grid,
+        in_specs=[row, kv, kv, row, stat, stat],
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
     # dk/dv computed per *query* head then group-summed to the kv head
-    # (avoids cross-program races for GQA).
+    # (avoids cross-program races for GQA). A q span wholly above a k
+    # block's diagonal keeps the index of the first one that is not, so
+    # that it is not fetched.
+    span = flash_span(sq, block_q, d, q.dtype)
+    spans = sq // span
+    if causal and spans > 1:
+        def at(j, i):
+            return jnp.maximum(
+                i, jnp.clip((j * block_k - offset) // span, 0, spans - 1))
+    else:
+        def at(j, i):
+            return i
+    rows = pl.BlockSpec((1, span, d), lambda b, j, i: (b, at(j, i), 0))
+    # the statistics lane-major, [BH, 1, Sq]: a transposed tile wants
+    # them along its lanes
+    stats = pl.BlockSpec((1, 1, span), lambda b, j, i: (b, 0, at(j, i)))
+    col_in = pl.BlockSpec((1, block_k, d),
+                          lambda b, j, i: (b // group, j, 0))
+    col_out = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     dk_full, dv_full = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          offset=sk - sq, block_q=block_q, block_k=block_k,
-                          num_q_blocks=nq),
+        functools.partial(_bwd_dkv_kernel, causal=causal, offset=offset,
+                          block_q=block_q),
         name="flash_bwd_dkv",
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, j, i, g_=group: (b // g_, j, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, j, i, g_=group: (b // g_, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
+        grid=(bh, sk // block_k, spans),
+        in_specs=[rows, col_in, col_in, rows, stats, stats],
+        out_specs=[col_out, col_out],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
@@ -294,10 +384,9 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse.reshape(bh, 1, sq), delta.reshape(bh, 1, sq))
 
     if group > 1:
         dk = dk_full.reshape(bkv, group, sk, d).sum(axis=1)
@@ -330,11 +419,13 @@ def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     b, sq, h, d = q.shape
-    qr = _reshape_in(q)
+    # the scale goes into q once, in the copy that lays it out: the
+    # kernels' score tiles come out scaled, and dk with them
+    qr = _reshape_in(q * scale)
     kr = _reshape_in(k)
     vr = _reshape_in(v)
-    out, lse = _fwd(qr, kr, vr, scale=scale, causal=causal,
-                    block_q=block_q, block_k=block_k, interpret=interpret)
+    out, lse = _fwd(qr, kr, vr, causal=causal, block_q=block_q,
+                    block_k=block_k, interpret=interpret)
     return _reshape_out(out, b, h), (qr, kr, vr, out, lse, b, h)
 
 
@@ -353,22 +444,29 @@ _flash.defvjp(lambda q, k, v, *a: _flash_fwd(q, k, v, *a),
               _flash_bwd)
 
 
+def _blocks(q, k, block_q, block_k):
+    """The blocks a call runs: the caller's, clamped to the sequence, or
+    the shape rule's where it names none."""
+    rule = flash_blocks_for(q.shape[1], k.shape[1], q.shape[-1], q.dtype)
+    return (min(block_q or rule[0], q.shape[1]),
+            min(block_k or rule[1], k.shape[1]))
+
+
 def flash_attention(q, k, v, *, causal=False, scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False):
+                    block_q=None, block_k=None, interpret=False):
     """Flash attention on [B, S, H, D] (paddle layout); supports GQA
     (fewer kv heads) and causal masking. Differentiable (custom VJP,
-    flash backward). Sequence lengths must divide the block sizes —
-    the dispatcher (kernels/__init__.py) falls back to the XLA path
-    otherwise."""
+    flash backward). ``block_q`` / ``block_k`` default to what the shape
+    allows (``tiling.flash_blocks_for``). Sequence lengths must divide
+    the block sizes — the dispatcher (kernels/__init__.py) falls back to
+    the XLA path otherwise."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    bq = min(block_q, q.shape[1])
-    bk = min(block_k, k.shape[1])
+    bq, bk = _blocks(q, k, block_q, block_k)
     return _flash(q, k, v, float(scale), bool(causal), bq, bk, interpret)
 
 
-def supported(q, k, v, *, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+def supported(q, k, v, *, block_q=None, block_k=None):
     """Whether the kernel handles these shapes (else XLA fallback).
 
     Beyond divisibility, this checks Mosaic's block-shape legality for
@@ -376,11 +474,9 @@ def supported(q, k, v, *, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
     mode can't catch an illegal block, so the dispatcher must reject it
     here before a doomed pallas_call is traced (BENCH_r02's failure mode).
     """
-    from .tiling import flash_specs_legal
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
+    bq, bk = _blocks(q, k, block_q, block_k)
     return (sq % bq == 0 and sk % bk == 0 and
             bq % 8 == 0 and bk % 8 == 0 and
             h % k.shape[2] == 0 and d <= 256 and

@@ -11,14 +11,71 @@ import pytest
 from paddle_tpu.kernels import autotune as at
 
 
+# the benchmark's flash shapes: the train cell's, and the two serving cells'
+# prefill groups (rows 1-8 of buckets 128-2,048 at 32 / 8 and 20 / 4 heads)
+PREFILLS = [(rows, bucket, h, kv) for h, kv in ((32, 8), (20, 4))
+            for rows in (1, 2, 4, 8)
+            for bucket in (128, 256, 512, 1024, 2048)]
+RULE_SHAPES = (
+    [("train-4k", 4, 4096, 4096, 16, 16, 128, jnp.bfloat16),
+     ("float32", 2, 1024, 1024, 8, 8, 128, jnp.float32),
+     ("head-dim-256", 2, 2048, 2048, 8, 2, 256, jnp.bfloat16),
+     ("head-dim-64-cross", 2, 512, 1024, 8, 8, 64, jnp.bfloat16),
+     ("long-32k", 1, 32768, 32768, 8, 8, 128, jnp.bfloat16),
+     ("short-64", 2, 64, 64, 4, 4, 64, jnp.bfloat16)]
+    + [(f"prefill-{rows}x{bucket}-h{h}kv{kv}", rows, bucket, bucket, h, kv,
+        128, jnp.bfloat16) for rows, bucket, h, kv in PREFILLS])
+
+
+class TestShapeRule:
+    @pytest.mark.parametrize("case", RULE_SHAPES, ids=lambda c: c[0])
+    def test_blocks_are_legal_divide_and_fit(self, case):
+        from paddle_tpu.kernels import tiling as T
+
+        _, b, sq, sk, h, kv, d, dtype = case
+        bq, bk = T.flash_blocks_for(sq, sk, d, dtype)
+        assert sq % bq == 0 and sk % bk == 0
+        assert bq <= T.FLASH_MAX_BLOCKS[0] and bk <= T.FLASH_MAX_BLOCKS[1]
+        assert T.flash_specs_legal(b * h, sq, sk, d, bq, bk, dtype)
+        assert T.flash_vmem_bytes(sq, sk, d, bq, bk, dtype) \
+            <= T.FLASH_VMEM_BUDGET
+        # what a step keeps resident stays inside its bound, whole
+        # sub-blocks of it
+        for s_, blk in ((sk, bk), (sq, bq)):
+            span = T.flash_span(s_, blk, d, dtype)
+            assert s_ % span == 0 and span % blk == 0
+            assert (4 * span * d * jnp.dtype(dtype).itemsize
+                    <= T.FLASH_RESIDENT_BYTES) or span == blk
+        # and the dispatcher's answer in "cached" mode is the rule's
+        got = at.flash_blocks((b, sq, h, d), (b, sk, kv, d), dtype, True,
+                              cache=at.AutotuneCache("/nonexistent/c.json"))
+        assert got == (bq, bk)
+
+    def test_the_cells_blocks(self):
+        from paddle_tpu.kernels.tiling import flash_blocks_for
+        assert flash_blocks_for(4096, 4096, 128, jnp.bfloat16) == (512, 512)
+        # a 128-token bucket keeps the block it had
+        assert flash_blocks_for(128, 128, 128, jnp.bfloat16) == (128, 128)
+        assert flash_blocks_for(256, 256, 128, jnp.bfloat16) == (256, 256)
+
+    def test_a_sequence_off_128_gets_no_block_that_divides_it(self):
+        # as before the rule: 192 tokens go to the XLA path
+        from paddle_tpu.kernels.tiling import flash_blocks_for
+        assert flash_blocks_for(192, 192, 128, jnp.bfloat16) == (128, 128)
+        assert flash_blocks_for(48, 80, 64, jnp.float32) == (48, 80)
+
+
 class TestCandidates:
-    def test_default_first_and_legal(self):
+    def test_rule_first_and_legal(self):
+        from paddle_tpu.kernels import tiling as T
+
         cands = at.flash_candidates(8, 2048, 2048, 128, jnp.bfloat16)
-        assert cands[0] == (128, 128)
-        assert len(cands) > 1
+        assert cands[0] == T.flash_blocks_for(2048, 2048, 128, jnp.bfloat16)
+        assert (128, 128) in cands and len(set(cands)) == len(cands) > 6
         for bq, bk in cands:
             assert 2048 % bq == 0 and 2048 % bk == 0
-            assert at._vmem_bytes(bq, bk, 128) <= at._VMEM_BUDGET
+            assert T.flash_vmem_bytes(2048, 2048, 128, bq, bk,
+                                      jnp.bfloat16) <= T.FLASH_VMEM_BUDGET
 
     def test_short_seq_clamps(self):
         cands = at.flash_candidates(8, 256, 256, 128, jnp.bfloat16)
@@ -26,6 +83,17 @@ class TestCandidates:
 
     def test_never_empty(self):
         assert at.flash_candidates(8, 8, 8, 64, jnp.float32)
+
+    def test_varlen_keeps_its_six(self):
+        cands = at.varlen_candidates(7, 7 * 32, 2048, 2048, 128,
+                                     jnp.bfloat16)
+        assert cands[0] == at.DEFAULT_BLOCKS
+        assert set(cands) == set(at.VARLEN_CANDIDATES)
+
+
+# what the shape rule gives TestFlashBlocks' call (2,048 x 2,048, d 128)
+RULE = (512, 512)
+KEY = "flash:cpu:bfloat16:b2h4kv2:q2048k2048d128:c1"
 
 
 class TestFlashBlocks:
@@ -51,7 +119,7 @@ class TestFlashBlocks:
     def test_persists_to_disk(self, tmp_path):
         path = str(tmp_path / "c.json")
         cache = at.AutotuneCache(path)
-        self._call(cache, lambda bq, bk: float(bq))   # smallest bq wins
+        self._call(cache, lambda bq, bk: float(bq + bk))   # smallest wins
         disk = json.load(open(path))
         (key,) = disk.keys()
         assert key.startswith("flash:")
@@ -66,12 +134,12 @@ class TestFlashBlocks:
         cache = at.AutotuneCache(str(tmp_path / "c.json"))
 
         def measure(bq, bk):
-            if (bq, bk) == (128, 128):
+            if (bq, bk) == RULE:
                 raise RuntimeError("compile failed")
-            return float(bq + bk)
+            return -float(bq + bk)
 
         got = self._call(cache, measure)
-        assert got != (128, 128)
+        assert got != RULE and got in at.CANDIDATES
 
     def test_all_fail_raises_with_compiler_message(self, tmp_path):
         # a kernel no candidate can compile is an error carrying the
@@ -91,9 +159,11 @@ class TestFlashBlocks:
         path = str(tmp_path / "c.json")
         cache = at.AutotuneCache(path)
         calls = []
-        # miss -> defaults, no measurement even with a measure fn given
+        # miss -> the shape rule, no measurement even with a measure fn
         got = self._call(cache, lambda bq, bk: calls.append(1) or 1.0)
-        assert got == (128, 128) and not calls
+        assert got == RULE and not calls
+        assert at.used_blocks()[KEY] == {"blocks": list(RULE),
+                                         "source": "shape-rule"}
         # pre-tuned entry -> honored
         at._USED.clear()
         cache2 = at.AutotuneCache(path)
@@ -168,11 +238,11 @@ class TestFlashBlocks:
             return x
 
         jax.jit(probe)(jnp.zeros(()))
-        assert seen["blocks"] == (128, 128)
+        assert seen["blocks"] == RULE
         assert seen["chunk"] == 1000   # default clamped to vocab
         used = at.used_blocks()
-        assert any(v.get("source") == "default-in-trace"
-                   for v in used.values())
+        assert {v.get("source") for v in used.values()} >= {
+            "default-in-trace", "shape-rule-in-trace"}
         # and nothing was persisted as a failure
         import os
         assert not os.path.exists(str(tmp_path / "c.json"))
@@ -193,7 +263,7 @@ class TestFlashBlocks:
             calls = []
             got = self._call(at.AutotuneCache(str(tmp_path / "c.json")),
                              lambda bq, bk: calls.append(1) or 1.0)
-            assert got == (128, 128) and not calls
+            assert got == RULE and not calls
         finally:
             flags.set_flags({"use_autotune": True})
 
@@ -202,7 +272,9 @@ class TestFlashBlocks:
         cache = at.AutotuneCache(str(tmp_path / "c.json"))
         got = at.flash_blocks((2, 2048, 4, 128), (2, 2048, 2, 128),
                               jnp.bfloat16, True, cache=cache)
-        assert got == (128, 128)
+        assert got == RULE
+        assert at.used_blocks()[KEY] == {"blocks": list(RULE),
+                                         "source": "shape-rule-not-tpu"}
 
 
 class TestBf16Moments:

@@ -79,6 +79,121 @@ class TestFlashAttention:
             kernels.unregister()
 
 
+# B, Sq, Sk, H, KV, D, causal, block_q, block_k, resident bytes (None: the
+# sequences whole in VMEM; a number cuts them into that many bytes' spans)
+LOOP_FORMS = {
+    "blocks-larger-than-the-sequence": (1, 64, 64, 2, 2, 32, True, 128, 128,
+                                        None),
+    "blocks-equal-to-the-sequence": (1, 64, 64, 2, 2, 32, True, 64, 64, None),
+    "blocks-smaller-than-the-sequence": (2, 128, 128, 2, 2, 32, True, 32, 32,
+                                         None),
+    # sq < sk, offset 40: no block boundary meets the diagonal
+    "bottom-right-offset-off-the-boundaries": (1, 64, 104, 2, 2, 32, True,
+                                               32, 8, None),
+    "offset-of-whole-blocks": (1, 64, 128, 2, 1, 32, True, 32, 64, None),
+    "gqa-group-1": (1, 64, 64, 3, 3, 32, True, 32, 32, None),
+    "gqa-group-4": (1, 64, 64, 4, 1, 32, True, 32, 32, None),
+    "gqa-group-5": (2, 64, 64, 10, 2, 32, True, 32, 32, None),
+    # a q block of 64 rows over k blocks of 16: each visits whole
+    # sub-blocks first and then four the diagonal crosses, side by side
+    "interior-beside-diagonal-wide-q": (1, 128, 128, 2, 2, 32, True, 64, 16,
+                                        None),
+    "interior-beside-diagonal-wide-k": (1, 128, 128, 2, 2, 32, True, 16, 64,
+                                        None),
+    "non-causal": (1, 64, 96, 2, 1, 32, False, 32, 48, None),
+    "non-causal-one-block": (1, 64, 64, 2, 2, 32, False, 64, 64, None),
+    # the sequences in spans of two sub-blocks: the grid's third axis
+    # runs, and a hidden span is neither fetched nor walked
+    "spans-causal": (1, 128, 128, 2, 1, 32, True, 16, 16, 4 * 32 * 32 * 4),
+    "spans-offset": (1, 64, 128, 2, 2, 32, True, 16, 32, 4 * 64 * 32 * 4),
+    "spans-non-causal": (1, 64, 128, 2, 2, 32, False, 32, 16,
+                         4 * 32 * 32 * 4),
+}
+
+
+def _resident(monkeypatch, nbytes):
+    if nbytes is not None:
+        from paddle_tpu.kernels import tiling
+        monkeypatch.setattr(tiling, "FLASH_RESIDENT_BYTES", nbytes)
+
+
+class TestFlashLoopForms:
+    @pytest.mark.parametrize("case", LOOP_FORMS)
+    def test_forward_and_gradients_match_reference(self, monkeypatch, case):
+        """The forward and all three gradients against sdpa_reference at
+        every form the tile loop takes."""
+        B, Sq, Sk, H, KV, D, causal, bq, bk, resident = LOOP_FORMS[case]
+        _resident(monkeypatch, resident)
+        q, k, v = rand((B, Sq, H, D)), rand((B, Sk, KV, D)), \
+            rand((B, Sk, KV, D))
+        w = rand((B, Sq, H, D))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=causal, block_q=bq,
+                                   block_k=bk, interpret=True)
+
+        def ref(q, k, v):
+            return sdpa_reference(q, k, v, causal=causal)
+
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(ref(q, k, v)),
+                                   rtol=1e-5, atol=1e-5)
+        g1 = jax.grad(lambda *a: (flash(*a) * w).sum(), (0, 1, 2))(q, k, v)
+        g2 = jax.grad(lambda *a: (ref(*a) * w).sum(), (0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("Sq,Sk,bq,bk,span_q,span_k", [
+        (4096, 4096, 512, 512, 4096, 4096),   # the train cell's call
+        (128, 128, 32, 32, 128, 128),
+        (64, 104, 32, 8, 64, 104),            # offset off the boundaries
+        (128, 128, 64, 16, 128, 32),          # K/V in spans of two
+        (128, 128, 16, 64, 32, 128),          # q and dO in spans of two
+        (256, 128, 32, 32, 64, 64),           # rows that see nothing
+    ])
+    def test_a_causal_call_runs_the_visible_pairs_and_no_other(
+            self, Sq, Sk, bq, bk, span_q, span_k):
+        """Counted from the loop bounds the kernels run (not timed): the
+        bodies a causal call executes are the (q block, k block) pairs
+        that hold a visible token pair, each once, and the ones that
+        build the mask are the ones the diagonal crosses; the dkv kernel's
+        turn of the loop visits the same pairs."""
+        offset = Sk - Sq
+        rows, cols = np.arange(Sq)[:, None], np.arange(Sk)[None, :]
+        seen = (cols <= rows + offset).reshape(Sq // bq, bq, Sk // bk, bk)
+        visible = seen.any(axis=(1, 3))
+        crossed = visible & ~seen.all(axis=(1, 3))
+
+        ran, masked = np.zeros_like(visible, int), np.zeros_like(visible, int)
+        per = span_k // bk
+        for qi in range(Sq // bq):
+            for kj in range(Sk // span_k):
+                whole, end = (int(x) for x in fa_mod.k_loop_bounds(
+                    qi, kj * span_k, offset=offset, block_q=bq, block_k=bk,
+                    count=per))
+                assert 0 <= whole <= end <= per
+                ran[qi, kj * per:kj * per + end] += 1
+                masked[qi, kj * per + whole:kj * per + end] += 1
+        np.testing.assert_array_equal(ran, visible)
+        np.testing.assert_array_equal(masked, crossed)
+
+        ran[:], masked[:] = 0, 0
+        per = span_q // bq
+        for ki in range(Sk // bk):
+            for qj in range(Sq // span_q):
+                start, whole = (int(x) for x in fa_mod.q_loop_bounds(
+                    ki, qj * span_q, offset=offset, block_q=bq, block_k=bk,
+                    count=per))
+                assert 0 <= start <= whole <= per
+                ran[qj * per + start:(qj + 1) * per, ki] += 1
+                masked[qj * per + start:qj * per + whole, ki] += 1
+        np.testing.assert_array_equal(ran, visible)
+        np.testing.assert_array_equal(masked, crossed)
+        if Sq == Sk == 4096:
+            assert visible.sum() == 36 and crossed.sum() == 8
+
+
 class TestFusedRMSNorm:
     def test_forward_backward_match(self):
         n, d = 256, 128
