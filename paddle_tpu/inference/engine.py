@@ -78,6 +78,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import math
 import time
 from collections import deque
@@ -95,6 +96,21 @@ from .paged import (PagedKVCache, PrefixCache, cache_decode_step,
                     cache_verify_window)
 
 _NO_SPAN = contextlib.nullcontext()     # _first_call's, after the first
+
+
+@contextlib.contextmanager
+def _first_call_span():
+    """``serving.compile`` round a program's first call, and then
+    ``gc.freeze()``: what tracing and compiling a program leaves behind
+    (jaxprs, executables, their caches: hundreds of thousands of objects a
+    cell) lives as long as the engine, and the collector's next full pass
+    would walk all of it again in the middle of serving: with 19 programs
+    warmed, one engine step of four in ten runs took 2-3 s longer
+    (PERF.md section 6, PR 31). Frozen objects are still freed by their
+    reference counts; only cycles among them would stay."""
+    with _trace.span("serving.compile"):
+        yield
+    gc.freeze()
 
 __all__ = ["EngineOverloaded", "Request", "RequestCost", "RequestOutput",
            "RequestRejected", "ServingEngine"]
@@ -313,7 +329,9 @@ class ServingEngine:
 
     ``family`` is a model module exposing the decoder seam
     (models.llama / models.moe; models.falcon_h1, which also keeps a
-    recurrent state a sequence beside the pages); ``params`` may be the
+    recurrent state a sequence beside the pages; models.phi4flash, whose
+    declared stack keeps pages of one layer, rings and states);
+    ``params`` may be the
     bf16 tree or the weight-only int8 tree from
     ``family.quantize_weights``."""
 
@@ -416,7 +434,9 @@ class ServingEngine:
             config, num_pages, self.page_size, self.max_pages_per_seq,
             kv_dtype, kv_quant=self._kv_quant,
             state_shapes=shapes(config) if self._recurrent else None,
-            state_rows=self.num_slots)
+            state_rows=self.num_slots,
+            pool_layout=getattr(family, "pool_layout", lambda c: None)(
+                config))
         # radix shared-prefix cache over the pool's committed pages;
         # None (flag off) short-circuits every hook to the original code
         self._prefix = PrefixCache(self.cache.alloc) if self._prefix_on \
@@ -903,7 +923,7 @@ class ServingEngine:
         if id(fn) in self._called:
             return _NO_SPAN
         self._called.add(id(fn))
-        return _trace.span("serving.compile")
+        return _first_call_span()
 
     def _free_slack(self) -> int:
         """Free pages the admission watermark may count: the free list

@@ -24,12 +24,19 @@ Three layers:
   ``_block``); one that keeps a recurrent state a sequence declares its
   leaves (``state_shapes``) and gives the mixer in two forms
   (``mixer_prefill``, ``mixer_decode``): models/falcon_h1.py does both.
+  A family whose stack is NOT one run of like layers declares it
+  (``segments(config)``, ``models/stack.py``): the two programs then run
+  a scan a segment with the whole cache as the carry, and the cache reads
+  the same declaration for how many layers keep pages, a ring row or a
+  state row (``_StackOps``; models/phi4flash.py: one pool layer that eight
+  layers read, a ring a window layer, a state a Mamba-1 layer).
   ``paged_prefill`` / ``paged_decode_step`` are the same two programs for
   a caller that holds the two pool halves and nothing else.
 
 Two kinds of state under one manager: K/V pages a token, and (for a
-family that declares one) a recurrent state a SEQUENCE: leaves
-``cache["state"][name]`` of shape ``[L, rows + 1, ...]``. A sequence owns
+family that declares one) a state a SEQUENCE, recurrent or a window's
+ring of keys and values: leaves ``cache["state"][name]`` of shape ``[L,
+rows + 1, ...]`` (``L`` the layers that keep that leaf). A sequence owns
 one row from ``alloc`` to ``free``; slots reach their rows through a row
 table (a block table of width 1), so moving a sequence to another slot
 copies nothing. The last row belongs to nobody: a prefill group's dummy
@@ -49,6 +56,7 @@ page 0 — the allocator owns the sentinel discipline.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 from typing import Dict, List, Optional, Tuple
 
@@ -480,7 +488,7 @@ class PrefixCache:
 
 def init_pool(config, num_pages: int, page_size: int, dtype=None,
               kv_quant: bool = False, state_shapes: Optional[dict] = None,
-              state_rows: int = 0) -> dict:
+              state_rows: int = 0, pool_layout=None) -> dict:
     """Fresh page pools, one [P, kv, ps, hd] grid per layer, stacked on
     a leading layer axis into ONE buffer a half (the decode step's layer
     scan carries it whole and names a layer by index). With
@@ -488,6 +496,10 @@ def init_pool(config, num_pages: int, page_size: int, dtype=None,
     ``state_shapes(config)``: leaf name -> (shape a row a layer, type))
     the cache also holds ``"state"``: each leaf ``[L, state_rows + 1,
     ...]`` of zeros, the last row owned by no sequence. With
+    A shape may name its own layer count, (shape, type, layers): a
+    family whose layers do not all keep that leaf. ``pool_layout``
+    (a family's ``pool_layout(config)``) is the pool's (layers, heads,
+    head size) where they are not the config's. With
     ``kv_quant`` (FLAGS_serving_kv_quant) each pool leaf is the
     quantized pair {"q": int8 codes, "s": f32 [L, P, kv] scale
     plane} — per-page per-kv-head write-time absmax scales ride the
@@ -495,8 +507,10 @@ def init_pool(config, num_pages: int, page_size: int, dtype=None,
     (CoW copy, fork refcount, scatter-with-drop) moves code and scale
     rows together. Zero scale = untouched page, dequantizing to 0."""
     dt = dtype if dtype is not None else config.dtype
-    shape = (config.num_hidden_layers, num_pages,
-             config.num_key_value_heads, page_size, config.head_dim)
+    layers, kv_heads, head_dim = pool_layout or (
+        config.num_hidden_layers, config.num_key_value_heads,
+        config.head_dim)
+    shape = (layers, num_pages, kv_heads, page_size, head_dim)
     if kv_quant:
         def leaf():
             return {"q": jnp.zeros(shape, jnp.int8),
@@ -505,9 +519,11 @@ def init_pool(config, num_pages: int, page_size: int, dtype=None,
     else:
         pool = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     if state_shapes:
+        # (shape a row a layer, type[, layers that keep the leaf])
         pool["state"] = {
-            name: jnp.zeros((shape[0], state_rows + 1) + tuple(row), t)
-            for name, (row, t) in state_shapes.items()}
+            name: jnp.zeros((spec[2] if len(spec) > 2 else layers,
+                             state_rows + 1) + tuple(spec[0]), spec[1])
+            for name, spec in state_shapes.items()}
     return pool
 
 
@@ -519,7 +535,8 @@ class PagedKVCache:
     def __init__(self, config, num_pages: int, page_size: int,
                  max_pages_per_seq: int, dtype=None,
                  kv_quant: bool = False,
-                 state_shapes: Optional[dict] = None, state_rows: int = 0):
+                 state_shapes: Optional[dict] = None, state_rows: int = 0,
+                 pool_layout=None):
         self.config = config
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
@@ -529,7 +546,8 @@ class PagedKVCache:
         self.pool = init_pool(config, num_pages, page_size, dtype,
                               kv_quant=self.kv_quant,
                               state_shapes=state_shapes,
-                              state_rows=self.state_rows)
+                              state_rows=self.state_rows,
+                              pool_layout=pool_layout)
         self.alloc = PageAllocator(num_pages, page_size, max_pages_per_seq,
                                    state_rows=self.state_rows)
         # page-row copy over EVERY leaf of the two page pools: the
@@ -767,6 +785,9 @@ def cache_prefill(family, params, ids, config, cache, page_rows, slen,
 
 def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
                   state_rows):
+    if hasattr(family, "segments"):
+        return _stack_prefill(family, params, ids, config, cache, page_rows,
+                              slen, state_rows)
     c = config
     G, S = ids.shape
     pool_k, pool_v = cache["k"], cache["v"]
@@ -828,6 +849,9 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
     owns, so its own row is untouched). Nothing is sliced out of the
     cache and nothing of its size is made, so a program that donates it
     holds it once."""
+    if hasattr(family, "segments"):
+        return _stack_decode(family, params, cache, block_tables, lengths,
+                             tokens, config, state_rows)
     c = config
     B = tokens.shape[0]
     pool_k, pool_v = cache["k"], cache["v"]
@@ -895,6 +919,240 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
         x = _rms(x, params["ln_f"], c.rms_norm_eps)
         logits = _logits(family, params, x[:, 0, :], c)
     return out, logits
+
+
+# ---------------------------------------------------------------------------
+# a declared stack: a scan a segment, the cache whole as the carry
+# ---------------------------------------------------------------------------
+
+def _last_position_attention(q, k, v, slen, scale):
+    """``q`` [G, 1, heads, hd], each row at position ``slen`` - 1 of its
+    prompt, over the prompt's keys and values ``k``, ``v`` [G, S, kv, hd]
+    (grouped-query; float32 scores, the probabilities in the values' type
+    as the kernels have them): [G, 1, heads, hd]."""
+    G, S, kv, hd = k.shape
+    qg = q.reshape(G, kv, -1, hd)
+    s = jnp.einsum("gkrd,gskd->gkrs", qg, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where((jnp.arange(S) < slen[:, None])[:, None, None, :], s,
+                  -jnp.inf)
+    a = jnp.einsum("gkrs,gskd->gkrd", jax.nn.softmax(s, -1).astype(v.dtype),
+                   v, preferred_element_type=jnp.float32)
+    return a.reshape(q.shape).astype(q.dtype)
+
+
+# The decode kernels behind a ``jit`` of their own: a stack calls the pool's
+# kernel from two segments and the ring's from one, in each of a cell's
+# decode-chunk programs, with the same shapes every time; the inner ``jit``
+# is traced once a process (a Pallas kernel's own trace is not cached, and
+# costs a program's first call half a second each).
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _paged_attention(q, pool_k, pool_v, tables, lengths, layer, *, scale):
+    from ..kernels import dispatched_paged_attention
+
+    return dispatched_paged_attention(q, pool_k, pool_v, tables, lengths,
+                                      scale=scale, layer=layer)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale"))
+def _ring_attention(q, ring_k, ring_v, layer, rows, lengths, *, window, scale):
+    from ..kernels import dispatched_ring_attention
+
+    return dispatched_ring_attention(q, ring_k, ring_v, layer, rows, lengths,
+                                     window=window, scale=scale)
+
+
+class _StackOps:
+    """The program's side of a declared stack's blocks
+    (``family.stack_block(kind, x, lp, config, i, ops)``): the cache, and
+    the calls that read and write it. One object serves a whole program;
+    ``_scan_stack`` hands it each scan body's carry (``cache``, ``shared``)
+    before the block runs and takes them back after, so the cache rides
+    every segment's scan whole and nothing of its size is sliced out of it
+    or made. ``shared`` is whatever one segment hands to the later ones.
+
+    ``prefill``: whole prompts ``[G, S]`` (``slen`` valid tokens a row,
+    pages ``page_rows``, the rows of state ``rows``). Else one token a
+    slot: ``lengths`` count it, ``tables`` are the slots' block tables,
+    ``rows`` their rows of state (an inactive slot's: nobody's)."""
+
+    def __init__(self, cache, *, prefill, tables, lengths, rows):
+        self.cache, self.shared = dict(cache), None
+        self.prefill, self.tables, self.rows = prefill, tables, rows
+        self.slen = self.lengths = lengths
+        self.P, _, self.ps = _pool_shape(cache["k"])[1:4]
+        self.prompt_kv = {}      # prefill: the keys and values a layer wrote
+
+    @property
+    def state(self):
+        return self.cache["state"]
+
+    @state.setter
+    def state(self, new):
+        self.cache["state"] = new
+
+    def write_state(self, layer, leaves):
+        """Prefill: what each row's sequence keeps of layer ``layer``."""
+        with jax.named_scope("ssm.scan"):
+            self.state = {**self.state, **{
+                k: self.state[k].at[layer, self.rows].set(
+                    v.astype(self.state[k].dtype))
+                for k, v in leaves.items()}}
+
+    def attend_pages(self, q, k, v, layer, *, scale):
+        """Attention over pool layer ``layer``: ``q`` [B, S, heads, hd];
+        ``k``, ``v`` [B, S, kv, hd] are written to the layer's pages first,
+        or None for a layer that reads what another wrote. Whole prompts
+        (prefill, with keys) attend causally among themselves; a decode
+        step's single positions read the pages through the paged kernel; a
+        prefill's last positions (``last_only`` segments) read the prompt's
+        keys and values where the layer that wrote them left them in hand
+        (the same numbers as its pages hold, and no kernel to trace into
+        each of a cell's prefill programs)."""
+        from ..nn.functional.attention import sdpa_raw
+
+        B, S = q.shape[:2]
+        if k is not None and self.prefill:
+            self.prompt_kv[layer] = (k, v)
+            with jax.named_scope("attn.kernel"):
+                a = sdpa_raw(q, k, v, is_causal=True, scale=scale)
+            with jax.named_scope("attn.kv_write"):
+                for half, t in (("k", k), ("v", v)):
+                    grid = jnp.moveaxis(t.reshape(
+                        B, S // self.ps, self.ps, *t.shape[2:]), 3, 2)
+                    self.cache[half] = self.cache[half].at[
+                        layer, self.tables].set(
+                            grid.astype(self.cache[half].dtype), mode="drop")
+            return a
+        if k is not None:
+            posw = jnp.maximum(self.lengths - 1, 0)
+            at = jnp.take_along_axis(self.tables, (posw // self.ps)[:, None],
+                                     axis=1)[:, 0]
+            at = jnp.where(self.lengths > 0, at, self.P)  # inactive: drop
+            for half, t in (("k", k), ("v", v)):
+                self.cache[half] = _kv_page_append(
+                    self.cache[half], layer, at, posw % self.ps, t[:, 0],
+                    self.P)
+        with jax.named_scope("attn.kernel"):
+            if layer in self.prompt_kv:
+                return _last_position_attention(
+                    q, *self.prompt_kv[layer], self.slen, scale)
+            return _paged_attention(
+                q[:, 0], self.cache["k"], self.cache["v"], self.tables,
+                self.lengths, layer, scale=scale)[:, None]
+
+    def attend_ring(self, q, k, v, layer, *, scale, window):
+        """Attention over the last ``window`` positions, kept a sequence
+        in ring ``layer`` of the state leaves ``ring_k`` / ``ring_v``
+        ([layers, rows, pages, kv, ps, hd]; position p at slot p mod the
+        ring's size). Prefill attends within the prompt and writes the
+        ring as the prompt's end leaves it; a decode step writes its
+        token's slot and reads the ring."""
+        from ..kernels import dispatched_window_flash
+
+        rk, rv = self.state["ring_k"], self.state["ring_v"]
+        pages, kv, ps, hd = rk.shape[2:]
+        ring = pages * ps
+        if self.prefill:
+            with jax.named_scope("attn.kernel"):
+                a = dispatched_window_flash(q, k, v, window=window,
+                                            scale=scale)
+            with jax.named_scope("attn.kv_write"):
+                # slot r holds the last position before slen that is r
+                # mod ring (one before the prompt's start is never valid)
+                last = self.slen[:, None] - 1
+                pos = last - jnp.mod(last - jnp.arange(ring)[None, :], ring)
+                pos = jnp.clip(pos, 0, k.shape[1] - 1)[:, :, None, None]
+                rk, rv = (r.at[layer, self.rows].set(jnp.moveaxis(
+                    jnp.take_along_axis(t, pos, axis=1).reshape(
+                        -1, pages, ps, kv, hd), 3, 2).astype(r.dtype))
+                    for r, t in ((rk, k), (rv, v)))
+            self.state = {**self.state, "ring_k": rk, "ring_v": rv}
+            return a
+        with jax.named_scope("attn.kv_write"):
+            slot = jnp.mod(self.lengths - 1, ring)
+            at = (layer, self.rows[:, None], (slot // ps)[:, None],
+                  jnp.arange(kv)[None, :], (slot % ps)[:, None])
+            rk, rv = (r.at[at].set(t[:, 0].astype(r.dtype),
+                                   unique_indices=False)
+                      for r, t in ((rk, k), (rv, v)))
+        self.state = {**self.state, "ring_k": rk, "ring_v": rv}
+        with jax.named_scope("attn.kernel"):
+            return _ring_attention(
+                q[:, 0], rk, rv, layer, self.rows, self.lengths,
+                window=window, scale=scale)[:, None]
+
+
+def _scan_stack(family, params, config, x, ops, to_last=None):
+    """``x`` through every segment of the family's declaration, in order:
+    a ``lax.scan`` a segment over ``params[kind]`` with (x, the cache,
+    what the segments share) as the carry; a segment of one layer is that
+    layer's call. ``to_last`` (prefill) cuts ``x`` and what is shared down
+    to each row's last position before the first ``last_only`` segment."""
+    for seg in family.segments(config):
+        if seg.last_only and to_last is not None:
+            x, ops.shared = jax.tree.map(to_last, (x, ops.shared))
+            to_last = None
+
+        def step(carry, xs, kind=seg.kind):
+            x, ops.cache, ops.shared = carry
+            x = family.stack_block(kind, x, xs[0], config, xs[1], ops)
+            return (x, ops.cache, ops.shared), None
+
+        carry = (x, ops.cache, ops.shared)
+        if seg.count == 1:
+            carry, _ = step(carry, (jax.tree.map(lambda a: a[0],
+                                                 params[seg.kind]), 0))
+        else:
+            carry, _ = lax.scan(step, carry, (params[seg.kind],
+                                              jnp.arange(seg.count)))
+        x, ops.cache, ops.shared = carry
+    return x
+
+
+def _stack_prefill(family, params, ids, config, cache, page_rows, slen,
+                   state_rows):
+    """``_prefill_pass`` for a declared stack. Segments marked
+    ``last_only`` run on each row's position ``slen`` - 1 alone, reading
+    the pages the segments before them wrote."""
+    c = config
+    S, ps = ids.shape[1], _pool_shape(cache["k"])[3]
+    E.enforce(S % ps == 0, f"padded prompt {S} not a multiple of "
+              f"page_size {ps}")
+    with jax.named_scope("embed"):
+        x = _embed(family, params, ids, c)
+    ops = _StackOps(cache, prefill=True, tables=page_rows, lengths=slen,
+                    rows=state_rows)
+    at = jnp.maximum(slen - 1, 0)[:, None, None]
+
+    def to_last(t):
+        ops.prefill = False
+        return jnp.take_along_axis(t, at, axis=1)
+
+    x = _scan_stack(family, params, c, x, ops, to_last)
+    with jax.named_scope("head"):
+        if ops.prefill:                       # no segment ran on the last
+            x = to_last(x)
+        logits = _logits(family, params,
+                         family.final_norm(params, x[:, 0], c), c)
+    return ops.cache, logits
+
+
+def _stack_decode(family, params, cache, block_tables, lengths, tokens,
+                  config, state_rows):
+    """``cache_decode_step`` for a declared stack."""
+    c = config
+    with jax.named_scope("embed"):
+        x = _embed(family, params, tokens, c)[:, None, :]
+    nobody = jax.tree.leaves(cache["state"])[0].shape[1] - 1
+    ops = _StackOps(cache, prefill=False, tables=block_tables,
+                    lengths=lengths,
+                    rows=jnp.where(lengths > 0, state_rows, nobody))
+    x = _scan_stack(family, params, c, x, ops)
+    with jax.named_scope("head"):
+        logits = _logits(family, params,
+                         family.final_norm(params, x[:, 0], c), c)
+    return ops.cache, logits
 
 
 def paged_prefill(family, params, ids, config, pool_k, pool_v, page_rows,

@@ -40,6 +40,11 @@ paged_attention_ref = _pa.paged_attention_ref
 ssm_state_update = _ssm.ssm_state_update
 ssm_state_update_ref = _ssm.ssm_state_update_ref
 ssd_chunked_scan = _ssm.ssd_chunked_scan
+ssm_state_update_s6 = _ssm.ssm_state_update_s6
+ssm_state_update_s6_ref = _ssm.ssm_state_update_s6_ref
+s6_scan = _ssm.s6_scan
+s6_scan_ref = _ssm.s6_scan_ref
+ring_window_attention = _pa.ring_window_attention
 
 __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "dispatched_fused_ce", "ring_attention",
@@ -47,6 +52,9 @@ __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "dispatched_paged_attention",
            "ssm_state_update", "ssm_state_update_ref", "ssd_chunked_scan",
            "dispatched_ssm_update",
+           "ssm_state_update_s6", "ssm_state_update_s6_ref", "s6_scan",
+           "s6_scan_ref", "dispatched_s6_update", "dispatched_s6_scan", "ring_window_attention",
+           "dispatched_ring_attention", "dispatched_window_flash",
            "flash_attention_segments", "segment_attention_ref",
            "count_skipped_blocks", "dispatched_segment_attention",
            "register", "unregister", "dispatch_stats", "reset_dispatch_stats"]
@@ -75,8 +83,13 @@ def dispatch_stats() -> dict:
     attention ``paged_decode_attn`` and its int8-page arm; ``varlen`` the
     segment (packed) flash kernels; ``ssm`` the in-place recurrent state
     update ``ssm_state_update`` (``ssm.py``), whose fallback gathers the
-    rows, updates them and scatters them back. A serving cell asserts
-    ``paged_fallback`` and ``ssm_fallback`` stay 0."""
+    rows, updates them and scatters them back. The siblings count with
+    them: ``flash`` the forward with a window (``dispatched_window_flash``),
+    ``paged`` the decode kernel over a window's ring
+    (``paged_decode_attn_window``), ``ssm`` the Mamba-1 update
+    ``ssm_state_update_s6``. A serving cell asserts ``paged_fallback``,
+    ``ssm_fallback`` and (where its prefill has a window) ``flash_fallback``
+    stay 0."""
     return dict(_DISPATCH_STATS)
 
 
@@ -229,6 +242,65 @@ def dispatched_ssm_update(state, layer, rows, decay, dtx, b, c):
                                      interpret=False)
     _DISPATCH_STATS["ssm_fallback"] += 1
     return _ssm.ssm_state_update_ref(state, layer, rows, decay, dtx, b, c)
+
+
+def dispatched_s6_update(state, layer, rows, dt, dtx, a, b, c):
+    """One token a slot through a Mamba-1 layer's recurrence against the
+    state ``[L, rows, N, C]`` (``kernels/ssm.py``): the Pallas kernel
+    ``ssm_state_update_s6`` on a TPU where the shapes are supported (the
+    state updated in place), the gather / update / scatter in plain XLA
+    elsewhere; counted as ``ssm`` / ``ssm_fallback``. Returns (state',
+    y [B, C] float32)."""
+    if _on_tpu() and _ssm.s6_supported(state, dt):
+        _DISPATCH_STATS["ssm"] += 1
+        return _ssm.ssm_state_update_s6(state, layer, rows, dt, dtx, a, b,
+                                        c, interpret=False)
+    _DISPATCH_STATS["ssm_fallback"] += 1
+    return _ssm.ssm_state_update_s6_ref(state, layer, rows, dt, dtx, a, b, c)
+
+
+def dispatched_s6_scan(x, dt, a, b, c):
+    """Whole sequences through a Mamba-1 layer's recurrence (prefill): the
+    Pallas scan ``s6_scan`` on a TPU where the shapes are supported, the
+    token-by-token ``lax.scan`` elsewhere; counted as ``ssm`` /
+    ``ssm_fallback``. Returns (y [G, S, C] float32, the last state)."""
+    if _on_tpu() and _ssm.s6_scan_supported(x, a):
+        _DISPATCH_STATS["ssm"] += 1
+        return _ssm.s6_scan(x, dt, a, b, c, interpret=False)
+    _DISPATCH_STATS["ssm_fallback"] += 1
+    return _ssm.s6_scan_ref(x, dt, a, b, c)
+
+
+def dispatched_ring_attention(q, ring_k, ring_v, layer, rows, lengths, *,
+                              window, scale=None):
+    """Decode attention over a window kept as a ring a sequence
+    (``paged_attention.ring_window_attention``): the paged kernel's window
+    form on a TPU, the gather reference with the same mask elsewhere;
+    counted as ``paged`` / ``paged_fallback``."""
+    tpu = _on_tpu() and _pa.supported(
+        q, jax.ShapeDtypeStruct(ring_k.shape[2:], ring_k.dtype),
+        jax.ShapeDtypeStruct((rows.shape[0], ring_k.shape[2]), jnp.int32))
+    _DISPATCH_STATS["paged" if tpu else "paged_fallback"] += 1
+    return _pa.ring_window_attention(q, ring_k, ring_v, layer, rows,
+                                     lengths, window=window, scale=scale,
+                                     ref=not tpu)
+
+
+def dispatched_window_flash(q, k, v, *, window, scale=None):
+    """Causal attention in which a query sees its own position and the
+    ``window - 1`` before it, on [B, S, H, D], forward only: the flash
+    forward with a window on a TPU where the shapes are supported, masked
+    plain attention elsewhere; counted as ``flash`` / ``flash_fallback``."""
+    if _on_tpu() and _fa.supported(q, k, v):
+        _DISPATCH_STATS["flash"] += 1
+        return _fa.flash_attention(q, k, v, causal=True, scale=scale,
+                                   window=window, interpret=False)
+    from ..nn.functional import attention as _att
+    _DISPATCH_STATS["flash_fallback"] += 1
+    d = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :] \
+        + (k.shape[1] - q.shape[1])
+    return _att.sdpa_reference(q, k, v, ((d >= 0) & (d < window))[None, None],
+                               scale=scale)
 
 
 def register(flash: bool = True, rms: bool = True, interpret: bool = False):

@@ -73,6 +73,18 @@ def k_loop_bounds(qi, span0, *, offset, block_q, block_k, count):
     return whole, end
 
 
+def k_window_bounds(qi, span0, *, window, offset, block_q, block_k, count):
+    """Where a window of ``window`` keys (a row sees columns > r + offset
+    - window) cuts the same span: sub-blocks before ``start`` lie wholly
+    behind every row's window, ``[start, clear)`` are crossed by its
+    edge, and from ``clear`` on it hides nothing."""
+    first = qi * block_q + offset - window + 1 - span0   # first row's edge
+    last = first + block_q - 1                           # the last row's
+    start = jnp.minimum(jnp.maximum(first, 0) // block_k, count)
+    clear = jnp.minimum(pl.cdiv(jnp.maximum(last, 0), block_k), count)
+    return start, clear
+
+
 def q_loop_bounds(ki, span0, *, offset, block_q, block_k, count):
     """The dkv kernel's turn of :func:`k_loop_bounds`: of a q span that
     starts at row ``span0``, sub-blocks ``[start, whole)`` are crossed by
@@ -98,10 +110,22 @@ def _loop(lo, hi, step):
     jax.lax.fori_loop(lo, hi, body, 0)
 
 
-def _walk_k(step, qi, span0, *, causal, count, **geometry):
+def _walk_k(step, qi, span0, *, causal, count, window=None, **geometry):
     """``step(c, crossed)`` for every sub-block of a K/V span that q block
-    ``qi`` sees, the ones it sees whole first."""
-    if causal:
+    ``qi`` sees, the ones it sees whole first. With a ``window`` (causal
+    only) the walk starts at the window's first sub-block: the ones its
+    edge crosses, the ones seen whole, the ones the diagonal crosses (a
+    sub-block both cross is walked once, masked)."""
+    if causal and window is not None:
+        whole, end = k_loop_bounds(qi, span0, count=count, **geometry)
+        start, clear = k_window_bounds(qi, span0, window=window,
+                                       count=count, **geometry)
+        edge = jnp.minimum(clear, end)
+        diag = jnp.maximum(whole, edge)
+        _loop(start, edge, lambda c: step(c, True))
+        _loop(edge, diag, lambda c: step(c, False))
+        _loop(diag, end, lambda c: step(c, True))
+    elif causal:
         whole, end = k_loop_bounds(qi, span0, count=count, **geometry)
         _loop(0, whole, lambda c: step(c, False))
         _loop(whole, end, lambda c: step(c, True))
@@ -114,7 +138,7 @@ def _walk_k(step, qi, span0, *, causal, count, **geometry):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
-                causal, offset, block_k):
+                causal, offset, block_k, window=None):
     block_q, d = q_ref.shape[1:]
     span = k_ref.shape[1]
     count = span // block_k
@@ -137,7 +161,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
         s = jax.lax.dot_general(q, k_ref[0, rows, :], _NT,
                                 preferred_element_type=jnp.float32)
         if crossed:
-            s = jnp.where(row_col >= diag0 + c * block_k, s, _NEG_INF)
+            seen = row_col >= diag0 + c * block_k
+            if window is not None:
+                # (a row with no key in its first sub-block adds ones to
+                # its sums there; the first key it does see, and the
+                # diagonal is always one, scales them by exp(-1e30) = 0)
+                seen = jnp.logical_and(
+                    seen, row_col < diag0 + c * block_k + window)
+            s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_s[:]                                 # [bq, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -149,7 +180,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
         m_s[:] = m_new
 
     _walk_k(step, qi, kj * span, causal=causal, offset=offset,
-            block_q=block_q, block_k=block_k, count=count)
+            block_q=block_q, block_k=block_k, count=count, window=window)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
@@ -163,11 +194,12 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _q_major(q, k, *, causal, block_q, block_k):
+def _q_major(q, k, *, causal, block_q, block_k, window=None):
     """Grid and BlockSpecs of the forward and dq kernels: (grid, a q
     block's rows, its row statistics, a K or V span). A span wholly above
     a q block's diagonal keeps the index of the last one that is not, so
-    that it is not fetched."""
+    that it is not fetched; with a ``window``, one wholly behind it the
+    index of the first that is not."""
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     group = bh // bkv
@@ -176,7 +208,11 @@ def _q_major(q, k, *, causal, block_q, block_k):
     if causal and spans > 1:
         def at(i, j):
             last = ((i + 1) * block_q - 1 + sk - sq) // span
-            return jnp.minimum(j, jnp.clip(last, 0, spans - 1))
+            j = jnp.minimum(j, jnp.clip(last, 0, spans - 1))
+            if window is None:
+                return j
+            first = (i * block_q + sk - sq - window + 1) // span
+            return jnp.maximum(j, jnp.clip(first, 0, spans - 1))
     else:
         def at(i, j):
             return j
@@ -191,15 +227,16 @@ def _q_major(q, k, *, causal, block_q, block_k):
     return (bh, sq // block_q, spans), row, stat, kv
 
 
-def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None):
     """q: [BH, Sq, D], already scaled; k/v: [BKV, Sk, D] with
     BH = BKV * group."""
     bh, sq, d = q.shape
     grid, row, stat, kv = _q_major(q, k, causal=causal, block_q=block_q,
-                                   block_k=block_k)
+                                   block_k=block_k, window=window)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal,
-                          offset=k.shape[1] - sq, block_k=block_k),
+                          offset=k.shape[1] - sq, block_k=block_k,
+                          window=window),
         name="flash_fwd",
         grid=grid,
         in_specs=[row, kv, kv],
@@ -453,16 +490,28 @@ def _blocks(q, k, block_q, block_k):
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None,
-                    block_q=None, block_k=None, interpret=False):
+                    block_q=None, block_k=None, window=None,
+                    interpret=False):
     """Flash attention on [B, S, H, D] (paddle layout); supports GQA
     (fewer kv heads) and causal masking. Differentiable (custom VJP,
     flash backward). ``block_q`` / ``block_k`` default to what the shape
     allows (``tiling.flash_blocks_for``). Sequence lengths must divide
     the block sizes — the dispatcher (kernels/__init__.py) falls back to
-    the XLA path otherwise."""
+    the XLA path otherwise. ``window`` (causal only, FORWARD only: no
+    backward pass is written for it) lets a query see its own position
+    and the ``window - 1`` before it: the K loop starts at the window's
+    first sub-block and the spans behind it are never fetched."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     bq, bk = _blocks(q, k, block_q, block_k)
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is a causal mask's lower bound")
+        b, _, h, _ = q.shape
+        out, _ = _fwd(_reshape_in(q * float(scale)), _reshape_in(k),
+                      _reshape_in(v), causal=True, block_q=bq, block_k=bk,
+                      interpret=interpret, window=int(window))
+        return _reshape_out(out, b, h)
     return _flash(q, k, v, float(scale), bool(causal), bq, bk, interpret)
 
 

@@ -96,7 +96,7 @@ def _pages_per_block(kv, ps, hd, itemsize, maxp) -> int:
 
 
 def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
-                   pages_per_block, quant):
+                   pages_per_block, quant, window=None):
     if quant:
         (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
          kbuf, vbuf, sems, slot_ref, acc, m_s, l_s) = refs
@@ -110,14 +110,22 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     length = len_ref[b]
-    nblk = pl.cdiv(length, tb)
+    # over a ring (``window``): the table's pages are a ring of max_pages *
+    # ps slots in which position p lies at slot p mod ring, so the slots
+    # that hold anything are the first min(length, ring)
+    ring = max_pages * ps
+
+    def held(n):
+        return n if window is None else jnp.minimum(n, ring)
+
+    nblk = pl.cdiv(held(length), tb)
 
     def block_dma(seq, blk, slot, start):
         """Start, or wait for, the copies of one block's LIVE pages: page
         ``blk * npb + j`` of sequence ``seq`` into row ``j`` of buffer
         ``slot``, all KV heads of the page in one copy. A table entry
         past the sequence's pages is never read."""
-        live = pl.cdiv(len_ref[seq], ps) - blk * npb
+        live = pl.cdiv(held(len_ref[seq]), ps) - blk * npb
         for j in range(npb):
             @pl.when(j < live)
             def _(j=j):
@@ -185,7 +193,16 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
         block_dma(b, i, cur, start=False)
 
         pos = i * tb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb), 2)
-        valid = pos < length
+        if window is None:
+            valid = pos < length
+        else:
+            # a slot's age: how far behind the newest position (length - 1,
+            # at slot (length - 1) mod ring) the position it holds lies;
+            # the window's positions are the ages under min(length, window)
+            age = jax.lax.rem(length - 1, jnp.int32(ring)) - pos
+            age = jnp.where(age < 0, age + ring, age)
+            valid = jnp.logical_and(age < jnp.minimum(length, window),
+                                    pos < ring)
         q = q_ref[0]                                     # [kv, gp, hd]
         # ps is a multiple of the sublane tile: a head's pages of the
         # block are [tb, hd] without moving data
@@ -244,7 +261,7 @@ def _pages_of(layer, block_tables, *pools):
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            scale=None, k_scales=None, v_scales=None,
-                           layer=None, interpret=False):
+                           layer=None, window=None, interpret=False):
     """Paged decode attention. q: [B, num_heads, head_dim]; k_pages /
     v_pages: [layers, num_pages, kv_heads, page_size, head_dim] with
     ``layer`` the int32 scalar (traced or not) that names the layer to
@@ -260,7 +277,12 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     pages are int8 codes (FLAGS_serving_kv_quant): the block table
     gathers each sequence's scales block by block (a tiny XLA gather),
     and dequantization folds into the two dots — page traffic stays
-    int8. Returns [B, num_heads, head_dim]."""
+    int8. With ``window`` a row of the table is a RING of ``max_pages *
+    page_size`` slots (position p at slot p mod ring) and ``lengths`` the
+    true lengths, however long: the keys read are those of the last
+    ``min(length, window)`` positions, and only the ring's pages are
+    fetched (the call is then named ``paged_decode_attn_window``).
+    Returns [B, num_heads, head_dim]."""
     quant = k_scales is not None
     bt, (k_pages, v_pages, k_scales, v_scales) = _pages_of(
         layer, block_tables, k_pages, v_pages, k_scales, v_scales)
@@ -278,7 +300,9 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     qg = q.reshape(B, kv, g, hd)
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    lengths = jnp.minimum(lengths.astype(jnp.int32), maxp * ps)
+    lengths = lengths.astype(jnp.int32)
+    if window is None:
+        lengths = jnp.minimum(lengths, maxp * ps)
 
     def per_seq(b, bt_, ln_):
         return (b, 0, 0, 0)
@@ -322,8 +346,9 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=ps,
-                          max_pages=maxp, pages_per_block=npb, quant=quant),
-        name="paged_decode_attn",
+                          max_pages=maxp, pages_per_block=npb, quant=quant,
+                          window=window),
+        name="paged_decode_attn" + ("" if window is None else "_window"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv, gp, hd), q.dtype),
         # the buffer in flight is handed from one sequence to the next
@@ -340,7 +365,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
                         scale=None, k_scales=None, v_scales=None,
-                        layer=None):
+                        layer=None, window=None):
     """Gather-based reference: same contract and masking semantics as the
     kernel (safe softmax — an empty sequence yields a zero row, never
     NaN), the pools with or without their layer axis as there. This is
@@ -375,7 +400,12 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     qf = q.astype(jnp.float32).reshape(B, kv, g, hd)
     s = jnp.einsum("bkgd,bmkpd->bkgmp", qf, kf) * scale
     pos = jnp.arange(maxp)[:, None] * ps + jnp.arange(ps)[None, :]
-    mask = pos[None] < lengths[:, None, None]          # [B, maxp, ps]
+    if window is None:
+        mask = pos[None] < lengths[:, None, None]      # [B, maxp, ps]
+    else:                                # the table's row is a ring
+        ring, n = maxp * ps, lengths[:, None, None]
+        age = jnp.mod(jnp.mod(n - 1, ring) - pos[None], ring)
+        mask = (age < jnp.minimum(n, window)) & (n > 0)
     s = jnp.where(mask[:, None, None], s, _NEG_INF)
     m = jnp.max(s, axis=(-2, -1), keepdims=True)
     e = jnp.where(mask[:, None, None], jnp.exp(s - m), 0.0)
@@ -410,3 +440,31 @@ def supported(q, k_pages, block_tables, quant=False) -> bool:
     # (Mosaic: "Slice shape along dimension 3 must be aligned to tiling
     # (128)") and cover the dtype's sublane tile (16 for bf16)
     return hd % 128 == 0 and ps % _sublane(q.dtype) == 0 and P >= 1
+
+
+def ring_table(layer, rows, ring_shape):
+    """The block table that reads rows ``rows`` [B] of layer ``layer`` of
+    a ring leaf ``[layers, rows, pages, kv, ps, hd]`` as pages of the leaf
+    flattened to ``[layers * rows * pages, kv, ps, hd]`` (a bitcast)."""
+    _, nrows, npages = ring_shape[:3]
+    first = (jnp.asarray(layer, jnp.int32) * nrows
+             + rows.astype(jnp.int32)) * npages
+    return first[:, None] + jnp.arange(npages, dtype=jnp.int32)[None, :]
+
+
+def ring_window_attention(q, ring_k, ring_v, layer, rows, lengths, *,
+                          window, scale=None, interpret=False, ref=False):
+    """Decode attention over a window kept as a ring a sequence: ``ring_k``
+    / ``ring_v`` [layers, rows, pages, kv_heads, page_size, head_dim]
+    (``pages * page_size`` slots a row, position p at slot p mod that),
+    slot i's row ``rows[i]``, ``lengths`` the true lengths (0: an empty
+    slot, a zero output row). The ring is read where it lies through
+    ``ragged_paged_attention``'s ``window`` form (``ref``: the gather
+    reference with the same mask)."""
+    bt = ring_table(layer, rows, ring_k.shape)
+    flat = [r.reshape((-1,) + r.shape[3:]) for r in (ring_k, ring_v)]
+    if ref:
+        return paged_attention_ref(q, *flat, bt, lengths, scale=scale,
+                                   window=window)
+    return ragged_paged_attention(q, *flat, bt, lengths, scale=scale,
+                                  window=window, interpret=interpret)
