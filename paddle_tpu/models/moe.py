@@ -257,9 +257,87 @@ def _expert_ffn(xe, lp):
                       _edeq(lp["e_down"], xe.dtype))
 
 
+def _rows(a, index):
+    """Rows of ``a`` at ``index`` (any shape); zeros where an index is
+    ``len(a)`` or more: an empty cell, a dropped slot."""
+    return jnp.take(a, index, axis=0, mode="fill", fill_value=0)
+
+
+def _slot_rows(a, dest):
+    """Rows of ``a`` at the slots' cells, slot-major: [k, T, D] for
+    ``dest`` [T, k], so that a sum over k adds whole [T, D] slabs (as
+    [T, k, D] the rows are copied into a layout that pads k to a tile's
+    sublanes)."""
+    return _rows(a, dest.T)
+
+
+# Capacity dispatch's token <-> grid map is a partial permutation: a kept
+# slot owns its cell and an occupied cell names its slot. The transpose of
+# a gather along it is a gather along its inverse, so the two row
+# movements bring their own backward rules and a train step holds no
+# scatter-add of rows (XLA's serialises, since rows might collide).
+# ``dest`` [T, k]: slot -> cell, E*C for a dropped slot; ``slot_of``
+# [E*C]: cell -> flat slot t*k + j, T*k for an empty cell; ``idx``
+# [E*C] = slot_of // k: cell -> token, T for an empty cell.
+
+@jax.custom_vjp
+def _dispatch_rows(x, idx, dest):
+    """xe[cell] = x[idx[cell]]: [T, D] -> [E*C, D]."""
+    return _rows(x, idx)
+
+
+def _dispatch_rows_fwd(x, idx, dest):
+    return _rows(x, idx), dest
+
+
+@jax.named_scope("moe.dispatch")
+def _dispatch_rows_bwd(dest, dxe):
+    # dx[t] = sum_j dxe[dest[t, j]], in float32 and cast once
+    dx = jnp.sum(_slot_rows(dxe, dest).astype(jnp.float32), axis=0)
+    return dx.astype(dxe.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(y, w, idx, slot_of, dest):
+    """routed[t] = sum_j w[t, j] * y[dest[t, j]]: [E*C, D] -> [T, D],
+    summed in float32."""
+    routed = jnp.sum(_slot_rows(y, dest).astype(jnp.float32)
+                     * w.T[..., None], axis=0)
+    return routed.astype(y.dtype)
+
+
+def _combine_rows_fwd(y, w, idx, slot_of, dest):
+    return _combine_rows(y, w, idx, slot_of, dest), (y, w, idx, slot_of,
+                                                     dest)
+
+
+@jax.named_scope("moe.combine")
+def _combine_rows_bwd(res, d):
+    y, w, idx, slot_of, dest = res
+    # dy[cell] = w[slot_of[cell]] * d[idx[cell]], read from d [T, D]: the
+    # [T*k, D] cotangent of the gathered rows never exists
+    w_cell = _rows(w.reshape(-1), slot_of)[:, None]
+    d_cell = _rows(d, idx).astype(jnp.float32)
+    dy = (d_cell * w_cell).astype(y.dtype)
+    # dw[t, j] = <y[dest[t, j]], d[t]>, taken cell by cell where both rows
+    # already lie, then read by the slots; a dropped slot reads zero
+    dw = _rows(jnp.sum(y.astype(jnp.float32) * d_cell, axis=-1), dest)
+    return dy, dw.astype(w.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def _moe_mlp_capacity(x, lp, config: MoEConfig, T):
     """Capacity gather dispatch (single-chip default): compute scales
-    with E*C ~ T*k*capacity_factor instead of E*T."""
+    with E*C ~ T*k*capacity_factor instead of E*T. Rows move between
+    token order and the [E, C] grid by gather in both directions, forward
+    and backward, through one pair of index vectors built once a call
+    (``slot_of`` / ``idx`` and ``dest``): the only scatter is the int32
+    one that builds ``slot_of``."""
     c = config
     E, k = c.num_experts, c.num_experts_per_tok
     C = moe_capacity(c, T)
@@ -273,15 +351,15 @@ def _moe_mlp_capacity(x, lp, config: MoEConfig, T):
         pos = jnp.sum((jnp.cumsum(oh, axis=0) - oh) * oh, axis=-1)  # [T*k]
         expert = topi.reshape(-1)                                   # [T*k]
         keep = pos < C
-        dest = expert * C + pos                                     # [T*k]
+        dest = jnp.where(keep, expert * C + pos, E * C)             # [T*k]
 
-        # Scatter each kept slot's TOKEN INDEX into the [E*C] grid; empty
-        # slots point at the appended zero row of xp (index T).
-        idx = jnp.full((E * C,), T, jnp.int32)
-        idx = idx.at[jnp.where(keep, dest, E * C)].set(
-            jnp.repeat(jnp.arange(T, dtype=jnp.int32), k), mode="drop")
-        xp = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
-        xe = jnp.take(xp, idx, axis=0).reshape(E, C, -1)        # [E, C, D]
+        # Scatter each kept slot's NUMBER into the [E*C] grid; an empty
+        # cell keeps T*k, and so names token T: a zero row to _rows.
+        slot_of = jnp.full((E * C,), T * k, jnp.int32).at[dest].set(
+            jnp.arange(T * k, dtype=jnp.int32), mode="drop")
+        idx = slot_of // k
+        dest = dest.reshape(T, k)
+        xe = _dispatch_rows(x, idx, dest).reshape(E, C, -1)     # [E, C, D]
 
     y = _expert_ffn(xe, lp)                                     # [E, C, D]
 
@@ -289,12 +367,9 @@ def _moe_mlp_capacity(x, lp, config: MoEConfig, T):
         # Combine: each (t, k) slot gathers its expert output row, scaled
         # by its (still-normalized) router weight; dropped slots
         # contribute 0.
-        yk = jnp.take(y.reshape(E * C, -1), jnp.where(keep, dest, 0),
-                      axis=0)
-        w = (topv.reshape(-1) * keep).astype(jnp.float32)[:, None]
-        routed = jnp.sum((yk.astype(jnp.float32) * w).reshape(T, k, -1),
-                         axis=1)
-        return routed.astype(x.dtype), aux
+        w = (topv * keep.reshape(T, k)).astype(jnp.float32)
+        routed = _combine_rows(y.reshape(E * C, -1), w, idx, slot_of, dest)
+        return routed, aux
 
 
 def _moe_mlp_dense(x, lp, config: MoEConfig, T, mesh):

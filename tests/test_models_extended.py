@@ -2,6 +2,8 @@
 DeepSeekMoE/Qwen2-MoE for EP, DiT/SD3 for diffusion). Strategy mirrors
 tests/test_models.py: tiny configs, loss decreases, sharded-vs-local
 parity on the 8-device CPU mesh."""
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -71,6 +73,61 @@ class TestMoE:
         assert moe.qwen2_moe_a14b().num_experts_per_tok == 8
         # param count sanity on tiny
         assert moe.count_params(moe.moe_tiny()) > 0
+
+
+def _oracle_slots(x, lp, config, T):
+    """(topv, aux, keep [T*k], dest [T*k]) of the layer's routing: GShard's
+    token-major priority order, slots past an expert's capacity dropped."""
+    E, C = config.num_experts, moe.moe_capacity(config, T)
+    topv, topi, aux = moe._route(x, lp, config)
+    oh = jax.nn.one_hot(topi.reshape(-1), E, dtype=jnp.int32)
+    pos = jnp.sum((jnp.cumsum(oh, axis=0) - oh) * oh, axis=-1)
+    return topv, aux, pos < C, topi.reshape(-1) * C + pos
+
+
+def _capacity_oracle(x, lp, config, T):
+    """The capacity layer as plain autodiff sees it: the take / take body
+    `_moe_mlp_capacity` had before its row movements got backward rules
+    of their own (PR 32), kept here as the oracle. Its gradient holds the
+    two row scatter-adds."""
+    c = config
+    E, k = c.num_experts, c.num_experts_per_tok
+    C = moe.moe_capacity(c, T)
+    topv, aux, keep, dest = _oracle_slots(x, lp, c, T)
+    idx = jnp.full((E * C,), T, jnp.int32)
+    idx = idx.at[jnp.where(keep, dest, E * C)].set(
+        jnp.repeat(jnp.arange(T, dtype=jnp.int32), k), mode="drop")
+    xp = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    xe = jnp.take(xp, idx, axis=0).reshape(E, C, -1)
+    y = moe._expert_ffn(xe, lp)
+    yk = jnp.take(y.reshape(E * C, -1), jnp.where(keep, dest, 0), axis=0)
+    w = (topv.reshape(-1) * keep).astype(jnp.float32)[:, None]
+    routed = jnp.sum((yk.astype(jnp.float32) * w).reshape(T, k, -1),
+                     axis=1)
+    return routed.astype(x.dtype), aux
+
+
+def _kept(x, lp, config, T):
+    """keep [T, k] of the layer's routing, by the oracle's bookkeeping."""
+    keep = _oracle_slots(x, lp, config, T)[2]
+    return np.asarray(keep).reshape(T, config.num_experts_per_tok)
+
+
+def _row_scatters(jaxpr, width):
+    """(primitive, operand shape) of every scatter in a jaxpr, nested
+    ones too, whose operand's last dimension is ``width``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            shape = eqn.invars[0].aval.shape
+            if shape and shape[-1] == width:
+                found.append((eqn.primitive.name, shape))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _row_scatters(sub, width)
+    return found
 
 
 class TestMoECapacityDispatch:
@@ -237,6 +294,160 @@ class TestMoECapacityDispatch:
             0, cfg.vocab_size, (2, 17)), jnp.int32)
         params, opt, loss = step(params, opt, ids)
         assert np.isfinite(float(loss))
+
+    # -- rows move by gather in both directions (PR 32): the token <->
+    # grid map is a partial permutation, so the backward of each gather
+    # is a gather through the inverse map
+
+    T = 96
+
+    def _layer(self, seed=0, **kw):
+        cfg = moe.moe_tiny(dispatch_mode="capacity", **kw)
+        params = moe.init_params(cfg, jax.random.key(seed))
+        lp = jax.tree.map(lambda a: a[0], params["layers"])
+        lp = {n: lp[n] for n in ("router", "e_gate", "e_up", "e_down")}
+        x = jax.random.normal(jax.random.key(seed + 1),
+                              (self.T, cfg.hidden_size), jnp.float32)
+        tgt = jax.random.normal(jax.random.key(seed + 2), x.shape,
+                                jnp.float32)
+        return cfg, lp, x, tgt
+
+    def _loss(self, layer, cfg, tgt):
+        def loss(x, lp):
+            routed, aux = layer(x, lp, cfg, self.T)
+            return jnp.sum(routed * tgt) + 0.1 * aux
+        return loss
+
+    # moe_tiny: E 4, k 2; the third case E 8, k 3 (a sum of three). C =
+    # T at factor E/k: nothing can drop. (factor, share of slots dropped)
+    @pytest.mark.parametrize("kw,dropped", [
+        (dict(capacity_factor=2.0), (0.0, 0.0)),
+        (dict(capacity_factor=0.55), (0.25, 0.45)),
+        (dict(capacity_factor=0.55, num_experts=8, num_experts_per_tok=3),
+         (0.25, 0.5)),
+    ], ids=["nothing-drops", "a-third-drops", "a-third-drops-k3"])
+    def test_loss_and_every_gradient_equal_plain_autodiff(self, kw, dropped):
+        cfg, lp, x, tgt = self._layer(**kw)
+        share = 1.0 - _kept(x, lp, cfg, self.T).mean()
+        assert dropped[0] <= share <= dropped[1], share
+        got = jax.value_and_grad(self._loss(moe._moe_mlp_capacity, cfg, tgt),
+                                 argnums=(0, 1))(x, lp)
+        want = jax.value_and_grad(self._loss(_capacity_oracle, cfg, tgt),
+                                  argnums=(0, 1))(x, lp)
+        np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
+        leaves = jax.tree_util.tree_leaves_with_path(got[1])
+        assert len(leaves) == 5            # x, router, three expert grids
+        for (path, a), b in zip(leaves, jax.tree.leaves(want[1])):
+            assert float(jnp.max(jnp.abs(b))) > 1e-4, path
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5,
+                err_msg=jax.tree_util.keystr(path))
+
+    def test_a_dropped_slot_gives_no_gradient(self):
+        cfg, lp, x, tgt = self._layer(seed=3, capacity_factor=0.01)
+        E, k, C = (cfg.num_experts, cfg.num_experts_per_tok,
+                   moe.moe_capacity(cfg, self.T))
+        keep = _kept(x, lp, cfg, self.T)
+        gone = ~keep.any(axis=1)           # tokens that lost every slot
+        assert C == 8 and gone.sum() > 8 and keep.any(axis=1).sum() > 8
+        # through the layer (the balance loss left out): a token whose
+        # slots all dropped gets no gradient at all, the others do
+        dx = jax.grad(lambda x: jnp.sum(
+            moe._moe_mlp_capacity(x, lp, cfg, self.T)[0] * tgt))(x)
+        dx = np.abs(np.asarray(dx)).sum(axis=1)
+        assert (dx[gone] == 0).all() and (dx[~gone] > 0).all()
+        # through each movement: with a cotangent of ones a token's row
+        # gradient counts its kept slots, and a dropped slot's router
+        # weight reads a zero row of y
+        _, topi, _ = moe._route(x, lp, cfg)
+        dest = np.full(keep.shape, E * C, np.int32)
+        slot_of = np.full((E * C,), self.T * k, np.int32)
+        fill = np.zeros(E, np.int32)
+        for s, e in enumerate(np.asarray(topi).reshape(-1)):
+            if fill[e] < C:
+                dest[s // k, s % k] = e * C + fill[e]
+                slot_of[e * C + fill[e]] = s
+            fill[e] += 1
+        assert ((dest < E * C) == keep).all()
+        dest, slot_of = jnp.asarray(dest), jnp.asarray(slot_of)
+        xe, vjp = jax.vjp(lambda x: moe._dispatch_rows(
+            x, slot_of // k, dest), x)
+        np.testing.assert_array_equal(
+            np.asarray(vjp(jnp.ones_like(xe))[0]),
+            np.repeat(keep.sum(axis=1, keepdims=True), x.shape[1], axis=1))
+        y = jax.random.normal(jax.random.key(9), xe.shape, jnp.float32)
+        w = jnp.ones(keep.shape, jnp.float32)
+        dw = jax.grad(lambda w: jnp.sum(moe._combine_rows(
+            y, w, slot_of // k, slot_of, dest) * tgt))(w)
+        dw = np.asarray(dw)
+        assert (dw[~keep] == 0).all() and (dw[keep] != 0).all()
+
+    def test_the_gradient_scatters_no_rows(self):
+        cfg, lp, x, tgt = self._layer()
+        D = cfg.hidden_size
+        assert D not in (self.T, cfg.num_experts, cfg.intermediate_size)
+        grad = lambda layer: jax.make_jaxpr(jax.grad(
+            self._loss(layer, cfg, tgt), argnums=(0, 1)))(x, lp).jaxpr
+        # the walker does find the oracle's two: [E*C, D] and [T+1, D]
+        C = moe.moe_capacity(cfg, self.T)
+        assert sorted(_row_scatters(grad(_capacity_oracle), D)) == [
+            ("scatter-add", (self.T + 1, D)),
+            ("scatter-add", (cfg.num_experts * C, D))]
+        assert _row_scatters(grad(moe._moe_mlp_capacity), D) == []
+        # what is left scatters int32 indices (slot_of) or the router's
+        # [T, E] (top_k's transpose), never a row
+        text = str(grad(moe._moe_mlp_capacity))
+        assert "scatter" in text
+
+    def test_train_step_with_scan_and_full_remat_matches(self, monkeypatch):
+        cfg = moe.moe_tiny(dispatch_mode="capacity", remat=True,
+                           capacity_factor=0.55)
+        assert cfg.num_hidden_layers == 2
+        ids = jnp.asarray(np.random.default_rng(4).integers(
+            0, cfg.vocab_size, (2, 49)), jnp.int32)
+
+        def one_step():
+            params = moe.init_params(cfg, jax.random.key(4))
+            step = moe.make_train_step(cfg, lr=1e-3)
+            return step(params, moe.adamw_init(params), ids)
+
+        got = one_step()
+        monkeypatch.setattr(moe, "_moe_mlp_capacity", _capacity_oracle)
+        want = one_step()
+        np.testing.assert_allclose(float(got[2]), float(want[2]), rtol=1e-6)
+        # the first moment after one step is (1 - b1) * gradient: linear
+        # in it, where the parameters' own update divides by its size
+        ms = jax.tree_util.tree_leaves_with_path(got[1]["m"])
+        for (path, a), b in zip(ms, jax.tree.leaves(want[1]["m"])):
+            scale = float(jnp.max(jnp.abs(b)))
+            assert scale > 0, path
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5 * scale,
+                err_msg=jax.tree_util.keystr(path))
+
+    def test_backward_gathers_keep_their_scopes(self):
+        # trace_scope files an op by the scopes in its op_name: the
+        # backward rules' gathers must read moe.dispatch / moe.combine,
+        # or prog.train.moe_ms loses them to unscoped_ms
+        cfg = moe.moe_tiny(dispatch_mode="capacity", remat=True)
+        params = moe.init_params(cfg, jax.random.key(0))
+        step = moe.make_train_step(cfg, lr=1e-3)
+        hlo = step.lower(params, moe.adamw_init(params),
+                         jnp.zeros((2, 17), jnp.int32)).compile().as_text()
+        back = {"moe.dispatch": 0, "moe.combine": 0}
+        for line in hlo.splitlines():
+            op = re.search(r"= (\S+) gather\(.*op_name=\"([^\"]*)\"", line)
+            if op is None or "transpose(" not in op.group(2) \
+                    or "rematted_computation" in op.group(2):
+                continue
+            parts = re.split(r"[/();:]", op.group(2))
+            scopes = [p for p in parts if p in back]
+            assert scopes, line
+            back[scopes[-1]] += 1
+        # dispatch's k-sum reads one gather; combine's dy reads two (the
+        # rows of d_routed and the cells' weights), its dw one (the
+        # cells' products, read by the slots)
+        assert back == {"moe.dispatch": 1, "moe.combine": 3}, back
 
 
 class TestDiT:
