@@ -235,6 +235,12 @@ class EngineStats:
         self.state_rows_in_use = 0
         self.peak_state_rows_in_use = 0
         self.state_rows_assigned = 0     # rows handed out, re-admissions too
+        # a family that routes (all 0 otherwise), summed over decode steps
+        # and layers: rows routed (the whole slot grid), experts that at
+        # least one row picked, rows the busiest expert took
+        self.expert_rows = 0
+        self.expert_reads = 0
+        self.expert_rows_busiest = 0
         self._occ_steps = 0      # decode steps weighted by slot count
         # shared-prefix radix cache (FLAGS_serving_prefix_cache)
         self.prefix_lookups = 0
@@ -303,13 +309,16 @@ def _decode_chunk(family, config, chunk, sampled, params, cache,
     KV, attend, sample the next. Done slots coast (writes dropped via
     length 0, outputs masked to -1; a recurrent state's row untouched).
     ``cache`` is the whole cache, one donated pytree; ``state_rows`` is
-    the slots' row table (None where the family keeps no state)."""
+    the slots' row table (None where the family keeps no state). The last
+    item is the expert every slot's token took, [chunk, layers, slots]
+    int8, from a family that routes (None from the others)."""
 
     def body(carry, key_t):
         cache, tok, kvl, done, gen = carry
         n = jnp.where(done, 0, kvl + 1)
-        cache, logits = cache_decode_step(
-            family, params, cache, block_tables, n, tok, config, state_rows)
+        cache, logits, picks = cache_decode_step(
+            family, params, cache, block_tables, n, tok, config, state_rows,
+            routes=True)
         kvl = jnp.where(done, kvl, kvl + 1)
         nxt = _sample_rows(logits, temps, key_t, sampled)
         emitted = jnp.where(done, -1, nxt)
@@ -317,11 +326,12 @@ def _decode_chunk(family, config, chunk, sampled, params, cache,
         hit_eos = (~done) & (nxt == eos)
         done = done | hit_eos | (gen >= max_new)
         tok = jnp.where(emitted >= 0, nxt, tok)
-        return (cache, tok, kvl, done, gen), emitted
+        return (cache, tok, kvl, done, gen), (
+            emitted, None if picks is None else picks[..., 0].astype(jnp.int8))
 
-    (cache, tok, kvl, done, gen), emitted = jax.lax.scan(
+    (cache, tok, kvl, done, gen), (emitted, picks) = jax.lax.scan(
         body, (cache, tokens, kv_len, done, gen), keys, length=chunk)
-    return cache, tok, kvl, done, gen, emitted
+    return cache, tok, kvl, done, gen, emitted, picks
 
 
 class ServingEngine:
@@ -330,7 +340,9 @@ class ServingEngine:
     ``family`` is a model module exposing the decoder seam
     (models.llama / models.moe; models.falcon_h1, which also keeps a
     recurrent state a sequence beside the pages; models.phi4flash, whose
-    declared stack keeps pages of one layer, rings and states);
+    declared stack keeps pages of one layer, rings and states;
+    models.zaya, which keeps two convolutions' tails a sequence and says
+    which expert each token took);
     ``params`` may be the
     bf16 tree or the weight-only int8 tree from
     ``family.quantize_weights``."""
@@ -482,6 +494,7 @@ class ServingEngine:
         # empty whenever step() has returned
         self._unfetched = deque()
         self._join = jax.jit(_join_first)
+        self._picks = None       # a routing family's chunk: not yet counted
         self._joins = set()      # group sizes whose join is compiled
         self._zero_rows = {}     # a greedy prefill group's temp and key, by g
         self._sampled = False
@@ -1590,7 +1603,8 @@ class ServingEngine:
                                      ck, ck_args, None, (1,), len(live_idx))
         with _trace.span("serving.decode_chunk.dispatch"), \
                 self._first_call(ck):
-            cache.pool, tok, kvl, done_a, gen_a, emitted = ck(*ck_args)
+            (cache.pool, tok, kvl, done_a, gen_a, emitted,
+             self._picks) = ck(*ck_args)
         self._dev.update(tokens=tok, kv_len=kvl, done=done_a, gen=gen_a)
         self._first_tokens()     # the prefills are done before the chunk is
         with _trace.span("serving.decode_chunk.fetch"):
@@ -1603,6 +1617,8 @@ class ServingEngine:
             emitted = np.asarray(emitted)                # [C, B]
         self._acct.downloaded(run)
         with _trace.span("serving.decode_chunk.emit"):
+            if self._picks is not None:
+                self._count_picks()
             counts = []
             cols = emitted.T.tolist()        # a slot's steps, a row each
             whole = bool((emitted >= 0).all())
@@ -1623,6 +1639,19 @@ class ServingEngine:
                     eos is not None and n > 0 and toks[-1] == eos)
         self._chunk_done(run, live_idx, C, counts)
         return True
+
+    def _count_picks(self):
+        """A chunk's picks ([steps, layers, slots]: the expert each slot's
+        row took, every slot of the grid, as the program routed them) into
+        the stats' three sums."""
+        with _trace.span("serving.decode_chunk.routes"):
+            picks = np.asarray(self._picks)
+            self._picks = None
+            took = (picks[..., None] == np.arange(
+                self.config.num_experts, dtype=picks.dtype)).sum(-2)
+            self.stats.expert_rows += picks.size
+            self.stats.expert_reads += int((took > 0).sum())
+            self.stats.expert_rows_busiest += int(took.max(-1).sum())
 
     def _chunk_done(self, run, live_idx: List[int], C: int, counts,
                     accepted=None):

@@ -30,6 +30,13 @@ Three layers:
   the same declaration for how many layers keep pages, a ring row or a
   state row (``_StackOps``; models/phi4flash.py: one pool layer that eight
   layers read, a ring a window layer, a state a Mamba-1 layer).
+  A family whose layers hand a second vector a token from one to the
+  next gives ``stream(x, config)`` (the layer scan's carry in place of
+  the residual stream alone), may keep weights out of the scan
+  (``params["experts"]``, read where they lie, by layer) and may return
+  a fourth item from ``paged_block``, which the programs hand back where
+  asked (``routes=True``): models/zaya.py, whose router carries its input
+  through the layers and whose blocks say which expert each token took.
   ``paged_prefill`` / ``paged_decode_step`` are the same two programs for
   a caller that holds the two pool halves and nothing else.
 
@@ -695,7 +702,9 @@ def _block(family, x, lp, c, cos, sin, attend, mix=None):
     head_dim], extra)`` is the program's own (plain causal attention, the
     paged kernel behind a page append, a gather of cached pages);
     ``extra`` is whatever it has to hand on (fresh K/V, updated pool
-    halves). Returns (x', attend's extra, the mixer's extra or None).
+    halves). Returns (x', attend's extra, the mixer's extra or None, what
+    the block hands its caller beside them or None: a routing family's
+    expert of each token).
 
     A family whose block is "ln1, q/k/v, rope, attention, wo, residual,
     then ``decode_mlp``" needs nothing more (llama, moe). Another gives
@@ -704,17 +713,48 @@ def _block(family, x, lp, c, cos, sin, attend, mix=None):
     program's way to the family's mixer, carrying the recurrent state."""
     compose = getattr(family, "paged_block", None)
     if compose is not None:
-        return compose(x, lp, c, cos, sin, attend, mix)
+        out = compose(x, lp, c, cos, sin, attend, mix)
+        return out if len(out) == 4 else (*out, None)
     q, k, v = _qkv_rope(x, lp, c, cos, sin)
     a, extra = attend(q, k, v)
     x = _attn_out(x, a, lp)
-    return family.decode_mlp(x, lp, c), extra, None
+    return family.decode_mlp(x, lp, c), extra, None, None
 
 
 def _embed(family, params, ids, c):
     scaled = getattr(family, "embed_tokens", None)
     return jnp.take(params["embed"], ids, axis=0) if scaled is None \
         else scaled(params, ids, c)
+
+
+def _stream(family, x, c):
+    """The layer scan's carry: the residual stream ``x``; or, where the
+    family's layers hand more than that from one to the next, what its
+    ``stream`` puts beside it."""
+    carried = getattr(family, "stream", None)
+    return x if carried is None else carried(x, c)
+
+
+def _residual(x):
+    """The residual stream of the layer scan's carry."""
+    return x[0] if isinstance(x, tuple) else x
+
+
+def _rotary_dim(c) -> int:
+    """The part of a head that rotates: all of it, but for a config that
+    says otherwise."""
+    return getattr(c, "rotary_dim", c.head_dim)
+
+
+def _layer_params(params, lp, layer):
+    """One layer's weights for its block: the scan's slice ``lp`` and,
+    where a family keeps weights beside ``params["layers"]``
+    (``params["experts"]``), those whole with the layer's index, for a
+    kernel that reads a layer where it lies (as a scan's slice XLA would
+    copy it out for the kernel every step)."""
+    if "experts" not in params:
+        return lp
+    return {**lp, "experts": params["experts"], "layer": layer}
 
 
 def _logits(family, params, x, c):
@@ -754,7 +794,7 @@ def _rows_a_pass(G: int) -> int:
 
 
 def cache_prefill(family, params, ids, config, cache, page_rows, slen,
-                  state_rows=None):
+                  state_rows=None, routes=False):
     """Consume a batch of padded prompts [G, S_pad] (S_pad a page
     multiple; rows are INDEPENDENT requests): writes every covered page
     of K/V into ``page_rows`` [G, S_pad/ps] (sentinel rows drop —
@@ -766,28 +806,36 @@ def cache_prefill(family, params, ids, config, cache, page_rows, slen,
     [G] as it is after ``slen[g]`` tokens, not after the padding; a
     dummy row names the row nobody owns. Rows being independent, a group
     of more than ``_PREFILL_PASS_ROWS`` rows with a state is taken in
-    equal passes, one after another in the same program."""
+    equal passes, one after another in the same program. With
+    ``routes`` a third item comes back: what every layer's block handed
+    out beside the stream ([L, G, S]: a routing family's expert of each
+    token; None from a family that hands nothing)."""
     G = ids.shape[0]
-    if "state" in cache:
-        per = _rows_a_pass(G)
-        if per < G:
-            def one_pass(cache, xs):
-                return _prefill_pass(family, params, xs[0], config, cache,
-                                     *xs[1:])
+    per = _rows_a_pass(G) if "state" in cache else G
+    if per < G:
+        def one_pass(cache, xs):
+            cache, *out = _prefill_pass(family, params, xs[0], config,
+                                        cache, *xs[1:])
+            return cache, tuple(out)
 
-            cache, logits = lax.scan(one_pass, cache, jax.tree.map(
-                lambda a: a.reshape(G // per, per, *a.shape[1:]),
-                (ids, page_rows, slen, state_rows)))
-            return cache, logits.reshape(G, -1)
-    return _prefill_pass(family, params, ids, config, cache, page_rows,
-                         slen, state_rows)
+        cache, (logits, picks) = lax.scan(one_pass, cache, jax.tree.map(
+            lambda a: a.reshape(G // per, per, *a.shape[1:]),
+            (ids, page_rows, slen, state_rows)))
+        logits = logits.reshape(G, -1)
+        if picks is not None:           # [passes, L, per, S] -> [L, G, S]
+            picks = jnp.moveaxis(picks, 0, 1).reshape(
+                picks.shape[1], G, -1)
+    else:
+        cache, logits, picks = _prefill_pass(
+            family, params, ids, config, cache, page_rows, slen, state_rows)
+    return (cache, logits, picks) if routes else (cache, logits)
 
 
 def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
                   state_rows):
     if hasattr(family, "segments"):
-        return _stack_prefill(family, params, ids, config, cache, page_rows,
-                              slen, state_rows)
+        return (*_stack_prefill(family, params, ids, config, cache,
+                                page_rows, slen, state_rows), None)
     c = config
     G, S = ids.shape
     pool_k, pool_v = cache["k"], cache["v"]
@@ -796,7 +844,7 @@ def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
               f"page_size {ps}")
     with jax.named_scope("embed"):
         x = _embed(family, params, ids, c)
-        cos, sin = rope_tables(S, c.head_dim, theta=c.rope_theta)
+        cos, sin = rope_tables(S, _rotary_dim(c), theta=c.rope_theta)
 
     from ..nn.functional.attention import sdpa_raw
 
@@ -810,11 +858,17 @@ def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
         def mix(h, lp):
             return family.mixer_prefill(h, lp, c, slen)
 
-    def step(carry, lp):
-        x, kvs, st = _block(family, carry, lp, c, cos, sin, attend, mix)
-        return x, (kvs, st)
+    def step(carry, xs):
+        lp = _layer_params(params, *xs) if "experts" in params else xs
+        x, kvs, st, picks = _block(family, carry, lp, c, cos, sin, attend,
+                                   mix)
+        return x, (kvs, st, picks)
 
-    x, ((ks, vs), st) = lax.scan(step, x, params["layers"])
+    x, ((ks, vs), st, picks) = lax.scan(
+        step, _stream(family, x, c),
+        (params["layers"], jnp.arange(L)) if "experts" in params
+        else params["layers"])
+    x = _residual(x)
     npad = S // ps
     with jax.named_scope("attn.kv_write"):
         # [L, G, S, kv, hd] -> [L, G, npad, kv, ps, hd] page grids
@@ -832,11 +886,11 @@ def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
         last = jnp.take_along_axis(
             x, jnp.maximum(slen - 1, 0)[:, None, None], axis=1)[:, 0]
         logits = _logits(family, params, last, c)
-    return out, logits
+    return out, logits, picks
 
 
 def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
-                      config, state_rows=None):
+                      config, state_rows=None, routes=False):
     """One incremental step over the fixed slot grid. ``tokens`` [B]
     sit at position ``lengths``-1 of their sequences (``lengths`` is the
     valid KV count INCLUDING each new token; 0 marks an inactive slot —
@@ -848,10 +902,12 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
     (``state_rows`` [B]; an inactive slot is sent to the row nobody
     owns, so its own row is untouched). Nothing is sliced out of the
     cache and nothing of its size is made, so a program that donates it
-    holds it once."""
+    holds it once. With ``routes`` a third item comes back, as
+    ``cache_prefill``'s: [L, B, 1]."""
     if hasattr(family, "segments"):
-        return _stack_decode(family, params, cache, block_tables, lengths,
-                             tokens, config, state_rows)
+        out = _stack_decode(family, params, cache, block_tables, lengths,
+                            tokens, config, state_rows)
+        return (*out, None) if routes else out
     c = config
     B = tokens.shape[0]
     pool_k, pool_v = cache["k"], cache["v"]
@@ -865,9 +921,10 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
         # (identical floats to a rope_tables row: same product, same cos
         # — but a fused elementwise chain instead of two table gathers
         # per step)
+        rd = _rotary_dim(c)
         inv = 1.0 / (c.rope_theta ** (
-            jnp.arange(0, c.head_dim, 2, jnp.float32) / c.head_dim))
-        freqs = posw.astype(jnp.float32)[:, None, None] * inv  # [B,1,hd/2]
+            jnp.arange(0, rd, 2, jnp.float32) / rd))
+        freqs = posw.astype(jnp.float32)[:, None, None] * inv  # [B,1,rd/2]
         cos, sin = jnp.cos(freqs), jnp.sin(freqs)
 
     page_idx = posw // ps
@@ -886,6 +943,7 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
     def step(carry, xs):
         x, pool_k, pool_v, state = carry
         lp, layer = xs
+        lp = _layer_params(params, lp, layer)
 
         def attend(q, k, v):
             kp = _kv_page_append(pool_k, layer, rows, off, k[:, 0], P)
@@ -905,20 +963,20 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
             def mix(h, lp):
                 return family.mixer_decode(h, lp, c, state, layer, srows)
 
-        x, (pool_k, pool_v), state = _block(family, x, lp, c, cos, sin,
-                                            attend, mix)
-        return (x, pool_k, pool_v, state), None
+        x, (pool_k, pool_v), state, picks = _block(
+            family, x, lp, c, cos, sin, attend, mix)
+        return (x, pool_k, pool_v, state), picks
 
-    (x, kc, vc, state), _ = lax.scan(
-        step, (x, pool_k, pool_v, state),
+    (x, kc, vc, state), picks = lax.scan(
+        step, (_stream(family, x, c), pool_k, pool_v, state),
         (params["layers"], jnp.arange(L)))
     out = {"k": kc, "v": vc}
     if state is not None:
         out["state"] = state
     with jax.named_scope("head"):
-        x = _rms(x, params["ln_f"], c.rms_norm_eps)
+        x = _rms(_residual(x), params["ln_f"], c.rms_norm_eps)
         logits = _logits(family, params, x[:, 0, :], c)
-    return out, logits
+    return (out, logits, picks) if routes else (out, logits)
 
 
 # ---------------------------------------------------------------------------
@@ -1226,7 +1284,7 @@ def cache_prefill_shared(family, params, ids, config, cache, page_rows,
                 a = sdpa_raw(q, ka, va, attn_mask=mask).reshape(G, S, -1)
             return a, (k, v)
 
-        x, kvs, _ = _block(family, carry, lp, c, cos, sin, attend)
+        x, kvs, _, _ = _block(family, carry, lp, c, cos, sin, attend)
         return x, kvs
 
     x, (ks, vs) = lax.scan(step, x, (params["layers"], pool_k, pool_v))
@@ -1352,7 +1410,7 @@ def cache_verify_window(family, params, tokens, config, cache,
                              attn_mask=mask[:, None]).reshape(B, C, -1)
             return a, (kp, vp)
 
-        x, kvs, _ = _block(family, carry, lp, c, cos, sin, attend)
+        x, kvs, _, _ = _block(family, carry, lp, c, cos, sin, attend)
         return x, kvs
 
     x, (kc, vc) = lax.scan(step, x, (params["layers"], pool_k, pool_v))

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from . import flash_attention as _fa
+from . import moe_experts as _moe
 from . import fused_ce as _fce
 from . import paged_attention as _pa
 from . import rms_norm as _rn
@@ -45,6 +46,8 @@ ssm_state_update_s6_ref = _ssm.ssm_state_update_s6_ref
 s6_scan = _ssm.s6_scan
 s6_scan_ref = _ssm.s6_scan_ref
 ring_window_attention = _pa.ring_window_attention
+expert_mlp = _moe.expert_mlp
+expert_mlp_ref = _moe.expert_mlp_ref
 
 __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "dispatched_fused_ce", "ring_attention",
@@ -55,6 +58,7 @@ __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "ssm_state_update_s6", "ssm_state_update_s6_ref", "s6_scan",
            "s6_scan_ref", "dispatched_s6_update", "dispatched_s6_scan", "ring_window_attention",
            "dispatched_ring_attention", "dispatched_window_flash",
+           "expert_mlp", "expert_mlp_ref", "dispatched_expert_mlp",
            "flash_attention_segments", "segment_attention_ref",
            "count_skipped_blocks", "dispatched_segment_attention",
            "register", "unregister", "dispatch_stats", "reset_dispatch_stats"]
@@ -71,7 +75,8 @@ _DISPATCH_STATS = {"flash": 0, "flash_fallback": 0,
                    "paged": 0, "paged_fallback": 0,
                    "paged_quant": 0, "paged_quant_fallback": 0,
                    "varlen": 0, "varlen_fallback": 0,
-                   "ssm": 0, "ssm_fallback": 0}
+                   "ssm": 0, "ssm_fallback": 0,
+                   "moe": 0, "moe_fallback": 0}
 
 
 def dispatch_stats() -> dict:
@@ -87,9 +92,11 @@ def dispatch_stats() -> dict:
     them: ``flash`` the forward with a window (``dispatched_window_flash``),
     ``paged`` the decode kernel over a window's ring
     (``paged_decode_attn_window``), ``ssm`` the Mamba-1 update
-    ``ssm_state_update_s6``. A serving cell asserts ``paged_fallback``,
-    ``ssm_fallback`` and (where its prefill has a window) ``flash_fallback``
-    stay 0."""
+    ``ssm_state_update_s6``; ``moe`` the dropless expert layer
+    ``moe_expert_mlp_*`` (``moe_experts.py``), whose fallback runs every
+    expert over every row. A serving cell asserts ``paged_fallback``,
+    ``ssm_fallback``, ``moe_fallback`` and (where its prefill has a window)
+    ``flash_fallback`` stay 0."""
     return dict(_DISPATCH_STATS)
 
 
@@ -269,6 +276,21 @@ def dispatched_s6_scan(x, dt, a, b, c):
         return _ssm.s6_scan(x, dt, a, b, c, interpret=False)
     _DISPATCH_STATS["ssm_fallback"] += 1
     return _ssm.s6_scan_ref(x, dt, a, b, c)
+
+
+def dispatched_expert_mlp(x, expert, gate, up, down, layer, *,
+                          name="moe_expert_mlp"):
+    """Each row of ``x`` [T, D] through its own expert's gated MLP, none
+    dropped (``kernels/moe_experts.py``): the Pallas kernel (``name`` in a
+    trace) on a TPU where the shapes are supported, reading only the
+    experts some row picked; every expert over every row in plain XLA
+    elsewhere (tier-1's CPU path). Counted as ``moe`` / ``moe_fallback``."""
+    if _on_tpu() and _moe.supported(x, gate):
+        _DISPATCH_STATS["moe"] += 1
+        return _moe.expert_mlp(x, expert, gate, up, down, layer, name=name,
+                               interpret=False)
+    _DISPATCH_STATS["moe_fallback"] += 1
+    return _moe.expert_mlp_ref(x, expert, gate, up, down, layer)
 
 
 def dispatched_ring_attention(q, ring_k, ring_v, layer, rows, lengths, *,

@@ -6,5 +6,7 @@ from . import llama  # noqa: F401
 from . import moe  # noqa: F401
 from . import ocr  # noqa: F401
 from . import phi4flash  # noqa: F401
+from . import zaya  # noqa: F401
 
-__all__ = ["llama", "moe", "dit", "ocr", "falcon_h1", "phi4flash"]
+__all__ = ["llama", "moe", "dit", "ocr", "falcon_h1", "phi4flash",
+           "zaya"]

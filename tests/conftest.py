@@ -53,6 +53,22 @@ STALE_SINCE_PR31 = (
     "test_present_configurations_keep_no_state_beside_keys_and_values"
     "[phi-4-mini-flash]",
     "test_the_cell_its_metrics_and_the_metrics_it_joined")
+# PR 33, the same kinds: ``...keep_no_state...[zaya1-8b]`` (the tails
+# of two convolutions a sequence), and in ``test_phi4flash_cell.py`` the two
+# tests that hold PR 31's entries to be the lists' LAST
+# (``test_the_cell_its_metrics_...``; ``test_benchmark_json_only_gained_
+# entries``, which strips its own cell from a list's end and no later one).
+# ``test_every_key_is_the_catalogs_and_nothing_is_cut`` there reads the
+# LAST configuration's ``reduced`` as its own.
+# ``tests/benchmark/test_zaya_cell.py`` holds what replaces them: the counted
+# rows; the three PRs' entries in order; the parent's entries unchanged but
+# for appended names.
+STALE_SINCE_PR33 = (
+    "test_present_configurations_keep_no_state_beside_keys_and_values"
+    "[zaya1-8b]",
+    "test_the_cell_its_metrics_and_the_metrics_it_joined",
+    "test_benchmark_json_only_gained_entries",
+    "test_every_key_is_the_catalogs_and_nothing_is_cut")
 
 
 def pytest_collection_modifyitems(items):
@@ -62,3 +78,8 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="the test predates the configuration "
                 "phi-4-mini-flash; see tests/conftest.py"))
+        elif item.name in STALE_SINCE_PR33 and (
+                "[" in item.name or "test_phi4flash_cell" in item.nodeid):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="the test predates the configuration "
+                "zaya1-8b; see tests/conftest.py"))

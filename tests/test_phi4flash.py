@@ -334,15 +334,21 @@ def test_pallas_s6_scan_against_the_plain_scan(G, S, N, C):
 
 def test_the_dispatchers_count_their_fallbacks_off_the_chip(model):
     from paddle_tpu import kernels
-    from paddle_tpu.inference import paged
 
     c, params = model
-    paged._paged_attention.clear_cache()    # traced once a process, else
-    paged._ring_attention.clear_cache()
+    # a dispatcher counts when it is TRACED, so the count is of what this
+    # process has not traced yet: every cache a trace can be taken from is
+    # emptied (the inner jits of ``paged``, a scan body's jaxpr, what an
+    # earlier test of this worker jitted at these shapes), and the
+    # dispatchers are the off-chip ones, not an interpret-mode
+    # registration an earlier file left behind
+    jax.clear_caches()
+    kernels.register()
     kernels.reset_dispatch_stats()
     P.forward(params, jnp.zeros((1, 8), jnp.int32), c)  # its three scans
     assert kernels.dispatch_stats()["ssm_fallback"] == 3
     assert sum(kernels.dispatch_stats().values()) == 3
+    jax.clear_caches()
     kernels.reset_dispatch_stats()
     cache = make_cache(c, 8, 1)
     rows = jnp.arange(8, dtype=jnp.int32)[None]
