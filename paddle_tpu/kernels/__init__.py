@@ -72,6 +72,7 @@ __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
 _DISPATCH_STATS = {"flash": 0, "flash_fallback": 0,
                    "rms": 0, "rms_fallback": 0,
                    "fused_ce": 0, "fused_ce_fallback": 0,
+                   "fused_ce_onepass": 0,
                    "paged": 0, "paged_fallback": 0,
                    "paged_quant": 0, "paged_quant_fallback": 0,
                    "varlen": 0, "varlen_fallback": 0,
@@ -84,7 +85,10 @@ def dispatch_stats() -> dict:
     how often the plain-XLA fallback (``*_fallback``). The table, kernel
     by counter: ``flash`` flash attention forward and backward
     (``flash_attention.py``); ``rms`` fused RMSNorm; ``fused_ce`` the
-    blockwise cross entropy; ``paged`` / ``paged_quant`` the paged decode
+    blockwise cross entropy, and ``fused_ce_onepass`` (counted by the rule
+    itself, ``fused_ce.py``) each trace of its one-pass differentiation
+    rule: 1 after a train step's trace, 0 after a forward-only one;
+    ``paged`` / ``paged_quant`` the paged decode
     attention ``paged_decode_attn`` and its int8-page arm; ``varlen`` the
     segment (packed) flash kernels; ``ssm`` the in-place recurrent state
     update ``ssm_state_update`` (``ssm.py``), whose fallback gathers the
