@@ -229,6 +229,10 @@ class EngineStats:
         self.tokens_generated = 0    # incl. the token sampled at prefill
         self.tokens_decoded = 0      # emitted by decode steps only
         self.tokens_prefilled = 0
+        # rows x width the prefill programs computed over, padding and
+        # dummy rows included: tokens_prefilled over it is how full the
+        # power-of-two grid was
+        self.prefill_grid_tokens = 0
         self.tokens_discarded = 0    # thrown away by preemption recompute
         self.peak_pages_in_use = 0
         # rows of recurrent state (a family that keeps one; else all 0)
@@ -266,9 +270,17 @@ class EngineStats:
                 "expired": self.expired, "shed": self.shed,
                 "decode_steps": self.decode_steps,
                 "tokens_generated": self.tokens_generated,
+                "tokens_decoded": self.tokens_decoded,
                 "tokens_prefilled": self.tokens_prefilled,
+                "prefill_grid_tokens": self.prefill_grid_tokens,
                 "tokens_discarded": self.tokens_discarded,
                 "peak_pages_in_use": self.peak_pages_in_use,
+                "state_rows_in_use": self.state_rows_in_use,
+                "peak_state_rows_in_use": self.peak_state_rows_in_use,
+                "state_rows_assigned": self.state_rows_assigned,
+                "expert_rows": self.expert_rows,
+                "expert_reads": self.expert_reads,
+                "expert_rows_busiest": self.expert_rows_busiest,
                 "batch_occupancy": round(self.occupancy(), 4),
                 "prefix_lookups": self.prefix_lookups,
                 "prefix_hits": self.prefix_hits,
@@ -1363,9 +1375,11 @@ class ServingEngine:
                 pf_args = (self.params, pf_kwargs.pop("ids"), cache.pool)
             run = self._acct.dispatching(spec_key, pf, pf_args, pf_kwargs,
                                          (2,), n)
-            with _trace.span("serving.prefill.dispatch"), \
+            with _trace.span("serving.prefill.dispatch", rows=g, width=s_eff,
+                             tokens=int(slen[:n].sum())), \
                     self._first_call(pf):
                 cache.pool, tok_a = pf(*pf_args, **pf_kwargs)
+            stats.prefill_grid_tokens += g * s_eff
             # the slots are taken now, with all that no token decides
             for j, (r, slot) in enumerate(zip(group, slots)):
                 tail = int(slen[j])
@@ -1462,7 +1476,9 @@ class ServingEngine:
               serving.step.admit          policy, page allocation, grouping
                 serving.prefill           per admitted group
                   .build                  numpy rows, keys, their upload
-                  .dispatch               the jitted call
+                  .dispatch               the jitted call (rows, width:
+                                          the grid it computes over;
+                                          tokens: the real ones in it)
                     serving.compile       first call of a program only
                   .fetch                  the download that waits
                   .emit                   first tokens to their slots

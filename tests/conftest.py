@@ -70,6 +70,16 @@ STALE_SINCE_PR33 = (
     "test_benchmark_json_only_gained_entries",
     "test_every_key_is_the_catalogs_and_nothing_is_cut")
 
+# PR 35 appended five per-layer metrics and no cell: the two tests of
+# ``test_zaya_cell.py`` that hold PR 33's entries to be ``per_layer``'s LAST
+# eight, and the list to be three longer than its parent's.
+# ``tests/benchmark/test_prefill_metrics.py`` holds what replaces them, by
+# name and not by position (every entry the parent had, unchanged and in
+# order; the five new ones), so the next PR that appends breaks nothing.
+STALE_SINCE_PR35 = (
+    "test_the_cell_its_metrics_and_the_metrics_it_joined",
+    "test_benchmark_json_only_gained_entries")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -83,3 +93,8 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="the test predates the configuration "
                 "zaya1-8b; see tests/conftest.py"))
+        elif item.name in STALE_SINCE_PR35 \
+                and "test_zaya_cell" in item.nodeid:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="the test predates PR 35's five "
+                "per-layer metrics; see tests/conftest.py"))

@@ -216,8 +216,11 @@ def live(model):
                "peak_pages_in_use"} <= set(vars(e.stats)),
     lambda e: callable(e.autoscale_payload) and e.draining is False
     and e.drain_complete is False,
+    # sched.prefill_fill_pct: one prompt of 5 in a bucket of 8 (PR 35)
+    lambda e: vars(e.stats)["prefill_grid_tokens"] == 8
+    and e.stats.tokens_prefilled == 5,
 ], ids=["_bucket", "_prefill_fns", "queue", "slots.req", "slots.tokens",
-        "outputs", "pages", "stats", "public"])
+        "outputs", "pages", "stats", "public", "prefill_grid"])
 def test_benchmark_reads_exist(live, read):
     assert read(live)
 
