@@ -398,6 +398,8 @@ def main(argv=None) -> int:
         d = served["dispatch"]
         require(d.get("paged", 0) >= 1 and not d.get("paged_fallback"),
                 f"server decode did not trace the Pallas paged kernel: {d}")
+        require(d.get("kv_write", 0) >= 1 and not d.get("kv_write_fallback"),
+                f"server decode did not trace the in-place KV write: {d}")
         require(len(served["buckets"]) <= 2,
                 f"more than two prefill buckets: {served['buckets']}")
 

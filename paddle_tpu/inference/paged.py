@@ -646,40 +646,65 @@ def _kv_pool_gather(pool, rows, dtype):
     return pool[rows].astype(dtype)
 
 
-@jax.named_scope("attn.kv_write")
 def _kv_page_append(leaf, layer, rows, off, val, P):
     """Append one token's [B, kv, hd] values at slot ``off`` of pages
-    ``rows`` of layer ``layer`` (sentinel ``P`` drops) — the decode-step
-    write. ``leaf`` is the WHOLE pool half [L, P, kv, ps, hd], the layer
-    scan's carry: the scatter names (layer, page) and touches nothing
-    else, so the pool stays one buffer, updated in place. Quantized pools
-    rescale the whole touched page: gather, dequantize, zero the
-    not-yet-written tail slots (a reused page's stale codes must not
-    inflate the scale), insert the token, requantize under the page's
-    fresh absmax, and scatter codes + scale row under one drop mask.
-    Committed slots re-round at most once per scale change — bounded
-    by page_size writes, inside the decode-parity SQNR budget."""
+    ``rows`` of layer ``layer`` (sentinel ``P`` drops) of a QUANTIZED pool
+    half, the ``{"q", "s"}`` pair — ``_kv_token_append``'s int8 arm.
+    ``leaf`` is the WHOLE pool half, the layer scan's carry: the scatters
+    name (layer, page) and touch nothing else, so the pool stays one
+    buffer, updated in place. The whole touched page is rescaled: gather,
+    dequantize, zero the not-yet-written tail slots (a reused page's
+    stale codes must not inflate the scale), insert the token, requantize
+    under the page's fresh absmax, and scatter codes + scale row under
+    one drop mask. Committed slots re-round at most once per scale
+    change — bounded by page_size writes, inside the decode-parity SQNR
+    budget."""
     B, kv = val.shape[0], val.shape[1]
     kvi = jnp.arange(kv)
-    if isinstance(leaf, dict):
-        ps = leaf["q"].shape[3]
-        rc = jnp.clip(rows, 0, P - 1)
-        page = (leaf["q"][layer, rc].astype(jnp.float32)
-                * leaf["s"][layer, rc][..., None, None])   # [B, kv, ps, hd]
-        keep = jnp.arange(ps)[None, None, :, None] \
-            <= off[:, None, None, None]
-        page = jnp.where(keep, page, 0.0)
-        page = page.at[jnp.arange(B)[:, None], kvi[None, :],
-                       off[:, None]].set(val.astype(jnp.float32),
-                                         unique_indices=True)
-        s = jnp.max(jnp.abs(page), axis=(-2, -1)) / _KV_QMAX
-        q = _kv_quantize(page, s[..., None, None])
-        return {"q": leaf["q"].at[layer, rows[:, None], kvi[None, :]].set(
-                    q, mode="drop", unique_indices=True),
-                "s": leaf["s"].at[layer, rows[:, None], kvi[None, :]].set(
-                    s, mode="drop", unique_indices=True)}
-    return leaf.at[layer, rows[:, None], kvi[None, :], off[:, None]].set(
-        val.astype(leaf.dtype), mode="drop", unique_indices=True)
+    ps = leaf["q"].shape[3]
+    rc = jnp.clip(rows, 0, P - 1)
+    page = (leaf["q"][layer, rc].astype(jnp.float32)
+            * leaf["s"][layer, rc][..., None, None])       # [B, kv, ps, hd]
+    keep = jnp.arange(ps)[None, None, :, None] <= off[:, None, None, None]
+    page = jnp.where(keep, page, 0.0)
+    page = page.at[jnp.arange(B)[:, None], kvi[None, :],
+                   off[:, None]].set(val.astype(jnp.float32),
+                                     unique_indices=True)
+    s = jnp.max(jnp.abs(page), axis=(-2, -1)) / _KV_QMAX
+    q = _kv_quantize(page, s[..., None, None])
+    return {"q": leaf["q"].at[layer, rows[:, None], kvi[None, :]].set(
+                q, mode="drop", unique_indices=True),
+            "s": leaf["s"].at[layer, rows[:, None], kvi[None, :]].set(
+                s, mode="drop", unique_indices=True)}
+
+
+# Behind a ``jit`` of its own, as the decode kernels further down are: a
+# Pallas kernel's trace is not cached, and every decode-chunk program of a
+# cell writes the same shapes.
+@jax.jit
+def _kv_token_write(pool_k, pool_v, layer, rows, off, k, v):
+    from ..kernels import dispatched_kv_token_write
+
+    return dispatched_kv_token_write(pool_k, pool_v, layer, rows, off, k, v)
+
+
+@jax.named_scope("attn.kv_write")
+def _kv_token_append(pool_k, pool_v, layer, rows, off, k, v):
+    """Append one token's keys and values ``k``, ``v`` [B, kv, hd] at
+    slot ``off`` of pages ``rows`` of layer ``layer`` of BOTH pool halves
+    — the decode-step write. A half is the WHOLE leaf ``[L, P, kv, ps,
+    hd]`` (a ring leaf ``[L, rows, pages, ...]``: ``rows`` then names
+    ``row * pages + page``), the layer scan's carry, and comes back in
+    its own buffer: one ``kv_token_write`` (``kernels/kv_write.py``) puts
+    each live slot's row into its sublane tile of both halves in place
+    and touches nothing else. ``rows`` at the sentinel (the pages of a
+    layer) write nothing; live slots name different pages. The int8 pair
+    goes a half at a time through ``_kv_page_append``."""
+    if isinstance(pool_k, dict):
+        P = pool_k["q"].shape[1]
+        return (_kv_page_append(pool_k, layer, rows, off, k, P),
+                _kv_page_append(pool_v, layer, rows, off, v, P))
+    return _kv_token_write(pool_k, pool_v, layer, rows, off, k, v)
 
 
 @jax.named_scope("attn.proj")
@@ -946,8 +971,8 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
         lp = _layer_params(params, lp, layer)
 
         def attend(q, k, v):
-            kp = _kv_page_append(pool_k, layer, rows, off, k[:, 0], P)
-            vp = _kv_page_append(pool_v, layer, rows, off, v[:, 0], P)
+            kp, vp = _kv_token_append(pool_k, pool_v, layer, rows, off,
+                                      k[:, 0], v[:, 0])
             with jax.named_scope("attn.kernel"):
                 if quant:
                     a = dispatched_paged_attention(
@@ -1087,10 +1112,9 @@ class _StackOps:
             at = jnp.take_along_axis(self.tables, (posw // self.ps)[:, None],
                                      axis=1)[:, 0]
             at = jnp.where(self.lengths > 0, at, self.P)  # inactive: drop
-            for half, t in (("k", k), ("v", v)):
-                self.cache[half] = _kv_page_append(
-                    self.cache[half], layer, at, posw % self.ps, t[:, 0],
-                    self.P)
+            self.cache["k"], self.cache["v"] = _kv_token_append(
+                self.cache["k"], self.cache["v"], layer, at, posw % self.ps,
+                k[:, 0], v[:, 0])
         with jax.named_scope("attn.kernel"):
             if layer in self.prompt_kv:
                 return _last_position_attention(
@@ -1127,13 +1151,13 @@ class _StackOps:
                     for r, t in ((rk, k), (rv, v)))
             self.state = {**self.state, "ring_k": rk, "ring_v": rv}
             return a
-        with jax.named_scope("attn.kv_write"):
-            slot = jnp.mod(self.lengths - 1, ring)
-            at = (layer, self.rows[:, None], (slot // ps)[:, None],
-                  jnp.arange(kv)[None, :], (slot % ps)[:, None])
-            rk, rv = (r.at[at].set(t[:, 0].astype(r.dtype),
-                                   unique_indices=False)
-                      for r, t in ((rk, k), (rv, v)))
+        # the ring's pages are pages: (row, slot // ps) of the leaf seen
+        # as [layers, rows * pages, ...]; an inactive slot writes nothing
+        slot = jnp.mod(self.lengths - 1, ring)
+        at = jnp.where(self.lengths > 0, self.rows * pages + slot // ps,
+                       rk.shape[1] * pages)
+        rk, rv = _kv_token_append(rk, rv, layer, at, slot % ps,
+                                  k[:, 0], v[:, 0])
         self.state = {**self.state, "ring_k": rk, "ring_v": rv}
         with jax.named_scope("attn.kernel"):
             return _ring_attention(

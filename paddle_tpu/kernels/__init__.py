@@ -5,7 +5,8 @@ the flash-attn wrapper (gpu/flash_attn_kernel.cu). TPU-native: hand-written
 pallas kernels for the ops where XLA's automatic fusion is not enough —
 flash attention (tiled online softmax on the MXU), paged decode attention,
 the in-place recurrent state update of a Mamba-2 layer
-(``ssm_state_update``) and fused RMSNorm; the
+(``ssm_state_update``), a decode step's token written into both halves of
+the page pool in place (``kv_token_write``) and fused RMSNorm; the
 rest of the reference's fused set (bias+act, rope, swiglu) is left to XLA
 fusion, which already emits single kernels for those elementwise chains.
 
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 from . import flash_attention as _fa
 from . import moe_experts as _moe
 from . import fused_ce as _fce
+from . import kv_write as _kw
 from . import paged_attention as _pa
 from . import rms_norm as _rn
 from . import ssm as _ssm
@@ -46,6 +48,8 @@ ssm_state_update_s6_ref = _ssm.ssm_state_update_s6_ref
 s6_scan = _ssm.s6_scan
 s6_scan_ref = _ssm.s6_scan_ref
 ring_window_attention = _pa.ring_window_attention
+kv_token_write = _kw.kv_token_write
+kv_token_write_ref = _kw.kv_token_write_ref
 expert_mlp = _moe.expert_mlp
 expert_mlp_ref = _moe.expert_mlp_ref
 
@@ -59,6 +63,8 @@ __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "s6_scan_ref", "dispatched_s6_update", "dispatched_s6_scan", "ring_window_attention",
            "dispatched_ring_attention", "dispatched_window_flash",
            "expert_mlp", "expert_mlp_ref", "dispatched_expert_mlp",
+           "kv_token_write", "kv_token_write_ref",
+           "dispatched_kv_token_write",
            "flash_attention_segments", "segment_attention_ref",
            "count_skipped_blocks", "dispatched_segment_attention",
            "register", "unregister", "dispatch_stats", "reset_dispatch_stats"]
@@ -77,7 +83,12 @@ _DISPATCH_STATS = {"flash": 0, "flash_fallback": 0,
                    "paged_quant": 0, "paged_quant_fallback": 0,
                    "varlen": 0, "varlen_fallback": 0,
                    "ssm": 0, "ssm_fallback": 0,
-                   "moe": 0, "moe_fallback": 0}
+                   "moe": 0, "moe_fallback": 0,
+                   "kv_write": 0, "kv_write_fallback": 0}
+
+# register(interpret=True): the dispatchers with no seam of their own to
+# install into run their kernels in interpret mode on any backend
+_INTERPRET = False
 
 
 def dispatch_stats() -> dict:
@@ -98,9 +109,13 @@ def dispatch_stats() -> dict:
     (``paged_decode_attn_window``), ``ssm`` the Mamba-1 update
     ``ssm_state_update_s6``; ``moe`` the dropless expert layer
     ``moe_expert_mlp_*`` (``moe_experts.py``), whose fallback runs every
-    expert over every row. A serving cell asserts ``paged_fallback``,
-    ``ssm_fallback``, ``moe_fallback`` and (where its prefill has a window)
-    ``flash_fallback`` stay 0."""
+    expert over every row; ``kv_write`` a decode step's token written
+    into both pool halves in place, ``kv_token_write``
+    (``kv_write.py``: once a trace of ``inference/paged.py``'s inner
+    ``jit``, so once a process a shape), whose fallback is two scatters
+    of a row a (slot, head). A serving cell asserts ``paged_fallback``,
+    ``ssm_fallback``, ``moe_fallback``, ``kv_write_fallback`` and (where
+    its prefill has a window) ``flash_fallback`` stay 0."""
     return dict(_DISPATCH_STATS)
 
 
@@ -297,6 +312,23 @@ def dispatched_expert_mlp(x, expert, gate, up, down, layer, *,
     return _moe.expert_mlp_ref(x, expert, gate, up, down, layer)
 
 
+def dispatched_kv_token_write(pool_k, pool_v, layer, rows, off, k, v):
+    """A decode step's new keys and values ``k``, ``v`` [B, kv, hd] into
+    row ``off`` of pages ``rows`` of layer ``layer`` of both pool halves
+    (``kernels/kv_write.py``): the Pallas kernel ``kv_token_write`` on a
+    TPU where the pool is supported (both halves updated in place, a
+    sublane tile a slot), the two scatters in plain XLA elsewhere
+    (tier-1's CPU path, a page under a tile, a head off the lanes);
+    counted as ``kv_write`` / ``kv_write_fallback``. Returns the two
+    pools."""
+    if (_INTERPRET or _on_tpu()) and _kw.supported(pool_k, k):
+        _DISPATCH_STATS["kv_write"] += 1
+        return _kw.kv_token_write(pool_k, pool_v, layer, rows, off, k, v,
+                                  interpret=_INTERPRET)
+    _DISPATCH_STATS["kv_write_fallback"] += 1
+    return _kw.kv_token_write_ref(pool_k, pool_v, layer, rows, off, k, v)
+
+
 def dispatched_ring_attention(q, ring_k, ring_v, layer, rows, lengths, *,
                               window, scale=None):
     """Decode attention over a window kept as a ring a sequence
@@ -336,9 +368,12 @@ def register(flash: bool = True, rms: bool = True, interpret: bool = False):
     multi-host jax.distributed.initialize and platform selection must be
     able to run first): on a TPU the kernels compile natively, anywhere
     else they fall back to the XLA math. ``interpret=True`` (tests) runs
-    the flash and rms kernels in pallas interpret mode on any backend."""
+    the flash and rms kernels, and the decode step's KV write, in pallas
+    interpret mode on any backend."""
     from ..nn.functional import attention as _att
     from ..nn.functional import norm as _norm
+    global _INTERPRET
+    _INTERPRET = interpret
     if flash:
         _att.register_flash_impl(_make_flash_dispatch(interpret))
         # the segment (sequence-packed) dispatcher self-gates on the
@@ -351,6 +386,8 @@ def register(flash: bool = True, rms: bool = True, interpret: bool = False):
 def unregister():
     from ..nn.functional import attention as _att
     from ..nn.functional import norm as _norm
+    global _INTERPRET
+    _INTERPRET = False
     _att.register_flash_impl(None)
     _att.register_segment_impl(None)
     _norm.register_rms_impl(None)

@@ -49,6 +49,10 @@ class TestPhases:
             chip_smoke.train_phase(L.llama_tiny(), batch=2, seq=16)
 
     def test_server_answers_and_checks_attention(self):
+        from paddle_tpu.inference import paged
+
+        # counted once a trace of the inner jit: forget an earlier test's
+        paged._kv_token_write.clear_cache()
         out = chip_smoke.serve_phase(
             L.llama_tiny(), prompt_lens=(20, 9, 30, 12),
             new_tokens=(6, 4, 8, 5), num_slots=2, page_size=8)
@@ -58,6 +62,9 @@ class TestPhases:
         # off the chip the decode attention is the reference, and counted
         assert out["dispatch"]["paged_fallback"] >= 1
         assert "paged" not in out["dispatch"]
+        # and the decode step's KV write is the two scatters, counted
+        assert out["dispatch"]["kv_write_fallback"] >= 1
+        assert "kv_write" not in out["dispatch"]
 
 
 class TestNoOffChipMode:

@@ -338,8 +338,12 @@ def _trace_train(packed):
 
 
 def _trace_decode(pages):
+    from paddle_tpu.inference import paged
     from paddle_tpu.inference.paged import init_pool, paged_decode_step
     from paddle_tpu.models import llama as L
+    # the KV write sits behind a jit of its own, whose cached trace holds
+    # whichever arm the dispatcher took when it was made
+    paged._kv_token_write.clear_cache()
     cfg = L.llama_tiny(dtype=jnp.bfloat16, hidden_size=256,
                        num_attention_heads=2, num_key_value_heads=2)
     params = jax.eval_shape(lambda k: L.init_params(cfg, k),
@@ -358,22 +362,29 @@ def test_decode_step_carries_the_pool_and_never_cuts_a_layer_out(
         monkeypatch, on_tpu):
     """Jaxpr level: the decode step's layer scan has both pool halves in
     its carry and no stacked output (a scan's ys is a fresh buffer: a
-    second pool and a copy of it every step); the kernel's operands are
-    the pool halves whole, [L, P, kv, ps, hd] read as [L * P, kv, ps, hd];
-    and no equation makes one layer [P, kv, ps, hd] of a half, on either
-    arm of the dispatcher."""
+    second pool and a copy of it every step); the two kernels' operands
+    are the pool halves whole, [L, P, kv, ps, hd] read as [L * P, kv, ps,
+    hd], and the write's two results are halves whole too; and no
+    equation makes one layer [P, kv, ps, hd] of a half, on either arm of
+    the dispatchers."""
     from paddle_tpu import kernels
     monkeypatch.setattr(kernels, "_on_tpu", lambda: on_tpu)
     jaxpr = _trace_decode(12).jaxpr    # (the tables name 8 pages: a gather)
     half = (2, 12, 2, 16, 128)               # llama_tiny here, 12 pages of 16
-    scan, = _eqns(jaxpr, "scan")
+    # the layer scan (the write kernel's loops over slots are scans too)
+    scan, = [e for e in _eqns(jaxpr, "scan")
+             if half in {v.aval.shape for v in e.outvars}]
     carried = scan.outvars[:scan.params["num_carry"]]
     assert [v.aval.shape for v in carried].count(half) == 2
     assert len(scan.outvars) == scan.params["num_carry"]       # no ys
     if on_tpu:
-        call, = _eqns(jaxpr, "pallas_call")
+        calls = {e.params["name"]: e for e in _eqns(jaxpr, "pallas_call")}
+        assert set(calls) == {"kv_token_write", "paged_decode_attn"}
         whole = (half[0] * half[1],) + half[2:]
-        assert [v.aval.shape for v in call.invars].count(whole) == 2
+        for call in calls.values():
+            assert [v.aval.shape for v in call.invars].count(whole) == 2
+        assert [v.aval.shape for v in
+                calls["kv_token_write"].outvars] == [whole, whole]
 
     assert half[1:] not in {v.aval.shape for e in _walk(jaxpr)
                             for v in e.outvars}
@@ -391,7 +402,7 @@ def _trace_rms(_):
     (_trace_train, False, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_trace_train, True, {"flash_seg_fwd", "flash_seg_bwd_dq",
                           "flash_seg_bwd_dkv"}),
-    (_trace_decode, None, {"paged_decode_attn"}),
+    (_trace_decode, None, {"paged_decode_attn", "kv_token_write"}),
     (_trace_rms, None, {"rms_norm_fwd", "rms_norm_bwd"}),
 ], ids=["train_step", "packed_train_step", "decode_step", "rms_norm"])
 def test_every_pallas_call_of_the_main_paths_is_named(monkeypatch, trace,

@@ -22,6 +22,7 @@ The load-bearing contracts:
   same on every rank (2-process launch CLI, slow lane), served from
   rank 0's /metrics?scope=fleet without peers joining the scrape.
 """
+import gc
 import json
 import math
 import os
@@ -99,10 +100,21 @@ def _server_threads():
 # server lifecycle
 # ---------------------------------------------------------------------------
 
+def _collect_dead_engines():
+    """An earlier test file's engine that died inside a reference cycle
+    may have been frozen before the collector reached it (the engine
+    calls ``gc.freeze()`` after each program's first call): its health
+    provider would then outlive it into this file's counts, by which
+    files xdist happens to hand this worker first."""
+    gc.unfreeze()
+    gc.collect()
+
+
 class TestServerLifecycle:
     def test_flag_off_no_thread_no_socket_no_registrations(self):
         """The acceptance off-path: both flags unset -> building and
         running an engine starts nothing and registers nothing."""
+        _collect_dead_engines()
         monitor.reset()
         server.stop_server()
         pt.set_flags({"FLAGS_enable_monitor": False,
@@ -274,6 +286,7 @@ class TestHealthz:
             server.unregister_health_provider("boom")
 
     def test_dead_owner_self_prunes_and_engines_coexist(self, mon):
+        _collect_dead_engines()
         eng, cfg = _tiny_engine()
         eng2, _ = _tiny_engine(num_slots=1)
         ok, payload = server.health()
@@ -283,8 +296,7 @@ class TestHealthz:
         assert len(serving) == 2
         assert {v["num_slots"] for v in serving.values()} == {1, 2}
         del eng, eng2
-        import gc
-        gc.collect()
+        _collect_dead_engines()
         ok, payload = server.health()
         assert not any(k.startswith("serving:")
                        for k in payload["providers"])

@@ -523,6 +523,73 @@ class TestPagedDecodeParity:
         np.testing.assert_array_equal(outs[0].tokens, want)
 
 
+# family, tiny config with heads that fill the lanes (float32: a tile is 8
+# rows, so pages of 8), the engine's sizes
+_KV_WRITE_FAMILIES = {
+    "llama": lambda: (L, L.llama_tiny(hidden_size=256, num_attention_heads=2,
+                                      num_key_value_heads=1),
+                      dict(num_slots=3, max_len=48, num_pages=24)),
+    "falcon_h1": lambda: (_family("falcon_h1"),
+                          _family("falcon_h1").falcon_h1_tiny(head_dim=128),
+                          dict(num_slots=3, max_len=48, num_pages=24)),
+    "phi4flash": lambda: (_family("phi4flash"),
+                          _family("phi4flash").phi4flash_tiny(
+                              hidden_size=256, num_attention_heads=4,
+                              num_key_value_heads=2, ring_page=8),
+                          dict(num_slots=3, max_len=48, num_pages=24)),
+    "zaya": lambda: (_family("zaya"), _family("zaya").zaya_tiny(head_dim=128),
+                     dict(num_slots=3, max_len=48, num_pages=24)),
+}
+
+
+def _family(name):
+    import importlib
+
+    return importlib.import_module(f"paddle_tpu.models.{name}")
+
+
+@pytest.mark.parametrize("name", _KV_WRITE_FAMILIES)
+def test_engine_tokens_through_the_kv_write_kernel_and_its_fallback(name):
+    """The same prompts through ``ServingEngine`` with the kernels
+    registered in interpret mode (a decode step's token goes through
+    ``kv_token_write``: the pool's pages, and Phi's rings) and with the
+    fallback's two scatters: identical greedy tokens, more slots than
+    requests at the end (inactive slots write nothing), and the
+    dispatcher counts ``kv_write`` on one side and ``kv_write_fallback``
+    on the other."""
+    from paddle_tpu import kernels as K
+    from paddle_tpu.inference import paged as paged_mod
+
+    family, cfg, sizes = _KV_WRITE_FAMILIES[name]()
+    params = family.init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(17)
+    prompts = _prompts(rng, cfg.vocab_size, (5, 11, 3, 9))
+
+    def serve():
+        paged_mod._kv_token_write.clear_cache()   # the inner jit's trace
+        before = K.dispatch_stats()
+        eng = ServingEngine(family, params, cfg, page_size=8,
+                            decode_chunk=3, **sizes)
+        outs = eng.run([Request(rid=i, prompt=p, max_new_tokens=7 + i)
+                        for i, p in enumerate(prompts)])
+        after = K.dispatch_stats()
+        return ([outs[i].tokens for i in range(len(prompts))],
+                {k: after[k] - before[k]
+                 for k in ("kv_write", "kv_write_fallback")})
+
+    want, counted = serve()
+    assert counted["kv_write"] == 0 and counted["kv_write_fallback"] >= 1
+    try:
+        K.register(interpret=True)
+        got, counted = serve()
+    finally:
+        K.register()
+        paged_mod._kv_token_write.clear_cache()
+    assert counted["kv_write"] >= 1 and counted["kv_write_fallback"] == 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
 class TestEngineScheduling:
     def test_randomized_arrival_length_trace(self):
         """Poisson-ish arrivals x random prompt/gen lengths through a

@@ -10,6 +10,7 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU's library, and
 every xdist worker imports every test file.
 """
+import math
 import re
 
 import jax
@@ -133,29 +134,68 @@ def _pool_shaped(text, shapes):
     return found
 
 
-@pytest.mark.parametrize("name,temp_gib", [("mistral-7b-v0.3", 1.0),
-                                           ("falcon-h1-34b", 0.5)])
-def test_decode_chunk_holds_the_pool_once(one_chip, monkeypatch, name,
+# leaf shape, slots, dtype: the four serving cells' pools (Phi's rings
+# too) and one float32 case
+KV_WRITES = {
+    "mistral-7b-v0.3": ((16, 832, 8, 64, 128), 64, jnp.bfloat16),
+    "falcon-h1-34b": ((6, 1408, 4, 64, 128), 128, jnp.bfloat16),
+    "zaya1-8b": ((20, 3840, 2, 64, 128), 64, jnp.bfloat16),
+    "phi-4-mini-flash-pool": ((1, 8192, 10, 64, 128), 128, jnp.bfloat16),
+    "phi-4-mini-flash-rings": ((8, 129, 33, 10, 16, 128), 128, jnp.bfloat16),
+    "float32-pages-of-8": ((4, 256, 2, 8, 128), 8, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", KV_WRITES)
+def test_kv_token_write_compiles_in_place(one_chip, case):
+    """The decode step's KV write at a cell's shapes: one custom call
+    named ``kv_token_write``, both pool halves back in their own buffers,
+    and nothing of a half's size beside them."""
+    from paddle_tpu.kernels import kv_write as KW
+
+    shape, B, dtype = KV_WRITES[case]
+    pool = _s(shape, dtype, one_chip)
+    val = _s((B,) + shape[-3::2], dtype, one_chip)
+    idx = _s((B,), jnp.int32, one_chip)
+    assert KW.supported(pool, val)
+    c = jax.jit(KW.kv_token_write, donate_argnums=(0, 1)).lower(
+        pool, pool, _s((), jnp.int32, one_chip), idx, idx, val, val).compile()
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kv_token_write" in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool.size * pool.dtype.itemsize
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
+# the cell's configuration, its page size, the most its temporaries may be
+@pytest.mark.parametrize("name,page,temp_gib", [
+    ("mistral-7b-v0.3", 16, 1.0), ("falcon-h1-34b", 16, 0.5),
+    ("zaya1-8b", 64, 0.1), ("phi-4-mini-flash", 64, 0.3)])
+def test_decode_chunk_holds_the_pool_once(one_chip, monkeypatch, name, page,
                                           temp_gib):
     """The engine's 16-step decode chunk at a serving cell's sizes: the
-    donated cache (the pool, and a recurrent family's state) comes back in
+    donated cache (the pool, and a family's state and rings) comes back in
     its own buffers, the temporaries are far under a pool half, and the
-    optimised module has no instruction that makes a pool half or a layer
-    of one but the two ``attn.kv_write`` scatters, which write into the
-    carry's own buffer, and the bitcasts that show the kernel each half
-    as [L * P, ...]: no copy, no slice, no second buffer. (With the
-    pool as the layer scan's xs/ys this read 4.20 GiB of temporaries for
-    Mistral's cell, a whole pool and 0.95.)"""
+    optimised module has no instruction that makes a pool half, a ring
+    leaf or a layer of one: no copy, no slice, no second buffer, and no
+    scatter. What writes a step's keys and values is one
+    ``kv_token_write`` a layer scan's body (a stack's: one for the pool's
+    layer, one for the rings), traced under ``attn.kv_write``, whose two
+    results are its two pool operands' buffers; the bitcasts show it and
+    the paged kernel each half as [L * P, ...]. (With the pool as the
+    layer scan's xs/ys this read 4.20 GiB of temporaries for Mistral's
+    cell, a whole pool and 0.95.)"""
     from benchmark.harness.manifest import Manifest, build_config
     from paddle_tpu import kernels
     from paddle_tpu.inference import engine
     from paddle_tpu.inference.paged import init_pool
 
     conf = Manifest().config(name)
-    slots, page = conf["serve"]["num_slots"], 16
+    slots = conf["serve"]["num_slots"]
     monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
     family, cfg = build_config(conf, "serve")
     shapes = getattr(family, "state_shapes", None)
+    layout = getattr(family, "pool_layout", lambda c: None)(cfg)
 
     def on(tree):
         return jax.tree.map(lambda a: _s(a.shape, a.dtype, one_chip), tree)
@@ -164,7 +204,8 @@ def test_decode_chunk_holds_the_pool_once(one_chip, monkeypatch, name,
         lambda: family.init_params(cfg, jax.random.PRNGKey(0))))
     cache = on(jax.eval_shape(lambda: init_pool(
         cfg, conf["serve"]["pool_tokens"] // page, page,
-        state_shapes=shapes(cfg) if shapes else None, state_rows=slots)))
+        state_shapes=shapes(cfg) if shapes else None, state_rows=slots,
+        **({} if layout is None else {"pool_layout": layout}))))
     chunk = 16
 
     def i32(*shape):
@@ -173,7 +214,7 @@ def test_decode_chunk_holds_the_pool_once(one_chip, monkeypatch, name,
     c = jax.jit(
         lambda *a: engine._decode_chunk(family, cfg, chunk, False, *a),
         donate_argnums=(1,)).lower(
-            params, cache, i32(slots, conf["serve"]["max_len"] // page),
+            params, cache, i32(slots, -(-conf["serve"]["max_len"] // page)),
             i32(slots) if shapes else None, i32(slots), i32(slots),
             _s((slots,), jnp.bool_, one_chip), i32(slots),
             _s((chunk, slots, 2), jnp.uint32, one_chip),
@@ -187,20 +228,21 @@ def test_decode_chunk_holds_the_pool_once(one_chip, monkeypatch, name,
     assert mem.temp_size_in_bytes < temp_gib * GiB, \
         mem.temp_size_in_bytes / GiB
 
-    half = cache["k"].shape
-    flat = (half[0] * half[1],) + half[2:]
-    kinds = {"bf16[%s]" % ",".join(map(str, s))
-             for s in (half, half[1:], flat)}
-    made = [(n, op, line) for n, op, line in _pool_shaped(c.as_text(), kinds)
+    text = c.as_text()
+    leaves = [cache["k"].shape] + sorted(
+        {a.shape for k, a in cache.get("state", {}).items() if "ring" in k})
+    kinds = {"bf16[%s]" % ",".join(map(str, s)) for leaf in leaves
+             for s in (leaf, leaf[1:], (math.prod(leaf[:-3]),) + leaf[-3:])}
+    made = [line for _, op, line in _pool_shaped(text, kinds)
             if op not in ("parameter", "get-tuple-element", "bitcast")]
-    assert made, "the scatters that append a step's keys and values"
-    for n, op, line in made:
-        assert not re.search("copy|dynamic-slice|dynamic-update-slice",
-                             n + " " + op), line
-        assert "AllocateBuffer" not in line, line
-        assert op == "scatter" or (op == "fusion"
-                                   and "attn.kv_write/scatter" in line), line
-    # one fused scatter a pool half, each into its operand's buffer
-    fused = [line for _, op, line in made if op == "fusion"]
-    assert len(fused) == 2
-    assert all('"aliasing_operands"' in line for line in fused)
+    assert not made, made
+    assert not [line for line in text.splitlines()
+                if "attn.kv_write" in line and "scatter" in line]
+    writes = [line for line in text.splitlines()
+              if "custom-call(" in line and "kv_token_write" in line]
+    assert len(writes) == len(leaves)
+    for line in writes:
+        assert re.search(r'op_name="[^"]*attn\.kv_write/[^"]*kv_token_write',
+                         line), line[:400]
+        assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" \
+            in line, line[:400]
