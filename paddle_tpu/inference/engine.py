@@ -240,11 +240,15 @@ class EngineStats:
         self.peak_state_rows_in_use = 0
         self.state_rows_assigned = 0     # rows handed out, re-admissions too
         # a family that routes (all 0 otherwise), summed over decode steps
-        # and layers: rows routed (the whole slot grid), experts that at
-        # least one row picked, rows the busiest expert took
+        # and layers: picks routed (the whole slot grid, k a row), experts
+        # held here that at least one pick named, picks the busiest held
+        # expert took; and of the picks, those held here (computed) and
+        # those held elsewhere (another chip's share of the experts)
         self.expert_rows = 0
         self.expert_reads = 0
         self.expert_rows_busiest = 0
+        self.expert_picks_held = 0
+        self.expert_picks_elsewhere = 0
         self._occ_steps = 0      # decode steps weighted by slot count
         # shared-prefix radix cache (FLAGS_serving_prefix_cache)
         self.prefix_lookups = 0
@@ -281,6 +285,8 @@ class EngineStats:
                 "expert_rows": self.expert_rows,
                 "expert_reads": self.expert_reads,
                 "expert_rows_busiest": self.expert_rows_busiest,
+                "expert_picks_held": self.expert_picks_held,
+                "expert_picks_elsewhere": self.expert_picks_elsewhere,
                 "batch_occupancy": round(self.occupancy(), 4),
                 "prefix_lookups": self.prefix_lookups,
                 "prefix_hits": self.prefix_hits,
@@ -323,7 +329,10 @@ def _decode_chunk(family, config, chunk, sampled, params, cache,
     ``cache`` is the whole cache, one donated pytree; ``state_rows`` is
     the slots' row table (None where the family keeps no state). The last
     item is the expert every slot's token took, [chunk, layers, slots]
-    int8, from a family that routes (None from the others)."""
+    (``[.., k]`` where a token takes k), int8 (int16 past 127 experts),
+    from a family that routes (None from the others)."""
+    pick = jnp.int8 if getattr(config, "num_experts", 0) <= 127 \
+        else jnp.int16
 
     def body(carry, key_t):
         cache, tok, kvl, done, gen = carry
@@ -339,7 +348,7 @@ def _decode_chunk(family, config, chunk, sampled, params, cache,
         done = done | hit_eos | (gen >= max_new)
         tok = jnp.where(emitted >= 0, nxt, tok)
         return (cache, tok, kvl, done, gen), (
-            emitted, None if picks is None else picks[..., 0].astype(jnp.int8))
+            emitted, None if picks is None else picks[:, :, 0].astype(pick))
 
     (cache, tok, kvl, done, gen), (emitted, picks) = jax.lax.scan(
         body, (cache, tokens, kv_len, done, gen), keys, length=chunk)
@@ -442,6 +451,7 @@ class ServingEngine:
         # counterpart for a state yet, and a guess is worse than a refusal.
         shapes = getattr(family, "state_shapes", None)
         self._recurrent = shapes is not None
+        layout = getattr(family, "pool_layout", lambda c: None)(config)
         if self._recurrent:
             for on, what, missing in (
                     (self._prefix_on, "serving_prefix_cache",
@@ -454,13 +464,26 @@ class ServingEngine:
                           f"its sequences keep a recurrent state beside "
                           f"their pages, and {missing} is not written",
                           error=E.UnimplementedError)
+        if layout is not None and len(layout) > 3:
+            # a latent pool: one row a token that keys and values share
+            for on, what, missing in (
+                    (self._prefix_on, "serving_prefix_cache",
+                     "a prefix cache over latent pages (the shared "
+                     "prefill's gather and decompression of the rows)"),
+                    (self._spec_decode, "serving_spec_decode",
+                     "a verify window over latent pages"),
+                    (self._kv_quant, "serving_kv_quant",
+                     "an int8 form of the latent row")):
+                E.enforce(not on, f"FLAGS_{what} with {family.__name__}: "
+                          f"its pool is one latent row a token, and "
+                          f"{missing} is not written",
+                          error=E.UnimplementedError)
         self.cache = PagedKVCache(
             config, num_pages, self.page_size, self.max_pages_per_seq,
             kv_dtype, kv_quant=self._kv_quant,
             state_shapes=shapes(config) if self._recurrent else None,
             state_rows=self.num_slots,
-            pool_layout=getattr(family, "pool_layout", lambda c: None)(
-                config))
+            pool_layout=layout)
         # radix shared-prefix cache over the pool's committed pages;
         # None (flag off) short-circuits every hook to the original code
         self._prefix = PrefixCache(self.cache.alloc) if self._prefix_on \
@@ -1657,17 +1680,29 @@ class ServingEngine:
         return True
 
     def _count_picks(self):
-        """A chunk's picks ([steps, layers, slots]: the expert each slot's
-        row took, every slot of the grid, as the program routed them) into
-        the stats' three sums."""
+        """A chunk's picks ([steps, layers, slots(, k)]: the experts each
+        slot's row took, every slot of the grid, as the program routed
+        them) into the stats' sums. A config that holds a share of the
+        experts (``held``: [first, end)) counts reads among those."""
         with _trace.span("serving.decode_chunk.routes"):
             picks = np.asarray(self._picks)
             self._picks = None
-            took = (picks[..., None] == np.arange(
-                self.config.num_experts, dtype=picks.dtype)).sum(-2)
+            E = self.config.num_experts
+            lo, hi = getattr(self.config, "held", (0, E))
+            # one count of every expert a (step, layer): a bincount over
+            # the picks offset by their (step, layer), not a comparison
+            # of every pick with every expert (256 x 8 picks against 12
+            # experts held was 2.5% of A.X K1's traced slice, PR 37)
+            flat = picks.reshape(-1, picks[0, 0].size).astype(np.int64)
+            n = flat.shape[0]
+            took = np.bincount((flat + E * np.arange(n)[:, None]).ravel(),
+                               minlength=n * E).reshape(n, E)[:, lo:hi]
+            held = int(took.sum())
             self.stats.expert_rows += picks.size
             self.stats.expert_reads += int((took > 0).sum())
             self.stats.expert_rows_busiest += int(took.max(-1).sum())
+            self.stats.expert_picks_held += held
+            self.stats.expert_picks_elsewhere += picks.size - held
 
     def _chunk_done(self, run, live_idx: List[int], C: int, counts,
                     accepted=None):

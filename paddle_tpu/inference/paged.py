@@ -37,6 +37,10 @@ Three layers:
   a fourth item from ``paged_block``, which the programs hand back where
   asked (``routes=True``): models/zaya.py, whose router carries its input
   through the layers and whose blocks say which expert each token took.
+  A family whose cache is ONE leaf (``pool_layout``'s fourth item names
+  the leaves: models/axk1.py's ``latent``, a row ``[c ; k_pe]`` a token
+  that multi-head latent attention reads for keys and values both)
+  writes and reads it through ``_StackOps.attend_latent``.
   ``paged_prefill`` / ``paged_decode_step`` are the same two programs for
   a caller that holds the two pool halves and nothing else.
 
@@ -506,7 +510,9 @@ def init_pool(config, num_pages: int, page_size: int, dtype=None,
     A shape may name its own layer count, (shape, type, layers): a
     family whose layers do not all keep that leaf. ``pool_layout``
     (a family's ``pool_layout(config)``) is the pool's (layers, heads,
-    head size) where they are not the config's. With
+    head size[, the leaves' names]) where they are not the config's: a
+    latent cache is one leaf, ``("latent",)``, in place of the ``k`` and
+    ``v`` halves. With
     ``kv_quant`` (FLAGS_serving_kv_quant) each pool leaf is the
     quantized pair {"q": int8 codes, "s": f32 [L, P, kv] scale
     plane} — per-page per-kv-head write-time absmax scales ride the
@@ -514,17 +520,24 @@ def init_pool(config, num_pages: int, page_size: int, dtype=None,
     (CoW copy, fork refcount, scatter-with-drop) moves code and scale
     rows together. Zero scale = untouched page, dequantizing to 0."""
     dt = dtype if dtype is not None else config.dtype
-    layers, kv_heads, head_dim = pool_layout or (
+    layers, kv_heads, head_dim, *names = pool_layout or (
         config.num_hidden_layers, config.num_key_value_heads,
         config.head_dim)
+    names = names[0] if names else ("k", "v")
     shape = (layers, num_pages, kv_heads, page_size, head_dim)
     if kv_quant:
+        E.enforce(names == ("k", "v"),
+                  f"FLAGS_serving_kv_quant with a pool of {names}: int8 "
+                  f"pages of a latent row (a scale a page, the write's "
+                  f"rescale, the latent kernel's dequantized read) are not "
+                  f"written", error=E.UnimplementedError)
+
         def leaf():
             return {"q": jnp.zeros(shape, jnp.int8),
                     "s": jnp.zeros(shape[:3], jnp.float32)}
         pool = {"k": leaf(), "v": leaf()}
     else:
-        pool = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+        pool = {name: jnp.zeros(shape, dt) for name in names}
     if state_shapes:
         # (shape a row a layer, type[, layers that keep the leaf])
         pool["state"] = {
@@ -557,14 +570,13 @@ class PagedKVCache:
                               pool_layout=pool_layout)
         self.alloc = PageAllocator(num_pages, page_size, max_pages_per_seq,
                                    state_rows=self.state_rows)
-        # page-row copy over EVERY leaf of the two page pools: the
+        # page-row copy over EVERY leaf of the page pools: the
         # quantized pool's scale planes share the page axis (axis 1) with
         # their codes, so one tree_map mirrors CoW onto codes and scales
         # exactly — the invariant the fork/CoW scale tests pin
         self._copy1 = jax.jit(
             lambda pool, src, dst: {**pool, **jax.tree.map(
-                lambda a: a.at[:, dst].set(a[:, src]),
-                {"k": pool["k"], "v": pool["v"]})},
+                lambda a: a.at[:, dst].set(a[:, src]), _pages(pool))},
             donate_argnums=(0,))
 
     def apply_cow(self, pairs):
@@ -689,7 +701,7 @@ def _kv_token_write(pool_k, pool_v, layer, rows, off, k, v):
 
 
 @jax.named_scope("attn.kv_write")
-def _kv_token_append(pool_k, pool_v, layer, rows, off, k, v):
+def _kv_token_append(pool_k, pool_v, layer, rows, off, k, v=None):
     """Append one token's keys and values ``k``, ``v`` [B, kv, hd] at
     slot ``off`` of pages ``rows`` of layer ``layer`` of BOTH pool halves
     — the decode-step write. A half is the WHOLE leaf ``[L, P, kv, ps,
@@ -699,11 +711,15 @@ def _kv_token_append(pool_k, pool_v, layer, rows, off, k, v):
     each live slot's row into its sublane tile of both halves in place
     and touches nothing else. ``rows`` at the sentinel (the pages of a
     layer) write nothing; live slots name different pages. The int8 pair
-    goes a half at a time through ``_kv_page_append``."""
+    goes a half at a time through ``_kv_page_append``. A latent pool's one
+    leaf is ``pool_k`` with ``pool_v`` and ``v`` None: (the leaf, None)."""
     if isinstance(pool_k, dict):
         P = pool_k["q"].shape[1]
         return (_kv_page_append(pool_k, layer, rows, off, k, P),
                 _kv_page_append(pool_v, layer, rows, off, v, P))
+    if pool_v is None:
+        return _kv_token_write(pool_k, None, layer, rows, off, k, None)[0], \
+            None
     return _kv_token_write(pool_k, pool_v, layer, rows, off, k, v)
 
 
@@ -793,6 +809,28 @@ def _pool_shape(pool_k):
     return (pool_k["q"] if isinstance(pool_k, dict) else pool_k).shape
 
 
+def _pages(cache):
+    """The page leaves of a cache: the two halves, or a latent pool's
+    one leaf (``init_pool``)."""
+    return {k: v for k, v in cache.items() if k != "state"}
+
+
+def _page_shape(cache):
+    """[L, P, heads, page, width] of the cache's page leaves."""
+    return _pool_shape(next(iter(_pages(cache).values())))
+
+
+def _halves(cache, what: str):
+    """(pool_k, pool_v) of a cache of key and value halves; a latent pool
+    is refused by name."""
+    E.enforce("k" in cache and "v" in cache,
+              f"{what}: this cache is a latent pool ({sorted(_pages(cache))}"
+              f"), one row a token for keys and values both, and {what} "
+              f"over it (a gather of its rows and their decompression) is "
+              f"not written", error=E.UnimplementedError)
+    return cache["k"], cache["v"]
+
+
 def _no_state(cache, what: str):
     E.enforce("state" not in cache,
               f"{what}: this cache holds a recurrent state a sequence, "
@@ -836,7 +874,8 @@ def cache_prefill(family, params, ids, config, cache, page_rows, slen,
     out beside the stream ([L, G, S]: a routing family's expert of each
     token; None from a family that hands nothing)."""
     G = ids.shape[0]
-    per = _rows_a_pass(G) if "state" in cache else G
+    per = _rows_a_pass(G) if "state" in cache or getattr(
+        family, "prefill_in_passes", False) else G
     if per < G:
         def one_pass(cache, xs):
             cache, *out = _prefill_pass(family, params, xs[0], config,
@@ -847,9 +886,9 @@ def cache_prefill(family, params, ids, config, cache, page_rows, slen,
             lambda a: a.reshape(G // per, per, *a.shape[1:]),
             (ids, page_rows, slen, state_rows)))
         logits = logits.reshape(G, -1)
-        if picks is not None:           # [passes, L, per, S] -> [L, G, S]
-            picks = jnp.moveaxis(picks, 0, 1).reshape(
-                picks.shape[1], G, -1)
+        if picks is not None:   # [passes, L, per, S, ..] -> [L, G, S, ..]
+            picks = jnp.moveaxis(picks, 0, 1)
+            picks = picks.reshape(picks.shape[:1] + (G,) + picks.shape[3:])
     else:
         cache, logits, picks = _prefill_pass(
             family, params, ids, config, cache, page_rows, slen, state_rows)
@@ -859,8 +898,8 @@ def cache_prefill(family, params, ids, config, cache, page_rows, slen,
 def _prefill_pass(family, params, ids, config, cache, page_rows, slen,
                   state_rows):
     if hasattr(family, "segments"):
-        return (*_stack_prefill(family, params, ids, config, cache,
-                                page_rows, slen, state_rows), None)
+        return _stack_prefill(family, params, ids, config, cache, page_rows,
+                              slen, state_rows)
     c = config
     G, S = ids.shape
     pool_k, pool_v = cache["k"], cache["v"]
@@ -932,7 +971,7 @@ def cache_decode_step(family, params, cache, block_tables, lengths, tokens,
     if hasattr(family, "segments"):
         out = _stack_decode(family, params, cache, block_tables, lengths,
                             tokens, config, state_rows)
-        return (*out, None) if routes else out
+        return out if routes else out[:2]
     c = config
     B = tokens.shape[0]
     pool_k, pool_v = cache["k"], cache["v"]
@@ -1037,6 +1076,14 @@ def _paged_attention(q, pool_k, pool_v, tables, lengths, layer, *, scale):
                                       scale=scale, layer=layer)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "rank"))
+def _mla_attention(q, pool, tables, lengths, layer, *, scale, rank):
+    from ..kernels import dispatched_mla_decode
+
+    return dispatched_mla_decode(q, pool, tables, lengths, rank=rank,
+                                 scale=scale, layer=layer)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "scale"))
 def _ring_attention(q, ring_k, ring_v, layer, rows, lengths, *, window, scale):
     from ..kernels import dispatched_ring_attention
@@ -1063,7 +1110,7 @@ class _StackOps:
         self.cache, self.shared = dict(cache), None
         self.prefill, self.tables, self.rows = prefill, tables, rows
         self.slen = self.lengths = lengths
-        self.P, _, self.ps = _pool_shape(cache["k"])[1:4]
+        self.P, _, self.ps = _page_shape(cache)[1:4]
         self.prompt_kv = {}      # prefill: the keys and values a layer wrote
 
     @property
@@ -1108,12 +1155,9 @@ class _StackOps:
                             grid.astype(self.cache[half].dtype), mode="drop")
             return a
         if k is not None:
-            posw = jnp.maximum(self.lengths - 1, 0)
-            at = jnp.take_along_axis(self.tables, (posw // self.ps)[:, None],
-                                     axis=1)[:, 0]
-            at = jnp.where(self.lengths > 0, at, self.P)  # inactive: drop
+            at, off = self._write_position()
             self.cache["k"], self.cache["v"] = _kv_token_append(
-                self.cache["k"], self.cache["v"], layer, at, posw % self.ps,
+                self.cache["k"], self.cache["v"], layer, at, off,
                 k[:, 0], v[:, 0])
         with jax.named_scope("attn.kernel"):
             if layer in self.prompt_kv:
@@ -1122,6 +1166,53 @@ class _StackOps:
             return _paged_attention(
                 q[:, 0], self.cache["k"], self.cache["v"], self.tables,
                 self.lengths, layer, scale=scale)[:, None]
+
+    def _write_position(self):
+        """A decode step's (page, row in it) a slot: an inactive slot's
+        page is the sentinel, which writes nothing."""
+        posw = jnp.maximum(self.lengths - 1, 0)
+        at = jnp.take_along_axis(self.tables, (posw // self.ps)[:, None],
+                                 axis=1)[:, 0]
+        return jnp.where(self.lengths > 0, at, self.P), posw % self.ps
+
+    def attend_latent(self, row, layer, q, k=None, v=None, *, scale,
+                      rank=None):
+        """Multi-head latent attention over pool layer ``layer`` of the
+        latent leaf: ``row`` [B, S, width] is what each position keeps
+        (``[c ; k_pe]``, padded), written to the layer's pages first. A
+        prefill attends causally among the prompt's own positions with the
+        family's DECOMPRESSED heads ``q``, ``k``, ``v`` [B, S, H, d]; a
+        decode step's single positions attend ABSORBED: ``q`` [B, 1, H,
+        width] against the rows through the latent kernel, the output
+        [B, 1, H, rank] the mix of the rows' first ``rank``."""
+        from ..nn.functional.attention import sdpa_raw
+
+        pool = self.cache["latent"]
+        if self.prefill:
+            with jax.named_scope("attn.kernel"):
+                a = sdpa_raw(q, k, v, is_causal=True, scale=scale)
+            with jax.named_scope("attn.kv_write"):
+                # a page more than the rows hold, at the sentinel (written
+                # nowhere): with one page alone XLA turns the scatter into
+                # an update of a slice and lays the WHOLE pool out again
+                # for it (a 5.55 GiB copy at a prompt of one page: the v5e
+                # compile of the cell's (1, 128) prefill, PR 37)
+                B, S, width = row.shape
+                grid = jnp.pad(row.reshape(B, S // self.ps, 1, self.ps,
+                                           width),
+                               ((0, 0), (0, 1), (0, 0), (0, 0), (0, 0)))
+                tables = jnp.pad(self.tables, ((0, 0), (0, 1)),
+                                 constant_values=self.P)
+                self.cache["latent"] = pool.at[layer, tables].set(
+                    grid.astype(pool.dtype), mode="drop")
+            return a
+        at, off = self._write_position()
+        pool, _ = _kv_token_append(pool, None, layer, at, off,
+                                   row[:, 0, None, :])
+        self.cache["latent"] = pool
+        with jax.named_scope("attn.kernel"):
+            return _mla_attention(q[:, 0], pool, self.tables, self.lengths,
+                                  layer, scale=scale, rank=rank)[:, None]
 
     def attend_ring(self, q, k, v, layer, *, scale, window):
         """Attention over the last ``window`` positions, kept a sequence
@@ -1170,7 +1261,11 @@ def _scan_stack(family, params, config, x, ops, to_last=None):
     a ``lax.scan`` a segment over ``params[kind]`` with (x, the cache,
     what the segments share) as the carry; a segment of one layer is that
     layer's call. ``to_last`` (prefill) cuts ``x`` and what is shared down
-    to each row's last position before the first ``last_only`` segment."""
+    to each row's last position before the first ``last_only`` segment.
+    A block may hand something out beside ``x`` (``(x, out)``: a routing
+    family's picks); returns (x, those of every such layer stacked in
+    the stack's order, or None)."""
+    outs = []
     for seg in family.segments(config):
         if seg.last_only and to_last is not None:
             x, ops.shared = jax.tree.map(to_last, (x, ops.shared))
@@ -1178,18 +1273,23 @@ def _scan_stack(family, params, config, x, ops, to_last=None):
 
         def step(carry, xs, kind=seg.kind):
             x, ops.cache, ops.shared = carry
-            x = family.stack_block(kind, x, xs[0], config, xs[1], ops)
-            return (x, ops.cache, ops.shared), None
+            lp = _layer_params(params, *xs)
+            x = family.stack_block(kind, x, lp, config, xs[1], ops)
+            x, out = x if isinstance(x, tuple) else (x, None)
+            return (x, ops.cache, ops.shared), out
 
         carry = (x, ops.cache, ops.shared)
         if seg.count == 1:
-            carry, _ = step(carry, (jax.tree.map(lambda a: a[0],
-                                                 params[seg.kind]), 0))
+            carry, out = step(carry, (jax.tree.map(lambda a: a[0],
+                                                   params[seg.kind]), 0))
+            out = None if out is None else out[None]
         else:
-            carry, _ = lax.scan(step, carry, (params[seg.kind],
-                                              jnp.arange(seg.count)))
+            carry, out = lax.scan(step, carry, (params[seg.kind],
+                                                jnp.arange(seg.count)))
         x, ops.cache, ops.shared = carry
-    return x
+        if out is not None:
+            outs.append(out)
+    return x, (jnp.concatenate(outs) if outs else None)
 
 
 def _stack_prefill(family, params, ids, config, cache, page_rows, slen,
@@ -1198,7 +1298,7 @@ def _stack_prefill(family, params, ids, config, cache, page_rows, slen,
     ``last_only`` run on each row's position ``slen`` - 1 alone, reading
     the pages the segments before them wrote."""
     c = config
-    S, ps = ids.shape[1], _pool_shape(cache["k"])[3]
+    S, ps = ids.shape[1], _page_shape(cache)[3]
     E.enforce(S % ps == 0, f"padded prompt {S} not a multiple of "
               f"page_size {ps}")
     with jax.named_scope("embed"):
@@ -1211,30 +1311,32 @@ def _stack_prefill(family, params, ids, config, cache, page_rows, slen,
         ops.prefill = False
         return jnp.take_along_axis(t, at, axis=1)
 
-    x = _scan_stack(family, params, c, x, ops, to_last)
+    x, picks = _scan_stack(family, params, c, x, ops, to_last)
     with jax.named_scope("head"):
         if ops.prefill:                       # no segment ran on the last
             x = to_last(x)
         logits = _logits(family, params,
                          family.final_norm(params, x[:, 0], c), c)
-    return ops.cache, logits
+    return ops.cache, logits, picks
 
 
 def _stack_decode(family, params, cache, block_tables, lengths, tokens,
                   config, state_rows):
-    """``cache_decode_step`` for a declared stack."""
+    """``cache_decode_step`` for a declared stack: (cache, logits, what the
+    blocks handed out or None)."""
     c = config
     with jax.named_scope("embed"):
         x = _embed(family, params, tokens, c)[:, None, :]
-    nobody = jax.tree.leaves(cache["state"])[0].shape[1] - 1
+    if "state" in cache:
+        nobody = jax.tree.leaves(cache["state"])[0].shape[1] - 1
+        state_rows = jnp.where(lengths > 0, state_rows, nobody)
     ops = _StackOps(cache, prefill=False, tables=block_tables,
-                    lengths=lengths,
-                    rows=jnp.where(lengths > 0, state_rows, nobody))
-    x = _scan_stack(family, params, c, x, ops)
+                    lengths=lengths, rows=state_rows)
+    x, picks = _scan_stack(family, params, c, x, ops)
     with jax.named_scope("head"):
         logits = _logits(family, params,
                          family.final_norm(params, x[:, 0], c), c)
-    return ops.cache, logits
+    return ops.cache, logits, picks
 
 
 def paged_prefill(family, params, ids, config, pool_k, pool_v, page_rows,
@@ -1273,7 +1375,7 @@ def cache_prefill_shared(family, params, ids, config, cache, page_rows,
     c = config
     G, S = ids.shape
     _no_state(cache, "a shared-prefix prefill")
-    pool_k, pool_v = cache["k"], cache["v"]
+    pool_k, pool_v = _halves(cache, "a shared-prefix prefill")
     L, P, kv, ps, hd = _pool_shape(pool_k)
     ncp = ctx_rows.shape[1]
     E.enforce(S % ps == 0, f"padded tail {S} not a multiple of "
@@ -1344,7 +1446,7 @@ def cache_verify_window(family, params, tokens, config, cache,
     c = config
     B, C = tokens.shape
     _no_state(cache, "a speculative verify window")
-    pool_k, pool_v = cache["k"], cache["v"]
+    pool_k, pool_v = _halves(cache, "a speculative verify window")
     quant = isinstance(pool_k, dict)
     L, P, kv, ps, hd = _pool_shape(pool_k)
     maxp = block_tables.shape[1]

@@ -27,6 +27,7 @@ from . import flash_attention as _fa
 from . import moe_experts as _moe
 from . import fused_ce as _fce
 from . import kv_write as _kw
+from . import mla_decode as _mla
 from . import paged_attention as _pa
 from . import rms_norm as _rn
 from . import ssm as _ssm
@@ -65,6 +66,7 @@ __all__ = ["flash_attention", "fused_rms_norm", "fused_cross_entropy",
            "expert_mlp", "expert_mlp_ref", "dispatched_expert_mlp",
            "kv_token_write", "kv_token_write_ref",
            "dispatched_kv_token_write",
+           "dispatched_mla_decode",
            "flash_attention_segments", "segment_attention_ref",
            "count_skipped_blocks", "dispatched_segment_attention",
            "register", "unregister", "dispatch_stats", "reset_dispatch_stats"]
@@ -84,7 +86,8 @@ _DISPATCH_STATS = {"flash": 0, "flash_fallback": 0,
                    "varlen": 0, "varlen_fallback": 0,
                    "ssm": 0, "ssm_fallback": 0,
                    "moe": 0, "moe_fallback": 0,
-                   "kv_write": 0, "kv_write_fallback": 0}
+                   "kv_write": 0, "kv_write_fallback": 0,
+                   "mla": 0, "mla_fallback": 0}
 
 # register(interpret=True): the dispatchers with no seam of their own to
 # install into run their kernels in interpret mode on any backend
@@ -113,9 +116,12 @@ def dispatch_stats() -> dict:
     into both pool halves in place, ``kv_token_write``
     (``kv_write.py``: once a trace of ``inference/paged.py``'s inner
     ``jit``, so once a process a shape), whose fallback is two scatters
-    of a row a (slot, head). A serving cell asserts ``paged_fallback``,
-    ``ssm_fallback``, ``moe_fallback``, ``kv_write_fallback`` and (where
-    its prefill has a window) ``flash_fallback`` stay 0."""
+    of a row a (slot, head); ``mla`` the absorbed decode attention over a
+    latent pool, ``mla_decode`` (``mla_decode.py``), whose fallback
+    gathers the slots' pages. A serving cell asserts ``paged_fallback``,
+    ``ssm_fallback``, ``moe_fallback``, ``kv_write_fallback``,
+    ``mla_fallback`` and (where its prefill has a window)
+    ``flash_fallback`` stay 0."""
     return dict(_DISPATCH_STATS)
 
 
@@ -298,29 +304,49 @@ def dispatched_s6_scan(x, dt, a, b, c):
 
 
 def dispatched_expert_mlp(x, expert, gate, up, down, layer, *,
-                          name="moe_expert_mlp"):
+                          name="moe_expert_mlp", **picks):
     """Each row of ``x`` [T, D] through its own expert's gated MLP, none
     dropped (``kernels/moe_experts.py``): the Pallas kernel (``name`` in a
     trace) on a TPU where the shapes are supported, reading only the
     experts some row picked; every expert over every row in plain XLA
-    elsewhere (tier-1's CPU path). Counted as ``moe`` / ``moe_fallback``."""
+    elsewhere (tier-1's CPU path). ``picks`` are ``expert_mlp``'s
+    ``weight``, ``first`` and ``routed`` (k picks a row, a held range of
+    the experts). Counted as ``moe`` / ``moe_fallback``."""
     if _on_tpu() and _moe.supported(x, gate):
         _DISPATCH_STATS["moe"] += 1
         return _moe.expert_mlp(x, expert, gate, up, down, layer, name=name,
-                               interpret=False)
+                               interpret=False, **picks)
     _DISPATCH_STATS["moe_fallback"] += 1
-    return _moe.expert_mlp_ref(x, expert, gate, up, down, layer)
+    return _moe.expert_mlp_ref(x, expert, gate, up, down, layer, **picks)
+
+
+def dispatched_mla_decode(q, pool, block_tables, lengths, *, rank, scale,
+                          layer=None):
+    """Absorbed latent attention over a latent page pool
+    (``kernels/mla_decode.py``): the Pallas kernel ``mla_decode`` on a TPU
+    where the shapes are supported (every page read once for all the
+    heads), the gather of the slots' pages in plain XLA elsewhere (tier-1's
+    CPU path). Counted as ``mla`` / ``mla_fallback``. Returns [B, heads,
+    rank]."""
+    if _on_tpu() and _mla.supported(q, pool, rank):
+        _DISPATCH_STATS["mla"] += 1
+        return _mla.mla_decode(q, pool, block_tables, lengths, rank=rank,
+                               scale=scale, layer=layer, interpret=False)
+    _DISPATCH_STATS["mla_fallback"] += 1
+    return _mla.mla_decode_ref(q, pool, block_tables, lengths, rank=rank,
+                               scale=scale, layer=layer)
 
 
 def dispatched_kv_token_write(pool_k, pool_v, layer, rows, off, k, v):
     """A decode step's new keys and values ``k``, ``v`` [B, kv, hd] into
     row ``off`` of pages ``rows`` of layer ``layer`` of both pool halves
+    (``pool_v`` and ``v`` None: the one leaf of a latent pool)
     (``kernels/kv_write.py``): the Pallas kernel ``kv_token_write`` on a
     TPU where the pool is supported (both halves updated in place, a
     sublane tile a slot), the two scatters in plain XLA elsewhere
     (tier-1's CPU path, a page under a tile, a head off the lanes);
-    counted as ``kv_write`` / ``kv_write_fallback``. Returns the two
-    pools."""
+    counted as ``kv_write`` / ``kv_write_fallback``. Returns the pools, a
+    tuple."""
     if (_INTERPRET or _on_tpu()) and _kw.supported(pool_k, k):
         _DISPATCH_STATS["kv_write"] += 1
         return _kw.kv_token_write(pool_k, pool_v, layer, rows, off, k, v,

@@ -1,5 +1,5 @@
 """A decode step's KV write: one token a slot into the page pool, both
-halves by one in-place Pallas call.
+halves (or the one leaf of a latent pool) by one in-place Pallas call.
 
 A decode step computes one new key and one new value a slot a layer,
 ``[B, kv_heads, head_dim]`` each, and each belongs at row ``off`` of a
@@ -31,6 +31,9 @@ donates the cache holds each half once and nothing of its size is made.
   ``unique_indices=True``: a slot owns the page its sequence is writing),
   so no tile is shared and the copies of a chunk may all be in flight at
   once.
+- A pool of ONE leaf (a latent cache, ``models/axk1.py``: a row ``[c ;
+  k_pe]`` a token that keys and values both read) is the same call with
+  no second half: ``pool_v`` and ``v`` None.
 - An INACTIVE slot (``rows`` outside ``[0, P)``: the engine's sentinel
   ``P``, which the scatter's ``mode="drop"`` dropped) issues no copy at
   all: ``pl.when``, never a redirected write, which could land stale
@@ -61,25 +64,28 @@ _VMEM_BUDGET = 8 * 1024 * 1024
 
 
 def _slots_per_chunk(B: int, kv: int, sub: int, hd: int,
-                     itemsize: int) -> int:
-    """The most slots whose tiles of both halves fit the budget, as a
+                     itemsize: int, halves: int = 2) -> int:
+    """The most slots whose tiles of every half fit the budget, as a
     divisor of ``B`` (a grid step takes a whole chunk)."""
-    c = max(1, min(B, _VMEM_BUDGET // (2 * kv * sub * hd * itemsize)))
+    c = max(1, min(B, _VMEM_BUDGET // (halves * kv * sub * hd * itemsize)))
     while B % c:
         c -= 1
     return c
 
 
-def _write_kernel(pages_ref, off_ref, k_ref, v_ref, k_in, v_in, k_hbm,
-                  v_hbm, kbuf, vbuf, sems):
-    del k_in, v_in                   # k_hbm's and v_hbm's own buffers
-    C, kv, sub, hd = kbuf.shape
+def _write_kernel(pages_ref, off_ref, *refs):
+    # per half: its new rows, its pool (aliased to its output, the copy
+    # the kernel writes), its VMEM tiles; then the semaphores
+    n = (len(refs) - 1) // 4
+    rows_in, hbms, bufs = refs[:n], refs[2 * n:3 * n], refs[3 * n:4 * n]
+    sems = refs[-1]
+    C, kv, sub, hd = bufs[0].shape
     base = pl.program_id(0) * C
-    halves = ((k_hbm, kbuf, k_ref, 0), (v_hbm, vbuf, v_ref, 1))
+    halves = tuple(zip(hbms, bufs, rows_in, range(n)))
 
     def copies(j, to_hbm):
-        """The two copies of slot ``j`` of the chunk: its tile of each
-        half into its buffer, or back."""
+        """The copies of slot ``j`` of the chunk: its tile of each half
+        into its buffer, or back."""
         i = base + j
         t0 = pl.multiple_of(off_ref[i] // sub * sub, sub)
         for hbm, buf, _, s in halves:
@@ -150,22 +156,28 @@ def kv_token_write(pool_k, pool_v, layer, rows, off, k, v, *,
                    interpret=False):
     """Write ``k``, ``v`` [B, kv_heads, head_dim] at row ``off`` [B] of
     pages ``rows`` [B] of layer ``layer`` of the two pool halves, in
-    place. ``pool_k`` / ``pool_v``: ``[layers, pages, kv_heads,
+    place (``pool_v`` and ``v`` None: a pool of one leaf, written
+    alone). ``pool_k`` / ``pool_v``: ``[layers, pages, kv_heads,
     page_size, head_dim]`` with ``layer`` an int32 scalar (traced or
     not), or one layer alone ``[pages, ...]`` with ``layer`` None; more
     axes between the layer's and the heads' count as pages, flattened (a
     ring leaf ``[layers, rows, pages, ...]`` takes ``row * pages +
     page``). ``rows`` lie in ``[0, P]``: ``P`` (the pages of a layer) is
     the sentinel of a slot with nothing to write (an ``off`` outside the
-    page writes nothing either). Live slots name different pages. Returns the two pools: every element no slot names
-    untouched, and each its input's own buffer where the caller donates
-    it."""
+    page writes nothing either). Live slots name different pages.
+    Returns the pools (a tuple of one for one leaf): every element no
+    slot names untouched, and each its input's own buffer where the
+    caller donates it."""
+    pools = tuple(p for p in (pool_k, pool_v) if p is not None)
+    vals = tuple(x for x in (k, v) if x is not None)
+    n = len(pools)
     shape = pool_k.shape
     kv, ps, hd = shape[-3:]
     B = k.shape[0]
     P = _pages(pool_k, layer)
     sub = _sublane(pool_k.dtype)
-    C = _slots_per_chunk(B, kv, sub, hd, jnp.dtype(pool_k.dtype).itemsize)
+    C = _slots_per_chunk(B, kv, sub, hd, jnp.dtype(pool_k.dtype).itemsize,
+                         n)
     rows, off = rows.astype(jnp.int32), off.astype(jnp.int32)
     first = 0 if layer is None else jnp.asarray(layer, jnp.int32) * P
     # what the scatter's mode="drop" dropped: a page or a row off the pool
@@ -173,17 +185,15 @@ def kv_token_write(pool_k, pool_v, layer, rows, off, k, v, *,
     pages = jnp.where(live, first + rows, -1)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    vals = pl.BlockSpec((C, kv, hd), lambda c, pages_ref, off_ref: (c, 0, 0))
+    row_spec = pl.BlockSpec((C, kv, hd),
+                            lambda c, pages_ref, off_ref: (c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B // C,),
-        in_specs=[vals, vals, hbm, hbm],
-        out_specs=[hbm, hbm],
-        scratch_shapes=[
-            pltpu.VMEM((C, kv, sub, hd), pool_k.dtype),
-            pltpu.VMEM((C, kv, sub, hd), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, C)),         # (K | V, slot)
-        ],
+        in_specs=[row_spec] * n + [hbm] * n,
+        out_specs=[hbm] * n,
+        scratch_shapes=[pltpu.VMEM((C, kv, sub, hd), p.dtype) for p in pools]
+        + [pltpu.SemaphoreType.DMA((n, C))],         # (K | V, slot)
     )
     flat = (-1, kv, ps, hd)
     out = pl.pallas_call(
@@ -191,20 +201,20 @@ def kv_token_write(pool_k, pool_v, layer, rows, off, k, v, *,
         name="kv_token_write",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((math.prod(shape[:-3]),) + shape[-3:],
-                                        p.dtype) for p in (pool_k, pool_v)],
-        # operands: pages, off, k, v, pool_k, pool_v -> outputs 0, 1
-        input_output_aliases={4: 0, 5: 1},
+                                        p.dtype) for p in pools],
+        # operands: pages, off, the new rows, the pools -> the outputs
+        input_output_aliases={2 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pages, off, k.astype(pool_k.dtype),
-      v.astype(pool_v.dtype), pool_k.reshape(flat), pool_v.reshape(flat))
+    )(pages, off, *(x.astype(p.dtype) for x, p in zip(vals, pools)),
+      *(p.reshape(flat) for p in pools))
     return tuple(o.reshape(shape) for o in out)
 
 
 def kv_token_write_ref(pool_k, pool_v, layer, rows, off, k, v):
-    """The same write as two XLA scatters, a row a (slot, head): the
-    contract of ``kv_token_write``, for a pool of any type."""
+    """The same write as XLA scatters, a row a (slot, head) of each half:
+    the contract of ``kv_token_write``, for a pool of any type."""
     kvi = jnp.arange(k.shape[1])
     at = (rows[:, None], kvi[None, :], off[:, None])
     if layer is not None:
@@ -217,4 +227,5 @@ def kv_token_write_ref(pool_k, pool_v, layer, rows, off, k, v):
             val.astype(pool.dtype), mode="drop",
             unique_indices=True).reshape(pool.shape)
 
-    return write(pool_k, k), write(pool_v, v)
+    return tuple(write(p, x) for p, x in ((pool_k, k), (pool_v, v))
+                 if p is not None)

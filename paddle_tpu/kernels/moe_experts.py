@@ -22,6 +22,16 @@ Weights lie ``[outputs, inputs]`` for ``gate`` and ``up`` ([F, D]: a
 block of outputs is one contiguous copy) and ``[inputs, outputs]`` for
 ``down`` ([F, D] too: a block of its inputs).
 
+A row may take k experts (``expert`` [T, k], each pick weighed by
+``weight`` [T, k], the k outputs summed back a row in float32), and the
+weights may be ONE CHIP'S SHARE of a layer's experts: the held range
+``[first, first + E)`` of the ``routed`` experts the router picks among
+(an expert-parallel deployment, ``models/axk1.py``). A pick inside the
+range gets a slot in the expert-by-expert layout; a pick elsewhere gets
+none, is never computed and adds nothing, so the sum is exactly this
+chip's part of the deployment's. One pick a row and every expert held
+(``models/zaya.py``) is the same walk as ever.
+
 ``expert_mlp_ref`` is the same layer in plain XLA (each expert over all
 rows, masked): every backend, what the dispatcher
 (``kernels/__init__.py``, counter ``moe_fallback``) takes off the TPU,
@@ -49,21 +59,26 @@ def row_tile(T: int, E: int, dtype) -> int:
     return tm
 
 
-def slots(expert, E: int, tm: int):
+def slots(expert, E: int, tm: int, partial: bool = False):
     """Where each row goes: (``slot`` [T] of each row in the padded
     layout, ``source`` [Mp] the row in each slot or T for padding,
     ``tile_expert`` [Mp / tm], ``used`` the tiles in use). A tile past
-    ``used`` names the last tile's expert."""
+    ``used`` names the last tile's expert. With ``partial`` a row whose
+    expert lies outside ``[0, E)`` gets no slot: its ``slot`` is ``Mp``."""
     T = expert.shape[0]
     nt = -(-(T + E * (tm - 1)) // tm)
-    hot = jax.nn.one_hot(expert, E, dtype=jnp.int32)
-    rank = jnp.take_along_axis(jnp.cumsum(hot, 0), expert[:, None],
+    hot = jax.nn.one_hot(expert, E, dtype=jnp.int32)     # outside: no 1
+    e = jnp.clip(expert, 0, E - 1) if partial else expert
+    rank = jnp.take_along_axis(jnp.cumsum(hot, 0), e[:, None],
                                1)[:, 0] - 1
     tiles = -(-hot.sum(0) // tm)                         # [E] tiles a run
     end = jnp.cumsum(tiles)
-    slot = (end - tiles)[expert] * tm + rank
+    slot = (end - tiles)[e] * tm + rank
+    if partial:
+        slot = jnp.where((expert >= 0) & (expert < E), slot, nt * tm)
     source = jnp.full((nt * tm,), T, jnp.int32).at[slot].set(
-        jnp.arange(T, dtype=jnp.int32), unique_indices=True)
+        jnp.arange(T, dtype=jnp.int32), unique_indices=True,
+        mode="drop" if partial else None)
     used = end[-1]
     tile_expert = jnp.searchsorted(
         end, jnp.minimum(jnp.arange(nt), used - 1), side="right")
@@ -107,22 +122,44 @@ def supported(x, gate) -> bool:
                                        jnp.dtype(jnp.float32)))
 
 
-def expert_mlp(x, expert, gate, up, down, layer, *, name="moe_expert_mlp",
-               interpret=False):
+def _picks(expert, first: int, routed, E: int):
+    """(the picks flat [T * k] relative to the held range, k, whether the
+    range is partial)."""
+    k = 1 if expert.ndim == 1 else expert.shape[1]
+    partial = first != 0 or (routed or E) != E
+    flat = expert.reshape(-1)
+    return (flat - first if first else flat), k, partial
+
+
+def expert_mlp(x, expert, gate, up, down, layer, *, weight=None, first=0,
+               routed=None, name="moe_expert_mlp", interpret=False):
     """Each row of ``x`` [T, D] through expert ``expert[t]`` of layer
     ``layer``: [T, D] in ``x``'s type. ``gate``, ``up``, ``down``
-    [L, E, F, D]."""
+    [L, E, F, D]. With ``expert`` [T, k] and ``weight`` [T, k]: the sum
+    over a row's picks of ``weight`` times the pick's output, where the
+    weights hold experts ``[first, first + E)`` of the ``routed`` the
+    picks index (picks elsewhere add nothing)."""
     T, D = x.shape
     _, E, F, _ = gate.shape
-    tm = row_tile(T, E, x.dtype)
+    picks, k, partial = _picks(expert, first, routed, E)
+    # a tile holds about an expert's expected share of the held picks
+    tm = row_tile(T * k * E // (routed or E), E, x.dtype)
     tf = _WIDTH_BLOCK if F % _WIDTH_BLOCK == 0 else 128
     with jax.named_scope("moe.dispatch"):
-        slot, source, tile_expert, used = slots(expert, E, tm)
+        slot, source, tile_expert, used = slots(picks, E, tm, partial)
         nt = tile_expert.shape[0]
-        xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[source]
+        xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[
+            source // k if k > 1 else source]
 
     def rows(t, f, te, layer, used):
-        return (jnp.minimum(t, used[0] - 1), 0)
+        last = used[0] - 1
+        if partial:
+            # a share may hold no pick of a call at all (a prompt's chunk
+            # of padding; a few rows whose picks are all another chip's):
+            # no tile in use, and a block index of -1 would copy from
+            # before the rows (the device halts on the bounds check)
+            last = jnp.maximum(last, 0)
+        return (jnp.minimum(t, last), 0)
 
     def weights(t, f, te, layer, used):
         # past the last tile in use: the block the last step held
@@ -149,11 +186,18 @@ def expert_mlp(x, expert, gate, up, down, layer, *, name="moe_expert_mlp",
         ys = call(tile_expert, jnp.asarray(layer, jnp.int32).reshape(1),
                   used.reshape(1), xs, gate, up, down)
     with jax.named_scope("moe.combine"):
-        return ys[slot]
+        if not partial and weight is None:
+            return ys[slot]
+        y = jnp.take(ys, slot, axis=0, mode="fill", fill_value=0)
+        if weight is None:
+            return y
+        return jnp.einsum("tkd,tk->td", y.reshape(T, k, D).astype(
+            jnp.float32), weight.astype(jnp.float32)).astype(x.dtype)
 
 
 @jax.named_scope("moe.experts")
-def expert_mlp_ref(x, expert, gate, up, down, layer):
+def expert_mlp_ref(x, expert, gate, up, down, layer, *, weight=None,
+                   first=0, routed=None):
     """``expert_mlp`` in plain XLA: every expert over every row, masked.
     The same products in the same types (float32 sums, the gated product
     rounded to ``x``'s type before ``down``)."""
@@ -162,5 +206,10 @@ def expert_mlp_ref(x, expert, gate, up, down, layer):
     uu = jnp.einsum("td,efd->etf", x, u, preferred_element_type=jnp.float32)
     h = (gg * jax.nn.sigmoid(gg) * uu).astype(x.dtype)
     y = jnp.einsum("etf,efd->etd", h, d, preferred_element_type=jnp.float32)
-    hot = jax.nn.one_hot(expert, g.shape[0], dtype=jnp.float32)   # [T, E]
+    if weight is None:
+        hot = jax.nn.one_hot(expert, g.shape[0], dtype=jnp.float32)  # [T, E]
+    else:                    # a pick outside the held range: no column
+        hot = jnp.einsum("tke,tk->te", jax.nn.one_hot(
+            expert - first, g.shape[0], dtype=jnp.float32),
+            weight.astype(jnp.float32))
     return jnp.einsum("etd,te->td", y, hot).astype(x.dtype)

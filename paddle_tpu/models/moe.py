@@ -75,6 +75,15 @@ class MoEConfig:
     # (the dense dispatch einsum is what GSPMD lowers to the EP a2a).
     dispatch_mode: Optional[str] = None
     capacity_factor: float = 1.25
+    # routing (``_route``): this family's rule is softmax over all the
+    # experts and the k picks renormalised, whatever its source's
+    # ``norm_topk_prob`` says (the deepseek-moe-16b configuration's stated
+    # departure)
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
     # Blockwise fused CE for the single-device loss (the 102k-vocab
     # logits of the DeepSeekMoE family are ~840M materialized); mesh
     # losses keep the einsum head for vocab-parallel GSPMD sharding.
@@ -232,12 +241,38 @@ def moe_capacity(config: MoEConfig, n_tokens: int) -> int:
 
 @jax.named_scope("moe.route")
 def _route(x, lp, config: MoEConfig):
-    """Shared router head: (topv [T,k] normalized f32, topi [T,k], aux)."""
+    """Shared router head: (topv [T,k] f32, topi [T,k], aux).
+
+    The config's routing keys, DeepSeek's names (``MoEConfig``'s defaults
+    are this family's own rule: softmax, the top k over all experts,
+    always renormalised): ``scoring_func`` ``"softmax"`` or ``"sigmoid"``
+    (each expert's score alone); ``n_group`` / ``topk_group``, the experts
+    taken ``n_group`` groups in order, a group scored by the sum of its
+    two best scores, the picks made among the best ``topk_group`` groups
+    only (DeepSeek-V3's group-limited rule); ``norm_topk_prob``, the k
+    picked scores over their sum; ``routed_scaling_factor``, times every
+    weight. ``models/axk1.py`` takes all four."""
     c = config
     logits = (x.astype(jnp.float32) @ lp["router"])         # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = lax.top_k(probs, c.num_experts_per_tok)    # [T, k]
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)     # renormalize
+    if c.scoring_func == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    choice = probs
+    if c.n_group > 1:
+        grouped = probs.reshape(probs.shape[:-1] + (c.n_group, -1))
+        best = jnp.sum(lax.top_k(grouped, 2)[0], -1)           # [T, groups]
+        keep = jax.nn.one_hot(lax.top_k(best, c.topk_group)[1], c.n_group,
+                              dtype=jnp.bool_).any(-2)
+        choice = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+            probs.shape)
+    topv, topi = lax.top_k(choice, c.num_experts_per_tok)   # [T, k]
+    if c.n_group > 1:
+        topv = jnp.take_along_axis(probs, topi, -1)
+    if c.norm_topk_prob:
+        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)  # renormalize
+    if c.routed_scaling_factor != 1.0:
+        topv = topv * c.routed_scaling_factor
     # switch-style load-balance aux loss (reference: moe gate aux):
     # fraction of ROUTED token-slots per expert x mean router prob
     sel = jnp.sum(jax.nn.one_hot(topi, c.num_experts, dtype=jnp.float32),

@@ -10,6 +10,7 @@ flash-attn vs math path.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -171,6 +172,35 @@ def rope_tables(seq_len: int, head_dim: int, *, theta: float = 10000.0,
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
     freqs = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), inv)
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction (DeepSeek-V2/V3's ``yarn_get_mscale``):
+    ``0.1 * mscale * ln(factor) + 1``, or 1 where the context is not
+    stretched."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, *, theta: float, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32, beta_slow: float = 1):
+    """YaRN's rotary frequencies [dim // 2] as DeepSeek-V3 makes them
+    (``DeepseekV3YarnRotaryEmbedding``): each pair's frequency mixed
+    between the plain one (extrapolated) and the plain one over
+    ``factor`` (interpolated) by a linear ramp over the pairs, from the
+    pair that turns ``beta_fast`` times in the original context (all
+    plain below it) to the one that turns ``beta_slow`` times (all
+    interpolated above it). Float32, as the source computes it."""
+    def pair(turns):
+        return dim * math.log(original_max_position_embeddings
+                              / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), dim - 1)
+    plain = 1.0 / (theta ** (jnp.arange(0, dim, 2, jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
 
 
 def gather_rope_rows(cos, sin, positions):

@@ -398,13 +398,37 @@ def _trace_rms(_):
         lambda c: norm._FUSED_RMS_IMPL(c, b, 1e-6).sum())(a))(x, w)
 
 
+def _trace_latent_decode(_):
+    """A.X K1's decode step at a tiny size: the latent kernel, the row's
+    in-place write and the held experts' kernel."""
+    from paddle_tpu.inference import paged
+    from paddle_tpu.inference.paged import cache_decode_step, init_pool
+    from paddle_tpu.models import axk1 as A
+    paged._kv_token_write.clear_cache()
+    paged._mla_attention.clear_cache()
+    cfg = A.axk1_tiny(dtype=jnp.bfloat16, hidden_size=128,
+                      moe_intermediate_size=128, kv_lora_rank=128,
+                      qk_rope_head_dim=64)
+    params = jax.eval_shape(lambda k: A.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: init_pool(cfg, 8, 16,
+                                            pool_layout=A.pool_layout(cfg)))
+    bt = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    vec = jax.ShapeDtypeStruct((2,), jnp.int32)
+    return jax.make_jaxpr(lambda p, c, b, n, t: cache_decode_step(
+        A, p, c, b, n, t, cfg))(params, pool, bt, vec, vec)
+
+
 @pytest.mark.parametrize("trace,arg,want", [
     (_trace_train, False, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_trace_train, True, {"flash_seg_fwd", "flash_seg_bwd_dq",
                           "flash_seg_bwd_dkv"}),
     (_trace_decode, None, {"paged_decode_attn", "kv_token_write"}),
     (_trace_rms, None, {"rms_norm_fwd", "rms_norm_bwd"}),
-], ids=["train_step", "packed_train_step", "decode_step", "rms_norm"])
+    (_trace_latent_decode, None, {"mla_decode", "kv_token_write",
+                                  "moe_expert_mlp_decode"}),
+], ids=["train_step", "packed_train_step", "decode_step", "rms_norm",
+        "latent_decode_step"])
 def test_every_pallas_call_of_the_main_paths_is_named(monkeypatch, trace,
                                                       arg, want):
     """Jaxpr level (nothing compiles): with the dispatchers believing

@@ -185,3 +185,56 @@ def test_dispatcher_counts_a_trace(pool, counter):
     for g, g2, w in zip(got, got2, want):
         np.testing.assert_array_equal(_bits(g), _bits(w))
         np.testing.assert_array_equal(_bits(g2), _bits(w))
+
+
+@pytest.mark.parametrize("offsets", ["first-row", "anywhere"])
+def test_the_one_leaf_of_a_latent_pool_is_written_alone(offsets):
+    """A latent pool (``models/axk1.py``: one row ``[c ; k_pe]`` of 640 a
+    token, no values beside it) through the same call with no second half:
+    one pool back, the reference's bit for bit, a row a live slot."""
+    shape, layer, B = (5, 9, 1, 128, 640), 3, 8
+    pool, _, _, rows, off, row, _ = _case(shape, layer, B, BF16,
+                                          OFFSETS[offsets], 11, (2,))
+    got = KW.kv_token_write(pool, None, layer, rows, off, row, None,
+                            interpret=True)
+    want = KW.kv_token_write_ref(pool, None, layer, rows, off, row, None)
+    assert len(got) == len(want) == 1 and got[0].shape == shape
+    np.testing.assert_array_equal(_bits(got[0]), _bits(want[0]))
+    live = np.asarray(rows) < 9
+    np.testing.assert_array_equal(
+        _bits(got[0][layer, rows[live], 0, off[live]]), _bits(row[live, 0]))
+    assert KW.supported(pool, row)
+    before = kernels.dispatch_stats()["kv_write_fallback"]
+    (off_chip,) = kernels.dispatched_kv_token_write(pool, None, layer, rows,
+                                                    off, row, None)
+    assert kernels.dispatch_stats()["kv_write_fallback"] == before + 1
+    np.testing.assert_array_equal(_bits(off_chip), _bits(want[0]))
+
+
+def test_two_halves_trace_to_the_parents_kernel():
+    """The K/V pools' write (every serving cell's) is the parent's program
+    equation for equation: PR 37 generalised the kernel to one leaf or
+    two and changed nothing for two."""
+    import importlib.util
+    import subprocess
+
+    try:
+        text = subprocess.run(
+            ["git", "show", "ca25dec:paddle_tpu/kernels/kv_write.py"],
+            cwd=__file__.rsplit("/tests/", 1)[0], check=True,
+            capture_output=True, text=True).stdout
+    except (OSError, subprocess.CalledProcessError):
+        pytest.skip("no git history here to read the parent's kernel from")
+    spec = importlib.util.spec_from_loader("paddle_tpu.kernels._parent_kw",
+                                           loader=None)
+    old = importlib.util.module_from_spec(spec)
+    old.__package__ = "paddle_tpu.kernels"
+    exec(compile(text, "_parent_kw", "exec"), old.__dict__)
+    shape, layer, B, dtype = POOLS["mistral-8-heads-pages-of-64"]
+    args = _case(shape, layer, B, dtype, OFFSETS["anywhere"], 3, (2,))
+
+    def trace(m):
+        return str(jax.make_jaxpr(lambda *a: m.kv_token_write(
+            *a, interpret=True))(*args))
+
+    assert trace(KW) == trace(old)
