@@ -20,14 +20,24 @@ TPU-native design, ``paged_attention.py``'s own:
   scalar prefetch; the pool stays in HBM (``memory_space=pl.ANY``), every
   layer of it, seen as ``[layers * pages, page_size, width]`` and read
   through a table of ``layer * pages + page``.
-- A sequence is read in blocks of N pages into a double-buffered VMEM
-  scratch, the next block's copies started before the current block is
-  computed on (after a sequence's last block: the first block of the next
-  sequence that holds a token). A page is copied ONCE for all the heads.
-- A trip is two MXU products on the whole block: ``[heads, width] x
-  [N * ps, width]^T`` for the scores (all 64 heads fill half of the
-  MXU's 128 rows) and ``[heads, N * ps] x [N * ps, r]`` for the values,
-  with one online-softmax update between them in float32.
+- A sequence is read in blocks of N pages into VMEM; a page is copied
+  ONCE for all the heads. The copies run ``_AHEAD`` (3) blocks ahead of
+  the block a trip waits for, as ONE stream over every sequence that
+  holds a token: a cursor in SMEM (sequence, block) names the next block
+  to start, and after a sequence's last block it moves to the next live
+  sequence's first, so the copies never drain at a sequence's end.
+- A block is two MXU products: ``[heads, width] x [N * ps, width]^T``
+  for the scores (all 64 heads fill half of the MXU's 128 rows) and
+  ``[heads, N * ps] x [N * ps, r]`` for the values, with one
+  online-softmax update between them in float32.
+- The loop is skewed by a block so that the MXU's work and the float32
+  work stand in one basic block: trip ``i`` makes block ``i + 1``'s score
+  product and, beside it, block ``i``'s softmax update (on the scores the
+  trip before made, kept in VMEM) and value product. So two blocks are
+  computed on while ``_AHEAD`` are in flight: ``_AHEAD + 2`` buffers. A
+  prologue makes block 0's scores and an epilogue finishes the last
+  block: the one stretch a sequence in which the softmax runs beside no
+  product.
 - The row is not a whole number of lane tiles (576 = 4.5 x 128): every
   block is the row WHOLE (a block equal to the array's last dimension is
   legal), and the value product reads a lane-aligned prefix of it.
@@ -48,20 +58,25 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import _NEG_INF, _pages_of, _sublane
 
-# A block's two buffers of pages fit _VMEM_BUDGET; _BLOCK_TOKENS caps it
-# (a sequence's last block is on average half dead, and a trip's score
-# tile [heads, tokens] grows with it).
+# A block's buffers of pages fit _VMEM_BUDGET; _BLOCK_TOKENS caps it (a
+# sequence's last block is on average half dead, and a trip's score tile
+# [heads, tokens] grows with it). A block's copies start _AHEAD blocks
+# before it is waited for, whatever sequence each belongs to: two buffers
+# a trip computes on, _AHEAD in flight (one block ahead left the copies
+# idle while a trip computed, and at every sequence's end: PERF.md §6).
 _VMEM_BUDGET = 8 * 1024 * 1024
 _BLOCK_TOKENS = 512
+_AHEAD = 3
+_BUFFERS = _AHEAD + 2
 
 
 def _pages_per_block(ps, width, itemsize, maxp) -> int:
-    by_vmem = _VMEM_BUDGET // (2 * ps * width * itemsize)
+    by_vmem = _VMEM_BUDGET // (_BUFFERS * ps * width * itemsize)
     return max(1, min(by_vmem, _BLOCK_TOKENS // ps, maxp))
 
 
-def _kernel(bt_ref, len_ref, q_ref, pool, o_ref, buf, sems, slot_ref, acc,
-            m_s, l_s, *, scale, page_size, max_pages, pages_per_block):
+def _kernel(bt_ref, len_ref, q_ref, pool, o_ref, buf, sems, at_ref, s_ref,
+            acc, m_s, l_s, *, scale, page_size, max_pages, pages_per_block):
     ps, npb = page_size, pages_per_block
     tb = npb * ps
     width = pool.shape[-1]
@@ -88,39 +103,50 @@ def _kernel(bt_ref, len_ref, q_ref, pool, o_ref, buf, sems, slot_ref, acc,
                 s < nb, len_ref[jnp.minimum(s, nb - 1)] == 0),
             lambda s: s + 1, seq + 1)
 
+    def issue(slot):
+        """Start the copies of the block at the cursor (``at_ref[0]``, its
+        sequence, ``at_ref[1]`` the block) into buffer ``slot``, and move
+        the cursor to the next block of a sequence that holds a token."""
+        seq, blk = at_ref[0], at_ref[1]
+
+        @pl.when(seq < nb)
+        def _():
+            block_dma(seq, blk, slot, start=True)
+            last = blk + 1 >= pl.cdiv(len_ref[seq], tb)
+            at_ref[0] = jax.lax.cond(last, lambda: next_live(seq),
+                                     lambda: seq)
+            at_ref[1] = jnp.where(last, 0, blk + 1)
+
     @pl.when(b == 0)
     def _first():
         # a row no copy filled is multiplied by p == 0 in a last block's
         # value product: it must not hold a NaN
         buf[...] = jnp.zeros_like(buf)
-        slot_ref[0] = 0
-        first = next_live(-1)
-
-        @pl.when(first < nb)
-        def _():
-            block_dma(first, 0, 0, start=True)
+        at_ref[0] = next_live(-1)
+        at_ref[1] = 0
+        at_ref[2] = 0                    # blocks waited for
+        for k in range(_AHEAD):
+            issue(k)
 
     acc[...] = jnp.zeros_like(acc)
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
-    slot0 = slot_ref[0]
+    base = at_ref[2]             # block i is in buffer (base + i) % _BUFFERS
 
-    def trip(i, carry):
-        cur = (slot0 + i) % 2
-        last = i + 1 == nblk
-        seq_n = jax.lax.cond(last, lambda: next_live(b), lambda: b)
-        blk_n = jnp.where(last, 0, i + 1)
-
-        @pl.when(seq_n < nb)
-        def _():
-            block_dma(seq_n, blk_n, 1 - cur, start=True)
-
+    def scores(i):
+        """Start the copies _AHEAD blocks on; wait for block ``i``'s; its
+        raw score product."""
+        issue((base + i + _AHEAD) % _BUFFERS)
+        cur = (base + i) % _BUFFERS
         block_dma(b, i, cur, start=False)
+        return jax.lax.dot_general(
+            q_ref[0], buf[cur].reshape(tb, width), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [heads, tb]
 
-        rows = buf[cur].reshape(tb, width)
-        s = jax.lax.dot_general(
-            q_ref[0], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [heads, tb]
+    def update(i, s):
+        """Fold block ``i`` (``s`` its raw scores) into the softmax."""
+        cur = (base + i) % _BUFFERS
+        s = s * scale
         pos = i * tb + jax.lax.broadcasted_iota(jnp.int32, (1, tb), 1)
         s = jnp.where(pos < length, s, _NEG_INF)
         m_prev, l_prev = m_s[:, :1], l_s[:, :1]
@@ -131,12 +157,23 @@ def _kernel(bt_ref, len_ref, q_ref, pool, o_ref, buf, sems, slot_ref, acc,
             l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_s.shape)
         m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
         acc[...] = acc[...] * alpha + jnp.dot(
-            p.astype(rows.dtype), buf[cur, :, :, :r].reshape(tb, r),
+            p.astype(buf.dtype), buf[cur, :, :, :r].reshape(tb, r),
             preferred_element_type=jnp.float32)
-        return carry
 
-    jax.lax.fori_loop(0, nblk, trip, 0)
-    slot_ref[0] = (slot0 + nblk) % 2
+    @pl.when(nblk > 0)
+    def _():
+        s_ref[...] = scores(0)
+
+        def trip(i, carry):
+            s_next = scores(i + 1)
+            update(i, s_ref[...])
+            s_ref[...] = s_next
+            return carry
+
+        jax.lax.fori_loop(0, nblk - 1, trip, 0)
+        update(nblk - 1, s_ref[...])
+
+    at_ref[2] = base + nblk
 
     l = l_s[:, :1]
     l = jnp.where(l == 0.0, 1.0, l)                       # empty slot -> 0
@@ -177,9 +214,10 @@ def mla_decode(q, pool, block_tables, lengths, *, rank, scale, layer=None,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, nh, rank), per_seq),
         scratch_shapes=[
-            pltpu.VMEM((2, npb, ps, width), flat.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((1,), jnp.int32),         # the buffer in flight
+            pltpu.VMEM((_BUFFERS, npb, ps, width), flat.dtype),
+            pltpu.SemaphoreType.DMA((_BUFFERS,)),
+            pltpu.SMEM((3,), jnp.int32),         # the copies' cursor
+            pltpu.VMEM((nh, npb * ps), jnp.float32),   # a block's scores
             pltpu.VMEM((nh, rank), jnp.float32),
             pltpu.VMEM((nh, 128), jnp.float32),
             pltpu.VMEM((nh, 128), jnp.float32),

@@ -117,11 +117,19 @@ def test_the_absorbed_decode_is_decompressed_attention():
 
 
 @pytest.mark.parametrize("dtype,lengths", [
-    (jnp.float32, [0, 37, 96, 5]), (jnp.bfloat16, [64, 1, 0, 90])])
+    (jnp.float32, [0, 37, 96, 5]), (jnp.bfloat16, [64, 1, 0, 90])] + [
+    (dtype, lengths) for dtype in (jnp.float32, jnp.bfloat16)
+    for lengths in ([0, 513, 1536, 1, 1100], [1024, 511, 0, 512, 1536],
+                    [1100, 1, 1024, 0], [0, 0, 0])])
 def test_the_latent_kernel_is_its_reference(dtype, lengths):
     """Pages of 16 rows of 640 scattered over a pool of two layers, the
-    second read; an empty slot is a zero row."""
-    B, H, W, ps, P, maxp = 4, 16, 640, 16, 30, 6
+    second read; an empty slot is a zero row. A block is 32 pages (512
+    tokens): the longer cases walk the skewed loop over up to three blocks
+    a slot, the copies streaming ahead across slots and past empty ones,
+    a one-block slot between two longer ones."""
+    B, H, W, ps = len(lengths), 16, 640, 16
+    maxp = max(6, -(-max(lengths) // ps))
+    P = B * maxp + 6
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, H, W)).astype(dtype)
     pool = jax.random.normal(ks[1], (2, P, 1, ps, W)).astype(dtype)
@@ -134,8 +142,8 @@ def test_the_latent_kernel_is_its_reference(dtype, lengths):
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
-    empty = lengths.index(0)
-    assert not np.any(np.asarray(got[empty], np.float32))
+    for empty in np.flatnonzero(np.asarray(lengths) == 0):
+        assert not np.any(np.asarray(got[empty], np.float32))
     assert MLA.supported(q, pool, 512)
     assert not MLA.supported(q, pool[:, :, :, :12], 512)      # a page of 12
     assert not MLA.supported(q, pool, 576)     # a value prefix off the lanes
