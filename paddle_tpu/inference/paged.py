@@ -1092,6 +1092,39 @@ def _ring_attention(q, ring_k, ring_v, layer, rows, lengths, *, window, scale):
                                      window=window, scale=scale)
 
 
+# The queries of a prompt above which its attention is taken a key/value
+# head at a time: the flash forward keeps beside its output a copy of the
+# scaled queries and a float32 statistic a row and head whose lane padding
+# makes it twice a 128-wide bf16 row, three times the queries in all (at
+# Command A+'s 128 heads a 16,384-token prompt's would be 1.5 GiB, more
+# than a full chip has to spare). Phi-4-mini-flash's longest prompt,
+# 160 MiB of queries, stays whole.
+_PROMPT_ATTN_BYTES = 192 << 20
+
+
+def _prompt_attention(attend, q, k, v):
+    """``attend(q, k, v)`` over whole prompts ``q`` [B, S, heads, hd],
+    ``k``, ``v`` [B, S, kv, hd]: in one call, or, where the queries pass
+    ``_PROMPT_ATTN_BYTES``, a key/value head and its query heads at a
+    time, each output written over the queries it was made from (in the
+    queries' own layout, heads and their numbers along one axis)."""
+    B, S, H, hd = q.shape
+    kv = k.shape[2]
+    if q.size * q.dtype.itemsize <= _PROMPT_ATTN_BYTES or kv == 1:
+        return attend(q, k, v)
+    w = H // kv * hd                    # a key/value head's query columns
+
+    def one(i, qa):
+        a = attend(lax.dynamic_slice_in_dim(qa, i * w, w, 2).reshape(
+            B, S, H // kv, hd), lax.dynamic_slice_in_dim(k, i, 1, 2),
+            lax.dynamic_slice_in_dim(v, i, 1, 2))
+        return lax.dynamic_update_slice_in_dim(
+            qa, a.reshape(B, S, w).astype(qa.dtype), i * w, 2)
+
+    return lax.fori_loop(0, kv, one, q.reshape(B, S, H * hd)).reshape(
+        q.shape)
+
+
 class _StackOps:
     """The program's side of a declared stack's blocks
     (``family.stack_block(kind, x, lp, config, i, ops)``): the cache, and
@@ -1145,7 +1178,8 @@ class _StackOps:
         if k is not None and self.prefill:
             self.prompt_kv[layer] = (k, v)
             with jax.named_scope("attn.kernel"):
-                a = sdpa_raw(q, k, v, is_causal=True, scale=scale)
+                a = _prompt_attention(functools.partial(
+                    sdpa_raw, is_causal=True, scale=scale), q, k, v)
             with jax.named_scope("attn.kv_write"):
                 for half, t in (("k", k), ("v", v)):
                     grid = jnp.moveaxis(t.reshape(
@@ -1228,8 +1262,9 @@ class _StackOps:
         ring = pages * ps
         if self.prefill:
             with jax.named_scope("attn.kernel"):
-                a = dispatched_window_flash(q, k, v, window=window,
-                                            scale=scale)
+                a = _prompt_attention(functools.partial(
+                    dispatched_window_flash, window=window, scale=scale),
+                    q, k, v)
             with jax.named_scope("attn.kv_write"):
                 # slot r holds the last position before slen that is r
                 # mod ring (one before the prompt's start is never valid)
@@ -1260,16 +1295,25 @@ def _scan_stack(family, params, config, x, ops, to_last=None):
     """``x`` through every segment of the family's declaration, in order:
     a ``lax.scan`` a segment over ``params[kind]`` with (x, the cache,
     what the segments share) as the carry; a segment of one layer is that
-    layer's call. ``to_last`` (prefill) cuts ``x`` and what is shared down
-    to each row's last position before the first ``last_only`` segment.
-    A block may hand something out beside ``x`` (``(x, out)``: a routing
-    family's picks); returns (x, those of every such layer stacked in
-    the stack's order, or None)."""
-    outs = []
+    layer's call. A kind that several segments declare (a pattern that
+    repeats) has its layers stacked in the stack's order, and each of its
+    segments takes the next ``count`` of them; the block is given a
+    layer's index among its kind's. ``to_last`` (prefill) cuts ``x`` and
+    what is shared down to each row's last position before the first
+    ``last_only`` segment. A block may hand something out beside ``x``
+    (``(x, out)``: a routing family's picks); returns (x, those of every
+    such layer stacked in the stack's order, or None)."""
+    outs, taken = [], {}
     for seg in family.segments(config):
         if seg.last_only and to_last is not None:
             x, ops.shared = jax.tree.map(to_last, (x, ops.shared))
             to_last = None
+        first = taken.get(seg.kind, 0)
+        taken[seg.kind] = first + seg.count
+        layers = params[seg.kind]
+        if first or jax.tree.leaves(layers)[0].shape[0] != seg.count:
+            layers = jax.tree.map(lambda a: a[first:first + seg.count],
+                                  layers)
 
         def step(carry, xs, kind=seg.kind):
             x, ops.cache, ops.shared = carry
@@ -1280,12 +1324,12 @@ def _scan_stack(family, params, config, x, ops, to_last=None):
 
         carry = (x, ops.cache, ops.shared)
         if seg.count == 1:
-            carry, out = step(carry, (jax.tree.map(lambda a: a[0],
-                                                   params[seg.kind]), 0))
+            carry, out = step(carry, (jax.tree.map(lambda a: a[0], layers),
+                                      first))
             out = None if out is None else out[None]
         else:
-            carry, out = lax.scan(step, carry, (params[seg.kind],
-                                                jnp.arange(seg.count)))
+            carry, out = lax.scan(step, carry, (
+                layers, jnp.arange(first, first + seg.count)))
         x, ops.cache, ops.shared = carry
         if out is not None:
             outs.append(out)

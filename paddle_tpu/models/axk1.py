@@ -14,16 +14,17 @@ One layer, pre-norm with a residual round each sublayer:
   ``W_O`` back out.
 - the first ``first_k_dense_replace`` layers: a SwiGLU of
   ``intermediate_size``; the others: the shared expert's SwiGLU plus the
-  routed experts' weighted sum (``models/moe.py::_route``: sigmoid
-  scores, the best 4 of 8 groups, the top 8 in them, renormalised, times
-  2.5).
+  routed experts' weighted sum (``models/expert_parallel.py``, the
+  router ``models/moe.py::_route``: sigmoid scores, the best 4 of 8
+  groups, the top 8 in them, renormalised, times 2.5).
 
 Serving only, and one chip's share of an expert-parallel deployment: the
 chip holds experts ``[first_held_expert, first_held_expert +
 n_routed_experts)`` of the router's ``n_router_outputs``, routes every
 token over all of them and computes the picks it holds
-(``kernels/moe_experts.py``); the picks held elsewhere are another chip's
-(nothing here stands in for them or for the all-to-all).
+(``models/expert_parallel.py``, shared with ``models/command_a.py``); the
+picks held elsewhere are another chip's (nothing here stands in for them
+or for the all-to-all).
 
 The cache is the point. What a token keeps a layer is ``[c ; k_pe]``,
 576 numbers, padded to 640 (``pool_layout``: a leaf of one "head" whose
@@ -52,7 +53,7 @@ from ..core import enforce as E
 from ..nn.functional.attention import (rope_raw, sdpa_reference,
                                        yarn_inv_freq, yarn_mscale)
 from .llama import _head_logits, _mm, _rms
-from .moe import _route
+from .expert_parallel import routed
 from .stack import Segment
 
 __all__ = ["AxK1Config", "axk1_tiny", "init_params", "segments",
@@ -383,43 +384,9 @@ def _mla(x, lp, c: AxK1Config, layer, ops):
 # the feed-forward sublayers
 # ---------------------------------------------------------------------------
 
-# Tokens a call of the expert kernel takes: its row layout is sized for
-# every pick held (a 16,384-token prompt's 131,072 picks would be 1.9 GB
-# of rows at this width), so a long prefill goes through in pieces.
-_EXPERT_TOKENS = 2048
-
-
-def _pieces(f, n: int, *rows):
-    """``f`` over rows [T, ..] in pieces of ``n`` (one call where T is at
-    most ``n`` or not a multiple of it): [T, ..]."""
-    T = rows[0].shape[0]
-    if T <= n or T % n:
-        return f(*rows)
-    y = jax.lax.map(lambda r: f(*r), tuple(
-        a.reshape((T // n, n) + a.shape[1:]) for a in rows))
-    return y.reshape((T,) + y.shape[2:])
-
-
 def _swiglu(h, lp):
     return _mm(jax.nn.silu(_mm(h, lp["w_gate"])) * _mm(h, lp["w_up"]),
                lp["w_down"])
-
-
-def _routed(h, lp, c: AxK1Config, name):
-    """The held picks' weighted sum [T, D] of rows ``h`` [T, D], and the
-    picks [T, k] (the router's outputs, held or not)."""
-    from ..kernels import dispatched_expert_mlp
-
-    with jax.named_scope("moe.route"):
-        w, e, _ = _route(h, lp, c)
-    ex = lp["experts"]
-
-    def call(hc, wc, ec):
-        return dispatched_expert_mlp(
-            hc, ec, ex["gate"], ex["up"], ex["down"], lp["layer"], weight=wc,
-            first=c.first_held_expert, routed=c.n_router_outputs, name=name)
-
-    return _pieces(call, _EXPERT_TOKENS, h, w, e), e
 
 
 def _moe(x, lp, c: AxK1Config):
@@ -429,9 +396,9 @@ def _moe(x, lp, c: AxK1Config):
     with jax.named_scope("mlp"):
         h = _rms(x, lp["ln2"], c.rms_norm_eps)
         shared = _swiglu(h, lp)
-    y, e = _routed(h.reshape(B * S, D), lp, c,
-                   "moe_expert_mlp_decode" if S == 1
-                   else "moe_expert_mlp_prefill")
+    y, e = routed(h.reshape(B * S, D), lp, c,
+                  "moe_expert_mlp_decode" if S == 1
+                  else "moe_expert_mlp_prefill", lp["layer"])
     with jax.named_scope("moe.combine"):
         x = x + (shared + y.reshape(B, S, D)).astype(x.dtype)
     return x, e.reshape(B, S, -1)

@@ -80,6 +80,22 @@ STALE_SINCE_PR35 = (
     "test_the_cell_its_metrics_and_the_metrics_it_joined",
     "test_benchmark_json_only_gained_entries")
 
+# The configuration command-a-plus-05-2026 came with a cell and a
+# per-layer metric, the same kinds again: ``test_present_configurations_
+# keep_no_state...[command-a-plus-05-2026]`` (a ring of keys and values a
+# window layer a sequence), and the two tests of ``test_axk1_cell.py`` that
+# hold A.X K1's entries to be the LAST of ``BENCHMARK.json``'s lists and
+# the lists one entry longer than their parent's.
+# ``tests/benchmark/test_command_a_cell.py`` holds what replaces them: the
+# counted rings; A.X K1's entries found by name
+# (``test_a_xk1s_entries_are_there_by_name``); every entry the parent had,
+# unchanged, but for appended names.
+STALE_SINCE_COMMAND_A = (
+    "test_present_configurations_keep_no_state_beside_keys_and_values"
+    "[command-a-plus-05-2026]",
+    "test_the_cell_its_metrics_and_the_metrics_it_joined",
+    "test_benchmark_json_only_gained_entries")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -98,3 +114,8 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="the test predates PR 35's five "
                 "per-layer metrics; see tests/conftest.py"))
+        elif item.name in STALE_SINCE_COMMAND_A and (
+                "[" in item.name or "test_axk1_cell" in item.nodeid):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="the test predates the configuration "
+                "command-a-plus-05-2026; see tests/conftest.py"))
