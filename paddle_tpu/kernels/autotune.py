@@ -187,6 +187,12 @@ def used_blocks() -> dict:
     return dict(_USED)
 
 
+def record_rule(key: str, **fields) -> None:
+    """Record in ``used_blocks()`` what a kernel took from its call's
+    shape alone, with no knob to tune (source ``shape-rule``)."""
+    _USED[key] = dict(fields, source="shape-rule")
+
+
 def _mode() -> str:
     """PADDLE_TPU_AUTOTUNE: "1" measure+cache (default), "cached" use
     cache hits but never measure (chip_smoke.py and the bench: their

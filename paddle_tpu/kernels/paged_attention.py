@@ -15,27 +15,40 @@ TPU-native design: the kernel's work follows the live tokens.
   fetches pages itself.
 - A sequence is read in BLOCKS of N pages: one ``make_async_copy`` a
   page carries all its KV heads (``kv_heads * page_size * head_dim``
-  contiguous elements of the pool) into a double-buffered VMEM scratch
-  laid out ``[kv_heads, N, page_size, head_dim]``, so a head's pages of
-  a block are ``[N * page_size, head_dim]`` without moving data.
-- The loop over a sequence's blocks is a ``fori_loop`` of
-  ``cdiv(length, N * page_size)`` trips. A table entry past the
-  sequence's live pages never becomes an index, a copy or a trip; a
-  length of 0 (an empty slot of the serving engine's fixed slot grid)
-  makes no trip and writes a zero row.
-- The next block's copies are started before the current block is
-  computed on; after a sequence's last block that is the first block of
-  the next sequence that holds a token, so the hand-over between grid
-  steps leaves no bubble (which buffer is in flight rides in SMEM).
-- A trip computes on the whole block, all KV heads batched: one
-  ``[kv, gp, hd] x [kv, N*ps, hd]`` score product, the positions past
-  ``length`` masked, ONE online-softmax update (float32 scores, running
-  max, sum and accumulator in VMEM scratch), then the PV product with
-  ``p`` cast to the pages' dtype.
+  contiguous elements of the pool) into a VMEM buffer laid out
+  ``[kv_heads, N, page_size, head_dim]``, so a head's pages of a block
+  are ``[N * page_size, head_dim]`` without moving data. A block whose
+  pages are all live starts and waits for its N copies in straight-line
+  code; a sequence's last block loops over its live pages.
+- The loop over a sequence's blocks runs ``cdiv(length, N * page_size)``
+  trips. A table entry past the sequence's live pages never becomes an
+  index, a copy or a trip; a length of 0 (an empty slot of the serving
+  engine's fixed slot grid) makes no trip and writes a zero row.
+- The copies run ``_AHEAD`` (3) blocks ahead of the block a trip waits
+  for, as ONE stream over every sequence that holds a token: a cursor in
+  SMEM (sequence, block) names the next block to start, and after a
+  sequence's last block it moves to the next live sequence's first, so
+  the copies never drain at a sequence's end. A count of the blocks
+  waited for rides in SMEM beside it: block ``i`` of the stream is in
+  buffer ``i mod _BUFFERS``.
+- The loop is skewed by a block so that the MXU's work and the float32
+  work stand in one basic block: trip ``i`` waits for block ``i + 1``
+  and makes its score product (all KV heads batched: ``[kv, gp, hd] x
+  [kv, N*ps, hd]``) and, beside it, block ``i``'s online-softmax update
+  (on the scores the trip before made, kept in VMEM; positions past
+  ``length`` masked; float32 running max, sum and accumulator) and PV
+  product (``p`` cast to the pages' dtype). So two blocks are computed
+  on while ``_AHEAD`` are in flight: ``_BUFFERS`` = ``_AHEAD + 2``. A
+  prologue makes a sequence's first scores and an epilogue finishes its
+  last block.
 - GQA: queries reshape to [B, kv_heads, group, head_dim]; the group dim
   is zero-padded to the sublane tile so every matmul is legal.
-- N follows from the shapes (``_pages_per_block``): what a fixed VMEM
-  budget holds of K and V, two buffers each, capped in tokens.
+- N follows from the shapes (``_pages_per_block``): what a buffer's
+  2 MiB holds of a block's K (or V), capped in tokens. The depth is one
+  constant for every shape, no knob, chosen by timing the kernel alone
+  on the v5e at 2, 3 and 4 blocks ahead at every serving cell's shape
+  (PERF.md section 5). Each call records its N, its depth and the bytes
+  it keeps in flight in ``autotune.used_blocks()``.
 
 Layouts: a pool half is ``[layers, num_pages, kv_heads, page_size,
 head_dim]`` (one page of every KV head is contiguous: 32 KiB at 8 heads
@@ -65,6 +78,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import autotune
+
 _NEG_INF = -1e30
 
 
@@ -76,22 +91,32 @@ def _sublane(dtype) -> int:
 # kernel
 # ---------------------------------------------------------------------------
 
-# What one block may hold. The block's K and V, two buffers each, fit
-# _VMEM_BUDGET: half of the 16 MiB of VMEM a kernel gets by default, the
-# rest left to q, the output, the score tiles and Mosaic's temporaries.
-# _BLOCK_TOKENS caps the block: the arithmetic of a trip is paid on the
+# What one block may hold. _BUFFER_BYTES is what a buffer of K (and one
+# of V) may hold: the 2 MiB it held when the kernel kept one block in
+# flight, so the stream changes when a copy starts and not the blocks.
+# There are _BUFFERS of each, two a trip computes on and _AHEAD in
+# flight. The kernel's VMEM limit, _VMEM_LIMIT, leaves 8 MiB beside them
+# to q, the output, the score tiles and Mosaic's temporaries (the v5e's
+# default is 16 MiB in all). _BLOCK_TOKENS caps the block, and binds at
+# every serving cell's shape: the arithmetic of a trip is paid on the
 # whole block, and a sequence's last block is on average half dead. On
 # the v5e blocks of 512 tokens read 36k live tokens of 64 sequences 2%
-# faster than blocks of 256 and a full pool 16% faster (PERF.md, PR 25).
-_VMEM_BUDGET = 8 * 1024 * 1024
+# faster than blocks of 256 and a full pool 16% faster (PERF.md section
+# 6). One block ahead, queued a sequence at a time, left the copies idle
+# while a trip computed and drained them at every sequence's end
+# (PERF.md section 5).
+_AHEAD = 3
+_BUFFERS = _AHEAD + 2
+_BUFFER_BYTES = 2 * 1024 * 1024
+_VMEM_LIMIT = 2 * _BUFFERS * _BUFFER_BYTES + 8 * 1024 * 1024
 _BLOCK_TOKENS = 512
 
 
 def _pages_per_block(kv, ps, hd, itemsize, maxp) -> int:
     """N, the pages one fetch and one online-softmax update cover: what
-    the VMEM budget and the token cap allow, at least one page and at
+    a buffer's bytes and the token cap allow, at least one page and at
     most a slot's whole table."""
-    by_vmem = _VMEM_BUDGET // (4 * kv * ps * hd * itemsize)
+    by_vmem = _BUFFER_BYTES // (kv * ps * hd * itemsize)
     return max(1, min(by_vmem, _BLOCK_TOKENS // ps, maxp))
 
 
@@ -99,10 +124,10 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
                    pages_per_block, quant, window=None):
     if quant:
         (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
-         kbuf, vbuf, sems, slot_ref, acc, m_s, l_s) = refs
+         kbuf, vbuf, sems, at_ref, sc_ref, acc, m_s, l_s) = refs
     else:
         (q_ref, k_hbm, v_hbm, o_ref,
-         kbuf, vbuf, sems, slot_ref, acc, m_s, l_s) = refs
+         kbuf, vbuf, sems, at_ref, sc_ref, acc, m_s, l_s) = refs
     ps, npb = page_size, pages_per_block
     tb = npb * ps                                    # tokens a block
     kv, hd = k_hbm.shape[1], k_hbm.shape[3]
@@ -124,17 +149,33 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
         """Start, or wait for, the copies of one block's LIVE pages: page
         ``blk * npb + j`` of sequence ``seq`` into row ``j`` of buffer
         ``slot``, all KV heads of the page in one copy. A table entry
-        past the sequence's pages is never read."""
+        past the sequence's pages is never read: a block whose pages are
+        all live is N copies in straight-line code, a last block a loop
+        over its live pages. (A branch a page cost the kernel 4% alone at
+        2 KV heads; the branches unrolled beside the straight-line copies
+        tripled the time to trace and lower it, which set-up pays.)"""
         live = pl.cdiv(held(len_ref[seq]), ps) - blk * npb
-        for j in range(npb):
-            @pl.when(j < live)
-            def _(j=j):
-                # a wait needs the copy's shape only, not its source
-                page = bt_ref[seq * max_pages + blk * npb + j] if start else 0
-                for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                    cp = pltpu.make_async_copy(
-                        hbm.at[page], buf.at[slot, :, j], sems.at[s, slot])
-                    cp.start() if start else cp.wait()
+
+        def one(j):
+            # a wait needs the copy's shape only, not its source
+            page = bt_ref[seq * max_pages + blk * npb + j] if start else 0
+            for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                cp = pltpu.make_async_copy(
+                    hbm.at[page], buf.at[slot, :, j], sems.at[s, slot])
+                cp.start() if start else cp.wait()
+
+        @pl.when(live >= npb)
+        def _():
+            for j in range(npb):
+                one(j)
+
+        def page(j, carry):
+            one(j)
+            return carry
+
+        @pl.when(live < npb)
+        def _():
+            jax.lax.fori_loop(0, live, page, 0)
 
     def next_live(seq):
         """The first sequence after ``seq`` that holds a token, or nb."""
@@ -143,18 +184,35 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
                 s < nb, len_ref[jnp.minimum(s, nb - 1)] == 0),
             lambda s: s + 1, seq + 1)
 
+    def issue(slot):
+        """Start the copies of the block at the cursor (``at_ref[0]`` its
+        sequence, ``at_ref[1]`` the block) into buffer ``slot``, and move
+        the cursor to the next block of a sequence that holds a token."""
+        seq, blk = at_ref[0], at_ref[1]
+
+        @pl.when(seq < nb)
+        def _():
+            block_dma(seq, blk, slot, start=True)
+            last = blk + 1 >= pl.cdiv(held(len_ref[seq]), tb)
+            at_ref[0] = jax.lax.cond(last, lambda: next_live(seq),
+                                     lambda: seq)
+            at_ref[1] = jnp.where(last, 0, blk + 1)
+
     @pl.when(b == 0)
     def _first():
         # a row of a buffer that no copy has filled yet is multiplied by
         # p == 0 in a last block's PV product: it must not hold a NaN.
         # (K needs no such care: its scores are replaced, not scaled.)
         vbuf[...] = jnp.zeros_like(vbuf)
-        slot_ref[0] = 0
-        first = next_live(-1)
+        at_ref[0] = next_live(-1)
+        at_ref[1] = 0
+        at_ref[2] = 0                    # blocks waited for
 
-        @pl.when(first < nb)
-        def _():
-            block_dma(first, 0, 0, start=True)
+        def start(k, carry):
+            issue(k)
+            return carry
+
+        jax.lax.fori_loop(0, _AHEAD, start, 0)
 
     if quant:
         # a block's per-page scales, [kv, npb], spread over the pages'
@@ -175,23 +233,35 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
     acc[...] = jnp.zeros_like(acc)
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
-    slot0 = slot_ref[0]
+    base = at_ref[2]        # block i is in buffer (base + i) % _BUFFERS
 
-    def trip(i, carry):
-        cur = (slot0 + i) % 2
-        # the next block's copies go out before this block is computed
-        # on: this sequence's next block, or after its last block the
-        # first block of the next sequence that holds a token
-        last = i + 1 == nblk
-        seq_n = jax.lax.cond(last, lambda: next_live(b), lambda: b)
-        blk_n = jnp.where(last, 0, i + 1)
-
-        @pl.when(seq_n < nb)
-        def _():
-            block_dma(seq_n, blk_n, 1 - cur, start=True)
-
+    def scores(i):
+        """Start the copies ``_AHEAD`` blocks on (into the buffer the trip
+        before finished with); wait for block ``i``'s; its scaled scores,
+        [kv, gp, tb] float32."""
+        issue((base + i + _AHEAD) % _BUFFERS)
+        cur = (base + i) % _BUFFERS
         block_dma(b, i, cur, start=False)
+        q = q_ref[0]                                     # [kv, gp, hd]
+        # ps is a multiple of the sublane tile: a head's pages of the
+        # block are [tb, hd] without moving data
+        k = kbuf[cur].reshape(kv, tb, hd)
+        if quant:
+            # every code of a (page, head) shares ONE scale, so
+            # dot(q, codes) * ks == dot(q, deq(codes)): the pages stay
+            # int8 in HBM and in VMEM
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * cols(ks_ref, i)
+        return s
 
+    def update(i, s):
+        """Fold block ``i`` (``s`` its scores) into the softmax and the
+        accumulator."""
+        cur = (base + i) % _BUFFERS
         pos = i * tb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb), 2)
         if window is None:
             valid = pos < length
@@ -203,21 +273,9 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
             age = jnp.where(age < 0, age + ring, age)
             valid = jnp.logical_and(age < jnp.minimum(length, window),
                                     pos < ring)
-        q = q_ref[0]                                     # [kv, gp, hd]
-        # ps is a multiple of the sublane tile: a head's pages of the
-        # block are [tb, hd] without moving data
-        k = kbuf[cur].reshape(kv, tb, hd)
         v = vbuf[cur].reshape(kv, tb, hd)
         if quant:
-            # every code of a (page, head) shares ONE scale, so
-            # dot(q, codes) * ks == dot(q, deq(codes)): the pages stay
-            # int8 in HBM and in VMEM
-            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [kv, gp, tb]
-        if quant:
-            s = s * cols(ks_ref, i)
+            v = v.astype(jnp.float32)
         s = jnp.where(valid, s, _NEG_INF)                # last block's tail
 
         m_prev = m_s[:, :, :1]
@@ -233,10 +291,21 @@ def _decode_kernel(bt_ref, len_ref, *refs, scale, page_size, max_pages,
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        return carry
 
-    jax.lax.fori_loop(0, nblk, trip, 0)
-    slot_ref[0] = (slot0 + nblk) % 2
+    @pl.when(nblk > 0)
+    def _():
+        sc_ref[...] = scores(0)
+
+        def trip(i, carry):
+            s_next = scores(i + 1)
+            update(i, sc_ref[...])
+            sc_ref[...] = s_next
+            return carry
+
+        jax.lax.fori_loop(0, nblk - 1, trip, 0)
+        update(nblk - 1, sc_ref[...])
+
+    at_ref[2] = base + nblk
 
     l = l_s[:, :, :1]
     l = jnp.where(l == 0.0, 1.0, l)                      # empty slot -> 0
@@ -294,8 +363,13 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     gp = max(sub, (g + sub - 1) // sub * sub)
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
-    npb = _pages_per_block(kv, ps, hd, jnp.dtype(k_pages.dtype).itemsize,
-                           maxp)
+    itemsize = jnp.dtype(k_pages.dtype).itemsize
+    npb = _pages_per_block(kv, ps, hd, itemsize, maxp)
+    name = "paged_decode_attn" + ("" if window is None else "_window")
+    autotune.record_rule(
+        f"{name}:{jnp.dtype(k_pages.dtype).name}:B{B}:q{nh}:kv{kv}:d{hd}:"
+        f"page{ps}:table{maxp}", pages_per_block=npb, blocks_ahead=_AHEAD,
+        bytes_in_flight=_AHEAD * 2 * npb * kv * ps * hd * itemsize)
 
     qg = q.reshape(B, kv, g, hd)
     if gp != g:
@@ -335,10 +409,11 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kv, gp, hd), per_seq),
         scratch_shapes=[
-            pltpu.VMEM((2, kv, npb, ps, hd), k_pages.dtype),
-            pltpu.VMEM((2, kv, npb, ps, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),     # (K | V, buffer)
-            pltpu.SMEM((1,), jnp.int32),         # the buffer in flight
+            pltpu.VMEM((_BUFFERS, kv, npb, ps, hd), k_pages.dtype),
+            pltpu.VMEM((_BUFFERS, kv, npb, ps, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, _BUFFERS)),   # (K | V, buffer)
+            pltpu.SMEM((3,), jnp.int32),         # the copies' cursor
+            pltpu.VMEM((kv, gp, npb * ps), jnp.float32),   # a block's scores
             pltpu.VMEM((kv, gp, hd), jnp.float32),
             pltpu.VMEM((kv, gp, 128), jnp.float32),
             pltpu.VMEM((kv, gp, 128), jnp.float32),
@@ -348,12 +423,13 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         functools.partial(_decode_kernel, scale=scale, page_size=ps,
                           max_pages=maxp, pages_per_block=npb, quant=quant,
                           window=window),
-        name="paged_decode_attn" + ("" if window is None else "_window"),
+        name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv, gp, hd), q.dtype),
-        # the buffer in flight is handed from one sequence to the next
+        # the copies' cursor is handed from one sequence to the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(bt.reshape(-1), lengths, *operands)
     return out[:, :, :g, :].reshape(B, nh, hd)

@@ -132,7 +132,7 @@ def _paged_case(arm, g, hd, ps, lengths, seed=0, kv=2):
     perm, at = rng.permutation(P), 0
     bt = np.full((B, maxp), perm[-1], np.int32)
     for b, n in enumerate(lengths):
-        need = -(-n // ps)
+        need = min(-(-n // ps), maxp)      # past a ring's end: all of it
         bt[b, :need] = perm[at:at + need]
         at += need
     owned = np.zeros(P, bool)
@@ -159,11 +159,12 @@ class TestKernelBlocks:
     def _small_blocks(self, monkeypatch):
         monkeypatch.setattr(PA, "_BLOCK_TOKENS", _BLOCK)
 
-    def _check(self, case):
+    def _check(self, case, window=None):
         q, kp, vp, bt, ln, kw, _ = case
         got = PA.ragged_paged_attention(q, kp, vp, bt, ln, interpret=True,
-                                        **kw)
-        want = PA.paged_attention_ref(q, kp, vp, bt, ln, **kw)
+                                        window=window, **kw)
+        want = PA.paged_attention_ref(q, kp, vp, bt, ln, window=window,
+                                      **kw)
         np.testing.assert_allclose(_f32(got), _f32(want), rtol=2e-2,
                                    atol=2e-2)
         empty = np.asarray(ln) == 0
@@ -186,6 +187,29 @@ class TestKernelBlocks:
     def test_one_length_between_empty_slots(self, arm, ps, which):
         n = self.LENGTHS[ps][which]
         self._check(_paged_case(arm, 4, 128, ps, (0, n, 0), seed=which))
+
+    # the copies' stream where its look-ahead crosses several short and
+    # empty sequences and runs past the last live one; in the window form
+    # also round rings that wrapped (a ring holds 160 tokens here)
+    STREAM = (1, 0, 16, 0, 0, 3, _BLOCK + 1, 0)
+    STREAM_RINGS = (1, 0, 200, 0, 0, 3, 161, 0)
+
+    @pytest.mark.parametrize("arm,ps,window", [
+        ("bf16", 16, None), ("int8", 32, None), ("bf16", 16, 48)],
+        ids=["bf16", "int8", "window"])
+    def test_the_stream_changes_when_a_copy_starts_not_what_is_computed(
+            self, arm, ps, window, monkeypatch):
+        """Three blocks ahead gives the reference's result, and one block
+        ahead the same bit for bit."""
+        lengths = self.STREAM if window is None else self.STREAM_RINGS
+        case = _paged_case(arm, 4, 128, ps, lengths, seed=7)
+        q, kp, vp, bt, ln, kw, _ = case
+        deep = self._check(case, window=window)
+        monkeypatch.setattr(PA, "_AHEAD", 1)
+        monkeypatch.setattr(PA, "_BUFFERS", 3)
+        one = PA.ragged_paged_attention(q, kp, vp, bt, ln, interpret=True,
+                                        window=window, **kw)
+        np.testing.assert_array_equal(_f32(one), _f32(deep))
 
     @pytest.mark.parametrize("arm,ps", [("bf16", 16), ("int8", 32)])
     def test_at_the_default_block(self, arm, ps, monkeypatch):

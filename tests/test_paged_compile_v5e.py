@@ -45,28 +45,52 @@ def one_chip():
 
 
 # slots, query heads, KV heads, head dim, page size, pool pages, table
-# pages, q dtype, int8 pages
+# pages, q dtype, int8 pages, window (a table row is then a ring)
 CASES = {
     "deepseek-moe-mha-16-16": (64, 16, 16, 128, 16, 3328, 288,
-                               jnp.bfloat16, False),
-    "int8-pages-of-32": (64, 32, 8, 128, 32, 1664, 144, jnp.bfloat16, True),
+                               jnp.bfloat16, False, None),
+    "int8-pages-of-32": (64, 32, 8, 128, 32, 1664, 144, jnp.bfloat16, True,
+                         None),
     "32-slots-tables-of-1024": (32, 32, 8, 128, 16, 8192, 1024,
-                                jnp.bfloat16, False),
+                                jnp.bfloat16, False, None),
     "int8-tables-of-1024": (32, 32, 8, 128, 32, 8192, 1024,
-                            jnp.bfloat16, True),
+                            jnp.bfloat16, True, None),
     "group-8-head-dim-256": (8, 64, 8, 256, 16, 512, 64, jnp.bfloat16,
-                             False),
-    "float32-pages-of-8": (8, 8, 2, 128, 8, 256, 16, jnp.float32, False),
+                             False, None),
+    "float32-pages-of-8": (8, 8, 2, 128, 8, 256, 16, jnp.float32, False,
+                           None),
     "one-slot-table-of-one-page": (1, 32, 8, 128, 16, 64, 1, jnp.bfloat16,
-                                   False),
+                                   False, None),
+    # the serving cells' own shapes: a layer's pool pages (rings: all
+    # layers' rows of pages) and a slot's table
+    "zaya1-8b-2-kv-heads": (64, 8, 2, 128, 64, 20 * 3840, 257,
+                            jnp.bfloat16, False, None),
+    "falcon-h1-34b-4-kv-heads": (128, 20, 4, 128, 64, 6 * 1408, 33,
+                                 jnp.bfloat16, False, None),
+    "mistral-7b-v0.3-8-kv-heads": (64, 32, 8, 128, 64, 16 * 832, 72,
+                                   jnp.bfloat16, False, None),
+    "phi-4-mini-flash-pool-10-pairs": (128, 40, 10, 128, 64, 8192, 257,
+                                       jnp.bfloat16, False, None),
+    "phi-4-mini-flash-rings": (128, 40, 10, 128, 16, 8 * 129 * 33, 33,
+                               jnp.bfloat16, False, 512),
+    "command-a-plus-pool": (56, 128, 8, 128, 64, 9207, 257, jnp.bfloat16,
+                            False, None),
+    "command-a-plus-rings-of-257": (56, 128, 8, 128, 16, 3 * 57 * 257, 257,
+                                    jnp.bfloat16, False, 4096),
 }
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_paged_decode_kernel_compiles(one_chip, case):
+    """The kernel compiles for the chip at the case's shape with its copy
+    stream's buffers, ``_AHEAD`` blocks in flight beside the two a trip
+    computes on, inside its VMEM limit; a block is what the token cap
+    allows (the stream never shrinks one); and the benchmark's readers
+    find the call by its name."""
+    from paddle_tpu.kernels import autotune
     from paddle_tpu.kernels import paged_attention as PA
 
-    B, nh, kv, hd, ps, P, maxp, dtype, quant = CASES[case]
+    B, nh, kv, hd, ps, P, maxp, dtype, quant, window = CASES[case]
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -77,17 +101,41 @@ def test_paged_decode_kernel_compiles(one_chip, case):
     assert PA.supported(q, pages, bt, quant=quant)
     if quant:
         scales = s((P, kv), jnp.float32)
-        c = jax.jit(lambda q, k, v, bt, ln, ks, vs: PA.ragged_paged_attention(
-            q, k, v, bt, ln, k_scales=ks, v_scales=vs)).lower(
-                q, pages, pages, bt, ln, scales, scales).compile()
+
+        def call(q, k, v, bt, ln, ks, vs):
+            return PA.ragged_paged_attention(q, k, v, bt, ln, k_scales=ks,
+                                             v_scales=vs)
+        args = (q, pages, pages, bt, ln, scales, scales)
     else:
-        c = jax.jit(PA.ragged_paged_attention).lower(
-            q, pages, pages, bt, ln).compile()
+        def call(q, k, v, bt, ln):
+            return PA.ragged_paged_attention(q, k, v, bt, ln,
+                                             window=window)
+        args = (q, pages, pages, bt, ln)
+    jaxpr = jax.make_jaxpr(call)(*args)
+    kernel, = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    scratch = [v.aval for v in kernel.params["jaxpr"].invars
+               if len(v.aval.shape) == 5]
+    npb = min(512 // ps, maxp)
+    assert PA._BUFFERS == PA._AHEAD + 2 >= 3
+    assert [a.shape for a in scratch] == [(PA._BUFFERS, kv, npb, ps, hd)] * 2
+    held = sum(a.size * a.dtype.itemsize for a in scratch)
+    assert held <= 2 * PA._BUFFERS * PA._BUFFER_BYTES < PA._VMEM_LIMIT
+    # every run prints how the stream engaged at each of its shapes
+    name = "paged_decode_attn" + ("" if window is None else "_window")
+    assert autotune.used_blocks()[
+        f"{name}:{pages.dtype.name}:B{B}:q{nh}:kv{kv}:d{hd}:page{ps}:"
+        f"table{maxp}"] == {
+            "pages_per_block": npb, "blocks_ahead": PA._AHEAD,
+            "bytes_in_flight": held * PA._AHEAD // PA._BUFFERS,
+            "source": "shape-rule"}
+    c = jax.jit(call).lower(*args).compile()
     text = c.as_text()
     # the names and the shape the benchmark's readers match: one custom
     # call, called paged_decode_attn, that returns one 4-D array
     assert text.count("tpu_custom_call") == 1
-    assert "paged_decode_attn" in text
+    assert ("paged_decode_attn" if window is None
+            else "paged_decode_attn_window") in text
 
 
 def _s(shape, dtype, where):
